@@ -122,12 +122,31 @@ class ProcessMonitor:
             time.sleep(0.1)
 
 
+def one_process_per_chip(n_children: int, platform: Optional[str],
+                         what: str) -> None:
+    """Refuse to start a second process of this machine on the default JAX
+    backend. A TPU chip admits one process: a second one fails in PJRT
+    start-up ("Unable to initialize backend 'tpu' ... libtpu multi-process
+    lockfile", a v5e under jax 0.9.0), and so does a child whose parent has
+    already touched JAX. Children pinned to the CPU backend, by ``platform``
+    or by an inherited ``JAX_PLATFORMS=cpu``, are as many as you like."""
+    effective = (platform or os.environ.get("JAX_PLATFORMS", "")).lower()
+    if n_children > 1 and effective != "cpu":
+        raise RuntimeError(
+            f"{what}: {n_children} processes on this machine would each "
+            f"initialise the default JAX backend, and a TPU chip admits one "
+            f"process. Pin them with platform='cpu' (or JAX_PLATFORMS=cpu), "
+            f"keep them in one process, or run one per machine")
+
+
 class ClusterLauncher:
     """Spawn ``num_processes`` copies of a worker script, each with the env a
     multi-host JAX job needs (coordinator address, process id/count).
 
-    Single-machine pods use distinct ``CUDA/TPU``-free CPU processes; on real
-    clusters run one launcher per host with ``process_id`` preassigned.
+    Single-machine pods use distinct CPU processes (``platform="cpu"``; more
+    than one worker on the default backend is refused at launch, see
+    :func:`one_process_per_chip`); on real clusters run one launcher per
+    host with ``process_id`` preassigned.
     """
 
     def __init__(self, num_processes: int, coordinator_port: int = 7877,
@@ -171,6 +190,8 @@ class ClusterLauncher:
         producing more than the OS pipe buffer."""
         import tempfile
 
+        one_process_per_chip(self.num_processes, self.platform,
+                             "ClusterLauncher")
         log_dir = log_dir or tempfile.mkdtemp(prefix="zoo_cluster_")
         os.makedirs(log_dir, exist_ok=True)
         self.log_dir = log_dir

@@ -27,6 +27,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from .compile_cache import enable_compile_cache
 from .config import MeshConfig, RuntimeConfig, apply_env_overrides
 from .locks import traced_lock
 
@@ -45,6 +46,7 @@ class ZooContext:
     def __init__(self, config: RuntimeConfig):
         import jax
 
+        enable_compile_cache()
         self.config = config
         if config.coordinator_address is not None:
             jax.distributed.initialize(

@@ -74,7 +74,7 @@ _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
 _LABEL_RE = re.compile(r"^[a-zA-Z_][a-zA-Z0-9_]*$")
 
 # latency-oriented default buckets (seconds): micro-batch waits are sub-ms,
-# tunnel RTTs reach hundreds of ms, training steps seconds
+# requests tens to hundreds of ms, training steps seconds
 DEFAULT_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
                    0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
 

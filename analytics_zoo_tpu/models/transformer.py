@@ -447,15 +447,16 @@ class PipelinedTransformerLM(Layer, KerasNet):
         (each device holds exactly its stage's weights, the GPipe layout);
         everything else is replicated.
 
-        Matches the exact top-level ``'blocks'`` key — a substring test would
+        Matches a path key that IS ``'blocks'`` — a substring test would
         also capture unrelated params that merely mention "blocks" in a
-        nested name and mis-shard them."""
+        nested name and mis-shard them. The key sits below the train
+        state's own (``params``; ``opt_state`` and the optimizer's moment
+        containers), so it is looked for anywhere on the path: testing
+        ``path[0]`` placed every stacked leaf whole on every device."""
         from jax.sharding import PartitionSpec as P
 
-        top = path[0] if path else None
-        top_key = getattr(top, "key", getattr(top, "idx", None)) \
-            if top is not None else None
-        if top_key == "blocks" and getattr(leaf, "ndim", 0) >= 1:
+        if (any(getattr(k, "key", None) == "blocks" for k in path)
+                and getattr(leaf, "ndim", 0) >= 1):
             _, pp = self._pp_mesh()
             if pp > 1 and self.n_block % pp:
                 raise ValueError(

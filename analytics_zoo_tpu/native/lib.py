@@ -3,24 +3,21 @@
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
-import sysconfig
-import tempfile
 from typing import Optional
 
+from ..common.compile_cache import CHECKOUT
 from ..common.locks import traced_lock
 
 import numpy as np
 
 log = logging.getLogger("analytics_zoo_tpu.native")
 
-_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-_SRC = os.path.join(_REPO_ROOT, "native", "zoo_native.cpp")
+_SRC = os.path.join(CHECKOUT, "native", "zoo_native.cpp")
 _SO_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_build")
-_SO = os.path.join(_SO_DIR, "zoo_native.so")
 
 _lib = None
 # zoo-lock: leaf
@@ -28,10 +25,20 @@ _lib_lock = traced_lock("lib._lib_lock")
 _build_failed = False
 
 
-def _compile() -> Optional[str]:
+def _so_path() -> str:
+    """The binary for the source as it stands. Keyed by the source's content,
+    not its mtime: a copied tree carries mtimes in any order, and a binary
+    built from other source must never load."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_SO_DIR, f"zoo_native-{digest}.so")
+
+
+def _compile(so: str) -> Optional[str]:
     os.makedirs(_SO_DIR, exist_ok=True)
+    tmp = f"{so}.tmp.{os.getpid()}"     # renamed into place: a concurrent
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-           _SRC, "-o", _SO]
+           _SRC, "-o", tmp]             # loader never sees a partial file
     try:
         r = subprocess.run(cmd, capture_output=True, timeout=120)
     except (FileNotFoundError, subprocess.TimeoutExpired) as e:
@@ -41,7 +48,8 @@ def _compile() -> Optional[str]:
         log.warning("native build failed; using numpy fallback:\n%s",
                     r.stderr.decode()[-2000:])
         return None
-    return _SO
+    os.replace(tmp, so)
+    return so
 
 
 def _load():
@@ -49,45 +57,21 @@ def _load():
     with _lib_lock:
         if _lib is not None or _build_failed:
             return _lib
-        if os.path.exists(_SO) and (
-                not os.path.exists(_SRC)  # shipped .so without sources
-                or os.path.getmtime(_SO) >= os.path.getmtime(_SRC)):
-            so = _SO
-        elif os.path.exists(_SRC):
-            so = _compile()
-        else:
-            log.warning("native sources and prebuilt .so both missing; "
-                        "using numpy fallback")
-            so = None
-        if so is None:
+        if not os.path.exists(_SRC):
+            log.warning("native sources missing (%s); using numpy fallback",
+                        _SRC)
             _build_failed = True
             return None
-        try:
-            _lib = _bind(ctypes.CDLL(so))
-        except Exception as e:
-            # bad/foreign-arch/stale-ABI .so: try one rebuild, else fall back
-            log.warning("prebuilt native lib unusable (%s); %s", e,
-                        "rebuilding" if os.path.exists(_SRC) else
-                        "using numpy fallback")
-            if os.path.exists(_SRC):
-                # only discard the .so when we can rebuild it — a transient
-                # dlopen failure must not destroy a shipped prebuilt forever
-                try:
-                    os.remove(so)
-                except OSError:
-                    pass
-                rebuilt = _compile()
-            else:
-                rebuilt = None
-            if rebuilt is None:
-                _build_failed = True
-                return None
+        so = _so_path()
+        if not os.path.exists(so):
+            so = _compile(so)
+        if so is not None:
             try:
-                _lib = _bind(ctypes.CDLL(rebuilt))
-            except Exception as e2:
-                log.warning("rebuilt native lib unusable (%s); numpy fallback", e2)
-                _build_failed = True
-                return None
+                _lib = _bind(ctypes.CDLL(so))
+            except (OSError, AttributeError, RuntimeError) as e:
+                log.warning("native lib %s unusable (%s); using numpy "
+                            "fallback", so, e)
+        _build_failed = _lib is None
         return _lib
 
 

@@ -94,12 +94,12 @@ class MultiHeadAttention(Layer):
                                      strategy=self.attn_strategy,
                                      causal=self.causal)
         if self._flash_single_device(t):
-            # no mesh context: an explicit 'flash' still means the kernel
-            # (it falls back internally when pallas is unavailable or the
-            # tiles don't divide), and 'auto' prefers it on TPU at the
-            # lengths where it measurably wins (LONGCTX_BENCH.json: faster
-            # than XLA full attention from 4k up, equal at 2k, and the only
-            # option past 16k where the (H, T, T) scores OOM)
+            # no mesh context: an explicit 'flash' means the kernel (a
+            # length its tiles cannot cover is an error naming the shape),
+            # and 'auto' prefers it on TPU from 2k tokens, where a
+            # pre-PR-1 measurement had it level with XLA full attention,
+            # faster from 4k up, and the only option past 16k where the
+            # (H, T, T) scores OOM
             from ...ops.flash_attention import flash_attention
 
             return flash_attention(q, k, v, self.causal)

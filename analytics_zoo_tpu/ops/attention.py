@@ -31,9 +31,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import PartitionSpec as P
 
-from ..common.compat import axis_size, shard_map
+from .backend import interpret_default
 
 NEG_INF = -1e30
 
@@ -69,8 +71,8 @@ def _ring_body(q, k_blk, v_blk, o, m, l, *, scale, causal, q_pos, k_pos):
 
 
 def _ring_attention_jnp(q, k, v, *, axis_name: str = "sp", causal: bool = False):
-    """Plain-jnp ring body (O(T_local²) score blocks) — fallback when the
-    pallas kernel is unavailable or the local sequence does not tile."""
+    """Plain-jnp ring body (O(T_local²) score blocks) — the off-TPU route,
+    and the one for local sequences the flash tiles do not divide."""
     n = axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, t_q, h, d = q.shape
@@ -130,16 +132,16 @@ def _merge_blocks(o, lse, o_blk, lse_blk):
 def _ring_flash(q, k, v, axis_name, causal, block_q, block_k):
     """Ring attention whose per-step body is the pallas flash kernel —
     O(block_q·block_k) score memory inside each ring step instead of the jnp
-    body's O(T_local²) (VERDICT r3 #3: "flash-within-ring is the composition
-    that makes long-context real")."""
+    body's O(T_local²): flash within ring is the composition that makes
+    long context real."""
     out, _ = _ring_flash_fwd_res(q, k, v, axis_name, causal, block_q, block_k)
     return out
 
 
 def _ring_flash_fwd_res(q, k, v, axis_name, causal, block_q, block_k):
-    from .flash_attention import _flash_fwd, _interpret_default
+    from .flash_attention import _flash_fwd
 
-    interpret = _interpret_default()
+    interpret = interpret_default()
     n = axis_size(axis_name)
     # non-causal rings never branch on block position — every visiting block
     # is dense. Emitting axis_index anyway leaves an (unused) PartitionId in
@@ -187,10 +189,10 @@ def _ring_flash_vjp_bwd(axis_name, causal, block_q, block_k, res, g):
     the saved GLOBAL lse (P = exp(S − lse) is exact for every block), so the
     backward is O(block) memory too. After n rotations every bundle is back on
     its home device with dk/dv fully accumulated; dq accumulates locally."""
-    from .flash_attention import _flash_bwd, _interpret_default
+    from .flash_attention import _flash_bwd
 
     q, k, v, out, lse = res
-    interpret = _interpret_default()
+    interpret = interpret_default()
     n = axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name) if causal else None  # see fwd note
     perm = [(j, (j + 1) % n) for j in range(n)]
@@ -276,9 +278,9 @@ def _zigzag_split(x, axis=1):
 
 
 def _zigzag_fwd_res(q, k, v, axis_name, block_q, block_k):
-    from .flash_attention import _flash_fwd, _interpret_default
+    from .flash_attention import _flash_fwd
 
-    interpret = _interpret_default()
+    interpret = interpret_default()
     n = axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, t_loc, h, d = q.shape
@@ -336,10 +338,10 @@ def _zigzag_vjp_bwd(axis_name, block_q, block_k, res, g):
     half-bundles rotate together and return home fully accumulated after n
     steps; dq halves accumulate locally. Every pair recomputes P from the
     saved global lse via the tiled flash backward kernels."""
-    from .flash_attention import _flash_bwd, _interpret_default
+    from .flash_attention import _flash_bwd
 
     q, k, v, out, lse = res
-    interpret = _interpret_default()
+    interpret = interpret_default()
     n = axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, t_loc, h, d = q.shape
@@ -406,14 +408,13 @@ _zigzag_ring_flash.defvjp(_zigzag_vjp_fwd, _zigzag_vjp_bwd)
 def _zigzag_ok(t: int, sp: int) -> bool:
     """Whether the zigzag layout applies: global T divides into 2·sp chunks
     AND each half-chunk tiles by the (env-default) flash blocks — otherwise
-    the caller should stay on the plain ring (which clamps/falls back)."""
-    from .flash_attention import default_blocks
+    the caller should stay on the plain ring."""
+    from .flash_attention import tiles_ok
 
     if t % (2 * sp):
         return False
     c = t // (2 * sp)
-    env_q, env_k = default_blocks(c, c)
-    return c % min(env_q, c) == 0 and c % min(env_k, c) == 0
+    return tiles_ok(c, c)
 
 
 def zigzag_ring_attention_local(q, k, v, *, axis_name: str = "sp",
@@ -423,21 +424,16 @@ def zigzag_ring_attention_local(q, k, v, *, axis_name: str = "sp",
     """Load-balanced causal ring attention; called INSIDE shard_map over the
     ZIGZAG layout (``zigzag_permutation``). Causal only — without masking the
     plain ring is already balanced."""
-    from .flash_attention import _HAS_PALLAS, default_blocks
+    from .flash_attention import resolve_blocks, tiles_ok
 
     if not causal:
         return ring_attention_local(q, k, v, axis_name=axis_name, causal=False,
                                     block_q=block_q, block_k=block_k)
     if q.shape[1] % 2:
         raise ValueError("zigzag local block needs an even sequence length")
-    if not _HAS_PALLAS:
-        raise ValueError("zigzag ring needs pallas (use strategy='ring' "
-                         "for the jnp fallback)")
     c = q.shape[1] // 2
-    env_q, env_k = default_blocks(c, c)
-    b_q = min(env_q if block_q is None else block_q, c)
-    b_k = min(env_k if block_k is None else block_k, c)
-    if c % b_q or c % b_k:
+    b_q, b_k = resolve_blocks(c, c, block_q, block_k)
+    if not tiles_ok(c, c, b_q, b_k):
         raise ValueError(f"zigzag half-chunk {c} must tile by blocks "
                          f"({b_q}/{b_k})")
     return _zigzag_ring_flash(q, k, v, axis_name, b_q, b_k)
@@ -450,25 +446,22 @@ def ring_attention_local(q, k, v, *, axis_name: str = "sp", causal: bool = False
     """Ring attention over ``axis_name``; called INSIDE shard_map.
 
     q/k/v: local blocks (B, T_local, H, D); global seq is sharded over the ring.
-    The per-step body is the pallas flash kernel whenever pallas is available
-    and the local sequence tiles evenly (``use_flash=None`` auto-detects);
-    otherwise the plain-jnp online-softmax body runs. Tile sizes default to
+    The per-step body is the pallas flash kernel on a TPU whenever the local
+    sequence tiles evenly (``use_flash=None`` auto-detects); otherwise the
+    plain-jnp online-softmax body runs. Tile sizes default to
     ``default_blocks()`` (env-tunable, like every flash call site).
     """
-    from .flash_attention import _HAS_PALLAS, default_blocks
+    from .flash_attention import resolve_blocks, tiles_ok
 
-    env_q, env_k = default_blocks(q.shape[1], k.shape[1])
-    b_q = min(env_q if block_q is None else block_q, q.shape[1])
-    b_k = min(env_k if block_k is None else block_k, k.shape[1])
-    tiles_ok = q.shape[1] % b_q == 0 and k.shape[1] % b_k == 0
+    b_q, b_k = resolve_blocks(q.shape[1], k.shape[1], block_q, block_k)
+    can_flash = tiles_ok(q.shape[1], k.shape[1], b_q, b_k)
     if use_flash is None:
         # auto only on real TPU: elsewhere the kernel runs in interpret mode
         # (correct but slow) — forcing use_flash=True still works for tests
-        use_flash = (_HAS_PALLAS and tiles_ok
-                     and jax.default_backend() == "tpu")
-    if use_flash and not (_HAS_PALLAS and tiles_ok):
+        use_flash = can_flash and jax.default_backend() == "tpu"
+    if use_flash and not can_flash:
         raise ValueError(
-            f"use_flash=True needs pallas and evenly-tiling local sequence "
+            f"use_flash=True needs a local sequence the flash tiles fit "
             f"(T_q={q.shape[1]}, T_k={k.shape[1]}, blocks {b_q}/{b_k})")
     if not use_flash:
         return _ring_attention_jnp(q, k, v, axis_name=axis_name, causal=causal)
@@ -494,12 +487,12 @@ def ulysses_attention_local(q, k, v, *, axis_name: str = "sp",
     q_h = a2a(q, 2, 1)
     k_h = a2a(k, 2, 1)
     v_h = a2a(v, 2, 1)
-    if jax.default_backend() == "tpu":
-        # blockwise kernel over the gathered sequence: O(block²) score memory
-        # per core instead of full_attention's O(T²) (falls back internally
-        # when pallas is unavailable or the sequence doesn't tile)
-        from .flash_attention import flash_attention
+    from .flash_attention import flash_attention, tiles_ok
 
+    if (jax.default_backend() == "tpu"
+            and tiles_ok(q_h.shape[1], k_h.shape[1])):
+        # blockwise kernel over the gathered sequence: O(block²) score memory
+        # per core instead of full_attention's O(T²)
         o = flash_attention(q_h, k_h, v_h, causal)
     else:  # interpret-mode pallas is slow; off-TPU uses the fused XLA path
         o = full_attention(q_h, k_h, v_h, causal=causal)
@@ -510,8 +503,9 @@ def prefer_flash_single_device(t: int) -> bool:
     """Auto-dispatch rule shared by the layer (mesh-less) and
     :func:`sharded_attention` (sp==1) paths, so both resolve identically:
     on TPU the pallas kernel beats XLA full attention from 4k up, matches
-    it at 2k at the model level (LONGCTX_BENCH.json, MFU_SWEEP.json), and
-    is the only option once the (H, T, T) score tensor would OOM.
+    it at 2k at the model level (MFU_SWEEP.json), and is the only option
+    once the (H, T, T) score tensor would OOM. A length the flash tiles do
+    not divide stays on full attention.
 
     Query length 1 — the KV-cache decode step — is excluded UNCONDITIONALLY
     (not just by the threshold): a single query row has nothing to tile, so
@@ -519,7 +513,9 @@ def prefer_flash_single_device(t: int) -> bool:
     plain attention is the fast path no matter how the threshold is tuned."""
     if t <= 1:
         return False
-    return jax.default_backend() == "tpu" and t >= 2048
+    from .flash_attention import tiles_ok
+
+    return jax.default_backend() == "tpu" and t >= 2048 and tiles_ok(t, t)
 
 
 def sharded_attention(q, k, v, mesh, *, strategy: str = "auto",

@@ -13,11 +13,13 @@ log-sum-exp (standard flash recompute: P = exp(S − lse)). Two passes:
 ``_bwd_dq_kernel`` (grid over Q tiles, folding K/V tiles) and
 ``_bwd_dkv_kernel`` (grid over K tiles, folding Q tiles). Like the forward,
 scores/probabilities live only in VMEM — peak HBM stays O(T·D), not O(T²),
-for training as well as inference. Only the non-pallas fallback materializes
-full attention.
+for training as well as inference.
 
-Layout: (B, T, H, D) like the other attention strategies. On non-TPU backends
-the kernel runs in interpreter mode (tests) or falls back to full attention.
+Layout: (B, T, H, D) like the other attention strategies. A sequence the tiles
+do not divide is an error that names the shape (:func:`tiles_ok` lets a router
+ask first); ``ops.attention.full_attention`` is the reference the parity tests
+compare against. Off TPU the kernels run in the Pallas interpreter
+(:func:`ops.backend.interpret_default`).
 """
 
 from __future__ import annotations
@@ -29,8 +31,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..common.compat import tpu_compiler_params
+from .backend import interpret_default
 
 NEG_INF = -1e30
 
@@ -102,15 +106,6 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             m_scr[:, 0] + jnp.log(safe_l[:, 0])
 
 
-try:  # pallas import kept optional: CPU-only deployments fall back to jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover - environment without pallas
-    _HAS_PALLAS = False
-
-
 def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_k: int,
                interpret: bool):
     b, t_q, h, d = q.shape
@@ -149,9 +144,10 @@ def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_k: int,
         # qi is NOT parallel: the lse out-block (one full row per bh) is
         # revisited by every qi step; parallel execution over qi would give
         # each core its own copy of the row and clobber other cores' slices
-        compiler_params=None if interpret else tpu_compiler_params(
+        compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="zoo_flash_fwd",
     )(qh, kh, vh)
     out4 = out.reshape(b, h, t_q, d).transpose(0, 2, 1, 3)
     lse4 = lse.reshape(b, h, t_q)
@@ -279,7 +275,7 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal: bool, block_q: int,
     # unlike the forward (whose lse OUT row is revisited by every qi), lse and
     # delta are read-only here and each middle-dim index owns a disjoint out
     # block, so only the innermost fold dim must stay sequential
-    dims = None if interpret else tpu_compiler_params(
+    dims = None if interpret else pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     dq = pl.pallas_call(
@@ -298,6 +294,7 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal: bool, block_q: int,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=dims,
         interpret=interpret,
+        name="zoo_flash_bwd_dq",
     )(qh, kh, vh, gh, lse3, delta)
 
     dk, dv = pl.pallas_call(
@@ -323,6 +320,7 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal: bool, block_q: int,
                         pltpu.VMEM((block_k, d), jnp.float32)],
         compiler_params=dims,
         interpret=interpret,
+        name="zoo_flash_bwd_dkv",
     )(qh, kh, vh, gh, lse3, delta)
 
     to4 = lambda a, t: a.reshape(b, h, t, d).transpose(0, 2, 1, 3)
@@ -340,9 +338,9 @@ def flash_attention(q, k, v, causal: bool = False,
     ``block_q``/``block_k`` default to :func:`default_blocks` (adaptive:
     largest power-of-two ≤512 dividing the sequence; overridable via
     ``ZOO_FLASH_BLOCK_Q/K`` — honored by EVERY call site: direct, sharded,
-    ring and Ulysses). Falls back to plain fused attention when pallas is
-    unavailable or the sequence does not tile evenly (the caller may pad
-    instead).
+    ring and Ulysses). Raises ``ValueError`` naming the shape when the
+    sequence does not tile evenly (the caller may pad, or ask
+    :func:`tiles_ok` and route to full attention instead).
     """
     out, _ = _flash_attention_fwd_res(q, k, v, causal, block_q, block_k,
                                       interpret)
@@ -360,13 +358,12 @@ def default_blocks(t_q: Optional[int] = None,
        device kind + (T_q, T_k) — populated by ``tune_flash_blocks`` /
        ``bench.py --int8-dispatch``'s MFU sweep);
     3. ADAPTIVE: the largest power-of-two tile ≤512 that divides the
-       sequence length — on a v5e the attention-only fwd+bwd runs ~4×
-       faster at 512×512 than at a fixed 128×128 (LONGCTX_BENCH.json:
-       55.6→14.2 ms/iter at T=16384), and at the model level 512-tiles are
-       worth ~22% MFU over 256-tiles (MFU_SWEEP.json: 0.538 vs 0.44 on the
-       seq-2048 TransformerLM). Falls back to 128 when the length is
-       unknown; a non-dividing length keeps the callers' existing
-       full-attention fallback behavior."""
+       sequence length. Measured before PR 1 and not on the current code:
+       on a v5e the attention-only fwd+bwd ran ~4× faster at 512×512 than
+       at a fixed 128×128, and at the model level 512-tiles were worth
+       ~22% MFU over 256-tiles (MFU_SWEEP.json: 0.538 vs 0.44 on the
+       seq-2048 TransformerLM). 128 when the length is unknown or nothing
+       larger divides it."""
     import os
 
     def auto(t: Optional[int]) -> int:
@@ -380,44 +377,58 @@ def default_blocks(t_q: Optional[int] = None,
     eq = os.environ.get("ZOO_FLASH_BLOCK_Q")
     ek = os.environ.get("ZOO_FLASH_BLOCK_K")
     if not (eq and ek):
-        try:      # tuned schedule for this device + sequence shape, if any
-            from .tuning import flash_lookup
+        from .tuning import flash_lookup
 
-            tuned = flash_lookup(t_q, t_k)
-        except Exception:  # cache layer must never break an attention trace
-            tuned = None
+        tuned = flash_lookup(t_q, t_k)
         if tuned is not None:
             return (int(eq) if eq else tuned[0],
                     int(ek) if ek else tuned[1])
     return (int(eq) if eq else auto(t_q), int(ek) if ek else auto(t_k))
 
 
-def _tiles_ok(q, k, block_q, block_k):
-    return (q.shape[1] % block_q == 0 and k.shape[1] % block_k == 0)
+def resolve_blocks(t_q: int, t_k: int, block_q: Optional[int] = None,
+                   block_k: Optional[int] = None) -> tuple:
+    """Tile sizes a call at these sequence lengths runs with: the explicit
+    arguments, else :func:`default_blocks`, clamped to the sequence."""
+    if block_q is None or block_k is None:
+        env_q, env_k = default_blocks(t_q, t_k)
+        block_q = env_q if block_q is None else block_q
+        block_k = env_k if block_k is None else block_k
+    return min(block_q, t_q), min(block_k, t_k)
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+def tiles_ok(t_q: int, t_k: int, block_q: Optional[int] = None,
+             block_k: Optional[int] = None,
+             interpret: Optional[bool] = None) -> bool:
+    """Whether the kernel can run at these sequence lengths — what a router
+    asks before selecting it. The resolved tiles must divide them, and a
+    compiled kernel needs a q tile that is a multiple of 128: each q tile
+    stores its log-sum-exp at a lane offset Mosaic must prove aligned
+    ("cannot statically prove that index in dimension 2 is a multiple of
+    128" at T=64 on a v5e), so sequences under 128 stay on full attention."""
+    block_q, block_k = resolve_blocks(t_q, t_k, block_q, block_k)
+    interpret = interpret_default() if interpret is None else interpret
+    return (t_q % block_q == 0 and t_k % block_k == 0
+            and (interpret or block_q % 128 == 0))
 
 
 def _resolve(q, k, block_q, block_k, interpret):
-    """Resolve env-default tile sizes, clamp them to the sequence, and resolve
-    interpret mode — shared by the forward and the VJP backward so both
-    always use identical tiling."""
-    env_q, env_k = default_blocks(q.shape[1], k.shape[1])
-    block_q = min(env_q if block_q is None else block_q, q.shape[1])
-    block_k = min(env_k if block_k is None else block_k, k.shape[1])
-    interpret = _interpret_default() if interpret is None else interpret
+    """Resolve tile sizes and interpret mode — shared by the forward and the
+    VJP backward so both always use identical tiling."""
+    t_q, t_k = q.shape[1], k.shape[1]
+    block_q, block_k = resolve_blocks(t_q, t_k, block_q, block_k)
+    interpret = interpret_default() if interpret is None else interpret
+    if not tiles_ok(t_q, t_k, block_q, block_k, interpret):
+        raise ValueError(
+            f"flash_attention: q{q.shape} k{k.shape} cannot run with blocks "
+            f"({block_q}, {block_k}): they must divide (T_q={t_q}, "
+            f"T_k={t_k}), and block_q must be a multiple of 128 when the "
+            f"kernel is compiled; pad the sequence or use full_attention")
     return block_q, block_k, interpret
 
 
 def _flash_attention_fwd_res(q, k, v, causal, block_q, block_k, interpret):
-    from .attention import full_attention
-
     block_q, block_k, interpret = _resolve(q, k, block_q, block_k, interpret)
-    if not _HAS_PALLAS or not _tiles_ok(q, k, block_q, block_k):
-        out = full_attention(q, k, v, causal=causal)
-        return out, None
     out, lse = _flash_fwd(q, k, v, causal=causal, block_q=block_q,
                           block_k=block_k, interpret=interpret)
     # checkpoint_name is identity outside jax.checkpoint; under a
@@ -431,22 +442,12 @@ def _flash_attention_fwd_res(q, k, v, causal, block_q, block_k, interpret):
 
 
 def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, interpret):
-    out, res = _flash_attention_fwd_res(q, k, v, causal, block_q, block_k,
-                                        interpret)
-    if res is None:  # fallback path: save inputs, recompute via full attention
-        res = (q, k, v, None, None)
-    return out, res
+    return _flash_attention_fwd_res(q, k, v, causal, block_q, block_k,
+                                    interpret)
 
 
 def _flash_vjp_bwd(causal, block_q, block_k, interpret, res, g):
     q, k, v, out, lse = res
-    if lse is None:
-        from .attention import full_attention
-
-        _, vjp = jax.vjp(
-            lambda q_, k_, v_: full_attention(q_, k_, v_, causal=causal),
-            q, k, v)
-        return vjp(g)
     block_q, block_k, interpret = _resolve(q, k, block_q, block_k, interpret)
     return _flash_bwd(q, k, v, out, lse, g, causal=causal,
                       block_q=block_q, block_k=block_k, interpret=interpret)

@@ -27,7 +27,9 @@ Two execution tiers share this scheme:
 
 :func:`int8_matmul` / :func:`int8_conv2d` route between the tiers via
 ``int8_fused.fused_mode()`` (``ZOO_INT8_FUSED`` env; default: fused on TPU,
-lax elsewhere) and fall back per-shape when a shape cannot tile.
+lax elsewhere) and, per shape, by what the kernels cover
+(``int8_fused.resolve_blocks`` / ``conv_supported``). A kernel that was
+selected and then cannot run raises; nothing falls back after the fact.
 """
 
 from __future__ import annotations
@@ -90,13 +92,18 @@ def int8_matmul(x: jnp.ndarray, packed: Dict[str, Any],
     ``x.shape[:-1] + (out,)`` in ``out_dtype`` (default f32).
 
     Routes to the fused pallas kernel (:func:`int8_fused.int8_matmul_fused`)
-    when the mode/shape allow, else the unfused lax path."""
+    when the mode is on and N and K tile, else the unfused lax path."""
     mode = int8_fused.fused_mode()
     if mode != "off":
-        y = int8_fused.int8_matmul_fused(
-            x, packed, out_dtype=out_dtype, interpret=(mode == "interpret"))
-        if y is not None:
-            return y
+        interpret = mode == "interpret"
+        k, n = packed["q"].shape
+        blocks = int8_fused.resolve_blocks(
+            int(np.prod(x.shape[:-1])), n, k, x.dtype, interpret=interpret)
+        if blocks is not None:
+            bm, bn, bk = blocks
+            return int8_fused.int8_matmul_fused(
+                x, packed, block_m=bm, block_n=bn, block_k=bk,
+                out_dtype=out_dtype, interpret=interpret)
     y = int8_matmul_unfused(x, packed)
     return y.astype(out_dtype) if out_dtype is not None else y
 
@@ -156,16 +163,16 @@ def int8_conv2d(x: jnp.ndarray, packed: Dict[str, Any], *, strides, padding,
     """NHWC × HWIO conv on the int8 MXU path; per-output-channel weight
     scales × per-pixel activation scales.
 
-    Routes to the fused pallas kernel (:func:`int8_fused.int8_conv2d_fused`,
-    stride/dilation (1,1)) when the mode/shape allow, else the unfused
-    tap-decomposed lax path — both compute the same per-pixel scheme."""
+    Routes to the fused pallas kernel (:func:`int8_fused.int8_conv2d_fused`)
+    when the mode is on and the kernel covers the strides and dilation, else
+    the unfused tap-decomposed lax path — both compute the same per-pixel
+    scheme."""
     mode = int8_fused.fused_mode()
-    if mode != "off":
-        y = int8_fused.int8_conv2d_fused(
+    if (mode != "off" and x.shape[0]
+            and int8_fused.conv_supported(strides, dilation)):
+        return int8_fused.int8_conv2d_fused(
             x, packed, strides=strides, padding=padding, dilation=dilation,
             out_dtype=out_dtype, interpret=(mode == "interpret"))
-        if y is not None:
-            return y
     y = int8_conv2d_unfused(x, packed, strides=strides, padding=padding,
                             dilation=dilation)
     return y.astype(out_dtype) if out_dtype is not None else y

@@ -17,17 +17,20 @@ pipeline lives inside one kernel per layer:
   accumulator, and only the final activation-dtype output block is written
   back — no int8 or dequantized-f32 intermediate ever touches HBM.
 
-The conv variant folds the KH×KW taps into the grid: each program owns one
-(batch, output-row) pair and accumulates ``window @ W[kh,kw]`` per tap with
-per-output-pixel activation scales (one abs-max over channels per pixel —
-the granularity the unfused path in :mod:`ops.int8` now matches).
+The conv variant folds the KH tap rows into the grid and unrolls the KW taps
+of a row inside the kernel: each program owns one (batch, output-row) pair
+and accumulates ``window @ W[kh,kw]`` per tap with per-output-pixel
+activation scales (one abs-max over channels per pixel — the granularity the
+unfused path in :mod:`ops.int8` now matches).
 
 Block sizes come from :mod:`ops.tuning` (on-disk autotuner cache keyed by
-device kind) with ``ZOO_INT8_BLOCK_M/N/K`` env overrides; shapes that do not
-tile fall back to the lax path (see :func:`ops.int8.int8_matmul`, the
-router).  On non-TPU backends the kernels run in interpreter mode for tests;
-production CPU inference keeps the lax path (an interpreted kernel is not a
-speedup).
+device kind) with ``ZOO_INT8_BLOCK_M/N/K`` env overrides.  The router
+(:func:`ops.int8.int8_matmul` / :func:`ops.int8.int8_conv2d`) asks
+:func:`resolve_blocks` / :func:`conv_supported` first and sends shapes the
+kernels do not cover to the lax path; a kernel that was selected and cannot
+run raises.  On non-TPU backends the kernels run in interpreter mode for
+tests; production CPU inference keeps the lax path (an interpreted kernel is
+not a speedup).
 """
 
 from __future__ import annotations
@@ -39,16 +42,10 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pallas import kept optional: CPU-only deployments fall back to lax
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover - environment without pallas
-    _HAS_PALLAS = False
-
-from ..common.compat import tpu_compiler_params
+from .backend import interpret_default
 
 #: Fixed pre-autotuner schedule (the constants the tuner sweeps around).
 DEFAULT_BLOCK_M = 256
@@ -64,34 +61,30 @@ _MIN_M, _MIN_N, _MIN_K = 8, 128, 128
 _MIN_INTERPRET = 8
 
 
-def has_pallas() -> bool:
-    return _HAS_PALLAS
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def fused_mode() -> str:
     """Routing decision for the int8 entry points: ``'compiled'`` (TPU),
     ``'interpret'`` (forced kernels on CPU — tests/structural gates), or
     ``'off'`` (lax path).
 
-    ``ZOO_INT8_FUSED``: ``0``/``off`` disables, ``1``/``on`` enables (kernels
-    interpret on non-TPU backends), ``interpret`` forces interpreter mode.
+    ``ZOO_INT8_FUSED``: ``0``/``off`` disables, ``1``/``on``/``interpret``
+    enable. Whether an enabled kernel compiles or interprets is the
+    backend's call alone (:func:`ops.backend.interpret_default`), so
+    ``interpret`` on a TPU is refused rather than obeyed.
     Default: compiled on TPU, off elsewhere — an interpreted kernel is
-    correctness-equal but orders of magnitude slower than the lax fallback.
+    correctness-equal but orders of magnitude slower than the lax path.
     """
-    if not _HAS_PALLAS:
-        return "off"
     env = os.environ.get("ZOO_INT8_FUSED", "").strip().lower()
     if env in ("0", "off", "false"):
         return "off"
-    if env == "interpret":
-        return "interpret"
-    if env in ("1", "on", "true"):
-        return "interpret" if _interpret_default() else "compiled"
-    return "off" if _interpret_default() else "compiled"
+    interpret = interpret_default()
+    if env == "interpret" and not interpret:
+        raise ValueError(
+            "ZOO_INT8_FUSED=interpret asks for the Pallas interpreter on a "
+            "TPU backend; kernels compile there (a test passes "
+            "interpret=True to the kernel instead)")
+    if env in ("1", "on", "true", "interpret"):
+        return "interpret" if interpret else "compiled"
+    return "off" if interpret else "compiled"
 
 
 def _pow2_floor(v: int) -> int:
@@ -104,7 +97,7 @@ def _pow2_ceil(v: int) -> int:
 
 def _shrink_to_divisor(dim: int, block: int, floor: int) -> Optional[int]:
     """Largest power-of-two ≤ ``block`` that divides ``dim`` and is ≥
-    ``floor`` — None when no such tile exists (caller falls back to lax)."""
+    ``floor`` — None when no such tile exists."""
     b = _pow2_floor(block)
     while b >= floor:
         if dim % b == 0:
@@ -122,7 +115,8 @@ def resolve_blocks(m: int, n: int, k: int, dtype,
     fused matmul: explicit args win, then ``ZOO_INT8_BLOCK_M/N/K`` env, then
     the tuning cache (per shape-bucket × dtype × device kind), then the fixed
     defaults; every choice is shrunk to a power-of-two divisor of its dim.
-    Returns None when N or K cannot tile (M is padded by the caller)."""
+    Returns None when N or K cannot tile (M is padded by the caller) — the
+    router's test for sending the shape to the lax path."""
     if block_m is None or block_n is None or block_k is None:
         env = tuple(os.environ.get(f"ZOO_INT8_BLOCK_{ax}")
                     for ax in ("M", "N", "K"))
@@ -197,9 +191,10 @@ def _fused_matmul_2d(x2, wq, ws_row, out_dtype, bm: int, bn: int, bk: int,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         # the (mi, ni) dims each own a disjoint output block; only the K fold
         # must stay sequential (it revisits the accumulator)
-        compiler_params=None if interpret else tpu_compiler_params(
+        compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="zoo_int8_matmul",
     )(x2, wq, ws_row)
 
 
@@ -208,16 +203,13 @@ def int8_matmul_fused(x: jnp.ndarray, packed: Dict[str, Any], *,
                       block_n: Optional[int] = None,
                       block_k: Optional[int] = None,
                       out_dtype=None,
-                      interpret: Optional[bool] = None
-                      ) -> Optional[jnp.ndarray]:
+                      interpret: Optional[bool] = None) -> jnp.ndarray:
     """``x @ W`` on the int8 MXU path with quantize+rescale fused into the
     kernel. ``packed`` is ``ops.int8.quantize_weight`` of an (in, out)
     kernel. Returns ``x.shape[:-1] + (out,)`` in ``out_dtype`` (default f32,
-    matching the unfused path), or **None** when the shape cannot tile — the
-    caller (the :func:`ops.int8.int8_matmul` router) falls back to lax."""
-    if not _HAS_PALLAS:
-        return None
-    interpret = _interpret_default() if interpret is None else interpret
+    matching the unfused path). Raises ``ValueError`` naming the shape when
+    N or K cannot tile (:func:`resolve_blocks` is the router's test)."""
+    interpret = interpret_default() if interpret is None else interpret
     wq = packed["q"]
     k, n = wq.shape
     lead = x.shape[:-1]
@@ -228,7 +220,9 @@ def int8_matmul_fused(x: jnp.ndarray, packed: Dict[str, Any], *,
     blocks = resolve_blocks(m, n, k, x.dtype, block_m, block_n, block_k,
                             interpret=interpret)
     if blocks is None:
-        return None
+        raise ValueError(f"int8_matmul_fused: x{x.shape} @ w{wq.shape} does "
+                         f"not tile (N and K need a power-of-two divisor of "
+                         f"at least {_MIN_INTERPRET if interpret else _MIN_N})")
     bm, bn, bk = blocks
     x2 = x.reshape(m, k)
     pad = (-m) % bm
@@ -247,48 +241,57 @@ def int8_matmul_fused(x: jnp.ndarray, packed: Dict[str, Any], *,
 
 def _int8_conv_kernel(x_ref, wq_ref, ws_ref, o_ref, acc_scr, *,
                       kw_total: int, wo: int):
-    """One (batch, output-row) pair; grid dim 2 folds the KH·KW taps.
+    """One (batch, output-row) pair; grid dim 2 folds the KH tap rows.
 
-    Tap t = kh·KW + kw reads input row ``ho + kh`` (via the x BlockSpec index
-    map) and its stride-1 window ``[kw : kw+Wo]``; each output pixel's window
-    row is quantized with its own channel-abs-max scale (per-pixel
-    granularity), dotted against the tap's (Cin, Cout) int8 slice on the MXU,
-    and accumulated in f32 VMEM."""
-    t = pl.program_id(2)
-    nt = pl.num_programs(2)
+    Step kh reads input row ``ho + kh`` (via the x BlockSpec index map) and,
+    for each of the KW taps of that row, its stride-1 window
+    ``[kw : kw+Wo]``. The KW loop is unrolled at trace time so every window
+    offset is static: Mosaic refuses a sublane offset it cannot prove a
+    multiple of 8, which a tap index read from the grid is not. Each output
+    pixel's window row is quantized with its own channel-abs-max scale
+    (per-pixel granularity), dotted against the tap's (Cin, Cout) int8 slice
+    on the MXU, and accumulated in f32 VMEM."""
+    kh = pl.program_id(2)
+    nkh = pl.num_programs(2)
 
-    @pl.when(t == 0)
+    @pl.when(kh == 0)
     def _init():
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    kw = jax.lax.rem(t, kw_total)
-    win = x_ref[0, 0, pl.ds(kw, wo), :].astype(jnp.float32)  # (Wo, Cin)
-    amax = jnp.max(jnp.abs(win), axis=1, keepdims=True)
-    scale = jnp.maximum(amax, 1e-12) * (1.0 / 127.0)        # (Wo, 1)
-    xq = jnp.clip(jnp.round(win / scale), -127, 127).astype(jnp.int8)
-    part = jax.lax.dot_general(xq, wq_ref[0, 0], (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.int32)
-    acc_scr[:] += part.astype(jnp.float32) * scale
+    for kw in range(kw_total):
+        win = x_ref[0, 0, kw:kw + wo, :].astype(jnp.float32)    # (Wo, Cin)
+        amax = jnp.max(jnp.abs(win), axis=1, keepdims=True)
+        scale = jnp.maximum(amax, 1e-12) * (1.0 / 127.0)        # (Wo, 1)
+        xq = jnp.clip(jnp.round(win / scale), -127, 127).astype(jnp.int8)
+        part = jax.lax.dot_general(xq, wq_ref[0, kw],
+                                   (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.int32)
+        acc_scr[:] += part.astype(jnp.float32) * scale
 
-    @pl.when(t == nt - 1)
+    @pl.when(kh == nkh - 1)
     def _finish():
         o_ref[0, 0] = (acc_scr[:] * ws_ref[...]).astype(o_ref.dtype)
+
+
+def conv_supported(strides, dilation) -> bool:
+    """The fused conv covers stride (1, 1) / dilation (1, 1) (the serving
+    conv shapes) — the router's test for sending a conv to the lax taps."""
+    return tuple(strides) == (1, 1) and tuple(dilation) == (1, 1)
 
 
 def int8_conv2d_fused(x: jnp.ndarray, packed: Dict[str, Any], *,
                       strides=(1, 1), padding="VALID", dilation=(1, 1),
                       out_dtype=None,
-                      interpret: Optional[bool] = None
-                      ) -> Optional[jnp.ndarray]:
+                      interpret: Optional[bool] = None) -> jnp.ndarray:
     """NHWC × HWIO int8 conv with per-pixel activation quantization fused
-    into the kernel. Supports stride (1, 1) / dilation (1, 1) (the serving
-    conv shapes); anything else returns None and the router falls back to
-    the lax tap-decomposition in :mod:`ops.int8` — same per-pixel math."""
-    if not _HAS_PALLAS:
-        return None
-    if tuple(strides) != (1, 1) or tuple(dilation) != (1, 1):
-        return None
-    interpret = _interpret_default() if interpret is None else interpret
+    into the kernel. Covers :func:`conv_supported` strides and dilations;
+    anything else is a ``ValueError`` (the router sends those to the lax
+    tap-decomposition in :mod:`ops.int8` — same per-pixel math)."""
+    if not conv_supported(strides, dilation):
+        raise ValueError(f"int8_conv2d_fused: strides={tuple(strides)} "
+                         f"dilation={tuple(dilation)} on x{x.shape}: only "
+                         f"(1, 1)/(1, 1) is fused")
+    interpret = interpret_default() if interpret is None else interpret
     wq = packed["q"]
     kh, kw, cin, cout = wq.shape
     out_dtype = jnp.float32 if out_dtype is None else out_dtype
@@ -302,27 +305,29 @@ def int8_conv2d_fused(x: jnp.ndarray, packed: Dict[str, Any], *,
     b, h, w, _ = x.shape
     ho, wo = h - kh + 1, w - kw + 1
     if b == 0 or ho <= 0 or wo <= 0:
-        return None
+        raise ValueError(f"int8_conv2d_fused: empty output for padded "
+                         f"x{x.shape} and kernel {wq.shape}")
     ws_row = packed["scale"].reshape(1, cout).astype(jnp.float32)
     kernel = functools.partial(_int8_conv_kernel, kw_total=kw, wo=wo)
     y = pl.pallas_call(
         kernel,
-        grid=(b, ho, kh * kw),
+        grid=(b, ho, kh),
         in_specs=[
-            # one full input row per program; the tap index selects which
+            # one full input row per program; the tap row selects which
             # (block-size-1 ⇒ index == element offset along H)
             pl.BlockSpec((1, 1, w, cin),
-                         lambda bi, hi, t: (bi, hi + t // kw, 0, 0)),
-            pl.BlockSpec((1, 1, cin, cout),
-                         lambda bi, hi, t: (t // kw, t % kw, 0, 0)),
+                         lambda bi, hi, t: (bi, hi + t, 0, 0)),
+            pl.BlockSpec((1, kw, cin, cout),
+                         lambda bi, hi, t: (t, 0, 0, 0)),
             pl.BlockSpec((1, cout), lambda bi, hi, t: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, wo, cout),
                                lambda bi, hi, t: (bi, hi, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, ho, wo, cout), out_dtype),
         scratch_shapes=[pltpu.VMEM((wo, cout), jnp.float32)],
-        compiler_params=None if interpret else tpu_compiler_params(
+        compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="zoo_int8_conv",
     )(x, wq, ws_row)
     return y

@@ -20,7 +20,11 @@ step that scores k draft tokens against the same paged cache in one pass
 Block schedule: ``block_h`` (heads per program) is the tunable knob —
 resolved via env ``ZOO_PAGED_BLOCK_H``, then the on-disk autotuner cache
 (:mod:`analytics_zoo_tpu.ops.tuning` ``PAGED`` op table, exactly like
-matmul/flash), then all-heads. Routing: :func:`use_kernel` — ``auto``
+matmul/flash), then all-heads. ``block_q`` (query rows per program) is
+derived, not tuned: the whole ``q_len`` while its softmax scratch fits
+scoped VMEM (every decode and verify step), else the largest tile that does
+(:func:`query_block` — the prefill-chunk and prefix-suffix widths, where an
+untiled call is refused by Mosaic). Routing: :func:`use_kernel` — ``auto``
 (kernel on TPU, reference path elsewhere: interpret-mode pallas is a
 correctness tool, not a fast path), forced ``on`` (interpret on CPU — the
 parity gates), or ``off`` via ``ZOO_PAGED_ATTENTION``.
@@ -43,20 +47,16 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .backend import interpret_default
 
 NEG_INF = -1e30
 
-try:  # pallas optional, same pattern as flash_attention/int8_fused
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover - environment without pallas
-    _HAS_PALLAS = False
-
-
-def has_pallas() -> bool:
-    return _HAS_PALLAS
+#: What the kernel sizes its query tile against: half of the 16 MiB scoped
+#: VMEM limit Mosaic enforces on a v5e, the rest left to its own temporaries.
+_VMEM_BUDGET = 8 * 2 ** 20
 
 
 def paged_mode() -> str:
@@ -73,8 +73,6 @@ def paged_mode() -> str:
 def use_kernel() -> bool:
     """Resolve routing at trace time (a jitted decode step bakes the answer,
     like ``flash_attention.default_blocks``)."""
-    if not _HAS_PALLAS:
-        return False
     mode = paged_mode()
     if mode == "off":
         return False
@@ -97,26 +95,46 @@ def default_block_h(h: int, *, q_len: int = 1,
         bh = int(env)
         return bh if h % bh == 0 else h
     if pages_per_slot and page_size and d:
-        try:
-            from .tuning import paged_lookup
+        from .tuning import paged_lookup
 
-            tuned = paged_lookup(q_len, pages_per_slot, page_size, h, d,
-                                 dtype if dtype is not None
-                                 else np.dtype("float32"))
-        except Exception:   # cache layer must never break a decode trace
-            tuned = None
+        tuned = paged_lookup(q_len, pages_per_slot, page_size, h, d,
+                             dtype if dtype is not None
+                             else np.dtype("float32"))
         if tuned is not None and h % tuned == 0:
             return tuned
     return h
 
 
+def query_block(q_len: int, block_h: int, d: int, dtype) -> int:
+    """Query rows per kernel program. Each (head, query) row costs f32
+    running max / sum / accumulator scratch (128-lane padded), the
+    double-buffered q and o tiles, and the f32 score temporaries of one
+    page; ``block_h * block_q`` rows must fit :data:`_VMEM_BUDGET`. Returns
+    ``q_len`` when it fits whole, else its largest divisor that is a
+    multiple of 8 (the sublane tile) and fits. Raises ``ValueError`` naming
+    the shape when there is none — a width the batcher must not admit."""
+    row_bytes = (4 * (128 + 128 + max(d, 128))
+                 + 4 * d * np.dtype(dtype).itemsize + 3 * 4 * 128)
+    cap = _VMEM_BUDGET // row_bytes // block_h
+    if q_len <= cap:
+        return q_len
+    for bq in range(cap - cap % 8, 0, -8):
+        if q_len % bq == 0:
+            return bq
+    raise ValueError(
+        f"paged_attention: q_len={q_len} with block_h={block_h}, d={d}, "
+        f"{np.dtype(dtype).name} needs a query tile of at most {cap} rows "
+        f"that is a multiple of 8 and divides q_len; there is none")
+
+
 def _paged_kernel(table_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, *, scale: float, page_size: int,
-                  q_len: int, block_h: int, d: int):
+                  q_len: int, block_q: int, block_h: int, d: int):
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
-    rows = block_h * q_len
+    qi = pl.program_id(2)
+    j = pl.program_id(3)
+    nj = pl.num_programs(3)
+    rows = block_h * block_q
 
     @pl.when(j == 0)
     def _init():
@@ -129,21 +147,21 @@ def _paged_kernel(table_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
     def body():
         # operands stay in storage dtype (bf16 MXU full-rate), statistics
         # accumulate in f32 — same discipline as the flash kernel
-        q = q_ref[0].transpose(1, 0, 2)             # (block_h, q_len, D)
+        q = q_ref[0].transpose(1, 0, 2)             # (block_h, block_q, D)
         k = k_ref[0].transpose(1, 0, 2)             # (block_h, page, D)
         v = v_ref[0].transpose(1, 0, 2)
         s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32) * scale
         kv_pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (block_h, q_len, page_size), 2)
-        q_idx = jax.lax.broadcasted_iota(
-            jnp.int32, (block_h, q_len, page_size), 1)
+            jnp.int32, (block_h, block_q, page_size), 2)
+        q_idx = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_h, block_q, page_size), 1)
         # query i sits at absolute position length - q_len + i: it sees the
         # whole prefix AND itself/earlier drafts, never later drafts
         bound = length - q_len + q_idx
         s = jnp.where(kv_pos <= bound, s, NEG_INF)
-        m_prev = m_scr[:rows, 0:1].reshape(block_h, q_len, 1)
-        l_prev = l_scr[:rows, 0:1].reshape(block_h, q_len, 1)
+        m_prev = m_scr[:rows, 0:1].reshape(block_h, block_q, 1)
+        l_prev = l_scr[:rows, 0:1].reshape(block_h, block_q, 1)
         m_new = jnp.maximum(m_prev, s.max(axis=2, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
@@ -158,8 +176,9 @@ def _paged_kernel(table_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[:rows, :] = jnp.broadcast_to(l_new.reshape(rows, 1),
                                            (rows, l_scr.shape[1]))
 
-    # skip pages holding no valid position (table entries there are scratch)
-    @pl.when(j * page_size < length)
+    # skip pages no query of this tile can see: past the slot's valid length
+    # (table entries there are scratch) or past the tile's last query
+    @pl.when(j * page_size < length - q_len + (qi + 1) * block_q)
     def _():
         body()
 
@@ -167,55 +186,60 @@ def _paged_kernel(table_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
     def _finish():
         l = l_scr[:rows, 0:1]
         safe_l = jnp.where(l == 0, 1.0, l)   # masked-out rows emit zeros
-        o = (acc_scr[:rows, :d] / safe_l).reshape(block_h, q_len, d)
+        o = (acc_scr[:rows, :d] / safe_l).reshape(block_h, block_q, d)
         o_ref[0] = o.transpose(1, 0, 2).astype(o_ref.dtype)
 
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     table: jax.Array, lengths: jax.Array, *,
                     page_size: int, block_h: Optional[int] = None,
+                    block_q: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Fused page-gather attention.
 
     ``q``: (B, q_len, H, D); ``k_pages``/``v_pages``: (P, page_size, H, D)
     — ONE layer's pool; ``table``: (B, pages_per_slot) int32; ``lengths``:
     (B,) int32 valid positions INCLUDING the q_len new tokens. Returns
-    (B, q_len, H, D). Falls back to the reference gather + masked-dot path
-    when pallas is unavailable."""
-    from .kv_cache import decode_attention_multi, paged_read
-
+    (B, q_len, H, D). A ``block_h`` that does not divide H, or a ``q_len``
+    no query tile fits (:func:`query_block`), is a ``ValueError`` naming
+    the shape; the gather + masked-dot path of :mod:`ops.kv_cache` is the
+    route :func:`use_kernel` selects off TPU and the parity reference."""
     b, q_len, h, d = q.shape
     pps = table.shape[1]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     if block_h is None:
         block_h = default_block_h(h, q_len=q_len, pages_per_slot=pps,
                                   page_size=page_size, d=d, dtype=q.dtype)
-    if not _HAS_PALLAS or h % block_h:
-        ks = paged_read(k_pages, table)
-        vs = paged_read(v_pages, table)
-        return decode_attention_multi(q, ks.astype(q.dtype),
-                                      vs.astype(q.dtype), lengths)
+    if h % block_h:
+        raise ValueError(f"paged_attention: block_h={block_h} does not "
+                         f"divide the {h} heads of q{q.shape}")
+    if block_q is None:
+        block_q = query_block(q_len, block_h, d, q.dtype)
+    if q_len % block_q:
+        raise ValueError(f"paged_attention: block_q={block_q} does not "
+                         f"divide q_len of q{q.shape}")
     scale = 1.0 / float(np.sqrt(d))
-    rows = max(8, block_h * q_len)
+    rows = max(8, block_h * block_q)
     kern = functools.partial(_paged_kernel, scale=scale, page_size=page_size,
-                             q_len=q_len, block_h=block_h, d=d)
+                             q_len=q_len, block_q=block_q, block_h=block_h,
+                             d=d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h // block_h, pps),
+        grid=(b, h // block_h, q_len // block_q, pps),
         in_specs=[
-            pl.BlockSpec((1, q_len, block_h, d),
-                         lambda b, hb, j, tbl, ln: (b, 0, hb, 0)),
-            # THE fusion: the K/V tile for grid step (b, ·, j) is page
+            pl.BlockSpec((1, block_q, block_h, d),
+                         lambda b, hb, qi, j, tbl, ln: (b, qi, hb, 0)),
+            # THE fusion: the K/V tile for grid step (b, ·, ·, j) is page
             # table[b, j] of the pool, resolved in the index map from the
             # scalar-prefetched table — no contiguous copy ever exists
             pl.BlockSpec((1, page_size, block_h, d),
-                         lambda b, hb, j, tbl, ln: (tbl[b, j], 0, hb, 0)),
+                         lambda b, hb, qi, j, tbl, ln: (tbl[b, j], 0, hb, 0)),
             pl.BlockSpec((1, page_size, block_h, d),
-                         lambda b, hb, j, tbl, ln: (tbl[b, j], 0, hb, 0)),
+                         lambda b, hb, qi, j, tbl, ln: (tbl[b, j], 0, hb, 0)),
         ],
-        out_specs=pl.BlockSpec((1, q_len, block_h, d),
-                               lambda b, hb, j, tbl, ln: (b, 0, hb, 0)),
+        out_specs=pl.BlockSpec((1, block_q, block_h, d),
+                               lambda b, hb, qi, j, tbl, ln: (b, qi, hb, 0)),
         scratch_shapes=[
             pltpu.VMEM((rows, 128), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
@@ -225,19 +249,16 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     return pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, q_len, h, d), q.dtype),
-        # the (slot, head-block) dims each own disjoint output blocks; only
-        # the page fold must stay sequential (online-softmax carry)
-        compiler_params=None if interpret else _tpu_params(),
+        # the (slot, head-block, query-tile) dims each own disjoint output
+        # blocks; only the page fold must stay sequential (online-softmax
+        # carry)
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
+        name="zoo_paged_attention",
     )(jnp.asarray(table, jnp.int32), jnp.asarray(lengths, jnp.int32),
       q, k_pages, v_pages)
-
-
-def _tpu_params():
-    from ..common.compat import tpu_compiler_params
-
-    return tpu_compiler_params(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def synthetic_paged_case(n_slots: int, pages_per_slot: int, page_size: int,
@@ -274,5 +295,5 @@ def synthetic_paged_case(n_slots: int, pages_per_slot: int, page_size: int,
     return q, k_pages, v_pages, jnp.asarray(table), jnp.asarray(lengths)
 
 
-__all__ = ["default_block_h", "has_pallas", "paged_attention", "paged_mode",
-           "synthetic_paged_case", "use_kernel"]
+__all__ = ["default_block_h", "paged_attention", "paged_mode",
+           "query_block", "synthetic_paged_case", "use_kernel"]

@@ -15,8 +15,9 @@ later
 process — ``InferenceModel.quantize_int8`` dispatch, ``flash_attention``
 call sites, the MFU bench — traces with tuned blocks instead of constants.
 
-Cache location: ``ZOO_TPU_TUNING_CACHE`` env, else
-``~/.cache/analytics_zoo_tpu/tuning.json``.  Schema (see
+Cache location: ``ZOO_TPU_TUNING_CACHE`` env, else ``.zoo_tuning.json`` at
+the root of the checkout, beside the compile cache — the block sizes a
+program compiles with never depend on a file outside it.  Schema (see
 docs/programming-guide/kernels.md)::
 
     {"version": 1,
@@ -46,6 +47,8 @@ from ..analysis.memory import memory_fields  # noqa: F401  (re-export: the
 # library code must not import from the bench script; existing callers and
 # the tuning-cache schema keep using tuning.memory_fields)
 from ..common import telemetry as _tm
+from ..common.compile_cache import CHECKOUT
+from .backend import interpret_default
 
 _SWEEPS = _tm.counter("zoo_kernel_tuning_sweeps_total",
                       "Autotuner candidate sweeps executed (one per "
@@ -64,10 +67,8 @@ _memo: Dict[str, Optional[dict]] = {}     # path -> parsed cache (None = bad)
 
 
 def cache_path() -> str:
-    return os.environ.get(
-        "ZOO_TPU_TUNING_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "analytics_zoo_tpu",
-                     "tuning.json"))
+    return os.environ.get("ZOO_TPU_TUNING_CACHE",
+                          os.path.join(CHECKOUT, ".zoo_tuning.json"))
 
 
 def device_kind() -> str:
@@ -146,8 +147,6 @@ def record(op: str, key: str, entry: dict) -> None:
     _store(path, data)
 
 
-
-
 def _time_probe(fn, *args, iters: int = 3, inner: int = 5) -> float:
     """Median wall time of ``inner`` chained dispatches (ms per call)."""
     import jax
@@ -206,10 +205,8 @@ def tune_int8_matmul(m: int, n: int, k: int, dtype=np.float32, *,
     from . import int8_fused
     from .int8 import quantize_weight
 
-    if not int8_fused.has_pallas():
-        return None
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     mb = bucket(m)
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(mb, k)), dtype)
@@ -295,12 +292,10 @@ def tune_flash_blocks(t_q: int, t_k: int, *, batch: int = 1, heads: int = 8,
     import jax
     import jax.numpy as jnp
 
-    from .flash_attention import _HAS_PALLAS, flash_attention
+    from .flash_attention import flash_attention
 
-    if not _HAS_PALLAS:
-        return None
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     rng = np.random.default_rng(0)
 
     def make(shape):
@@ -389,10 +384,8 @@ def tune_paged_attention(q_len: int, pages_per_slot: int, page_size: int,
 
     from . import paged_attention as pa
 
-    if not pa.has_pallas():
-        return None
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     q, k_pages, v_pages, table, lengths = pa.synthetic_paged_case(
         n_slots, pages_per_slot, page_size, h, d, q_len=q_len, dtype=dtype)
     _SWEEPS.labels(op=PAGED_OP).inc()

@@ -65,12 +65,9 @@ def _worker_main(widx, inbox, outbox, init_blob, chaos_blob, hb_interval_s):
     re-installed here so cross-process fault plans (kill worker 1 at its 2nd
     task) stay deterministic.
     """
-    try:
-        import jax
+    import jax
 
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
+    jax.config.update("jax_platforms", "cpu")
     from ..common import chaos as chaos_mod
 
     if chaos_blob is not None:

@@ -95,7 +95,7 @@ def sharded_gather(table, ids, mesh, axis: str = "dp", *,
     ZERO rows (no shard owns them) — unlike ``jnp.take``'s clamp — so padded
     vocab tails read as explicit zeros.
     """
-    from ..common.compat import shard_map
+    from jax import shard_map
 
     n = mesh.shape.get(axis, 1) if mesh is not None else 1
     ids = jnp.asarray(ids, jnp.int32)

@@ -23,10 +23,10 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
-
-from ..common.compat import axis_size, shard_map
 
 
 def stack_stage_params(params_list):

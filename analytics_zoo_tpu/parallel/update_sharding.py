@@ -40,9 +40,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax.lax import axis_size
 from jax.sharding import PartitionSpec as P
-
-from ..common.compat import axis_size
 
 __all__ = [
     "FlatParamMeta", "FlatUpdateState", "MasterWeightsState",
@@ -297,7 +296,7 @@ def make_comm_probe(mesh, n_elems: int, axis: str = "dp",
     it. The returned fn is pre-warmed (compiled) so the first observation is
     not a compile.
     """
-    from ..common.compat import shard_map
+    from jax import shard_map
 
     n = mesh.shape.get(axis, 1)
     n_elems = min(max(1, n_elems), PROBE_MAX_ELEMS)

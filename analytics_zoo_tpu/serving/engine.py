@@ -446,6 +446,11 @@ class ClusterServing:
             try:
                 self.model.warm_up(sample)
             except Exception:
+                # same contract as the checks below: "raise" fails start()
+                # (a dispatch that cannot compile will fail every request),
+                # "warn" logs and serves
+                if checks == "raise":
+                    raise
                 logger.exception("warmup predict failed (shape=%s); the "
                                  "first real request will compile instead",
                                  shape)

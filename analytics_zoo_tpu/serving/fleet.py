@@ -79,6 +79,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ..common import telemetry as _tm
 from ..common.chaos import chaos_point
+from ..common.cluster import one_process_per_chip
 from ..common.locks import traced_lock
 from ..common.resilience import (CircuitBreaker, HealthRegistry,
                                  RetryAbortedError, RetryPolicy)
@@ -965,6 +966,10 @@ class FleetSupervisor:
                                    capacity=slot.capacity)
             slot.agent.start()
             return
+        one_process_per_chip(
+            1 + sum(s.proc is not None for s in self._hosts.values()
+                    if s.hid != hid),
+            self.platform, f"fleet host agent {hid}")
         cmd = [sys.executable, "-m", "analytics_zoo_tpu.serving.hostagent",
                "--hid", hid,
                "--broker-host", self.config.queue_host,
@@ -1074,6 +1079,10 @@ class FleetSupervisor:
                 dedup_results=True)
             handle.engine.start()
         else:
+            one_process_per_chip(
+                1 + sum(h.proc is not None for r, h in self._handles.items()
+                        if r != rid),
+                self.platform, f"fleet replica {rid}")
             cmd = [sys.executable, "-m", "analytics_zoo_tpu.serving.fleet",
                    "--replica", rid,
                    "--broker-host", self.config.queue_host,

@@ -372,6 +372,16 @@ class ContinuousBatcher:
                 self.pool,
                 block_tokens=int(prefix_block_tokens) or page_size,
                 page_size=page_size, max_pages=int(prefix_cache_pages))
+        # the paged kernel runs these widths as its query length (a prefill
+        # chunk; a prefix-hit suffix bucket, pow2 up to the cache cap):
+        # refuse here a width it cannot tile, not at the first long prompt
+        from ..ops.paged_attention import query_block
+
+        for width in (int(prefill_chunk_tokens),
+                      self.cfg.max_seq_len if self.prefix_cache else 0):
+            if width:
+                query_block(width, self.cfg.n_heads, self.cfg.head_dim,
+                            self.cfg.dtype)
         self.prefix_tokens_saved = 0
         self.peak_pages_in_use = 0
         self.registry = registry
@@ -1676,6 +1686,30 @@ class ContinuousBatcher:
         return enforce(findings, mode,
                        _logging.getLogger("analytics_zoo_tpu.serving"))
 
+    def _decode_args(self):
+        """Avals of the ONE decode (or k-token verify) dispatch."""
+        import jax
+        import jax.numpy as jnp
+
+        cfg = self.cfg
+        b = self.n_slots
+        sds = jax.ShapeDtypeStruct
+        ids_aval = (sds((b, self.spec_k), jnp.int32) if self.spec_k >= 2
+                    else sds((b,), jnp.int32))
+        return (self.params, self.cache, ids_aval,
+                sds((b,), jnp.int32), sds((b, cfg.pages_per_slot), jnp.int32),
+                sds((b,), jnp.uint32), sds((b,), jnp.uint32),
+                sds((b,), jnp.float32))
+
+    def lower_decode(self):
+        """``jax.stages.Lowered`` of the decode (or verify) dispatch the loop
+        runs: for a caller that wants to read the program (StableHLO text
+        and the kernels in it, memory analysis) rather than trust the
+        routing."""
+        dispatch = (self._verify_fn(self.spec_k) if self.spec_k >= 2
+                    else self._decode)
+        return dispatch.lower(*self._decode_args())
+
     def decode_memory(self) -> Dict[str, Any]:
         """Memory picture of the ONE decode executable, for the bench gate:
         the compiled buffer table (``alias_size_in_bytes`` is the donated
@@ -1684,23 +1718,14 @@ class ContinuousBatcher:
         their difference is the second pool-sized buffer the ``cache-alias``
         rule exists to prevent."""
         import jax
-        import jax.numpy as jnp
         import jax.tree_util as jtu
 
         from ..analysis.memory import memory_fields, profile_jaxpr
 
         cfg = self.cfg
-        b = self.n_slots
         spec = self.spec_k >= 2
-        sds = jax.ShapeDtypeStruct
-        ids_aval = (sds((b, self.spec_k), jnp.int32) if spec
-                    else sds((b,), jnp.int32))
-        args = (self.params, self.cache, ids_aval,
-                sds((b,), jnp.int32), sds((b, cfg.pages_per_slot), jnp.int32),
-                sds((b,), jnp.uint32), sds((b,), jnp.uint32),
-                sds((b,), jnp.float32))
-        dispatch = self._verify_fn(self.spec_k) if spec else self._decode
-        fields = memory_fields(dispatch.lower(*args).compile())
+        args = self._decode_args()
+        fields = memory_fields(self.lower_decode().compile())
         step = (self.model.verify_step if spec else self.model.decode_step)
         closed = jax.make_jaxpr(
             lambda p, c, ids, ln, tb, sd, ti, tp: step(

@@ -1,9 +1,7 @@
 """Anomaly detection — LSTM forecaster residuals flag anomalies
 (apps/anomaly-detection + examples/anomalydetection parity)."""
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import numpy as np
 

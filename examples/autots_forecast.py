@@ -1,9 +1,7 @@
 """Zouwu AutoTS — automated time-series forecasting
 (zouwu/autots parity: AutoTSTrainer.fit → TSPipeline predict/save/load)."""
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import numpy as np
 import pandas as pd

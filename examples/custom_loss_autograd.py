@@ -2,9 +2,7 @@
 (pyzoo/zoo/examples/autograd parity; the reference's Variable algebra collapses
 to plain jnp under jax.grad)."""
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import jax.numpy as jnp
 import numpy as np

@@ -8,9 +8,7 @@ the frozen phase — no per-layer ``trainable`` flags to mutate."""
 
 import sys
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import jax
 import numpy as np

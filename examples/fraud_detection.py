@@ -2,9 +2,7 @@
 binary classification over transaction features; the notebook undersamples the
 majority class and evaluates AUC/precision-recall)."""
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import numpy as np
 

@@ -4,9 +4,7 @@ dataset directory — pass a dogs-vs-cats style dir layout to use real files).""
 
 import sys
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import numpy as np
 

@@ -2,9 +2,7 @@
 similarity ranking with backbone embeddings). A backbone's penultimate
 features embed each image; cosine similarity ranks the gallery for a query."""
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import numpy as np
 

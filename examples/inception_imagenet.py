@@ -7,9 +7,7 @@ synthetic stand-in dataset is generated."""
 
 import sys
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import numpy as np
 

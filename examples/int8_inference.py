@@ -2,9 +2,7 @@
 (examples/vnni parity): quantize a trained model's weights to int8 inside the
 InferenceModel pool and compare accuracy + memory."""
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import numpy as np
 

@@ -11,9 +11,7 @@ pipeline end-to-end."""
 import os
 import sys
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import numpy as np
 

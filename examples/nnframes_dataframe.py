@@ -1,9 +1,7 @@
 """NNFrames — Spark-ML-style fit on a DataFrame of columns
 (examples/nnframes parity)."""
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import numpy as np
 import pandas as pd

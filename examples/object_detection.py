@@ -1,9 +1,7 @@
 """Object detection — SSD train + mAP evaluation on synthetic shapes
 (examples/objectdetection parity)."""
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import numpy as np
 

@@ -1,9 +1,7 @@
 """ONNX ingestion — import a graph, run it, fine-tune it
 (pyzoo/zoo/pipeline/api/onnx loader parity; no onnx package needed)."""
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import numpy as np
 

@@ -1,9 +1,7 @@
 """QA ranking with KNRM — rank-hinge training + NDCG/MAP evaluation
 (examples/qaranker parity)."""
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import numpy as np
 

@@ -2,9 +2,7 @@
 CIFAR-style data). Uses the backbone-zoo resnet18 with label smoothing and a
 cosine-decayed Adam, the TPU-native analog of the reference's SGD recipe."""
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import numpy as np
 

@@ -1,9 +1,7 @@
 """Seq2seq chatbot — encoder/decoder over token ids with greedy inference
 (examples/chatbot parity; synthetic echo-ish corpus)."""
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import numpy as np
 

@@ -1,9 +1,7 @@
 """Cluster serving quickstart — broker + serving job + InputQueue/OutputQueue
 client (pyzoo/zoo/examples/serving + serving quick_start parity, one process)."""
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import numpy as np
 

@@ -4,9 +4,7 @@ batches): frames flow through the Cluster-Serving stream (broker → pipelined
 engine → result hash) with an SSD detector as the served model; detections
 stream back per frame."""
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import numpy as np
 

@@ -3,9 +3,7 @@
 by a trained TextClassifier): text flows through the serving stream as indexed
 sequences; the engine batches and classifies, results stream back."""
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import numpy as np
 

@@ -1,9 +1,7 @@
 """Text classification — TextSet pipeline → TextClassifier (CNN encoder)
 (examples/textclassification parity)."""
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import numpy as np
 

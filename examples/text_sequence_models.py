@@ -7,9 +7,7 @@ derivable from token ids, answer spans marked by a special token, intents from
 the leading word. Everything here is one jittable program per model; the CRF
 loss/decode are `lax.scan` dynamic programs (no dynamic shapes)."""
 
-from _common import SMOKE, force_cpu_if_no_tpu
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import numpy as np  # noqa: E402
 

@@ -1,9 +1,7 @@
 """Flagship transformer LM — bf16 compute, optional remat, flash attention
 (the model behind __graft_entry__; examples/attention parity)."""
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import numpy as np
 

@@ -3,9 +3,7 @@ notebooks. Encoder â†’ (mean, log_var) â†’ GaussianSampler reparameterization â†
 decoder; loss = reconstruction + KL, written as a plain JAX custom loss
 (the autograd-capability path)."""
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import jax.numpy as jnp
 import numpy as np

@@ -1,9 +1,7 @@
 """Wide & Deep recommendation over feature columns
 (examples/recommendation WND parity)."""
 
-from _common import force_cpu_if_no_tpu, SMOKE
-
-force_cpu_if_no_tpu()
+from _common import SMOKE
 
 import numpy as np
 

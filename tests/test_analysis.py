@@ -41,7 +41,7 @@ def test_golden_collective_budget(devices):
     """psum where the budget demands a reduce-scatter → one finding."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from analytics_zoo_tpu.common.compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(devices), ("dp",))
     fn = shard_map(lambda v: jax.lax.psum(v, "dp"), mesh=mesh,
@@ -58,7 +58,7 @@ def test_golden_collective_budget_in_loop(devices):
     the total count matches the budget."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from analytics_zoo_tpu.common.compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(devices), ("dp",))
 
@@ -82,7 +82,7 @@ def test_golden_collective_budget_hlo(devices):
     """Compiled-HLO layer: budget mismatch on real post-XLA text."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from analytics_zoo_tpu.common.compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(devices), ("dp",))
     fn = jax.jit(shard_map(lambda v: jax.lax.psum(v, "dp"), mesh=mesh,
@@ -417,12 +417,11 @@ def test_inference_model_fused_check(zoo_ctx, monkeypatch, np_rng):
     x = np_rng.normal(size=(4, 32)).astype(np.float32)
     # healthy fused path: clean in raise mode
     assert im.check_fused_dispatch(x, mode="raise") == []
-    # break the fused tier (kernels silently refuse every shape — the
-    # regression class): caught at model-load time
+    # break the fused tier (the router finds no shape tileable and sends
+    # everything to lax — the regression class): caught at model-load time
     from analytics_zoo_tpu.ops import int8_fused
 
-    monkeypatch.setattr(int8_fused, "int8_matmul_fused",
-                        lambda *a, **k: None)
+    monkeypatch.setattr(int8_fused, "resolve_blocks", lambda *a, **k: None)
     findings = im.check_fused_dispatch(x, mode="warn")
     assert {f.rule for f in findings} == {"fused-int8-dispatch"}
     with pytest.raises(GraphLintError, match="fused-int8-dispatch"):
@@ -442,8 +441,7 @@ def test_serving_warmup_runs_fused_check(zoo_ctx, monkeypatch, np_rng):
     cs._warm_model()                                  # healthy: no raise
     from analytics_zoo_tpu.ops import int8_fused
 
-    monkeypatch.setattr(int8_fused, "int8_matmul_fused",
-                        lambda *a, **k: None)
+    monkeypatch.setattr(int8_fused, "resolve_blocks", lambda *a, **k: None)
     im._compiled.clear()
     with pytest.raises(GraphLintError, match="fused-int8-dispatch"):
         cs._warm_model()

@@ -141,9 +141,14 @@ def test_batcher_rejects_invalid_chunk_config(model_and_params):
 
 def test_prefill_chunk_bit_identical_to_whole_prefill(model_and_params):
     """Chunked prefill writes the SAME K/V pages and produces the SAME
-    final-position logits as the one-shot prefill — bitwise, not approx:
-    page 0 is scratch in both, every masked lane lands there, and the
-    per-chunk positions/page-indices reproduce the whole run exactly."""
+    final-position logits as the one-shot prefill: page 0 is scratch in
+    both, every masked lane lands there, and the per-chunk positions and
+    page indices reproduce the whole run. The two run different programs
+    (one 16-row forward with plain attention; 8-row chunks through the
+    gathered cache), whose f32 reductions XLA is free to order differently,
+    so "same" is a few ulp at the values' scale, not bitwise. What the
+    batcher promises bitwise, identical TOKENS chunked or whole, is
+    ``test_chunked_bit_identical_to_whole_prompt`` below."""
     m, params = model_and_params
     rng = np.random.default_rng(3)
     L, ct, bucket = 14, 8, 16
@@ -171,12 +176,13 @@ def test_prefill_chunk_bit_identical_to_whole_prefill(model_and_params):
             params, cache_b, chunk, np.array([n_done], np.int32),
             np.array([n_valid], np.int32), wide, page_size=cfg.page_size)
 
-    assert np.array_equal(np.asarray(whole_logits),
-                          np.asarray(chunk_logits))
+    np.testing.assert_allclose(np.asarray(chunk_logits),
+                               np.asarray(whole_logits), rtol=0, atol=2e-6)
     for leaf in ("k", "v"):
         a = np.asarray(cache_a[leaf])[:, row]
         b = np.asarray(cache_b[leaf])[:, row]
-        assert np.array_equal(a, b), f"cache leaf {leaf} diverged"
+        np.testing.assert_allclose(b, a, rtol=0, atol=2e-6,
+                                   err_msg=f"cache leaf {leaf} diverged")
 
 
 # ---------------------------------------------------------------- lint

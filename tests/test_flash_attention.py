@@ -34,12 +34,18 @@ def test_flash_single_tile_and_uneven_block_clamp():
                                rtol=2e-5)
 
 
-def test_flash_fallback_on_non_divisible():
-    # T=50 does not tile by 16 → silently uses full attention (same numbers)
+def test_flash_non_divisible_names_the_shape():
+    # T=50 does not tile by 16: a kernel that was selected and cannot run is
+    # an error, never a silent switch to full attention
+    from analytics_zoo_tpu.ops.flash_attention import tiles_ok
+
     q, k, v = make_qkv(t=50)
-    got = flash_attention(q, k, v, False, 16, 16, True)
-    want = full_attention(q, k, v)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
+    assert not tiles_ok(50, 50, 16, 16) and tiles_ok(64, 64, 16, 16)
+    # compiled, Mosaic wants each q tile's lse store 128-lane aligned
+    assert not tiles_ok(64, 64, interpret=False)
+    assert tiles_ok(2048, 2048, interpret=False)
+    with pytest.raises(ValueError, match=r"\(16, 16\).*T_q=50"):
+        flash_attention(q, k, v, False, 16, 16, True)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -240,10 +246,10 @@ def test_default_blocks_env_knobs(monkeypatch):
 
 
 def test_default_blocks_adaptive(monkeypatch):
-    """Tile adaptivity is a 4× kernel lever (LONGCTX_BENCH.json): largest
+    """Tile adaptivity is a kernel lever (4× before PR 1): largest
     power-of-two ≤512 dividing the sequence; env always wins; unknown or
-    non-dividing lengths keep the 128 fallback (callers then fall back to
-    full attention exactly as before)."""
+    non-dividing lengths get 128 (``tiles_ok`` then tells an auto router to
+    stay on full attention)."""
     from analytics_zoo_tpu.ops.flash_attention import default_blocks
 
     monkeypatch.delenv("ZOO_FLASH_BLOCK_Q", raising=False)
@@ -252,7 +258,7 @@ def test_default_blocks_adaptive(monkeypatch):
     assert default_blocks(512, 1024) == (512, 512)
     assert default_blocks(256, 384) == (256, 128)   # 384 = 3·128
     assert default_blocks(16384, None) == (512, 128)
-    assert default_blocks(300, 300) == (128, 128)   # non-dividing: fallback
+    assert default_blocks(300, 300) == (128, 128)   # non-dividing
     monkeypatch.setenv("ZOO_FLASH_BLOCK_Q", "1024")
     assert default_blocks(2048, 2048) == (1024, 512)  # env wins per-axis
 

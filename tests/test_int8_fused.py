@@ -111,9 +111,9 @@ def test_fused_matmul_3d_leading_dims(np_rng):
     assert np.max(np.abs(np.asarray(y) - f32)) / np.max(np.abs(f32)) < 0.03
 
 
-def test_router_falls_back_when_untileable(fused_interpret, np_rng):
-    """K that no power-of-two tile divides → int8_matmul silently uses the
-    lax path (identical results, no crash)."""
+def test_router_sends_untileable_to_lax(fused_interpret, np_rng):
+    """K that no power-of-two tile divides → int8_matmul routes the shape to
+    the lax path (identical results, no crash)."""
     x = np_rng.normal(size=(4, 33)).astype(np.float32)
     w = np_rng.normal(size=(33, 7)).astype(np.float32)
     packed = _packed(w)
@@ -150,12 +150,20 @@ def test_fused_conv_matches_unfused_per_pixel(padding, np_rng):
                                rtol=1e-4, atol=1e-4)
 
 
-def test_fused_conv_rejects_strided(np_rng):
+def test_fused_kernels_name_the_shape_they_cannot_run(np_rng):
+    """A kernel that was selected and cannot run raises; the router asks
+    ``conv_supported`` / ``resolve_blocks`` first and never gets here."""
     x = np_rng.normal(size=(1, 8, 8, 8)).astype(np.float32)
     packed = _packed(np_rng.normal(size=(3, 3, 8, 8)).astype(np.float32))
-    assert int8_fused.int8_conv2d_fused(
-        jnp.asarray(x), packed, strides=(2, 2), padding="VALID",
-        interpret=True) is None
+    assert not int8_fused.conv_supported((2, 2), (1, 1))
+    with pytest.raises(ValueError, match=r"strides=\(2, 2\)"):
+        int8_fused.int8_conv2d_fused(
+            jnp.asarray(x), packed, strides=(2, 2), padding="VALID",
+            interpret=True)
+    w = _packed(np_rng.normal(size=(33, 7)).astype(np.float32))
+    with pytest.raises(ValueError, match=r"\(4, 33\)"):
+        int8_fused.int8_matmul_fused(jnp.zeros((4, 33), jnp.float32), w,
+                                     interpret=True)
 
 
 @pytest.mark.parametrize("strides,dilation", [((1, 1), (1, 1)),

@@ -1,0 +1,89 @@
+"""Mosaic compiles the Pallas kernels for a v5e, checked without a chip.
+
+Every other kernel test runs the Pallas interpreter, and a kernel that only
+ever ran interpreted can be one Mosaic refuses: it refused the int8 conv (a
+tap offset it could not prove sublane-aligned), the paged kernel at prefill
+widths (16 MiB of scoped VMEM) and the flash kernel under 128 tokens (an
+unaligned lane store) before anything here ran on a TPU. libtpu compiles for
+a described topology from a CPU host, so these compile ahead of time, with
+``interpret=False`` said out loud, the shapes ``chip_smoke.py`` runs in its
+train, generation and int8 phases, plus the widest the serving config admits.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from analytics_zoo_tpu.ops import int8_fused
+from analytics_zoo_tpu.ops.flash_attention import flash_attention
+from analytics_zoo_tpu.ops.paged_attention import paged_attention
+
+BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """``compile(fn, (shape, dtype), ...)`` for one device of a v5e 2x2."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu, or one that knows no v5e
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    sharding = SingleDeviceSharding(topo.devices[0])
+
+    def compile(fn, *avals):
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+                for shape, dtype in avals]
+        # the precision a TPU process runs at, not conftest's "highest":
+        # Mosaic takes an fp32 contract precision on f32 operands only
+        # ("Bad lhs type" on bf16 and int8)
+        with jax.default_matmul_precision("default"):
+            text = jax.jit(fn).lower(*args).compile().as_text()
+        assert "tpu_custom_call" in text
+        return text
+
+    return compile
+
+
+def test_flash_forward_and_backward_at_the_training_shape(v5e):
+    qkv = ((8, 2048, 8, 128), BF16)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: flash_attention(
+            *a, True, None, None, False).astype(F32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    assert v5e(grads, qkv, qkv, qkv).count("tpu_custom_call") == 3
+
+
+# decode, speculative verify, and the widest query the config admits: a
+# prefix-hit suffix bucket as long as gen_max_seq_len (one slot, table one
+# chunk wider than the slot's pages)
+@pytest.mark.parametrize("slots,q_len,table", [(8, 1, 128), (8, 4, 128),
+                                               (1, 2048, 256)])
+@pytest.mark.parametrize("dtype", [BF16, F32])
+def test_paged_attention_at_the_serving_shapes(v5e, slots, q_len, table,
+                                               dtype):
+    pool = ((8 * 128 + 1, 16, 8, 128), dtype)
+    v5e(lambda q, k, v, tb, ln: paged_attention(
+        q, k, v, tb, ln, page_size=16, interpret=False),
+        ((slots, q_len, 8, 128), dtype), pool, pool,
+        ((slots, table), I32), ((slots,), I32))
+
+
+@pytest.mark.parametrize("m", [1, 16, 512])
+def test_fused_int8_matmul_at_the_mlp_shapes(v5e, m):
+    v5e(lambda x, q, s: int8_fused.int8_matmul_fused(
+        x, {"q": q, "scale": s}, interpret=False),
+        ((m, 256), BF16), ((256, 512), I8), ((512,), F32))
+
+
+@pytest.mark.parametrize("hw,cin,cout,k,dtype", [
+    (56, 64, 64, 3, BF16), (14, 256, 256, 3, F32), (224, 3, 64, 7, BF16)])
+def test_fused_int8_conv_at_resnet_shapes(v5e, hw, cin, cout, k, dtype):
+    v5e(lambda x, q, s: int8_fused.int8_conv2d_fused(
+        x, {"q": q, "scale": s}, padding="SAME", interpret=False),
+        ((2, hw, hw, cin), dtype), ((k, k, cin, cout), I8), ((cout,), F32))

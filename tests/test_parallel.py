@@ -10,9 +10,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from analytics_zoo_tpu.common.compat import shard_map
 from analytics_zoo_tpu.ops.attention import full_attention, sharded_attention
 
 

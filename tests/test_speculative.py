@@ -17,8 +17,8 @@ from analytics_zoo_tpu.models.transformer import TransformerLM
 from analytics_zoo_tpu.ops.kv_cache import (decode_attention_multi,
                                             paged_read, sample_tokens)
 from analytics_zoo_tpu.ops.paged_attention import (default_block_h,
-                                                   has_pallas,
                                                    paged_attention,
+                                                   query_block,
                                                    synthetic_paged_case)
 from analytics_zoo_tpu.ops.speculative import (SpecDecodeConfig,
                                                propose_kgram,
@@ -119,14 +119,14 @@ def _random_paged_case(np_rng, q_len, dtype, n_slots=4, h=HEADS * 2, d=16,
     return q, kp, vp, table, lengths, page_size
 
 
-@pytest.mark.skipif(not has_pallas(), reason="pallas unavailable")
-@pytest.mark.parametrize("q_len", [1, 4])
+# (16, 8): the query-tiled grid the prefill-chunk widths run with
+@pytest.mark.parametrize("q_len,block_q", [(1, None), (4, None), (16, 8)])
 @pytest.mark.parametrize("block_h", [None, 1, 2])
-def test_kernel_parity_f32(np_rng, q_len, block_h):
+def test_kernel_parity_f32(np_rng, q_len, block_q, block_h):
     q, kp, vp, table, lengths, ps = _random_paged_case(
         np_rng, q_len, jnp.float32)
     got = paged_attention(q, kp, vp, table, lengths, page_size=ps,
-                          block_h=block_h, interpret=True)
+                          block_h=block_h, block_q=block_q, interpret=True)
     ref = decode_attention_multi(q, paged_read(kp, table),
                                  paged_read(vp, table), lengths)
     # live rows match the reference; the fully-masked slot differs BY
@@ -137,7 +137,6 @@ def test_kernel_parity_f32(np_rng, q_len, block_h):
     assert np.all(np.asarray(got)[-1] == 0.0)
 
 
-@pytest.mark.skipif(not has_pallas(), reason="pallas unavailable")
 @pytest.mark.parametrize("q_len", [1, 4])
 def test_kernel_parity_bf16(np_rng, q_len):
     q, kp, vp, table, lengths, ps = _random_paged_case(
@@ -150,6 +149,21 @@ def test_kernel_parity_bf16(np_rng, q_len):
                                np.asarray(ref, np.float32)[:-1],
                                atol=2e-2, rtol=0)
     assert np.all(np.asarray(got, np.float32)[-1] == 0.0)
+
+
+def test_query_block_fits_vmem_or_names_the_shape():
+    """Decode and verify widths run untiled; prefill-chunk widths get the
+    largest 8-multiple divisor whose scratch fits; a width with none is an
+    error naming the shape, and so is a block that does not divide."""
+    assert query_block(4, 8, 128, jnp.bfloat16) == 4
+    assert query_block(2048, 8, 128, jnp.bfloat16) == 256
+    assert query_block(2048, 8, 128, jnp.float32) == 128
+    with pytest.raises(ValueError, match="q_len=1030"):
+        query_block(1030, 8, 128, jnp.bfloat16)
+    q, kp, vp, table, lengths = synthetic_paged_case(2, 4, 8, 4, 16, q_len=4)
+    with pytest.raises(ValueError, match="block_h=3"):
+        paged_attention(q, kp, vp, table, lengths, page_size=8, block_h=3,
+                        interpret=True)
 
 
 def test_default_block_h_env_and_divisibility(monkeypatch):
@@ -165,8 +179,6 @@ def test_paged_tuning_table(tmp_path, monkeypatch):
     """The PAGED op rides the same autotuner cache as matmul/flash: a sweep
     persists the winning block_h, lookups answer from it, and the kernel's
     default consults it."""
-    if not has_pallas():
-        pytest.skip("pallas unavailable")
     from analytics_zoo_tpu.ops import tuning
 
     monkeypatch.setenv("ZOO_TPU_TUNING_CACHE", str(tmp_path / "tuning.json"))

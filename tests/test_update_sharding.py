@@ -72,12 +72,13 @@ def _leaves(est):
 def test_grad_accum_matches_big_batch_byte_exact_f32(zoo_ctx, shuffle):
     """K microbatches == one big batch, bit-for-bit in f32 on dyadic data.
 
-    Single-step equality is byte-exact on BOTH update paths. Multi-step
-    equality stays byte-exact on the flat-sharded path (K=1 and K=4 feed the
-    identical psum_scatter exchange); on the replicated path later steps walk
-    off the dyadic lattice (update granularity compounds past the f32
-    mantissa, and XLA's backward-dot reduction order then differs between the
-    micro and full batch shapes), so those are compared within one ulp."""
+    Single-step equality is byte-exact on BOTH update paths: every product
+    and partial sum is exactly representable, so no reduction order can
+    change it. Later steps walk off the dyadic lattice (update granularity
+    compounds past the f32 mantissa), and XLA is then free to order the
+    backward-dot reduction differently for the micro and the full batch
+    shape — on the flat-sharded path as much as on the replicated one — so
+    multi-step results are compared within one ulp."""
     x, y = _dyadic_data(B=64)
     for sharded in (False, True):
         common = dict(shuffle=shuffle, log_every_n_steps=10 ** 9,
@@ -93,13 +94,9 @@ def test_grad_accum_matches_big_batch_byte_exact_f32(zoo_ctx, shuffle):
         e1.fit((x, y), batch_size=32, epochs=4)       # 6 more steps
         eK.fit((x, y), batch_size=32, epochs=4)
         for a, b in zip(_leaves(e1), _leaves(eK)):
-            if sharded:
-                np.testing.assert_array_equal(
-                    a, b, err_msg=f"multi-step flat shuffle={shuffle}")
-            else:
-                np.testing.assert_allclose(
-                    a, b, rtol=0, atol=2e-7,
-                    err_msg=f"multi-step replicated shuffle={shuffle}")
+            np.testing.assert_allclose(
+                a, b, rtol=0, atol=2e-7,
+                err_msg=f"multi-step sharded={sharded} shuffle={shuffle}")
 
 
 def test_grad_accum_matches_big_batch_bf16_tolerance(zoo_ctx):

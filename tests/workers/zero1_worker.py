@@ -42,7 +42,7 @@ def main():
     from analytics_zoo_tpu.common import (MeshConfig, RuntimeConfig,
                                           init_zoo_context)
     from analytics_zoo_tpu.common.cluster import barrier
-    from analytics_zoo_tpu.common.compat import shard_map
+    from jax import shard_map
     from analytics_zoo_tpu.parallel import update_sharding as upd
 
     ctx = init_zoo_context(RuntimeConfig(platform="cpu",
