@@ -79,12 +79,6 @@ _GRAD_NORM = _tm.histogram("zoo_train_grad_norm",
                            "at log points",
                            buckets=(0.001, 0.01, 0.1, 0.5, 1, 2.5, 5, 10, 25,
                                     100, 1000))
-_COMM = _tm.histogram("zoo_train_comm_seconds",
-                      "Measured one-round gradient-exchange time (param-sized "
-                      "collective probe on the dp axis, timed off the hot "
-                      "path at each log point)",
-                      buckets=(.0001, .0005, .001, .0025, .005, .01, .025,
-                               .05, .1, .25, 1))
 
 
 class _GracefulStop(BaseException):
@@ -142,7 +136,6 @@ class Estimator:
         # flat (BigDL AllReduceParameter-layout) update sharding: static
         # flattening meta, built by _init_state when the mode engages
         self._flat_meta = None
-        self._comm_probe_cache = None
         self.mesh = mesh if mesh is not None else get_zoo_context().mesh
         # models that carry their own placement strategy (e.g.
         # PipelinedTransformerLM's stage-over-pp layout) expose
@@ -841,7 +834,6 @@ class Estimator:
                     compute_ms = max(0.0, (now - win_t0 - win_data_wait)
                                      / win_steps) * 1e3
                     _COMPUTE.observe(compute_ms / 1e3)
-                    self._observe_comm()
                     _mw.sample("estimator.step")
                     if self.train_summary:
                         self.train_summary.add_scalars(ts.iteration, {
@@ -853,9 +845,8 @@ class Estimator:
                                 "%.2fms /step)",
                                 epoch, ts.iteration, loss_val, gnorm_val,
                                 throughput, data_ms, compute_ms)
-                    # fresh clock: the comm probe (and its first-call
-                    # compile) ran after `now` and must not be attributed to
-                    # the NEXT window's ComputeMs
+                    # fresh clock: the summary writes and the log line ran
+                    # after `now` and are not the NEXT window's ComputeMs
                     win_t0, win_steps, win_data_wait = (time.perf_counter(),
                                                         0, 0.0)
                 if (checkpoint_trigger is not None and checkpoint_trigger(ts)
@@ -992,7 +983,6 @@ class Estimator:
                 throughput = seen / max(now - t0, 1e-9)
                 compute_ms = (now - win_t0) / max(1, win_steps) * 1e3
                 _COMPUTE.observe(compute_ms / 1e3)
-                self._observe_comm()
                 _mw.sample("estimator.step")
                 if self.train_summary:
                     self.train_summary.add_scalars(ts.iteration, {
@@ -1001,7 +991,7 @@ class Estimator:
                         "DataWaitMs": 0.0, "ComputeMs": compute_ms})
                 logger.info("epoch %d iter %d loss %.4f throughput %.1f rec/s",
                             epoch, ts.iteration, loss_val, throughput)
-                # fresh clock: keep the comm probe out of the next window
+                # fresh clock: keep the log point out of the next window
                 win_t0, win_steps = time.perf_counter(), 0
             if (checkpoint_trigger is not None and cfg.checkpoint_dir
                     and self._trigger_crossed(checkpoint_trigger, ts, block)):
@@ -1089,25 +1079,6 @@ class Estimator:
             self._recompile_tracker = SignatureTracker("estimator.step",
                                                        max_distinct=4)
         self._recompile_tracker.add(key)
-
-    def _observe_comm(self):
-        """Feed ``zoo_train_comm_seconds``: time one param-sized gradient-
-        exchange round (psum, or reduce-scatter + all-gather under update
-        sharding) on the dp axis. A measured probe at log-point cadence — the
-        in-step collective is fused into the jitted program and cannot be
-        timed from the host."""
-        if self.mesh.shape.get("dp", 1) <= 1 or self.train_state is None:
-            return
-        if self._comm_probe_cache is None:
-            n_elems = sum(
-                int(np.prod(l.shape)) for l in
-                jax.tree_util.tree_leaves(self.train_state["params"]))
-            self._comm_probe_cache = upd.make_comm_probe(
-                self.mesh, n_elems, sharded=self._update_mode() is not None)
-        fn, vec = self._comm_probe_cache
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(vec))
-        _COMM.observe(time.perf_counter() - t0)
 
     @staticmethod
     def _batch_signature(batch) -> Tuple:

@@ -14,13 +14,13 @@ from .embedding_sharding import (TableSharding, owned_row_range, pad_rows,
                                  row_shard_spec, shard_embedding_tables,
                                  sharded_gather, sharded_table_layers)
 from .update_sharding import (collective_counts, flat_exchange, flat_meta,
-                              make_comm_probe, make_update_sharding,
+                              make_update_sharding,
                               shard_spec_over_axis, with_master_weights)
 
 __all__ = [
     "pipeline_apply", "stack_stage_params",
     "TP_RULES", "TableSharding", "build_mesh", "collective_counts",
-    "flat_exchange", "flat_meta", "full_attention", "make_comm_probe",
+    "flat_exchange", "flat_meta", "full_attention",
     "make_param_sharding", "make_update_sharding", "owned_row_range",
     "pad_rows", "replicated", "ring_attention_local", "row_shard_spec",
     "shard_embedding_tables", "shard_spec_over_axis", "sharded_attention",

@@ -46,7 +46,7 @@ from jax.sharding import PartitionSpec as P
 __all__ = [
     "FlatParamMeta", "FlatUpdateState", "MasterWeightsState",
     "collective_counts", "flat_exchange", "flat_meta", "flatten_tree",
-    "make_comm_probe", "make_update_sharding", "shard_spec_over_axis",
+    "make_update_sharding", "shard_spec_over_axis",
     "unflatten_tree", "with_master_weights",
 ]
 
@@ -272,47 +272,6 @@ def with_master_weights(tx: optax.GradientTransformation
         return new_params, MasterWeightsState(inner2, master2)
 
     return optax.GradientTransformation(init, update)
-
-
-# ------------------------------------------------------------------ comm probe
-# probe ceiling: 16M f32 elements = 64 MiB. Above this the probe measures a
-# capped vector instead of the full param count — a telemetry probe must not
-# hold (and all-gather) gigabytes next to a training state that ZeRO-1 just
-# shrank to fit
-PROBE_MAX_ELEMS = 16 * 1024 * 1024
-
-
-def make_comm_probe(mesh, n_elems: int, axis: str = "dp",
-                    sharded: bool = False):
-    """Jitted one-round grad-exchange probe over an ``n_elems`` f32 vector:
-    ``psum`` (replicated exchange) or ``psum_scatter`` + tiled ``all_gather``
-    (sharded exchange). The engine times a call at each log point to feed
-    ``zoo_train_comm_seconds`` — a measured collective round of the real
-    exchange size on the real mesh, off the jitted hot path. ``n_elems`` is
-    capped at :data:`PROBE_MAX_ELEMS` (64 MiB of f32) so the cached probe
-    vector can never crowd out training memory on billion-param models.
-
-    Returns ``(fn, vec)``; call ``jax.block_until_ready(fn(vec))`` and time
-    it. The returned fn is pre-warmed (compiled) so the first observation is
-    not a compile.
-    """
-    from jax import shard_map
-
-    n = mesh.shape.get(axis, 1)
-    n_elems = min(max(1, n_elems), PROBE_MAX_ELEMS)
-    npad = ((n_elems + n - 1) // n) * n
-
-    def body(v):
-        if sharded:
-            s = jax.lax.psum_scatter(v, axis, scatter_dimension=0, tiled=True)
-            return jax.lax.all_gather(s, axis, axis=0, tiled=True)
-        return jax.lax.psum(v, axis)
-
-    fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
-                           check_vma=False))
-    vec = jnp.ones((npad,), jnp.float32)
-    jax.block_until_ready(fn(vec))      # pre-warm: compile outside the timing
-    return fn, vec
 
 
 # --------------------------------------------------------------- HLO forensics

@@ -194,7 +194,10 @@ def do_info(args) -> int:
         if isinstance(gen, dict):
             entry = {k: gen.get(k) for k in
                      ("served_streams", "active_slots", "backlog",
-                      "model_version", "ts")}
+                      # the decode loop thread's seconds by exclusive phase
+                      # since start, and the steps they bought: two readings
+                      # and a subtraction say where the loop's time goes
+                      "steps", "loop_seconds", "model_version", "ts")}
             prefix = gen.get("prefix")
             if isinstance(prefix, dict):
                 # shared-prefix KV cache headline: fraction of prefills

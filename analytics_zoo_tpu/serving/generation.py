@@ -25,10 +25,21 @@ per-request ``serving.gen.stream`` span on the engine side, same propagation
 rules as the one-shot path. Telemetry: ``zoo_gen_tokens_total``,
 ``zoo_gen_inter_token_seconds``, ``zoo_gen_requests_total{outcome}``, and
 active-slots / free-pages gauges.
+
+The path accounts for its own time (docs/observability.md has the tables):
+``zoo_gen_loop_seconds_total{phase}`` splits every second of the decode loop's
+thread into exclusive phases (:class:`_LoopClock`; each phase is also a
+``serving.gen.loop.<phase>`` profiler region, on the device trace's clock),
+and a request's legs are histograms on one fine ladder: ingress (client
+``submit`` to the engine's source), queue wait (``submit`` to leaving the
+backlog), prefill (to the first token on the host; the two sum to
+``zoo_gen_ttft_seconds``) and egress (a frame handed to the sink until its
+``XADD`` returned).
 """
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import queue
 import threading
@@ -51,8 +62,9 @@ from ..ops.kv_cache import (OutOfPages, PagePool, PrefixCache, SCRATCH_PAGE,
 from . import qos as _qos
 from .client import _Conn
 from .config import ServingConfig
-from .schema import (DEADLINE_KEY, PRIORITY_KEY, TRACE_KEY, payload_deadline,
-                     payload_priority, payload_trace)
+from .schema import (DEADLINE_KEY, PRIORITY_KEY, SENT_KEY, TRACE_KEY,
+                     payload_deadline, payload_priority, payload_sent_at,
+                     payload_trace)
 
 logger = logging.getLogger("analytics_zoo_tpu.serving.generation")
 
@@ -75,16 +87,43 @@ _GEN_STEPS = _tm.counter("zoo_gen_decode_steps_total",
                          "Multi-slot decode steps executed")
 _GEN_ITL = _tm.histogram("zoo_gen_inter_token_seconds",
                          "Per-stream time between consecutive emitted tokens",
-                         buckets=(.001, .0025, .005, .01, .025, .05, .1,
-                                  .25, .5, 1.0, 2.5))
+                         buckets=_tm.LATENCY_LADDER)
 _GEN_TTFT = _tm.histogram(
     "zoo_gen_ttft_seconds",
     "Per-stream time from submit to the first emitted token, by priority "
-    "class — queue wait + prefill wait + prefill compute (chunked prefill "
-    "makes this a scheduling outcome: the budget trades running streams' "
-    "ITL against new streams' TTFT)",
-    labels=("priority",),
-    buckets=(.005, .01, .025, .05, .1, .25, .5, 1.0, 2.5, 5.0, 10.0))
+    "class: zoo_gen_queue_wait_seconds + zoo_gen_prefill_seconds of the same "
+    "request (chunked prefill makes this a scheduling outcome: the budget "
+    "trades running streams' ITL against new streams' TTFT)",
+    labels=("priority",), buckets=_tm.LATENCY_LADDER)
+_GEN_INGRESS = _tm.histogram(
+    "zoo_gen_ingress_seconds",
+    "GenerationClient.submit (a wall-clock stamp in the payload; absent "
+    "from old clients, then not observed) to the engine's source thread "
+    "taking the entry off the broker stream", buckets=_tm.LATENCY_LADDER)
+_GEN_QUEUE_WAIT = _tm.histogram(
+    "zoo_gen_queue_wait_seconds",
+    "Per-stream time from submit to leaving the backlog for a decode slot, "
+    "by priority class (the first leg of zoo_gen_ttft_seconds)",
+    labels=("priority",), buckets=_tm.LATENCY_LADDER)
+_GEN_PREFILL = _tm.histogram(
+    "zoo_gen_prefill_seconds",
+    "Per-stream time from leaving the backlog to the first token on the "
+    "host, by prefill bucket ('chunked' under chunked prefill, the waits "
+    "between chunks included): the second leg of zoo_gen_ttft_seconds",
+    labels=("bucket",), buckets=_tm.LATENCY_LADDER)
+_GEN_EGRESS = _tm.histogram(
+    "zoo_gen_egress_seconds",
+    "A frame handed to the engine's sink queue until its XADD to the "
+    "broker returned", buckets=_tm.LATENCY_LADDER)
+#: the exclusive phases of the decode loop's thread (docs/observability.md)
+LOOP_PHASES = ("swap", "admit", "prefill_host", "prefill_wait", "decode_host",
+               "decode_wait", "emit", "idle", "other")
+_GEN_LOOP_SECONDS = _tm.counter(
+    "zoo_gen_loop_seconds_total",
+    "Seconds of the continuous batcher's loop thread by exclusive phase; "
+    "the phases sum to the thread's wall time (the *_wait phases are the "
+    "host waiting for device work, idle is the wait for a request)",
+    labels=("phase",))
 _GEN_PREFILL_CHUNKS = _tm.counter(
     "zoo_gen_prefill_chunks_total",
     "Chunked-prefill dispatches executed (each fills at most "
@@ -159,11 +198,109 @@ def _next_pow2(n: int) -> int:
     return b
 
 
+class _Phase:
+    """``with clock.phase(name):`` (see :class:`_LoopClock`)."""
+
+    __slots__ = ("_clock", "_name")
+
+    def __init__(self, clock: "_LoopClock", name: str):
+        self._clock = clock
+        self._name = name
+
+    def __enter__(self):
+        self._clock._push(self._name)
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._clock._pop()
+        return False
+
+
+_NO_PHASE = contextlib.nullcontext()
+
+
+class _LoopClock:
+    """Closed accounting of the decode loop thread's wall time.
+
+    The thread is in exactly one phase at a time: entering a phase suspends
+    the one around it and leaving resumes it, so the phases are exclusive,
+    and what no phase claims of a loop pass goes to ``other`` when the pass
+    closes. Their sum is the thread's wall time, to the clock's resolution.
+    Each stretch is a :func:`telemetry.region` ``serving.gen.loop.<phase>``:
+    flat, never nested, so a profiler trace shows them side by side on the
+    loop thread's line. A thread other than the loop's (``close()`` failing
+    the streams left) gets a no-op."""
+
+    def __init__(self):
+        self._children = {p: _GEN_LOOP_SECONDS.labels(phase=p)
+                          for p in LOOP_PHASES}
+        #: this batcher's own seconds by phase (the counter family is shared
+        #: by every batcher of the process); read by ``stats()``
+        self.seconds: Dict[str, float] = dict.fromkeys(LOOP_PHASES, 0.0)
+        self._tid: Optional[int] = None
+        self._stack: List[str] = []     # phases entered, innermost last
+        self._open = None               # the innermost phase's running region
+        self._pass_t0 = 0.0
+        self._accounted = 0.0           # seconds of this pass in some phase
+
+    def begin(self) -> None:
+        """The calling thread is the loop from now on (a respawn too)."""
+        self._tid = threading.get_ident()
+        self._stack.clear()
+        self._open = None
+        self._accounted = 0.0
+        self._pass_t0 = time.perf_counter()
+
+    def phase(self, name: str):
+        if threading.get_ident() != self._tid:
+            return _NO_PHASE
+        return _Phase(self, name)
+
+    def _start(self, name: str) -> None:
+        self._open = _tm.region("serving.gen.loop." + name,
+                                self._children[name])
+        self._open.__enter__()
+
+    def _stop(self, name: str) -> None:
+        self._open.__exit__(None, None, None)
+        dt = self._open.seconds
+        self.seconds[name] += dt
+        self._accounted += dt
+
+    def _push(self, name: str) -> None:
+        outer = self._stack[-1] if self._stack else None
+        self._stack.append(name)
+        if outer == name:               # the same phase goes on
+            return
+        if outer is not None:
+            self._stop(outer)
+        self._start(name)
+
+    def _pop(self) -> None:
+        name = self._stack.pop()
+        outer = self._stack[-1] if self._stack else None
+        if outer == name:
+            return
+        self._stop(name)
+        if outer is not None:
+            self._start(outer)
+
+    def close_pass(self) -> None:
+        """End of a loop pass, no phase open: the pass's remainder is
+        ``other``, and the next pass starts at the same reading."""
+        now = time.perf_counter()
+        other = max(0.0, now - self._pass_t0 - self._accounted)
+        self._children["other"].inc(other)
+        self.seconds["other"] += other
+        self._pass_t0 = now
+        self._accounted = 0.0
+
+
 class _Request:
     """One generation request's host-side state."""
 
     __slots__ = ("uri", "prompt", "max_new_tokens", "temperature", "seed",
-                 "eos_id", "on_chunk", "ctx", "submitted_t", "cancelled",
+                 "eos_id", "on_chunk", "ctx", "submitted_t", "dequeued_t",
+                 "first_token_t", "prefill_bucket", "cancelled",
                  "last_emit_t", "priority", "deadline", "seq",
                  "cached_prefix_tokens")
 
@@ -178,9 +315,15 @@ class _Request:
         self.eos_id = eos_id
         self.on_chunk = on_chunk
         self.ctx = ctx
+        # the server-side timeline, on time.perf_counter(): submitted,
+        # taken from the backlog for a slot (the latest time, if a dry pool
+        # sent it back), first token on the host, latest token
         self.submitted_t = time.perf_counter()
-        self.cancelled = False
+        self.dequeued_t = self.submitted_t
+        self.first_token_t: Optional[float] = None
         self.last_emit_t: Optional[float] = None
+        self.prefill_bucket = ""        # zoo_gen_prefill_seconds' label
+        self.cancelled = False
         # overload QoS (serving/qos.py): admission runs in (priority,
         # deadline) order; critical requests may preempt bulk decode slots
         self.priority = _qos.normalize_priority(priority)
@@ -256,7 +399,7 @@ class _Slot:
 
     __slots__ = ("request", "length", "generated", "last_token", "pages",
                  "handle", "history", "pending_drafts", "prefix_keys",
-                 "prefilling", "prefill_done", "chunks", "admitted_t")
+                 "prefilling", "prefill_done", "chunks")
 
     def __init__(self, request: _Request, length: int, last_token: int,
                  pages: List[int], history: Optional[List[int]] = None,
@@ -272,7 +415,6 @@ class _Slot:
         self.prefilling = False
         self.prefill_done = 0           # prompt tokens already in the cache
         self.chunks = 0                 # chunk dispatches spent on this slot
-        self.admitted_t = time.perf_counter()
         # full token sequence (prompt + emitted) — the self-drafting k-gram
         # proposer's corpus; maintained in plain mode too so a hot-swap into
         # speculative mode can draft for in-flight streams immediately
@@ -450,6 +592,7 @@ class ContinuousBatcher:
         # per-entry utilization field
         self._occupied_slot_steps = 0
         self._decode_tokens = 0          # decode-phase tokens (excl prefill)
+        self._clock = _LoopClock()
 
         cfg = self.cfg
         # Donate the KV page pool into both dispatches (the cache-alias
@@ -640,27 +783,15 @@ class ContinuousBatcher:
             return sum(s is not None for s in self._slots)
 
     def _loop(self):
+        clock = self._clock
+        clock.begin()
         try:
             while not self._stop.is_set():
                 # deterministic fault site: the kill-the-engine-mid-stream
                 # drill severs the loop here; the supervisor respawns it
                 chaos_point("serving.generate")
                 try:
-                    self._apply_pending_swap()
-                    self._admit()
-                    if self.prefill_chunk_tokens:
-                        # spend at most one budget of prefill chunks, THEN
-                        # decode: running streams advance every loop pass no
-                        # matter how deep the prefill backlog (starvation-
-                        # free by construction)
-                        self._prefill_chunks()
-                    if self.active_slots() == 0:
-                        if (self._pending.empty() and not self._backlog
-                                and not self._preempted):
-                            self._wake.wait(timeout=0.05)
-                            self._wake.clear()
-                        continue
-                    self._step()
+                    self._loop_pass()
                 except Exception as e:
                     # a DETERMINISTIC step failure (XLA error, poisoned
                     # cache state) must fail the in-flight streams, not
@@ -670,10 +801,33 @@ class ContinuousBatcher:
                     logger.exception("decode step failed; failing the "
                                      "active streams")
                     self._fail_all_active(f"decode step failed: {e}")
+                clock.close_pass()
         except WorkerKilled:
             logger.warning("generation decode loop killed mid-stream; "
                            "slots/cache intact, awaiting respawn")
+        finally:
+            clock.close_pass()
+
+    def _loop_pass(self):
+        clock = self._clock
+        if self._pending_swap is not None:
+            with clock.phase("swap"):
+                self._apply_pending_swap()
+        with clock.phase("admit"):
+            self._admit()
+            if self.prefill_chunk_tokens:
+                # spend at most one budget of prefill chunks, THEN decode:
+                # running streams advance every loop pass no matter how deep
+                # the prefill backlog (starvation-free by construction)
+                self._prefill_chunks()
+        if self.active_slots() == 0:
+            if (self._pending.empty() and not self._backlog
+                    and not self._preempted):
+                with clock.phase("idle"):
+                    self._wake.wait(timeout=0.05)
+                self._wake.clear()
             return
+        self._step()
 
     def _fail_all_active(self, error: str):
         with self._lock:
@@ -827,6 +981,7 @@ class ContinuousBatcher:
                 if not self._preempt_for(cand_new):
                     return
             req = self._backlog.pop(0)
+            req.dequeued_t = time.perf_counter()
             if req.uri in self._cancelled_uris:
                 self._cancelled_uris.remove(req.uri)
                 req.cancelled = True
@@ -896,7 +1051,7 @@ class ContinuousBatcher:
             # chunked mode routes EVERY prefill through the chunk executable
             # (short prompts take one chunk) — one code path, one identity
             return self._begin_chunked_prefill(req)
-        t_admit = time.perf_counter()
+        clock = self._clock
         slot_idx = self._slots.index(None)
         cfg = self.cfg
         n_prompt = int(req.prompt.size)
@@ -951,7 +1106,8 @@ class ContinuousBatcher:
                         "prefix-share write isolation violated: "
                         + "; ".join(f.message for f in findings))
             with _tm.span("serving.gen.prefill", remote=req.ctx, uri=req.uri,
-                          bucket=bucket, cached_tokens=start):
+                          bucket=bucket, cached_tokens=start), \
+                    clock.phase("prefill_host"):
                 ids = np.zeros((1, bucket), np.int32)
                 ids[0, :n_suffix] = req.prompt[start:]
                 table = np.full((1, cfg.pages_per_slot), SCRATCH_PAGE,
@@ -970,7 +1126,8 @@ class ContinuousBatcher:
                     logits, np.array([req.seed], np.uint32),
                     np.array([0], np.uint32),
                     np.array([req.temperature], np.float32))
-                tok = int(np.asarray(first)[0])
+                with clock.phase("prefill_wait"):
+                    tok = int(np.asarray(first)[0])
             if self.prefix_cache is not None:
                 # deterministic fault site: the chaos drill kills the loop
                 # HERE — after compute, before publish. The handler below
@@ -995,6 +1152,7 @@ class ContinuousBatcher:
             self.pool.release(held)
             raise
         self.prefill_buckets.add(bucket)
+        req.prefill_bucket = str(bucket)
         _GEN_TOKENS.labels(phase="prefill").inc(n_suffix)
         if start:
             req.cached_prefix_tokens = start
@@ -1003,7 +1161,6 @@ class ContinuousBatcher:
         slot = _Slot(req, n_prompt, tok, list(row),
                      history=req.prompt.tolist() + [tok],
                      prefix_keys=keys)
-        slot.admitted_t = t_admit
         if self.spec_k >= 2:
             from ..ops.speculative import propose_kgram
 
@@ -1013,8 +1170,9 @@ class ContinuousBatcher:
             self._table[slot_idx, :] = SCRATCH_PAGE
             self._table[slot_idx, :n_pg] = row
             self._slots[slot_idx] = slot
-        self._emit(slot, [tok])
-        self._maybe_finish(slot_idx)
+        with clock.phase("emit"):
+            self._emit(slot, [tok])
+            self._maybe_finish(slot_idx)
 
     # chunked prefill (ISSUE 20) ----------------------------------------------
 
@@ -1088,6 +1246,7 @@ class ContinuousBatcher:
             req.cached_prefix_tokens = start
             self.prefix_tokens_saved += start
             _GEN_PREFIX_TOKENS_SAVED.inc(start)
+        req.prefill_bucket = "chunked"
         slot = _Slot(req, n_prompt, -1, list(row), prefix_keys=keys)
         slot.generated = 0              # token 0 samples at finalize
         slot.prefilling = True
@@ -1166,7 +1325,8 @@ class ContinuousBatcher:
         chaos_point("prefill.chunk")
         try:
             with _tm.span("serving.gen.prefill.chunk", remote=req.ctx,
-                          uri=req.uri, n_done=n_done, n_valid=n_valid):
+                          uri=req.uri, n_done=n_done, n_valid=n_valid), \
+                    self._clock.phase("prefill_host"):
                 ids = np.zeros((1, ct), np.int32)
                 ids[0, :n_valid] = req.prompt[n_done:n_done + n_valid]
                 # WIDE table: a chunk ending at position n_done+ct-1 can
@@ -1210,11 +1370,14 @@ class ContinuousBatcher:
         order matters: a chaos kill at the publish site leaves a clean
         decoding slot that merely never published — nothing to unwind."""
         req = slot.request
-        first = self._sample(
-            logits, np.array([req.seed], np.uint32),
-            np.array([0], np.uint32),
-            np.array([req.temperature], np.float32))
-        tok = int(np.asarray(first)[0])
+        clock = self._clock
+        with clock.phase("prefill_host"):
+            first = self._sample(
+                logits, np.array([req.seed], np.uint32),
+                np.array([0], np.uint32),
+                np.array([req.temperature], np.float32))
+            with clock.phase("prefill_wait"):
+                tok = int(np.asarray(first)[0])
         slot.last_token = tok
         slot.generated = 1
         slot.history = req.prompt.tolist() + [tok]
@@ -1235,8 +1398,9 @@ class ContinuousBatcher:
                              reason="budget", entries=sweep["entries"],
                              pages=sweep["pages"],
                              held_pages=sweep["held_pages"])
-        self._emit(slot, [tok])
-        self._maybe_finish(idx)
+        with clock.phase("emit"):
+            self._emit(slot, [tok])
+            self._maybe_finish(idx)
 
     # decode ------------------------------------------------------------------
 
@@ -1304,84 +1468,89 @@ class ContinuousBatcher:
         or squeezed out of the k-page lookahead by a dry pool) rides the
         SAME single-token executable plain decode uses, so those streams
         emit and truncate exactly as the non-speculative loop would."""
-        cfg = self.cfg
-        b = self.n_slots
-        ids = np.zeros(b, np.int32)
-        lengths = np.zeros(b, np.int32)
-        seeds = np.zeros(b, np.uint32)
-        tok_idx = np.zeros(b, np.uint32)
-        temps = np.zeros(b, np.float32)
-        finishes = []
-        live: List[int] = []
-        prefilling: List[int] = []
-        with self._lock:
-            for i in (range(b) if rows is None else rows):
-                slot = self._slots[i]
+        clock = self._clock
+        with clock.phase("decode_host"):
+            cfg = self.cfg
+            b = self.n_slots
+            ids = np.zeros(b, np.int32)
+            lengths = np.zeros(b, np.int32)
+            seeds = np.zeros(b, np.uint32)
+            tok_idx = np.zeros(b, np.uint32)
+            temps = np.zeros(b, np.float32)
+            finishes = []
+            live: List[int] = []
+            prefilling: List[int] = []
+            with self._lock:
+                for i in (range(b) if rows is None else rows):
+                    slot = self._slots[i]
+                    if slot is None:
+                        continue
+                    if slot.request.cancelled:
+                        finishes.append(self._retire_locked(i, "cancelled"))
+                        continue
+                    if slot.prefilling:
+                        # mid-prefill: masked out of the dispatch below — an
+                        # unmasked row would take a position-0 K/V write into
+                        # its REAL first page (silent prompt corruption)
+                        prefilling.append(i)
+                        continue
+                    # grow: the position being written this step needs its page
+                    p = slot.length // cfg.page_size
+                    if self._table[i, p] == SCRATCH_PAGE:
+                        try:
+                            (pg,) = self._alloc_pages(1)
+                        except OutOfPages:
+                            finishes.append(self._retire_locked(
+                                i, "truncated",
+                                error="kv page pool exhausted"))
+                            continue
+                        self._table[i, p] = pg
+                        slot.pages.append(pg)
+                        self._note_pool_peak()
+                    ids[i] = slot.last_token
+                    lengths[i] = slot.length
+                    seeds[i] = slot.request.seed
+                    tok_idx[i] = slot.generated
+                    temps[i] = slot.request.temperature
+                    live.append(i)
+                table = self._table.copy()
+            if rows is not None:
+                for i in range(b):
+                    if i not in live:  # mask non-members (incl. spec-active)
+                        table[i, :] = SCRATCH_PAGE
+            else:
+                for i in prefilling:
+                    table[i, :] = SCRATCH_PAGE
+            for fin in finishes:       # final-frame callbacks OUTSIDE the lock
+                self._finish_cb(*fin)
+            if not live:
+                return
+            self.decode_shapes.add((b, cfg.pages_per_slot, cfg.page_size))
+            t0 = time.monotonic()
+            next_ids, _logits, self.cache = self._decode(
+                self.params, self.cache, ids, lengths, table, seeds, tok_idx,
+                temps)
+            with clock.phase("decode_wait"):
+                next_ids = np.asarray(next_ids)
+            self.step_ema.observe(time.monotonic() - t0)
+            self.steps += 1
+            self._occupied_slot_steps += len(live)
+            _GEN_STEPS.inc()
+            _mw.sample("serving.decode")
+        with clock.phase("emit"):
+            for i in live:
+                with self._lock:
+                    slot = self._slots[i]
                 if slot is None:
                     continue
-                if slot.request.cancelled:
-                    finishes.append(self._retire_locked(i, "cancelled"))
-                    continue
-                if slot.prefilling:
-                    # mid-prefill: masked out of the dispatch below — an
-                    # unmasked row would take a position-0 K/V write into
-                    # its REAL first page (silent prompt corruption)
-                    prefilling.append(i)
-                    continue
-                # grow: the position being written this step needs its page
-                p = slot.length // cfg.page_size
-                if self._table[i, p] == SCRATCH_PAGE:
-                    try:
-                        (pg,) = self._alloc_pages(1)
-                    except OutOfPages:
-                        finishes.append(self._retire_locked(
-                            i, "truncated", error="kv page pool exhausted"))
-                        continue
-                    self._table[i, p] = pg
-                    slot.pages.append(pg)
-                    self._note_pool_peak()
-                ids[i] = slot.last_token
-                lengths[i] = slot.length
-                seeds[i] = slot.request.seed
-                tok_idx[i] = slot.generated
-                temps[i] = slot.request.temperature
-                live.append(i)
-            table = self._table.copy()
-        if rows is not None:
-            for i in range(b):
-                if i not in live:  # mask non-members (incl. spec-active)
-                    table[i, :] = SCRATCH_PAGE
-        else:
-            for i in prefilling:
-                table[i, :] = SCRATCH_PAGE
-        for fin in finishes:       # final-frame callbacks OUTSIDE the lock
-            self._finish_cb(*fin)
-        if not live:
-            return
-        self.decode_shapes.add((b, cfg.pages_per_slot, cfg.page_size))
-        t0 = time.monotonic()
-        next_ids, _logits, self.cache = self._decode(
-            self.params, self.cache, ids, lengths, table, seeds, tok_idx,
-            temps)
-        next_ids = np.asarray(next_ids)
-        self.step_ema.observe(time.monotonic() - t0)
-        self.steps += 1
-        self._occupied_slot_steps += len(live)
-        _GEN_STEPS.inc()
-        _mw.sample("serving.decode")
-        for i in live:
-            with self._lock:
-                slot = self._slots[i]
-            if slot is None:
-                continue
-            tok = int(next_ids[i])
-            slot.length += 1           # last_token is now cached
-            slot.last_token = tok
-            slot.generated += 1
-            slot.history.append(tok)
-            self._decode_tokens += 1
-            self._emit(slot, [tok])
-            self._maybe_finish(i)
+                tok = int(next_ids[i])
+                slot.length += 1           # last_token is now cached
+                slot.last_token = tok
+                slot.generated += 1
+                slot.history.append(tok)
+                self._decode_tokens += 1
+                self._emit(slot, [tok])
+                self._maybe_finish(i)
 
     def _step_spec(self):
         """One speculative verify step: draft k-1 tokens per slot (k-gram
@@ -1397,137 +1566,142 @@ class ContinuousBatcher:
         stream emits: not its tokens, and not its truncation point."""
         from ..ops.speculative import propose_kgram
 
-        cfg = self.cfg
-        b = self.n_slots
-        k = self.spec_k
-        ids = np.zeros((b, k), np.int32)
-        lengths = np.zeros(b, np.int32)
-        seeds = np.zeros(b, np.uint32)
-        tok_idx = np.zeros(b, np.uint32)
-        temps = np.zeros(b, np.float32)
-        finishes = []
-        tail: List[int] = []
-        prefilling: List[int] = []
-        with self._lock:
-            for i, slot in enumerate(self._slots):
+        clock = self._clock
+        with clock.phase("decode_host"):
+            cfg = self.cfg
+            b = self.n_slots
+            k = self.spec_k
+            ids = np.zeros((b, k), np.int32)
+            lengths = np.zeros(b, np.int32)
+            seeds = np.zeros(b, np.uint32)
+            tok_idx = np.zeros(b, np.uint32)
+            temps = np.zeros(b, np.float32)
+            finishes = []
+            tail: List[int] = []
+            prefilling: List[int] = []
+            with self._lock:
+                for i, slot in enumerate(self._slots):
+                    if slot is None:
+                        continue
+                    if slot.request.cancelled:
+                        finishes.append(self._retire_locked(i, "cancelled"))
+                        continue
+                    if slot.prefilling:
+                        # mid-prefill: masked out of the verify dispatch (and
+                        # NOT a tail row — nothing decodes until finalize)
+                        prefilling.append(i)
+                        continue
+                    if slot.length + k > cfg.max_seq_len:
+                        # tail regime: fewer than k positions remain (or a swap
+                        # raised k mid-stream) — single-token path below; this
+                        # row is masked out of the verify dispatch
+                        tail.append(i)
+                        continue
+                    # grow: the verify step writes positions
+                    # length .. length+k-1; allocate every page they span.
+                    # A dry pool mid-lookahead is NOT a truncation — plain
+                    # decode would only need the first of these pages — so the
+                    # slot takes the single-token path this pass instead
+                    # (pages already claimed stay; they back later positions)
+                    first_pg = slot.length // cfg.page_size
+                    last_pg = (slot.length + k - 1) // cfg.page_size
+                    dry = False
+                    for p in range(first_pg, last_pg + 1):
+                        if self._table[i, p] != SCRATCH_PAGE:
+                            continue
+                        try:
+                            (pg,) = self._alloc_pages(1)
+                        except OutOfPages:
+                            tail.append(i)
+                            dry = True
+                            break
+                        self._table[i, p] = pg
+                        slot.pages.append(pg)
+                        self._note_pool_peak()
+                    if dry:
+                        continue
+                    drafts = slot.pending_drafts
+                    if drafts is None or len(drafts) != k - 1:
+                        drafts = propose_kgram(slot.history, k - 1,
+                                               self.spec_ngram)
+                        slot.pending_drafts = drafts
+                    ids[i, 0] = slot.last_token
+                    ids[i, 1:] = drafts
+                    lengths[i] = slot.length
+                    seeds[i] = slot.request.seed
+                    tok_idx[i] = slot.generated
+                    temps[i] = slot.request.temperature
+                table = self._table.copy()
+                active = [i for i, s in enumerate(self._slots)
+                          if s is not None and not s.prefilling]
+            spec_rows = [i for i in active if i not in tail]
+            for i in tail + prefilling:
+                # scratch these rows' tables in the COPY: their verify-step
+                # writes land in scratch, never past their table's end (tail)
+                # and never into a half-prefilled prompt (prefilling)
+                table[i, :] = SCRATCH_PAGE
+            for fin in finishes:       # final-frame callbacks OUTSIDE the lock
+                self._finish_cb(*fin)
+            if not spec_rows:
+                if tail:
+                    self._step_plain(rows=tail)
+                return
+            self.decode_shapes.add((b, cfg.pages_per_slot, cfg.page_size, k))
+            t0 = time.monotonic()
+            accepted, tokens, draft_probs, self.cache = self._verify_fn(k)(
+                self.params, self.cache, ids, lengths, table, seeds, tok_idx,
+                temps)
+            with clock.phase("decode_wait"):
+                accepted = np.asarray(accepted)
+                tokens = np.asarray(tokens)
+                draft_probs = np.asarray(draft_probs)
+            self.step_ema.observe(time.monotonic() - t0)
+            self.steps += 1
+            self.spec_steps += 1
+            self._occupied_slot_steps += len(spec_rows)
+            _GEN_STEPS.inc()
+            _GEN_SPEC_STEPS.inc()
+            _mw.sample("serving.decode")
+        with clock.phase("emit"):
+            for i in spec_rows:
+                with self._lock:
+                    slot = self._slots[i]
                 if slot is None:
                     continue
-                if slot.request.cancelled:
-                    finishes.append(self._retire_locked(i, "cancelled"))
-                    continue
-                if slot.prefilling:
-                    # mid-prefill: masked out of the verify dispatch (and
-                    # NOT a tail row — nothing decodes until finalize)
-                    prefilling.append(i)
-                    continue
-                if slot.length + k > cfg.max_seq_len:
-                    # tail regime: fewer than k positions remain (or a swap
-                    # raised k mid-stream) — single-token path below; this
-                    # row is masked out of the verify dispatch
-                    tail.append(i)
-                    continue
-                # grow: the verify step writes positions
-                # length .. length+k-1; allocate every page they span.
-                # A dry pool mid-lookahead is NOT a truncation — plain
-                # decode would only need the first of these pages — so the
-                # slot takes the single-token path this pass instead
-                # (pages already claimed stay; they back later positions)
-                first_pg = slot.length // cfg.page_size
-                last_pg = (slot.length + k - 1) // cfg.page_size
-                dry = False
-                for p in range(first_pg, last_pg + 1):
-                    if self._table[i, p] != SCRATCH_PAGE:
-                        continue
-                    try:
-                        (pg,) = self._alloc_pages(1)
-                    except OutOfPages:
-                        tail.append(i)
-                        dry = True
+                req = slot.request
+                a = int(accepted[i])
+                # emit the confirmed run + the correction/bonus, clipped at the
+                # request budget / eos (any clip also satisfies _maybe_finish,
+                # so a partially-consumed run always retires)
+                emit: List[int] = []
+                for tok in (int(tokens[i, j]) for j in range(a + 1)):
+                    emit.append(tok)
+                    if req.eos_id is not None and tok == req.eos_id:
                         break
-                    self._table[i, p] = pg
-                    slot.pages.append(pg)
-                    self._note_pool_peak()
-                if dry:
-                    continue
-                drafts = slot.pending_drafts
-                if drafts is None or len(drafts) != k - 1:
-                    drafts = propose_kgram(slot.history, k - 1,
-                                           self.spec_ngram)
-                    slot.pending_drafts = drafts
-                ids[i, 0] = slot.last_token
-                ids[i, 1:] = drafts
-                lengths[i] = slot.length
-                seeds[i] = slot.request.seed
-                tok_idx[i] = slot.generated
-                temps[i] = slot.request.temperature
-            table = self._table.copy()
-            active = [i for i, s in enumerate(self._slots)
-                      if s is not None and not s.prefilling]
-        spec_rows = [i for i in active if i not in tail]
-        for i in tail + prefilling:
-            # scratch these rows' tables in the COPY: their verify-step
-            # writes land in scratch, never past their table's end (tail)
-            # and never into a half-prefilled prompt (prefilling)
-            table[i, :] = SCRATCH_PAGE
-        for fin in finishes:       # final-frame callbacks OUTSIDE the lock
-            self._finish_cb(*fin)
-        if not spec_rows:
-            if tail:
-                self._step_plain(rows=tail)
-            return
-        self.decode_shapes.add((b, cfg.pages_per_slot, cfg.page_size, k))
-        t0 = time.monotonic()
-        accepted, tokens, draft_probs, self.cache = self._verify_fn(k)(
-            self.params, self.cache, ids, lengths, table, seeds, tok_idx,
-            temps)
-        accepted = np.asarray(accepted)
-        tokens = np.asarray(tokens)
-        draft_probs = np.asarray(draft_probs)
-        self.step_ema.observe(time.monotonic() - t0)
-        self.steps += 1
-        self.spec_steps += 1
-        self._occupied_slot_steps += len(spec_rows)
-        _GEN_STEPS.inc()
-        _GEN_SPEC_STEPS.inc()
-        _mw.sample("serving.decode")
-        for i in spec_rows:
-            with self._lock:
-                slot = self._slots[i]
-            if slot is None:
-                continue
-            req = slot.request
-            a = int(accepted[i])
-            # emit the confirmed run + the correction/bonus, clipped at the
-            # request budget / eos (any clip also satisfies _maybe_finish,
-            # so a partially-consumed run always retires)
-            emit: List[int] = []
-            for tok in (int(tokens[i, j]) for j in range(a + 1)):
-                emit.append(tok)
-                if req.eos_id is not None and tok == req.eos_id:
-                    break
-                if slot.generated + len(emit) >= req.max_new_tokens:
-                    break
-            slot.length += a + 1       # certain token + accepted drafts
-            slot.last_token = emit[-1]
-            slot.generated += len(emit)
-            slot.history.extend(emit)
-            slot.pending_drafts = None
-            self._decode_tokens += len(emit)
-            self.spec_drafted += k - 1
-            self.spec_accepted += a
-            _GEN_SPEC_TOKENS.labels(kind="drafted").inc(k - 1)
-            _GEN_SPEC_TOKENS.labels(kind="accepted").inc(a)
-            for j in range(min(a + 1, k - 1)):
-                _GEN_SPEC_ACCEPT_PROB.observe(float(draft_probs[i, j]))
-            self._emit(slot, emit)
-            self._maybe_finish(i)
-            with self._lock:
-                slot = self._slots[i]
-            if slot is not None:
-                # draft the NEXT proposals now: a slot preempted before its
-                # next verify parks carrying this pending draft state
-                slot.pending_drafts = propose_kgram(
-                    slot.history, k - 1, self.spec_ngram)
+                    if slot.generated + len(emit) >= req.max_new_tokens:
+                        break
+                slot.length += a + 1       # certain token + accepted drafts
+                slot.last_token = emit[-1]
+                slot.generated += len(emit)
+                slot.history.extend(emit)
+                slot.pending_drafts = None
+                self._decode_tokens += len(emit)
+                self.spec_drafted += k - 1
+                self.spec_accepted += a
+                _GEN_SPEC_TOKENS.labels(kind="drafted").inc(k - 1)
+                _GEN_SPEC_TOKENS.labels(kind="accepted").inc(a)
+                for j in range(min(a + 1, k - 1)):
+                    _GEN_SPEC_ACCEPT_PROB.observe(float(draft_probs[i, j]))
+                self._emit(slot, emit)
+                self._maybe_finish(i)
+                with self._lock:
+                    slot = self._slots[i]
+                if slot is not None:
+                    # draft the NEXT proposals now: a slot preempted before its
+                    # next verify parks carrying this pending draft state
+                    with clock.phase("decode_host"):
+                        slot.pending_drafts = propose_kgram(
+                            slot.history, k - 1, self.spec_ngram)
         if tail:
             self._step_plain(rows=tail)
 
@@ -1538,15 +1712,24 @@ class ContinuousBatcher:
         if req.last_emit_t is not None:
             _GEN_ITL.observe(now - req.last_emit_t)
         else:
-            # first token of the stream: TTFT (submit -> first emit) plus
-            # the prefill accounting the bench's drive() reads off the
-            # first frame (chunks spent, admission -> first-token wait)
+            # first token of the stream: TTFT and its two legs (submit ->
+            # leaving the backlog -> first token on the host), which also
+            # ride the first frame's meta with the chunks spent (the bench's
+            # drive() reads them there)
+            req.first_token_t = now
+            queue_s = req.dequeued_t - req.submitted_t
+            prefill_s = now - req.dequeued_t
+            _GEN_QUEUE_WAIT.labels(priority=req.priority).observe(queue_s)
+            _GEN_PREFILL.labels(bucket=req.prefill_bucket).observe(prefill_s)
             _GEN_TTFT.labels(priority=req.priority).observe(
-                now - req.submitted_t)
-            meta["ttft_s"] = round(now - req.submitted_t, 6)
+                queue_s + prefill_s)
+            _tm.record_span("serving.gen.queue", req.submitted_t,
+                            req.dequeued_t, remote=req.ctx, uri=req.uri,
+                            priority=req.priority)
+            meta["ttft_s"] = round(queue_s + prefill_s, 6)
             meta["chunks"] = slot.chunks
-            meta["prefill_wait_ms"] = round(
-                (now - slot.admitted_t) * 1e3, 3)
+            meta["queue_wait_ms"] = round(queue_s * 1e3, 3)
+            meta["prefill_wait_ms"] = round(prefill_s * 1e3, 3)
         req.last_emit_t = now
         self.tokens_generated += len(tokens)
         _GEN_TOKENS.labels(phase="decode").inc(len(tokens))
@@ -1613,12 +1796,20 @@ class ContinuousBatcher:
             # shed outcomes: the computed backoff rides the final frame so
             # HTTP/broker consumers can relay an honest Retry-After
             meta["retry_after_s"] = round(retry_after_s, 4)
+        if req.first_token_t is not None:
+            # the request's server-side timeline, retired now
+            meta["timeline_s"] = {
+                "queue": round(req.dequeued_t - req.submitted_t, 6),
+                "prefill": round(req.first_token_t - req.dequeued_t, 6),
+                "decode": round(req.last_emit_t - req.first_token_t, 6),
+                "total": round(time.perf_counter() - req.submitted_t, 6)}
         if req.on_chunk is not None:
-            try:
-                req.on_chunk(tokens, True, meta)
-            except Exception:   # a consumer bug must not poison the loop
-                logger.exception("final-frame callback failed for %s",
-                                 req.uri)
+            with self._clock.phase("emit"):
+                try:
+                    req.on_chunk(tokens, True, meta)
+                except Exception:   # a consumer bug must not poison the loop
+                    logger.exception("final-frame callback failed for %s",
+                                     req.uri)
 
     # ------------------------------------------------------------- hot swap
 
@@ -1759,6 +1950,10 @@ class ContinuousBatcher:
             "preempted_parked": preempted,
             "backlog": len(self._backlog),
             "step_ema_s": round(self.step_ema.value(), 6),
+            # the loop thread's seconds by exclusive phase since start (this
+            # batcher's share of zoo_gen_loop_seconds_total)
+            "loop_seconds": {p: round(v, 6)
+                             for p, v in self._clock.seconds.items()},
             "free_pages": self.pool.free_count(),
             "page_capacity": self.pool.capacity,
             "steps": self.steps,
@@ -1968,6 +2163,13 @@ class GenerationEngine:
 
     def _admit_entry(self, entry_id: str, payload: Any):
         ctx = payload_trace(payload)
+        sent_at = payload_sent_at(payload)
+        if sent_at is not None:
+            # across machines the two wall clocks may disagree: a negative
+            # reading is no reading
+            ingress_s = time.time() - sent_at
+            if ingress_s >= 0:
+                _GEN_INGRESS.observe(ingress_s)
         # resolve the reply stream FIRST: a payload with a good uri but a
         # bad field (max_new_tokens="abc") must get its error frame on the
         # stream the client is actually polling
@@ -1978,7 +2180,8 @@ class GenerationEngine:
             # stream (the stream's own final frame reports "cancelled");
             # the cancel entry itself just needs acking
             self.batcher.cancel_uri(uri)
-            self._sink_q.put(("ack", entry_id, uri, 0, [], {}, False, None))
+            self._sink_q.put(("ack", entry_id, uri, 0, [], {}, False, None,
+                              None))
             return
         try:
             prompt = np.asarray(payload["prompt"], np.int32).reshape(-1)
@@ -1997,7 +2200,7 @@ class GenerationEngine:
             self._sink_q.put(("chunk", entry_id, uri, 0, [],
                               {"outcome": "error",
                                "error": f"malformed request: {e}"}, True,
-                              ctx))
+                              ctx, time.perf_counter()))
             return
         seq_counter = [0]
         t0 = time.perf_counter()
@@ -2011,8 +2214,11 @@ class GenerationEngine:
                 _tm.record_span("serving.gen.stream", t0, time.perf_counter(),
                                 remote=_ctx, uri=_uri,
                                 n_tokens=meta.get("n_tokens", 0))
+            # the last field is the instant the frame was handed over:
+            # zoo_gen_egress_seconds runs from here to its XADD returning
             self._sink_q.put(("chunk", _eid, _uri, seq, list(tokens),
-                              meta if final else {}, final, _ctx))
+                              meta if final else {}, final, _ctx,
+                              time.perf_counter()))
 
         try:
             self.batcher.submit(prompt, uri=uri, on_chunk=on_chunk,
@@ -2020,7 +2226,7 @@ class GenerationEngine:
         except Exception as e:   # invalid prompt (too long, empty)
             self._sink_q.put(("chunk", entry_id, uri, 0, [],
                               {"outcome": "error", "error": str(e)}, True,
-                              ctx))
+                              ctx, time.perf_counter()))
 
     def _sink_loop(self):
         conn = self._connect("gen.sink")
@@ -2034,7 +2240,8 @@ class GenerationEngine:
                     if self._stop.is_set():
                         break
                     continue
-                kind, entry_id, uri, seq, tokens, meta, final, ctx = item
+                (kind, entry_id, uri, seq, tokens, meta, final, ctx,
+                 t_handed) = item
                 try:
                     if kind == "ack":   # cancel frames carry no reply
                         conn.call("XACK", self.stream, self.group, [entry_id])
@@ -2050,6 +2257,7 @@ class GenerationEngine:
                     if ctx is not None:
                         frame[TRACE_KEY] = ctx
                     conn.call("XADD", GEN_OUT_PREFIX + uri, frame)
+                    _GEN_EGRESS.observe(time.perf_counter() - t_handed)
                     if final:
                         conn.call("XACK", self.stream, self.group, [entry_id])
                         self.served_streams += 1
@@ -2112,6 +2320,7 @@ class GenerationClient:
             dl = _qos.deadline_from_ms(deadline_ms)
         with _tm.span("serving.gen.send", uri=uri) as sp:
             payload = {"uri": uri, TRACE_KEY: sp.wire_context(),
+                       SENT_KEY: time.time(),
                        "prompt": np.asarray(prompt, np.int32).reshape(-1),
                        "max_new_tokens": int(max_new_tokens),
                        "temperature": float(temperature), "seed": int(seed),

@@ -48,6 +48,11 @@ MODEL_VERSION_KEY = "model_version"
 PRIORITY_KEY = "priority"
 DEADLINE_KEY = "deadline"
 
+# Wall-clock instant (epoch seconds, like the absolute deadlines above) at
+# which a generation client sent its request: the engine's source observes
+# ``zoo_gen_ingress_seconds`` from it. Old clients omit it.
+SENT_KEY = "sent_at"
+
 
 def payload_priority(payload: Any) -> str:
     """Tolerant read of a request payload's priority class (``normal``
@@ -67,6 +72,13 @@ def payload_deadline(payload: Any) -> Optional[float]:
     if isinstance(payload, dict):
         return normalize_deadline(payload.get(DEADLINE_KEY))
     return None
+
+
+def payload_sent_at(payload: Any) -> Optional[float]:
+    """Tolerant read of a request payload's send instant (epoch seconds;
+    ``None`` when absent/malformed)."""
+    v = payload.get(SENT_KEY) if isinstance(payload, dict) else None
+    return float(v) if isinstance(v, (int, float)) and v > 0 else None
 
 
 def payload_model_version(payload: Any) -> Optional[str]:

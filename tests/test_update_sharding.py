@@ -221,12 +221,6 @@ def test_mixed_precision_trains_with_f32_masters(zoo_ctx):
             "samples", {}).get("", {"count": 0})["count"]
 
     assert count(snap1) > count(snap0)
-    # comm probe fed the exchange-time histogram on the dp mesh
-    def ccount(snap):
-        return snap.get("zoo_train_comm_seconds", {}).get(
-            "samples", {}).get("", {"count": 0})["count"]
-
-    assert ccount(snap1) > ccount(snap0)
 
 
 def test_mixed_precision_gspmd_masters_replicated_mesh(zoo_ctx):
