@@ -109,7 +109,8 @@ class RuleContext:
     # decode-shape-stability: the (shape, dtype-name) of every KV-cache leaf
     # the traced decode step carries — the rule asserts each one reappears
     # unchanged among the outputs (cache threaded, no per-step growth) and
-    # bounds intermediate sizes by the largest cache leaf
+    # bounds intermediate sizes by one K pool over all layers (half the
+    # leaves' bytes)
     decode_cache_avals: Optional[Sequence[Tuple[Tuple[int, ...], str]]] = None
     # memory tier (analysis/memory.py + rules/memory.py):
     # hbm-budget: declared per-device HBM budget; the static live-range peak

@@ -155,8 +155,9 @@ class TransformerLM(Layer, KerasNet):
                       n_pages: Optional[int] = None, dtype=None):
         """Build a paged KV cache for ``n_slots`` concurrent decode
         sequences. Returns ``(KVCacheConfig, cache)`` where ``cache`` is the
-        ``{"k", "v"}`` page-pool pytree threaded through
-        :meth:`prefill`/:meth:`decode_step`."""
+        page-pool pytree threaded through :meth:`prefill`/
+        :meth:`decode_step`: ``{"k": (k_0, ...), "v": (v_0, ...)}``, one pool
+        per layer, each ``(n_pages, page_size, n_heads, head_dim)``."""
         from ..nn.module import compute_dtype
         from ..ops.kv_cache import KVCacheConfig, init_cache
 
@@ -179,6 +180,21 @@ class TransformerLM(Layer, KerasNet):
             dtype=dtype or compute_dtype())
         return cfg, init_cache(cfg)
 
+    def _thread_cache(self, params, cache, h, layer_fn):
+        """Run the blocks in order, each on ITS layer's K and V pool:
+        ``layer_fn(blk, block_params, h, k_pool, v_pool) -> (h, k_pool,
+        v_pool)``. Returns ``(h, cache)`` with the same pytree structure as
+        ``cache`` — no leaf is sliced out of or stored back into a larger
+        array, so with the cache donated every pool aliases input to output
+        and the scatter inside ``layer_fn`` writes in place."""
+        k_pools, v_pools = [], []
+        for i, blk in enumerate(self.blocks):
+            h, kp, vp = layer_fn(blk, params[f"block{i}"], h,
+                                 cache["k"][i], cache["v"][i])
+            k_pools.append(kp)
+            v_pools.append(vp)
+        return h, {"k": tuple(k_pools), "v": tuple(v_pools)}
+
     def prefill(self, params, cache, ids, lengths, table, *, page_size: int):
         """One batched forward that fills the cache and returns last-token
         logits.
@@ -188,7 +204,9 @@ class TransformerLM(Layer, KerasNet):
         ``table``: (B, pages_per_slot) int32 page tables (entries past the
         allocated prefix = scratch). Causal masking means pad positions are
         never attended by valid queries, so their scratch writes are inert.
-        Returns ``(logits (B, V) f32 — at position length-1, cache)``.
+        ``cache`` holds one pool per layer (:func:`~analytics_zoo_tpu.ops.
+        kv_cache.init_cache`); each block's K/V are scattered into its own
+        pool. Returns ``(logits (B, V) f32 — at position length-1, cache)``.
         """
         from ..ops.kv_cache import prefill_write
 
@@ -197,19 +215,19 @@ class TransformerLM(Layer, KerasNet):
         h = jnp.take(params["token_embeddings"], ids, axis=0)
         h = h + params["pos_embeddings"][: ids.shape[1]][None]
         h = as_compute(h)
-        k_cache, v_cache = cache["k"], cache["v"]
-        for i, blk in enumerate(self.blocks):
-            h, k, v = blk.apply_with_kv(params[f"block{i}"], h)
-            k_cache = k_cache.at[i].set(
-                prefill_write(k_cache[i], table, k, page_size=page_size))
-            v_cache = v_cache.at[i].set(
-                prefill_write(v_cache[i], table, v, page_size=page_size))
+
+        def layer(blk, p, h, k_pool, v_pool):
+            h, k, v = blk.apply_with_kv(p, h)
+            return (h, prefill_write(k_pool, table, k, page_size=page_size),
+                    prefill_write(v_pool, table, v, page_size=page_size))
+
+        h, cache = self._thread_cache(params, cache, h, layer)
         h, _ = self.ln_f.apply(params["ln_f"], {}, h)
         last = jnp.take_along_axis(
             h, jnp.maximum(lengths - 1, 0)[:, None, None].astype(jnp.int32),
             axis=1)[:, 0]                                    # (B, hidden)
         logits = last @ jnp.asarray(params["logits_kernel"], last.dtype)
-        return logits.astype(jnp.float32), {"k": k_cache, "v": v_cache}
+        return logits.astype(jnp.float32), cache
 
     def prefill_from(self, params, cache, ids, start, lengths, table, *,
                      page_size: int):
@@ -267,19 +285,16 @@ class TransformerLM(Layer, KerasNet):
         h = jnp.take(params["token_embeddings"], ids, axis=0)
         h = h + jnp.take(params["pos_embeddings"], positions, axis=0)
         h = as_compute(h)
-        k_cache, v_cache = cache["k"], cache["v"]
-        for i, blk in enumerate(self.blocks):
-            h, kp, vp = blk.verify_step(
-                params[f"block{i}"], h, k_cache[i], v_cache[i], table,
-                n_done, page_size=page_size)
-            k_cache = k_cache.at[i].set(kp)
-            v_cache = v_cache.at[i].set(vp)
+        h, cache = self._thread_cache(
+            params, cache, h,
+            lambda blk, p, h, k_pool, v_pool: blk.verify_step(
+                p, h, k_pool, v_pool, table, n_done, page_size=page_size))
         h, _ = self.ln_f.apply(params["ln_f"], {}, h)
         last_row = jnp.maximum(n_valid - 1, 0)
         last = jnp.take_along_axis(
             h, last_row[:, None, None].astype(jnp.int32), axis=1)[:, 0]
         logits = last @ jnp.asarray(params["logits_kernel"], last.dtype)
-        return logits.astype(jnp.float32), {"k": k_cache, "v": v_cache}
+        return logits.astype(jnp.float32), cache
 
     def decode_step(self, params, cache, ids, lengths, table, seeds,
                     token_idx, temperature, *, page_size: int,
@@ -291,8 +306,10 @@ class TransformerLM(Layer, KerasNet):
         position ``ids`` occupies; ``seeds``/``token_idx``/``temperature``:
         (B,) per-request sampling state (see
         :func:`analytics_zoo_tpu.ops.kv_cache.sample_tokens`). Returns
-        ``(next_ids (B,) int32, logits (B, V) f32, cache)`` — cache shapes
-        identical in and out (the decode-shape-stability invariant).
+        ``(next_ids (B,) int32, logits (B, V) f32, cache)`` — one pool per
+        layer, the same pytree with identical shapes in and out (the
+        decode-shape-stability invariant), so a donated cache is written
+        where it lies.
         """
         from ..ops.kv_cache import sample_tokens
 
@@ -301,19 +318,16 @@ class TransformerLM(Layer, KerasNet):
         h = jnp.take(params["token_embeddings"], ids, axis=0)[:, None]
         h = h + jnp.take(params["pos_embeddings"], lengths, axis=0)[:, None]
         h = as_compute(h)
-        k_cache, v_cache = cache["k"], cache["v"]
-        for i, blk in enumerate(self.blocks):
-            h, kp, vp = blk.decode_step(
-                params[f"block{i}"], h, k_cache[i], v_cache[i], table,
-                lengths, page_size=page_size)
-            k_cache = k_cache.at[i].set(kp)
-            v_cache = v_cache.at[i].set(vp)
+        h, cache = self._thread_cache(
+            params, cache, h,
+            lambda blk, p, h, k_pool, v_pool: blk.decode_step(
+                p, h, k_pool, v_pool, table, lengths, page_size=page_size))
         h, _ = self.ln_f.apply(params["ln_f"], {}, h)
         logits = (h[:, 0] @ jnp.asarray(params["logits_kernel"], h.dtype)
                   ).astype(jnp.float32)
         next_ids = sample_tokens(logits, seeds, token_idx, temperature,
                                  top_k=top_k)
-        return next_ids, logits, {"k": k_cache, "v": v_cache}
+        return next_ids, logits, cache
 
     def verify_step(self, params, cache, ids, lengths, table, seeds,
                     token_idx, temperature, *, page_size: int,
@@ -341,19 +355,16 @@ class TransformerLM(Layer, KerasNet):
         h = jnp.take(params["token_embeddings"], ids, axis=0)
         h = h + jnp.take(params["pos_embeddings"], positions, axis=0)
         h = as_compute(h)
-        k_cache, v_cache = cache["k"], cache["v"]
-        for i, blk in enumerate(self.blocks):
-            h, kp, vp = blk.verify_step(
-                params[f"block{i}"], h, k_cache[i], v_cache[i], table,
-                lengths, page_size=page_size)
-            k_cache = k_cache.at[i].set(kp)
-            v_cache = v_cache.at[i].set(vp)
+        h, cache = self._thread_cache(
+            params, cache, h,
+            lambda blk, p, h, k_pool, v_pool: blk.verify_step(
+                p, h, k_pool, v_pool, table, lengths, page_size=page_size))
         h, _ = self.ln_f.apply(params["ln_f"], {}, h)
         logits = (h @ jnp.asarray(params["logits_kernel"], h.dtype)
                   ).astype(jnp.float32)                       # (B, k, V)
         accepted, tokens, draft_probs = verify_draft_tokens(
             logits, ids[:, 1:], seeds, token_idx, temperature, top_k=top_k)
-        return accepted, tokens, draft_probs, {"k": k_cache, "v": v_cache}
+        return accepted, tokens, draft_probs, cache
 
     def compute_output_shape(self, input_shape):
         return tuple(input_shape) + (self.vocab,)

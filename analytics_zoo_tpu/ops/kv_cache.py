@@ -6,13 +6,22 @@ per EMITTED token. This module is the TPU-native fix — the decode-side state
 store behind ``TransformerLM.prefill()``/``decode_step()`` and the continuous
 batcher (:mod:`analytics_zoo_tpu.serving.generation`):
 
-* **Pages, not ragged buffers.** K/V live in a preallocated pool of
-  fixed-size pages, ``(n_layers, n_pages, page_size, n_heads, head_dim)``.
+* **Pages, not ragged buffers.** K/V live in preallocated pools of
+  fixed-size pages, ``(n_pages, page_size, n_heads, head_dim)``.
   A sequence *slot* owns an int32 page-table row mapping its logical
   positions to pool pages; pages are handed out by the host-side
   :class:`PagePool` as sequences grow and returned when they retire, so HBM
   is sized for the *working set* (active tokens), not
   ``n_slots × max_seq_len`` worst case.
+* **One pool per layer.** The cache pytree is ``{"k": (k_0, ..., k_{L-1}),
+  "v": (v_0, ..., v_{L-1})}``: one array per layer for K and one for V, all
+  indexed by the same page ids. Each block scatters its new rows into its
+  own leaf and hands that leaf to the attention kernel; nothing is sliced
+  out of or stored back into a larger array. With the pytree donated, XLA
+  aliases every leaf input to output and the scatter writes a few rows
+  where the pool lies (a stacked ``(n_layers, ...)`` pool cost a copy of one
+  layer's pool out and back around every write, in every layer, every
+  step).
 * **One decode executable.** Every device op here has shapes fixed by the
   cache config — ``(n_slots, pages_per_slot)`` tables, ``(n_slots,)``
   lengths — and masks to each row's true length instead of reshaping, the
@@ -80,12 +89,19 @@ class KVCacheConfig:
         return self.n_slots * self.pages_per_slot + 1
 
 
-def init_cache(cfg: KVCacheConfig) -> Dict[str, jax.Array]:
-    """Preallocate the K/V page pools (zeros; contents only ever read through
-    a length mask, so stale pages are invisible)."""
-    shape = (cfg.n_layers, cfg.total_pages, cfg.page_size, cfg.n_heads,
-             cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+#: ``{"k": one pool per layer, "v": one pool per layer}`` — see the module
+#: docstring; ``cache["k"][i]`` is layer ``i``'s K pool.
+KVCache = Dict[str, Tuple[jax.Array, ...]]
+
+
+def init_cache(cfg: KVCacheConfig) -> KVCache:
+    """Preallocate the K/V page pools, one ``(n_pages, page_size, n_heads,
+    head_dim)`` array per layer (zeros; contents only ever read through a
+    length mask, so stale pages are invisible)."""
+    shape = (cfg.total_pages, cfg.page_size, cfg.n_heads, cfg.head_dim)
+    return {name: tuple(jnp.zeros(shape, cfg.dtype)
+                        for _ in range(cfg.n_layers))
+            for name in ("k", "v")}
 
 
 class PagePool:
@@ -498,17 +514,18 @@ class PrefixCache:
 # device ops — all shapes fixed by KVCacheConfig; traced once
 # ---------------------------------------------------------------------------
 
-def copy_page(cache: Dict[str, jax.Array], src, dst) -> Dict[str, jax.Array]:
-    """Copy one page's K and V across every layer, ``src`` -> ``dst`` — the
-    copy-on-write op for the one partially-shared boundary page of a
+def copy_page(cache: KVCache, src, dst) -> KVCache:
+    """Copy one page's K and V in every layer's pool, ``src`` -> ``dst`` —
+    the copy-on-write op for the one partially-shared boundary page of a
     full-prompt prefix hit. ``src``/``dst`` are traced int32 scalars, so
     every (src, dst) pair rides ONE compiled executable; jit with the cache
-    donated and the copy is an in-place page-sized update, not a second
-    pool."""
+    donated and the copy is an in-place page-sized update of each pool, not
+    a second pool."""
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
-    return {name: pages.at[:, dst].set(pages[:, src])
-            for name, pages in cache.items()}
+    return jax.tree_util.tree_map(
+        lambda pages: pages.at[dst].set(pages[src]), cache)
+
 
 def paged_write(pages: jax.Array, table: jax.Array, pos: jax.Array,
                 new: jax.Array, *, page_size: int) -> jax.Array:

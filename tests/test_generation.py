@@ -75,8 +75,13 @@ def _teacher_forced_parity(m, params, seq, prefill_len, atol):
                                    atol=atol, rtol=0)
 
 
-def test_prefill_decode_logit_parity_f32(model_and_params, np_rng):
-    m, params = model_and_params
+@pytest.mark.parametrize("n_layers", [1, 3])
+def test_prefill_decode_logit_parity_f32(n_layers, np_rng):
+    # one pool per layer: at 3 layers a block that read or wrote another
+    # layer's pool would see the wrong K/V and miss the full forward
+    m = TransformerLM(vocab=VOCAB, hidden_size=HIDDEN, n_block=n_layers,
+                      n_head=HEADS, seq_len=SEQ)
+    params, _ = m.build(jax.random.PRNGKey(0))
     seq = np_rng.integers(1, VOCAB, size=20).astype(np.int32)
     # f32: the cached path reassociates reductions differently from the
     # one-shot forward, so "exact" means float-epsilon-scale, not bit-equal
@@ -515,6 +520,47 @@ def test_decode_shape_stability_rule_flags_growth():
     findings2 = lint_jaxpr(closed2, ctx=ctx,
                            rules=["decode-shape-stability"])
     assert any("host round-trip" in f.message for f in findings2)
+
+
+def test_decode_lint_passes_logits_that_outweigh_one_layers_pool():
+    """One pool per layer made the largest cache LEAF a 1/n_layers-th of
+    what it was; the growth limit stayed one K pool over all layers, so a
+    small model with a large vocabulary still lints clean."""
+    m = TransformerLM(vocab=512, hidden_size=HIDDEN, n_block=4, n_head=HEADS,
+                      seq_len=SEQ)
+    params, _ = m.build(jax.random.PRNGKey(0))
+    b = ContinuousBatcher(m, params, n_slots=2, page_size=4, max_seq_len=8,
+                          autostart=False)
+    try:
+        layer_pool = b.cache["k"][0].nbytes
+        logits = b.n_slots * 512 * 4
+        assert layer_pool < logits < 4 * layer_pool
+        assert b.check_decode_stability("raise") == []
+    finally:
+        b.close()
+
+
+@pytest.mark.parametrize("rows,flagged", [(2, False), (3, True)])
+def test_decode_lint_growth_limit_is_half_the_leaves(rows, flagged):
+    """Four (8, 4) leaves (two layers' K and V): an (rows*8, 4) intermediate
+    may outweigh a leaf, up to one K pool over both layers (16 rows of 4),
+    and is flagged beyond it."""
+    import jax.numpy as jnp
+
+    from analytics_zoo_tpu.analysis import RuleContext
+    from analytics_zoo_tpu.analysis.graphlint import lint_jaxpr
+
+    def step(k0, k1, v0, v1, x):
+        big = jnp.tile(x, (rows, 1))                  # (rows * 8, 4)
+        return k0 + big[:8], k1, v0, v1
+
+    leaf = jnp.zeros((8, 4))
+    closed = jax.make_jaxpr(step)(leaf, leaf, leaf, leaf, leaf)
+    ctx = RuleContext(where="test",
+                      decode_cache_avals=[((8, 4), "float32")] * 4)
+    findings = lint_jaxpr(closed, ctx=ctx, rules=["decode-shape-stability"])
+    assert any("one K pool over all layers" in f.message
+               for f in findings) == flagged, [str(f) for f in findings]
 
 
 def test_generation_engine_graph_checks_raise(model_and_params, broker,

@@ -68,6 +68,36 @@ def test_profile_donation_credit_removes_second_pool():
     assert plain.peak_eqn is not None
 
 
+N_LAYER_LEAVES = 6
+
+
+def _per_layer_cache_step(params, cache, x):
+    """``_cache_step``'s twin on the serving layout: one leaf per layer,
+    each scattered into and returned, nothing sliced out of a stack."""
+    y = x @ params
+    return y, {"k": tuple(c.at[0].set(c[0] + y[0]) for c in cache["k"])}
+
+
+def test_profile_donation_credit_covers_every_per_layer_leaf():
+    leaf = jax.ShapeDtypeStruct((64, 64), jnp.float32)
+    closed = jax.make_jaxpr(_per_layer_cache_step)(
+        leaf, {"k": (leaf,) * N_LAYER_LEAVES}, leaf)
+    pool = N_LAYER_LEAVES * 64 * 64 * 4
+    plain = profile_jaxpr(closed)
+    donated = profile_jaxpr(
+        closed, donated_invars=[False] + [True] * N_LAYER_LEAVES + [False])
+    # un-donated, every leaf's scatter makes a new buffer beside its input:
+    # a second pool in all (the peaks also differ by a few row-sized
+    # temporaries). Donated, each leaf aliases its own output.
+    assert plain.peak_live_bytes - donated.peak_live_bytes >= pool - 1024
+    assert donated.aliased_out_bytes >= pool
+    # donating half the leaves buys half the credit
+    half = profile_jaxpr(
+        closed, donated_invars=[False] + [True, False] * (N_LAYER_LEAVES // 2)
+        + [False])
+    assert half.aliased_out_bytes == pool // 2
+
+
 def test_profile_scan_body_counts_once():
     """A scan body's temporary contributes its size ONCE (buffers are
     reused per iteration), and is tagged in_loop."""
@@ -387,10 +417,11 @@ def test_decode_cache_alias_both_polarities():
         with pytest.raises(GraphLintError, match="cache-alias"):
             b.check_decode_stability("raise")
         fs = b.check_decode_stability("warn")
-        # the k and v pools share (shape, dtype) — ONE deduped finding for
-        # the one missing donate_argnums, counting both leaves
+        # every layer's k and v pool shares (shape, dtype) — ONE deduped
+        # finding for the one missing donate_argnums, counting all the leaves
+        # (2 blocks x {k, v})
         f = _one(fs, "cache-alias")
-        assert dict(f.data)["leaves"] == 2
+        assert dict(f.data)["leaves"] == 4
     finally:
         b.close()
     b = _tiny_batcher()                      # donate_cache=True default
