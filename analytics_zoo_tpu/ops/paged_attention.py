@@ -1,4 +1,5 @@
-"""Fused paged-attention pallas kernel (ISSUE 14).
+"""Fused paged-attention pallas kernel (ISSUE 14; one program a slot since
+ISSUE 27).
 
 ``decode_attention`` (kv_cache.py) is a plain masked dot over a
 *host-gathered contiguous view*: ``paged_read`` materializes the full
@@ -6,36 +7,50 @@
 so decode is bandwidth-bound on data it mostly re-reads — the gathered copy
 is written once and read once, doubling cache traffic for zero FLOPs.
 
-This kernel fuses the page-table gather INTO the attention loop: the grid
-walks ``(slot, head-block, page)`` and each program's K/V tile is fetched
-straight from the page pool by indexing the scalar-prefetched page table in
-the BlockSpec index map (``pltpu.PrefetchScalarGridSpec`` — the table is in
-SMEM before the first tile DMA issues, so the gather costs nothing extra).
-QK dot, online-softmax statistics and the PV accumulate all live in VMEM;
-nothing page-sized ever round-trips HBM. Supports query length 1 (the
-classic decode step) AND ``q_len = k > 1`` — the speculative-decode verify
-step that scores k draft tokens against the same paged cache in one pass
-(:mod:`analytics_zoo_tpu.ops.speculative`).
+This kernel fuses the page-table gather INTO the attention loop. The grid is
+``(slot, head-block, query-tile)`` and has no page axis: the pools stay in
+HBM (``memory_space=pl.ANY``), the page table and the lengths are scalar-
+prefetched into SMEM (``pltpu.PrefetchScalarGridSpec``), and each program
+walks ITS OWN slot's pages in a ``fori_loop`` over *compute blocks* of
+:func:`pages_per_block` pages, bounded by the last position its queries can
+see. A block's pages are copied into VMEM one ``make_async_copy`` each,
+straight from ``pool[table[slot, j]]``, into one of two buffers, so the next
+block's pages fly while this block is folded: one QK dot as wide as the
+block (128 tokens at 16-token pages), the masked online-softmax update in
+f32, one PV dot. Nothing page-sized ever round-trips HBM. Supports query
+length 1 (the classic decode step) AND ``q_len = k > 1`` — the speculative-
+decode verify step that scores k draft tokens against the same paged cache
+in one pass (:mod:`analytics_zoo_tpu.ops.speculative`) and the prefill-chunk
+and prefix-suffix widths.
 
 Block schedule: ``block_h`` (heads per program) is the tunable knob —
 resolved via env ``ZOO_PAGED_BLOCK_H``, then the on-disk autotuner cache
 (:mod:`analytics_zoo_tpu.ops.tuning` ``PAGED`` op table, exactly like
-matmul/flash), then all-heads. ``block_q`` (query rows per program) is
-derived, not tuned: the whole ``q_len`` while its softmax scratch fits
-scoped VMEM (every decode and verify step), else the largest tile that does
-(:func:`query_block` — the prefill-chunk and prefix-suffix widths, where an
-untiled call is refused by Mosaic). Routing: :func:`use_kernel` — ``auto``
-(kernel on TPU, reference path elsewhere: interpret-mode pallas is a
-correctness tool, not a fast path), forced ``on`` (interpret on CPU — the
-parity gates), or ``off`` via ``ZOO_PAGED_ATTENTION``.
+matmul/flash), then all-heads. ``block_q`` (query rows per program) and the
+pages of a compute block are derived from the shapes, not tuned: the whole
+``q_len`` while its softmax scratch fits scoped VMEM (every decode and
+verify step), else the largest tile that does (:func:`query_block` — the
+prefill-chunk and prefix-suffix widths, where an untiled call is refused by
+Mosaic); as many pages as make 128 tokens and fit beside it
+(:func:`pages_per_block`). Routing: :func:`use_kernel` — ``auto`` (kernel on
+TPU, reference path elsewhere: interpret-mode pallas is a correctness tool,
+not a fast path), forced ``on`` (interpret on CPU — the parity gates), or
+``off`` via ``ZOO_PAGED_ATTENTION``.
 
 Semantics match :func:`~analytics_zoo_tpu.ops.kv_cache.decode_attention_multi`:
 ``lengths[b]`` counts VALID cache positions *including* the q_len new tokens
 (already written by ``paged_write_multi``), and query ``i`` attends to
 positions ``<= lengths[b] - q_len + i`` — causal within the step, full
-prefix before it. Pages holding no valid position are skipped entirely
-(``pl.when`` on the scalar-prefetched length), so cost tracks each slot's
-true length, not the table capacity.
+prefix before it. Cost tracks each slot's true length, not the table
+capacity: a program fetches only the pages that hold a position it can see
+(the table entries past them point at scratch and are never read), so a
+slot of 270 tokens is three blocks whatever ``pages_per_slot`` is, and a
+slot that holds no stream (its first table entry is ``SCRATCH_PAGE``, which
+a live slot's never is) fetches nothing and emits zeros. Measured on the
+v5e at the serving cell's shape (32 slots, 16 heads of 128, table 128,
+PERF.md section 6, PR 27): 11 us a call with every slot empty, 37 us with 4
+live streams of 60-900 tokens, 414 us with 20 of 1,500 (three quarters of
+the HBM roofline for the pages read).
 """
 
 from __future__ import annotations
@@ -51,12 +66,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .backend import interpret_default
+from .kv_cache import SCRATCH_PAGE
 
 NEG_INF = -1e30
 
 #: What the kernel sizes its query tile against: half of the 16 MiB scoped
-#: VMEM limit Mosaic enforces on a v5e, the rest left to its own temporaries.
+#: VMEM limit Mosaic enforces on a v5e, the rest left to the K and V page
+#: buffers (:func:`pages_per_block`) and to Mosaic's own temporaries.
 _VMEM_BUDGET = 8 * 2 ** 20
+
+#: Tokens of K and V one compute block folds: the MXU's width.
+_BLOCK_TOKENS = 128
 
 
 def paged_mode() -> str:
@@ -109,10 +129,11 @@ def query_block(q_len: int, block_h: int, d: int, dtype) -> int:
     """Query rows per kernel program. Each (head, query) row costs f32
     running max / sum / accumulator scratch (128-lane padded), the
     double-buffered q and o tiles, and the f32 score temporaries of one
-    page; ``block_h * block_q`` rows must fit :data:`_VMEM_BUDGET`. Returns
-    ``q_len`` when it fits whole, else its largest divisor that is a
-    multiple of 8 (the sublane tile) and fits. Raises ``ValueError`` naming
-    the shape when there is none — a width the batcher must not admit."""
+    compute block (128 columns); ``block_h * block_q`` rows must fit
+    :data:`_VMEM_BUDGET`. Returns ``q_len`` when it fits whole, else its
+    largest divisor that is a multiple of 8 (the sublane tile) and fits.
+    Raises ``ValueError`` naming the shape when there is none — a width the
+    batcher must not admit."""
     row_bytes = (4 * (128 + 128 + max(d, 128))
                  + 4 * d * np.dtype(dtype).itemsize + 3 * 4 * 128)
     cap = _VMEM_BUDGET // row_bytes // block_h
@@ -127,44 +148,107 @@ def query_block(q_len: int, block_h: int, d: int, dtype) -> int:
         f"that is a multiple of 8 and divides q_len; there is none")
 
 
-def _paged_kernel(table_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, scale: float, page_size: int,
-                  q_len: int, block_q: int, block_h: int, d: int):
-    b = pl.program_id(0)
-    qi = pl.program_id(2)
-    j = pl.program_id(3)
-    nj = pl.num_programs(3)
-    rows = block_h * block_q
+def pages_per_block(pages_per_slot: int, page_size: int, block_h: int,
+                    d: int, dtype) -> int:
+    """Pages one compute block fetches and folds at once. Derived, not
+    tuned: a power of two, at most :data:`_BLOCK_TOKENS` tokens (a block any
+    longer makes a short context fetch mostly padding; this many fill the
+    MXU's columns), at most the table, and small enough that the K and V
+    page buffers (two of each: the next block's pages fly while this one is
+    computed) and the head-major copies the dots read fit half of
+    :data:`_VMEM_BUDGET`. The table need not be a multiple: the last block
+    of a slot fetches only the pages it has."""
+    page_bytes = page_size * block_h * d * np.dtype(dtype).itemsize
+    fit = min(max(1, _BLOCK_TOKENS // page_size), pages_per_slot,
+              max(1, _VMEM_BUDGET // 2 // (6 * page_bytes)))
+    return 1 << (fit.bit_length() - 1)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+def _paged_kernel(table_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, sems, m_scr, l_scr, acc_scr, *, scale: float,
+                  page_size: int, q_len: int, block_q: int, block_h: int,
+                  d: int, n_block: int):
+    b = pl.program_id(0)
+    hb = pl.program_id(1)
+    qi = pl.program_id(2)
+    rows = block_h * block_q
+    bk = n_block * page_size
+    pps = table_ref.shape[1]
 
     length = lengths_ref[b]
+    # positions some query of this tile can see are [0, n_vis): query i sits
+    # at absolute position length - q_len + i and sees itself and everything
+    # before it. A slot that holds no stream (its table is all scratch: a
+    # live slot's first page never is) sees nothing and fetches nothing.
+    n_vis = jnp.where(table_ref[b, 0] == SCRATCH_PAGE, 0,
+                      length - q_len + (qi + 1) * block_q)
+    n_pages = jnp.clip(pl.cdiv(n_vis, page_size), 0, pps)
+    n_blocks = pl.cdiv(n_pages, n_block)
 
-    def body():
-        # operands stay in storage dtype (bf16 MXU full-rate), statistics
-        # accumulate in f32 — same discipline as the flash kernel
-        q = q_ref[0].transpose(1, 0, 2)             # (block_h, block_q, D)
-        k = k_ref[0].transpose(1, 0, 2)             # (block_h, page, D)
-        v = v_ref[0].transpose(1, 0, 2)
+    def page_copies(i, buf, p):
+        """The K and the V copy of page ``p`` of block ``i`` into buffer
+        ``buf``: this program's heads of pool page ``table[b, i*P + p]``."""
+        page = table_ref[b, i * n_block + p]
+        heads = pl.ds(hb * block_h, block_h)
+        return (pltpu.make_async_copy(k_hbm.at[page, :, heads, :],
+                                      k_buf.at[buf, p], sems.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[page, :, heads, :],
+                                      v_buf.at[buf, p], sems.at[1, buf]))
+
+    def for_pages(i, buf, act):
+        # only the pages that hold a visible position: past them the table
+        # points at scratch and the buffer keeps whatever it held
+        def one(p, carry):
+            for copy in page_copies(i, buf, p):
+                act(copy)
+            return carry
+        jax.lax.fori_loop(0, jnp.minimum(n_block, n_pages - i * n_block),
+                          one, None)
+
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        for_pages(0, 0, lambda c: c.start())
+
+    # operands stay in storage dtype (bf16 MXU full-rate), statistics
+    # accumulate in f32 — same discipline as the flash kernel
+    q = q_ref[0].transpose(1, 0, 2)                 # (block_h, block_q, D)
+    # query i's last visible position: the whole prefix AND itself/earlier
+    # drafts, never later drafts
+    bound = length - q_len + qi * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_h, block_q, bk), 1)
+
+    def block(i, carry):
+        buf = i % 2
+
+        @pl.when(i + 1 < n_blocks)
+        def _next():
+            for_pages(i + 1, 1 - buf, lambda c: c.start())
+
+        for_pages(i, buf, lambda c: c.wait())
+        k = k_buf[buf].reshape(bk, block_h, d)
+        v = v_buf[buf].reshape(bk, block_h, d)
+        # rows of pages this block did not fetch hold stale bits: their
+        # scores are masked below, and 0 * NaN is NaN, so V's become zeros
+        tok = i * bk + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+        v = jnp.where(tok < n_vis, v, jnp.zeros_like(v))
+        k = k.transpose(1, 0, 2)                    # (block_h, bk, D)
+        v = v.transpose(1, 0, 2)
         s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32) * scale
-        kv_pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (block_h, block_q, page_size), 2)
-        q_idx = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_h, block_q, page_size), 1)
-        # query i sits at absolute position length - q_len + i: it sees the
-        # whole prefix AND itself/earlier drafts, never later drafts
-        bound = length - q_len + q_idx
-        s = jnp.where(kv_pos <= bound, s, NEG_INF)
+        seen = i * bk + jax.lax.broadcasted_iota(
+            jnp.int32, (block_h, block_q, bk), 2) <= bound
+        s = jnp.where(seen, s, NEG_INF)
         m_prev = m_scr[:rows, 0:1].reshape(block_h, block_q, 1)
         l_prev = l_scr[:rows, 0:1].reshape(block_h, block_q, 1)
         m_new = jnp.maximum(m_prev, s.max(axis=2, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
+        # exact zeros where masked, also in a row that has seen nothing yet
+        # (there s - m_new is 0, not -inf)
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
         l_new = l_prev * corr + p.sum(axis=2, keepdims=True)
         pv = jax.lax.dot_general(p.astype(v.dtype), v,
                                  (((2,), (1,)), ((0,), (0,))),
@@ -175,19 +259,14 @@ def _paged_kernel(table_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
                                            (rows, m_scr.shape[1]))
         l_scr[:rows, :] = jnp.broadcast_to(l_new.reshape(rows, 1),
                                            (rows, l_scr.shape[1]))
+        return carry
 
-    # skip pages no query of this tile can see: past the slot's valid length
-    # (table entries there are scratch) or past the tile's last query
-    @pl.when(j * page_size < length - q_len + (qi + 1) * block_q)
-    def _():
-        body()
+    jax.lax.fori_loop(0, n_blocks, block, None)
 
-    @pl.when(j == nj - 1)
-    def _finish():
-        l = l_scr[:rows, 0:1]
-        safe_l = jnp.where(l == 0, 1.0, l)   # masked-out rows emit zeros
-        o = (acc_scr[:rows, :d] / safe_l).reshape(block_h, block_q, d)
-        o_ref[0] = o.transpose(1, 0, 2).astype(o_ref.dtype)
+    l = l_scr[:rows, 0:1]
+    safe_l = jnp.where(l == 0, 1.0, l)      # rows that saw nothing emit zeros
+    o = (acc_scr[:rows, :d] / safe_l).reshape(block_h, block_q, d)
+    o_ref[0] = o.transpose(1, 0, 2).astype(o_ref.dtype)
 
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -219,28 +298,32 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     if q_len % block_q:
         raise ValueError(f"paged_attention: block_q={block_q} does not "
                          f"divide q_len of q{q.shape}")
+    n_block = pages_per_block(pps, page_size, block_h, d, k_pages.dtype)
     scale = 1.0 / float(np.sqrt(d))
     rows = max(8, block_h * block_q)
     kern = functools.partial(_paged_kernel, scale=scale, page_size=page_size,
                              q_len=q_len, block_q=block_q, block_h=block_h,
-                             d=d)
+                             d=d, n_block=n_block)
+    q_spec = pl.BlockSpec((1, block_q, block_h, d),
+                          lambda b, hb, qi, tbl, ln: (b, qi, hb, 0))
+    kv_buf = pltpu.VMEM((2, n_block, page_size, block_h, d), k_pages.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h // block_h, q_len // block_q, pps),
+        # no page axis: a program walks its own slot's pages, as many as
+        # hold something its queries see
+        grid=(b, h // block_h, q_len // block_q),
         in_specs=[
-            pl.BlockSpec((1, block_q, block_h, d),
-                         lambda b, hb, qi, j, tbl, ln: (b, qi, hb, 0)),
-            # THE fusion: the K/V tile for grid step (b, ·, ·, j) is page
-            # table[b, j] of the pool, resolved in the index map from the
-            # scalar-prefetched table — no contiguous copy ever exists
-            pl.BlockSpec((1, page_size, block_h, d),
-                         lambda b, hb, qi, j, tbl, ln: (tbl[b, j], 0, hb, 0)),
-            pl.BlockSpec((1, page_size, block_h, d),
-                         lambda b, hb, qi, j, tbl, ln: (tbl[b, j], 0, hb, 0)),
+            q_spec,
+            # THE fusion: the pools stay where they lie, and each program
+            # copies in the pages its slot's row of the scalar-prefetched
+            # table names — no contiguous copy ever exists
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, block_q, block_h, d),
-                               lambda b, hb, qi, j, tbl, ln: (b, qi, hb, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
+            kv_buf, kv_buf,
+            pltpu.SemaphoreType.DMA((2, 2)),        # (K | V, buffer)
             pltpu.VMEM((rows, 128), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
             pltpu.VMEM((rows, max(d, 128)), jnp.float32),
@@ -249,12 +332,9 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     return pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, q_len, h, d), q.dtype),
-        # the (slot, head-block, query-tile) dims each own disjoint output
-        # blocks; only the page fold must stay sequential (online-softmax
-        # carry)
+        # every program owns its output block and its copies end with it
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
         name="zoo_paged_attention",
     )(jnp.asarray(table, jnp.int32), jnp.asarray(lengths, jnp.int32),
@@ -266,7 +346,9 @@ def synthetic_paged_case(n_slots: int, pages_per_slot: int, page_size: int,
                          dtype=np.float32, lengths=None, rng=None):
     """Random ``(q, k_pages, v_pages, table, lengths)`` laid out exactly
     like the serving cache — page 0 scratch, each slot's valid prefix on
-    sequentially allocated pages, unallocated entries scratch. The ONE
+    pages drawn in random order (as a pool that has served and freed
+    streams hands them out: neighbours in a table are not neighbours in
+    the pool), unallocated entries scratch. The ONE
     fixture builder shared by the autotuner sweep
     (:func:`~analytics_zoo_tpu.ops.tuning.tune_paged_attention`), the bench
     parity gate and the kernel tests, so none can drift from the real
@@ -287,13 +369,15 @@ def synthetic_paged_case(n_slots: int, pages_per_slot: int, page_size: int,
                              * max_len // (2 * n_slots)).astype(np.int32)
     lengths = np.asarray(lengths, np.int32)
     table = np.zeros((n_slots, pages_per_slot), np.int32)
-    nxt = 1
+    free = rng.permutation(np.arange(1, n_pages))
+    nxt = 0
     for i in range(n_slots):
         for j in range(-(-int(lengths[i]) // page_size)):
-            table[i, j] = nxt
+            table[i, j] = free[nxt]
             nxt += 1
     return q, k_pages, v_pages, jnp.asarray(table), jnp.asarray(lengths)
 
 
 __all__ = ["default_block_h", "paged_attention", "paged_mode",
-           "query_block", "synthetic_paged_case", "use_kernel"]
+           "pages_per_block", "query_block", "synthetic_paged_case",
+           "use_kernel"]

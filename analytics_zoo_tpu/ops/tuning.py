@@ -352,7 +352,11 @@ PAGED_CANDIDATES: Sequence[int] = (1, 2, 4, 8, 16)
 
 def paged_key(q_len: int, pages_per_slot: int, page_size: int, h: int,
               d: int, dtype) -> str:
-    return shape_key(q_len, pages_per_slot, page_size, h, d, dtype=dtype)
+    # "/walk": swept against the kernel that walks a slot's own pages in
+    # compute blocks (ISSUE 27). An entry without the suffix was timed on
+    # the kernel whose grid ran over every table entry and is never read
+    return shape_key(q_len, pages_per_slot, page_size, h, d,
+                     dtype=dtype) + "/walk"
 
 
 def paged_lookup(q_len: int, pages_per_slot: int, page_size: int, h: int,

@@ -61,17 +61,24 @@ def test_flash_forward_and_backward_at_the_training_shape(v5e):
 
 # decode, speculative verify, and the widest query the config admits: a
 # prefix-hit suffix bucket as long as gen_max_seq_len (one slot, table one
-# chunk wider than the slot's pages)
-@pytest.mark.parametrize("slots,q_len,table", [(8, 1, 128), (8, 4, 128),
-                                               (1, 2048, 256)])
-@pytest.mark.parametrize("dtype", [BF16, F32])
+# chunk wider than the slot's pages); then the benchmark's serving cell as it
+# is (Cerebras-GPT-1.3B: 32 slots, 16 heads of 128, 2,304 pages), its decode
+# step and a 2,048-token chunk
+@pytest.mark.parametrize("slots,q_len,table,heads,pages,dtype", [
+    (8, 1, 128, 8, 1025, BF16), (8, 1, 128, 8, 1025, F32),
+    (8, 4, 128, 8, 1025, BF16), (8, 4, 128, 8, 1025, F32),
+    (1, 2048, 256, 8, 1025, BF16), (1, 2048, 256, 8, 1025, F32),
+    (32, 1, 128, 16, 2304, BF16), (1, 2048, 128, 16, 2304, BF16)])
 def test_paged_attention_at_the_serving_shapes(v5e, slots, q_len, table,
-                                               dtype):
-    pool = ((8 * 128 + 1, 16, 8, 128), dtype)
-    v5e(lambda q, k, v, tb, ln: paged_attention(
+                                               heads, pages, dtype):
+    pool = ((pages, 16, heads, 128), dtype)
+    text = v5e(lambda q, k, v, tb, ln: paged_attention(
         q, k, v, tb, ln, page_size=16, interpret=False),
-        ((slots, q_len, 8, 128), dtype), pool, pool,
+        ((slots, q_len, heads, 128), dtype), pool, pool,
         ((slots, table), I32), ((slots,), I32))
+    # one Mosaic call, under the name the benchmark's readers select by
+    assert text.count("tpu_custom_call") == 1
+    assert "zoo_paged_attention" in text
 
 
 @pytest.mark.parametrize("m", [1, 16, 512])

@@ -122,9 +122,16 @@ def test_implicit_ncf_beats_random_ranking(zoo_ctx):
     model = ImplicitNCF(user_count=n_users, item_count=n_items, n_negatives=4,
                         user_embed=8, item_embed=8, hidden_layers=(16, 8),
                         mf_embed=8)
+    # log (and so read the loss back) at every step: with no log point the
+    # loop keeps several steps in flight, and XLA:CPU runs the 8 virtual
+    # devices' executions on a pool as large as the host's cores, where a
+    # later step can take the thread an earlier step's all-gather waits for
+    # ("Expected 8 threads to join the rendezvous, but only 7 of them
+    # arrived", abort after 40 s; seen on 8 cores whenever the executables
+    # came out of the compile cache). One step in flight cannot deadlock
     est = Estimator(model, optimizer=Adam(lr=5e-3), loss=implicit_bce_loss,
                     mesh=zoo_ctx.mesh,
-                    config=TrainConfig(log_every_n_steps=10**9))
+                    config=TrainConfig(log_every_n_steps=1))
     est.fit((train, np.zeros(len(train), "float32")), batch_size=2048, epochs=8)
 
     flat = ev.reshape(-1, 2).astype("int32")
