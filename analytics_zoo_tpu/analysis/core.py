@@ -1,7 +1,7 @@
 """Rule engine core: findings, rules, registry, enforcement.
 
 The platform carries structural invariants that used to be enforced by
-one-off walkers buried in ``bench.py`` — ZeRO-1's one-reduce-scatter/
+one-off walkers buried in a benchmark script — ZeRO-1's one-reduce-scatter/
 one-all-gather budget (PR 5), the fused-int8 no-HBM-intermediate guarantee
 (PR 6), the bf16/f32 dtype discipline. This module is the shared substrate
 those checks now run on: a :class:`Rule` walks an artifact (a traced jaxpr,

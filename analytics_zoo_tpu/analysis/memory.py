@@ -21,8 +21,7 @@ third analysis tier next to the graph rules (PR 7) and the concurrency lint
 * **HLO buffer-table ingestion** — :func:`memory_fields` reads the
   structured ``compiled.memory_analysis()`` (PJRT ``CompiledMemoryStats``:
   argument/output/temp/**alias** sizes) when the backend provides it, else
-  routes the textual dump through :func:`parse_xla_memory_analysis` (the
-  PR-5 parser, migrated here out of ``bench.py``; an alias remains there).
+  routes the textual dump through :func:`parse_xla_memory_analysis`.
 * **Witness check** — :func:`check_memory_witness` cross-checks the runtime
   allocation witness (:mod:`analytics_zoo_tpu.common.memwitness`, the
   PR-11-style dynamic half: ``ZOO_TPU_MEM_WITNESS`` samples live-array bytes
@@ -49,9 +48,7 @@ __all__ = [
 ]
 
 # --------------------------------------------------------------------------
-# XLA memory-analysis ingestion (structured PJRT stats + the text parser
-# migrated from bench.py — ops/tuning.py and the OOM handler route through
-# these instead of importing library code from the bench script)
+# XLA memory-analysis ingestion (structured PJRT stats + the text parser)
 # --------------------------------------------------------------------------
 
 _MEM_SIZE_SUFFIX = {"": 1, "B": 1, "K": 2 ** 10, "M": 2 ** 20,

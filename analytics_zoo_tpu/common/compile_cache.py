@@ -11,8 +11,8 @@ from __future__ import annotations
 import os
 
 #: Root of the checkout: the directory that holds the ``analytics_zoo_tpu``
-#: package. Caches the program writes (compiled executables, kernel tuning)
-#: live under it, in paths ``.gitignore`` lists.
+#: package. The cache the program writes (compiled executables) lives
+#: under it, in a path ``.gitignore`` lists.
 CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
@@ -25,7 +25,8 @@ def enable_compile_cache() -> str:
     ``jax_compilation_cache_dir`` and this changes nothing. Otherwise the
     cache is ``<checkout>/.jax_cache``. Called when a ``ZooContext`` is built
     (``init_zoo_context``, or the lazy default), by the serving stack,
-    ``bench.py`` and ``chip_smoke.py``; nothing else sets the directory."""
+    ``benchmark/run.py`` and ``chip_smoke.py``; nothing else sets the
+    directory."""
     import jax
 
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):

@@ -572,65 +572,17 @@ class InferenceModel:
         concurrency slot."""
         return self._served_version.get(threading.get_ident())
 
-    def predict_async(self, inputs):
-        """Dispatch a predict WITHOUT waiting; returns ``fetch() -> result``.
-
-        The XLA execution (and its host→device transfer) is enqueued before
-        this returns; ``fetch()`` blocks only on the device→host result
-        transfer. On a remote accelerator this is what lets a caller overlap
-        the round-trip of batch N with assembling/dispatching batch N+1 —
-        the serving engine's double-buffered dispatch rides this.
-
-        The concurrency semaphore is held from dispatch until ``fetch()``
-        completes (an in-flight request IS a borrowed replica); every
-        returned ``fetch`` must therefore be called exactly once.
-        """
-        arrs, multi, n = self._validate_inputs(inputs)
-        t0 = time.perf_counter()
-        self._sem.acquire()
-        with self._lock:
-            self._borrowed += 1
-            self.borrowed_peak = max(self.borrowed_peak, self._borrowed)
-        self._served_version[threading.get_ident()] = self.version
-        try:
-            dispatched = self._dispatch_chunks(arrs, multi, n)
-        except BaseException:
-            with self._lock:
-                self._borrowed -= 1
-            self._sem.release()
-            raise
-        released = [False]  # fetch-once guard; check-and-set under self._lock
-
-        def fetch():
-            try:
-                return self._gather_chunks(dispatched)
-            finally:
-                # atomic test-and-set: two concurrent fetch() calls must not
-                # both release the semaphore / decrement _borrowed, or the
-                # concurrency bound silently inflates
-                with self._lock:
-                    first = not released[0]
-                    released[0] = True
-                    if first:
-                        self._borrowed -= 1
-                if first:
-                    self._sem.release()
-                    if self.summary is not None:
-                        self.summary.add_batch(n, time.perf_counter() - t0)
-
-        return fetch
-
     # ------------------------------------------------------- device-level access
 
     def device_apply(self):
         """``(apply_fn, params, state)`` — the exact computation ``predict``
         compiles, with params/state already device-resident.
 
-        Public escape hatch for AOT export and device-resident benchmarking
-        (serving_bench.py times int8-vs-bf16 through this so the measurement
-        cannot silently decouple from the real predict path): after
-        ``quantize_int8`` the returned ``apply_fn``/``params`` are the
-        quantized ones."""
+        Public escape hatch for AOT export and for whoever inspects the
+        program (the ``fused-int8-dispatch`` rule and ``chip_smoke.py``
+        lower it, so what they check cannot decouple from the real predict
+        path): after ``quantize_int8`` the returned ``apply_fn``/``params``
+        are the quantized ones."""
         if self._apply is None:
             raise RuntimeError("no model loaded (call load/load_zoo first)")
         return self._apply, self._params, self._state

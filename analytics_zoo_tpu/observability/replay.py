@@ -12,7 +12,7 @@ emit, so a recorded run and a candidate run are directly diffable:
   functions the live tiers used. Replaying a recording under it must
   reproduce the recorded decision sequence **exactly** (kinds, order,
   fields — decisions carry no timestamps), which :func:`verify_incumbent`
-  asserts; ``bench.py --replay`` gates on it.
+  asserts (``tests/test_flight_recorder.py`` gates on it).
 * Candidate policies (e.g. :class:`WatermarkAdmissionPolicy`) see the same
   inputs and may decide differently; :func:`diff_runs` lists the
   divergences and feeds ``zoo_flight_replay_divergence_total``, and
@@ -258,7 +258,7 @@ def verify_incumbent(records: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
 
 def score_admission(run: ReplayRun) -> Dict[str, Any]:
     """Outcome summary for one policy's admission decisions — the numbers
-    ``bench.py --replay`` compares across policies."""
+    to compare across policies."""
     considered = admitted = shed = 0
     shed_by_priority: Dict[str, int] = {}
     retry: List[float] = []

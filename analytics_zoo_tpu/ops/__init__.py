@@ -1,5 +1,5 @@
 """TPU compute ops: attention strategies (full/ring/zigzag/Ulysses), pallas
-kernels (flash attention, fused-quantization int8), block-schedule tuning."""
+kernels (flash attention, paged attention, fused-quantization int8)."""
 
 from .attention import (full_attention, ring_attention_local, sharded_attention,
                         ulysses_attention_local, zigzag_permutation,

@@ -502,10 +502,11 @@ def ulysses_attention_local(q, k, v, *, axis_name: str = "sp",
 def prefer_flash_single_device(t: int) -> bool:
     """Auto-dispatch rule shared by the layer (mesh-less) and
     :func:`sharded_attention` (sp==1) paths, so both resolve identically:
-    on TPU the pallas kernel beats XLA full attention from 4k up, matches
-    it at 2k at the model level (MFU_SWEEP.json), and is the only option
-    once the (H, T, T) score tensor would OOM. A length the flash tiles do
-    not divide stays on full attention.
+    on TPU the pallas kernel beat XLA full attention from 4k up and matched
+    it at 2k at the model level (a sweep made before PR 1; not measured on
+    the current code), and is the only option once the (H, T, T) score
+    tensor would OOM. A length the flash tiles do not divide stays on full
+    attention.
 
     Query length 1 — the KV-cache decode step — is excluded UNCONDITIONALLY
     (not just by the threshold): a single query row has nothing to tile, so

@@ -336,11 +336,10 @@ def flash_attention(q, k, v, causal: bool = False,
     """Blockwise attention, (B, T, H, D) → (B, T, H, D).
 
     ``block_q``/``block_k`` default to :func:`default_blocks` (adaptive:
-    largest power-of-two ≤512 dividing the sequence; overridable via
-    ``ZOO_FLASH_BLOCK_Q/K`` — honored by EVERY call site: direct, sharded,
-    ring and Ulysses). Raises ``ValueError`` naming the shape when the
-    sequence does not tile evenly (the caller may pad, or ask
-    :func:`tiles_ok` and route to full attention instead).
+    largest power-of-two ≤512 dividing the sequence, at EVERY call site:
+    direct, sharded, ring and Ulysses). Raises ``ValueError`` naming the
+    shape when the sequence does not tile evenly (the caller may pad, or
+    ask :func:`tiles_ok` and route to full attention instead).
     """
     out, _ = _flash_attention_fwd_res(q, k, v, causal, block_q, block_k,
                                       interpret)
@@ -349,22 +348,10 @@ def flash_attention(q, k, v, causal: bool = False,
 
 def default_blocks(t_q: Optional[int] = None,
                    t_k: Optional[int] = None) -> tuple:
-    """Flash tile sizes. Read at trace time — a jitted program bakes the
-    values it saw. Resolution order:
-
-    1. ``ZOO_FLASH_BLOCK_Q`` / ``ZOO_FLASH_BLOCK_K`` env (sweeps,
-       dev/mfu_sweep.py) — always wins;
-    2. the on-disk tuning cache (``ops.tuning.flash_lookup``, keyed by
-       device kind + (T_q, T_k) — populated by ``tune_flash_blocks`` /
-       ``bench.py --int8-dispatch``'s MFU sweep);
-    3. ADAPTIVE: the largest power-of-two tile ≤512 that divides the
-       sequence length. Measured before PR 1 and not on the current code:
-       on a v5e the attention-only fwd+bwd ran ~4× faster at 512×512 than
-       at a fixed 128×128, and at the model level 512-tiles were worth
-       ~22% MFU over 256-tiles (MFU_SWEEP.json: 0.538 vs 0.44 on the
-       seq-2048 TransformerLM). 128 when the length is unknown or nothing
-       larger divides it."""
-    import os
+    """Flash tile sizes, a function of the sequence lengths alone: the
+    largest power-of-two tile ≤512 that divides the length, 128 when the
+    length is unknown or nothing larger divides it. A caller that wants
+    other tiles passes ``block_q`` / ``block_k``."""
 
     def auto(t: Optional[int]) -> int:
         if t is None:
@@ -374,16 +361,7 @@ def default_blocks(t_q: Optional[int] = None,
             b //= 2
         return b
 
-    eq = os.environ.get("ZOO_FLASH_BLOCK_Q")
-    ek = os.environ.get("ZOO_FLASH_BLOCK_K")
-    if not (eq and ek):
-        from .tuning import flash_lookup
-
-        tuned = flash_lookup(t_q, t_k)
-        if tuned is not None:
-            return (int(eq) if eq else tuned[0],
-                    int(ek) if ek else tuned[1])
-    return (int(eq) if eq else auto(t_q), int(ek) if ek else auto(t_k))
+    return auto(t_q), auto(t_k)
 
 
 def resolve_blocks(t_q: int, t_k: int, block_q: Optional[int] = None,
@@ -391,9 +369,9 @@ def resolve_blocks(t_q: int, t_k: int, block_q: Optional[int] = None,
     """Tile sizes a call at these sequence lengths runs with: the explicit
     arguments, else :func:`default_blocks`, clamped to the sequence."""
     if block_q is None or block_k is None:
-        env_q, env_k = default_blocks(t_q, t_k)
-        block_q = env_q if block_q is None else block_q
-        block_k = env_k if block_k is None else block_k
+        auto_q, auto_k = default_blocks(t_q, t_k)
+        block_q = auto_q if block_q is None else block_q
+        block_k = auto_k if block_k is None else block_k
     return min(block_q, t_q), min(block_k, t_k)
 
 

@@ -4,9 +4,9 @@ The reference bounds sequence models by single-node memory (SURVEY §5.7);
 its largest classifier heads materialize full (N, V) score matrices. For a
 TPU LM at vocab 32k, f32 logits are 1 GB per 8k tokens — at batch 32 ×
 seq 2048 that is 8 GB of HBM, which is what forces large batches into
-rematerialization (MFU_SWEEP.json: batches ≥16 drop to ~0.35 MFU under
-remat). This op is the LM-head analog of flash attention: never hold the
-full logits.
+rematerialization (a sweep made before PR 1 saw batches ≥16 drop to ~0.35
+MFU under remat; not measured on the current code). This op is the LM-head
+analog of flash attention: never hold the full logits.
 
 Mechanism (``jax.custom_vjp``, like ops/flash_attention.py):
 

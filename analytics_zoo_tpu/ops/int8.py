@@ -98,7 +98,7 @@ def int8_matmul(x: jnp.ndarray, packed: Dict[str, Any],
         interpret = mode == "interpret"
         k, n = packed["q"].shape
         blocks = int8_fused.resolve_blocks(
-            int(np.prod(x.shape[:-1])), n, k, x.dtype, interpret=interpret)
+            int(np.prod(x.shape[:-1])), n, k, interpret=interpret)
         if blocks is not None:
             bm, bn, bk = blocks
             return int8_fused.int8_matmul_fused(

@@ -23,8 +23,8 @@ and accumulates ``window @ W[kh,kw]`` per tap with per-output-pixel
 activation scales (one abs-max over channels per pixel — the granularity the
 unfused path in :mod:`ops.int8` now matches).
 
-Block sizes come from :mod:`ops.tuning` (on-disk autotuner cache keyed by
-device kind) with ``ZOO_INT8_BLOCK_M/N/K`` env overrides.  The router
+Block sizes are the caller's arguments or the fixed defaults, shrunk to
+divisors of the shape (:func:`resolve_blocks`).  The router
 (:func:`ops.int8.int8_matmul` / :func:`ops.int8.int8_conv2d`) asks
 :func:`resolve_blocks` / :func:`conv_supported` first and sends shapes the
 kernels do not cover to the lax path; a kernel that was selected and cannot
@@ -47,7 +47,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .backend import interpret_default
 
-#: Fixed pre-autotuner schedule (the constants the tuner sweeps around).
+#: The schedule of a call that passes no blocks.
 DEFAULT_BLOCK_M = 256
 DEFAULT_BLOCK_N = 256
 DEFAULT_BLOCK_K = 512
@@ -106,31 +106,19 @@ def _shrink_to_divisor(dim: int, block: int, floor: int) -> Optional[int]:
     return None
 
 
-def resolve_blocks(m: int, n: int, k: int, dtype,
+def resolve_blocks(m: int, n: int, k: int,
                    block_m: Optional[int] = None,
                    block_n: Optional[int] = None,
                    block_k: Optional[int] = None,
                    interpret: bool = False) -> Optional[Tuple[int, int, int]]:
     """Resolve the (block_m, block_n, block_k) schedule for an (M,K)×(K,N)
-    fused matmul: explicit args win, then ``ZOO_INT8_BLOCK_M/N/K`` env, then
-    the tuning cache (per shape-bucket × dtype × device kind), then the fixed
-    defaults; every choice is shrunk to a power-of-two divisor of its dim.
-    Returns None when N or K cannot tile (M is padded by the caller) — the
-    router's test for sending the shape to the lax path."""
-    if block_m is None or block_n is None or block_k is None:
-        env = tuple(os.environ.get(f"ZOO_INT8_BLOCK_{ax}")
-                    for ax in ("M", "N", "K"))
-        tuned = None
-        if not any(env):
-            from . import tuning
-
-            tuned = tuning.matmul_lookup(m, n, k, dtype)
-        block_m = block_m or (int(env[0]) if env[0] else None) or \
-            (tuned and tuned[0]) or DEFAULT_BLOCK_M
-        block_n = block_n or (int(env[1]) if env[1] else None) or \
-            (tuned and tuned[1]) or DEFAULT_BLOCK_N
-        block_k = block_k or (int(env[2]) if env[2] else None) or \
-            (tuned and tuned[2]) or DEFAULT_BLOCK_K
+    fused matmul: explicit args, else the fixed defaults; every choice is
+    shrunk to a power-of-two divisor of its dim. Returns None when N or K
+    cannot tile (M is padded by the caller) — the router's test for sending
+    the shape to the lax path."""
+    block_m = block_m or DEFAULT_BLOCK_M
+    block_n = block_n or DEFAULT_BLOCK_N
+    block_k = block_k or DEFAULT_BLOCK_K
     # M need not divide: the caller zero-pads the rows up to a block multiple
     # (ragged shape-bucket edges); clamp near M so a tiny batch doesn't pay a
     # full 256-row tile of padding compute
@@ -217,7 +205,7 @@ def int8_matmul_fused(x: jnp.ndarray, packed: Dict[str, Any], *,
     out_dtype = jnp.float32 if out_dtype is None else out_dtype
     if m == 0:
         return jnp.zeros(lead + (n,), out_dtype)
-    blocks = resolve_blocks(m, n, k, x.dtype, block_m, block_n, block_k,
+    blocks = resolve_blocks(m, n, k, block_m, block_n, block_k,
                             interpret=interpret)
     if blocks is None:
         raise ValueError(f"int8_matmul_fused: x{x.shape} @ w{wq.shape} does "

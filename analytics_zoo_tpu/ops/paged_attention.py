@@ -23,11 +23,9 @@ decode verify step that scores k draft tokens against the same paged cache
 in one pass (:mod:`analytics_zoo_tpu.ops.speculative`) and the prefill-chunk
 and prefix-suffix widths.
 
-Block schedule: ``block_h`` (heads per program) is the tunable knob —
-resolved via env ``ZOO_PAGED_BLOCK_H``, then the on-disk autotuner cache
-(:mod:`analytics_zoo_tpu.ops.tuning` ``PAGED`` op table, exactly like
-matmul/flash), then all-heads. ``block_q`` (query rows per program) and the
-pages of a compute block are derived from the shapes, not tuned: the whole
+Block schedule: a function of the shapes alone. ``block_h`` (heads per
+program) is all heads unless the caller passes another divisor. ``block_q``
+(query rows per program) and the pages of a compute block: the whole
 ``q_len`` while its softmax scratch fits scoped VMEM (every decode and
 verify step), else the largest tile that does (:func:`query_block` — the
 prefill-chunk and prefix-suffix widths, where an untiled call is refused by
@@ -91,38 +89,14 @@ def paged_mode() -> str:
 
 
 def use_kernel() -> bool:
-    """Resolve routing at trace time (a jitted decode step bakes the answer,
-    like ``flash_attention.default_blocks``)."""
+    """Resolve routing at trace time (a jitted decode step bakes the
+    answer)."""
     mode = paged_mode()
     if mode == "off":
         return False
     if mode == "on":
         return True
     return jax.default_backend() == "tpu"
-
-
-def default_block_h(h: int, *, q_len: int = 1,
-                    pages_per_slot: Optional[int] = None,
-                    page_size: Optional[int] = None,
-                    d: Optional[int] = None, dtype=None) -> int:
-    """Heads per kernel program. Resolution order mirrors
-    ``flash_attention.default_blocks``: ``ZOO_PAGED_BLOCK_H`` env, then the
-    tuning cache's ``paged`` table, then all heads in one program (the small
-    working sets of decode rarely pressure VMEM, and fewer grid steps win
-    when they fit)."""
-    env = os.environ.get("ZOO_PAGED_BLOCK_H")
-    if env:
-        bh = int(env)
-        return bh if h % bh == 0 else h
-    if pages_per_slot and page_size and d:
-        from .tuning import paged_lookup
-
-        tuned = paged_lookup(q_len, pages_per_slot, page_size, h, d,
-                             dtype if dtype is not None
-                             else np.dtype("float32"))
-        if tuned is not None and h % tuned == 0:
-            return tuned
-    return h
 
 
 def query_block(q_len: int, block_h: int, d: int, dtype) -> int:
@@ -288,8 +262,10 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     if interpret is None:
         interpret = interpret_default()
     if block_h is None:
-        block_h = default_block_h(h, q_len=q_len, pages_per_slot=pps,
-                                  page_size=page_size, d=d, dtype=q.dtype)
+        # all heads in one program: decode's working set is small, and 8
+        # heads a program ran 35-38% slower than 16 on the v5e (PERF.md
+        # section 6, PR 27)
+        block_h = h
     if h % block_h:
         raise ValueError(f"paged_attention: block_h={block_h} does not "
                          f"divide the {h} heads of q{q.shape}")
@@ -349,9 +325,8 @@ def synthetic_paged_case(n_slots: int, pages_per_slot: int, page_size: int,
     pages drawn in random order (as a pool that has served and freed
     streams hands them out: neighbours in a table are not neighbours in
     the pool), unallocated entries scratch. The ONE
-    fixture builder shared by the autotuner sweep
-    (:func:`~analytics_zoo_tpu.ops.tuning.tune_paged_attention`), the bench
-    parity gate and the kernel tests, so none can drift from the real
+    fixture builder shared by ``chip_smoke.py``'s parity phase and the
+    kernel tests, so neither can drift from the real
     :class:`~analytics_zoo_tpu.ops.kv_cache.PagePool` layout.
 
     ``lengths`` (optional, (n_slots,) int): valid positions per slot
@@ -378,6 +353,6 @@ def synthetic_paged_case(n_slots: int, pages_per_slot: int, page_size: int,
     return q, k_pages, v_pages, jnp.asarray(table), jnp.asarray(lengths)
 
 
-__all__ = ["default_block_h", "paged_attention", "paged_mode",
+__all__ = ["paged_attention", "paged_mode",
            "pages_per_block", "query_block", "synthetic_paged_case",
            "use_kernel"]

@@ -10,8 +10,7 @@ KV cache (:mod:`analytics_zoo_tpu.ops.kv_cache`) and
   into free slots and finished ones retired *per decode step*, so aggregate
   throughput tracks active tokens instead of the slowest request in a batch
   (the reference's run-to-completion Flink batches are exactly the
-  anti-pattern: ``admit_policy="batch"`` reproduces them for the bench's
-  ≥1.5× comparison).
+  anti-pattern).
 * :class:`GenerationEngine` — the broker-facing job: consumes generation
   requests from ``generation_stream`` (XREADGROUP, same consumer-group
   semantics as the one-shot engine) and streams frame-per-chunk token deltas
@@ -440,18 +439,12 @@ class ContinuousBatcher:
     killed loop is respawned by a supervisor with cache/slot state intact,
     so in-flight streams survive (kill-the-engine drill in
     tests/test_generation.py).
-
-    ``admit_policy``: ``"continuous"`` (default) admits whenever a slot is
-    free; ``"batch"`` is the run-to-completion baseline — admission only
-    when EVERY slot is free — kept for the bench's ≥1.5× comparison.
     """
 
     def __init__(self, model, params, *, n_slots: int = 8,
                  page_size: int = 16, max_seq_len: Optional[int] = None,
                  n_pages: Optional[int] = None, top_k: int = 0,
                  spec_k: int = 0, spec_ngram: int = 3,
-                 admit_policy: str = "continuous",
-                 batch_window_s: float = 0.05,
                  prefix_cache_pages: int = 0,
                  prefix_block_tokens: int = 0,
                  prefill_chunk_tokens: int = 0,
@@ -462,8 +455,6 @@ class ContinuousBatcher:
                  donate_cache: bool = True,
                  registry: Optional[HealthRegistry] = None,
                  autostart: bool = True):
-        if admit_policy not in ("continuous", "batch"):
-            raise ValueError(f"unknown admit_policy {admit_policy!r}")
         if spec_k < 0:
             raise ValueError(f"spec_k must be >= 0, got {spec_k}")
         if page_size & (page_size - 1):
@@ -493,13 +484,6 @@ class ContinuousBatcher:
         self.n_slots = int(n_slots)
         # clamp to the vocabulary: lax.top_k with k > V fails at trace time
         self.top_k = min(int(top_k), getattr(model, "vocab", int(top_k)))
-        self.admit_policy = admit_policy
-        # batch (run-to-completion) mode only: wait this long for a full
-        # wave before sealing a partial one — the real RTC server's batching
-        # window, and what keeps the bench comparison honest (a wave of 1
-        # would flatter continuous mode)
-        self.batch_window_s = float(batch_window_s)
-        self._pending_since: Optional[float] = None
         self.cfg, self.cache = model.init_kv_cache(
             n_slots, page_size=page_size, max_seq_len=max_seq_len,
             n_pages=n_pages)
@@ -749,8 +733,6 @@ class ContinuousBatcher:
                 _cb(tokens, final, meta)
 
         req.on_chunk = fanout
-        if self._pending.empty() and not self._backlog:
-            self._pending_since = time.monotonic()
         self._pending.put(req)
         self._wake.set()
         return handle
@@ -889,19 +871,8 @@ class ContinuousBatcher:
         self._backlog = keep
 
     def _admission_open(self) -> bool:
-        if self.admit_policy == "continuous":
-            return any(s is None for s in self._slots) or bool(
-                self._backlog and self._backlog[0].priority == "critical")
-        # run-to-completion: only between waves, and only once a FULL wave is
-        # pending (or the batching window expired) — partial waves would
-        # understate the baseline this mode exists to represent
-        if any(s is not None for s in self._slots):
-            return False
-        if len(self._backlog) >= self.n_slots:
-            return True
-        since = self._pending_since
-        return since is not None and \
-            time.monotonic() - since >= self.batch_window_s
+        return any(s is None for s in self._slots) or bool(
+            self._backlog and self._backlog[0].priority == "critical")
 
     def _preempt_for(self, req: _Request) -> bool:
         """Make room for a critical request by preempting a BULK slot: the
@@ -949,9 +920,8 @@ class ContinuousBatcher:
 
     def _admit(self):
         self._drain_pending()
-        # the policy gate opens ONCE per loop pass; a wave then fills every
-        # free slot (checking the gate per-request would seal a batch-mode
-        # wave after its first admission)
+        # the gate is asked ONCE per loop pass; the pass then fills every
+        # free slot
         if not self._admission_open():
             return
         while not self._stop.is_set():

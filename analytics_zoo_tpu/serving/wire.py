@@ -2,8 +2,8 @@
 
 The seed protocol encoded every tensor as ``.npy`` → base64 → JSON list over
 TCP; at serving batch sizes the data plane (encode + copy + parse), not the
-model, dominated the request round trip (SERVING_BENCH.json: 71 ms dispatch
-RTT for microsecond TPU work — the same bottleneck BigDL 2.0 calls out for
+model, dominated the request round trip (71 ms dispatch RTT for microsecond
+TPU work on a remote chip, before PR 1 — the same bottleneck BigDL 2.0 calls out for
 its serving pipeline). This module replaces that hot path with a versioned
 binary frame:
 

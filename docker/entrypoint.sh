@@ -1,16 +1,11 @@
 #!/bin/sh
 # serve  -> cluster-serving stack (broker + engine + HTTP frontend)
-# bench  -> the north-star benchmark
 # anything else -> exec verbatim (python train.py, pytest, a shell, ...)
 set -e
 case "$1" in
   serve)
     shift
     exec python -m analytics_zoo_tpu.serving.stack --host 0.0.0.0 "$@"
-    ;;
-  bench)
-    shift
-    exec python /opt/zoo/bench.py "$@"
     ;;
   *)
     exec "$@"

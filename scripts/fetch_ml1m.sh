@@ -1,10 +1,9 @@
 #!/usr/bin/env bash
-# Fetch the real MovieLens-1M ratings and point the NCF bench/examples at it.
+# Fetch the real MovieLens-1M ratings and point the NCF example at it.
 #
-# The bench (bench.py) and analytics_zoo_tpu.data.datasets.movielens_1m read
-# the file named by the ML1M_RATINGS env var; without it they fall back to a
-# statistically-matched synthetic dataset so everything still runs hermetically
-# on hosts with no network egress.
+# examples/ncf_recommendation.py reads the file named by the ML1M_RATINGS env
+# var; without it it falls back to a statistically-matched synthetic dataset
+# so everything still runs hermetically on hosts with no network egress.
 #
 # Usage: scripts/fetch_ml1m.sh [dest-dir]   (default ~/.zoo_datasets)
 set -euo pipefail
@@ -19,5 +18,5 @@ if [ ! -f "$DEST_ROOT/ml-1m/ratings.dat" ]; then
   rm -f "$ZIP"
 fi
 
-echo "MovieLens-1M ready. Run benchmarks with:"
+echo "MovieLens-1M ready. Use it with:"
 echo "  export ML1M_RATINGS=$DEST_ROOT/ml-1m/ratings.dat"

@@ -17,10 +17,8 @@
 # The graph-layer rules need a traced computation, so they run where one
 # exists: TrainConfig.graph_checks at fit() start (now incl. hbm-budget /
 # donation-missed / peak-temporary), InferenceModel/serving warmup at
-# model-load time (hbm-budget + cache-alias on the decode step), and the
-# bench gates (--int8-dispatch / --update-sharding / --generation). This
-# script is the host-layer CI gate and is wired into
-# scripts/run_serving_bench.sh --quick. The dynamic halves are gated by
+# model-load time (hbm-budget + cache-alias on the decode step). This
+# script is the host-layer CI gate. The dynamic halves are gated by
 # scripts/run_chaos_suite.sh via `python -m analytics_zoo_tpu.analysis
 # --witness` (locks) and `--mem-witness` (allocations).
 set -euo pipefail

@@ -15,11 +15,13 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from analytics_zoo_tpu.analysis.rules import lint_sharded_gather
 from analytics_zoo_tpu.common import TrainConfig
 from analytics_zoo_tpu.engine import Estimator
 from analytics_zoo_tpu.nn import Sequential
 from analytics_zoo_tpu.nn import layers as L
 from analytics_zoo_tpu.nn.layers.embedding import Embedding, FusedPairEmbedding
+from analytics_zoo_tpu.parallel import collective_counts
 from analytics_zoo_tpu.parallel import embedding_sharding as es
 
 pytestmark = pytest.mark.embedding
@@ -236,3 +238,24 @@ def test_sharded_opt_state_is_shard_local(zoo_ctx):
     for m in moments:
         assert m.sharding.spec in (P("dp"), P("dp", None))
         assert m.addressable_shards[0].data.shape[0] == 64 // 8
+    # the model-parallel gather's collective pair is in the compiled step:
+    # ids all-gathered to the owner shards, rows back by reduce-scatter
+    counts = collective_counts(est._train_step.lower(
+        est.train_state, est._to_global((x, y))).compile().as_text())
+    assert counts.get("all-gather", 0) >= 1, counts
+    assert counts.get("reduce-scatter", 0) >= 1, counts
+
+
+def test_shard_local_gather_fits_where_the_dense_table_cannot():
+    """``lint_sharded_gather`` traces the block ONE device executes: with a
+    table 4x the per-device budget, rows/8 and the batch's partials fit; a
+    budget under the shard itself is a finding."""
+    rows, width, batch = 4096, 16, 64
+    table_bytes = rows * width * 4
+    assert lint_sharded_gather(rows, width, batch, 8,
+                               hbm_budget_bytes=table_bytes // 4) == []
+    tight = lint_sharded_gather(rows, width, batch, 8,
+                                hbm_budget_bytes=table_bytes // 16)
+    assert [f.rule for f in tight] == ["hbm-budget"]
+    with pytest.raises(ValueError, match="must divide"):
+        lint_sharded_gather(rows + 1, width, batch, 8)

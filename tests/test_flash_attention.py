@@ -219,8 +219,8 @@ def test_flash_gradients_bf16_close_to_f32_oracle():
 
 @pytest.mark.parametrize("bq,bk", [(64, 128), (256, 64), (128, 256)])
 def test_flash_nondefault_tile_sizes_match_oracle(bq, bk):
-    """dev/mfu_sweep.py sweeps flash tile sizes via ZOO_FLASH_BLOCK_Q/K —
-    every tiling must stay numerically identical to the oracle, fwd and dq."""
+    """A caller may pass tiles other than ``default_blocks``' — every tiling
+    must stay numerically identical to the oracle, fwd and dq."""
     rng = np.random.default_rng(3)
     q, k, v = (jnp.asarray(rng.normal(size=(2, 256, 2, 16)), jnp.float32)
                for _ in range(3))
@@ -234,33 +234,18 @@ def test_flash_nondefault_tile_sizes_match_oracle(bq, bk):
                                rtol=2e-4, atol=2e-4)
 
 
-def test_default_blocks_env_knobs(monkeypatch):
-    from analytics_zoo_tpu.ops.flash_attention import default_blocks
-
-    monkeypatch.delenv("ZOO_FLASH_BLOCK_Q", raising=False)
-    monkeypatch.delenv("ZOO_FLASH_BLOCK_K", raising=False)
-    assert default_blocks() == (128, 128)
-    monkeypatch.setenv("ZOO_FLASH_BLOCK_Q", "256")
-    monkeypatch.setenv("ZOO_FLASH_BLOCK_K", "512")
-    assert default_blocks() == (256, 512)
-
-
-def test_default_blocks_adaptive(monkeypatch):
-    """Tile adaptivity is a kernel lever (4× before PR 1): largest
-    power-of-two ≤512 dividing the sequence; env always wins; unknown or
+def test_default_blocks_adaptive():
+    """The largest power-of-two ≤512 dividing the sequence; unknown or
     non-dividing lengths get 128 (``tiles_ok`` then tells an auto router to
     stay on full attention)."""
     from analytics_zoo_tpu.ops.flash_attention import default_blocks
 
-    monkeypatch.delenv("ZOO_FLASH_BLOCK_Q", raising=False)
-    monkeypatch.delenv("ZOO_FLASH_BLOCK_K", raising=False)
+    assert default_blocks() == (128, 128)
     assert default_blocks(2048, 2048) == (512, 512)
     assert default_blocks(512, 1024) == (512, 512)
     assert default_blocks(256, 384) == (256, 128)   # 384 = 3·128
     assert default_blocks(16384, None) == (512, 128)
     assert default_blocks(300, 300) == (128, 128)   # non-dividing
-    monkeypatch.setenv("ZOO_FLASH_BLOCK_Q", "1024")
-    assert default_blocks(2048, 2048) == (1024, 512)  # env wins per-axis
 
 
 def test_prefer_flash_single_device_rule(monkeypatch):

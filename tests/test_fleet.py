@@ -6,8 +6,8 @@ size-triggered AOF compaction).
 
 Replicas here are thread-mode ClusterServing engines over a stub
 device-bound model (predict sleeps, GIL released — the routing tier is what
-is under test, not XLA); the subprocess replica path is exercised by
-`bench.py --fleet` / the stack entrypoint.
+is under test, not XLA); the subprocess replica path is exercised by the
+stack entrypoint (`tests/test_serving_stack.py`).
 """
 
 import json
@@ -22,6 +22,8 @@ import numpy as np
 import pytest
 
 from analytics_zoo_tpu.inference import InferenceModel
+from analytics_zoo_tpu.observability import events as _ev
+from analytics_zoo_tpu.observability import export_trace
 from analytics_zoo_tpu.observability import recorder as _flight
 from analytics_zoo_tpu.serving import (ClusterServing, FleetSupervisor,
                                        InputQueue, OutputQueue, ReplicaRouter,
@@ -210,6 +212,13 @@ def test_kill_one_of_four_midburst_zero_loss(zoo_ctx, tmp_path):
         dump = _await_flight_dump(rec)
         assert dump["trigger"] == "failover"
         assert any(e["kind"] == "fleet.failover" for e in dump["events"])
+        # the failover is ONE decision event for the killed replica, and the
+        # trace it names exports whole (the fleet.failover span is in it)
+        (failover,) = [e for e in _ev.events(kind="fleet.failover")
+                       if e.fields.get("replica") == "r1"]
+        trace = export_trace(failover.trace_id)
+        assert any(s["name"] == "fleet.failover"
+                   for s in trace["traceEvents"])
     finally:
         _flight.uninstall()
         if fleet is not None:

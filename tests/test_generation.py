@@ -131,8 +131,10 @@ def test_no_page_leak_across_retirements(batcher, np_rng):
     assert batcher.active_slots() == 0
     stats = batcher.stats()
     assert stats["requests"].get("ok") == 12
-    # bucket invariant: the multi-slot decode step compiled exactly one shape
+    # bucket invariant: the multi-slot decode step compiled exactly one
+    # shape, and prompts of 5-8 tokens prefilled in power-of-two buckets
     assert stats["distinct_decode_shapes"] == 1
+    assert stats["prefill_buckets"] == [8]
 
 
 def test_pool_exhaustion_truncates_not_deadlocks(model_and_params):
@@ -148,6 +150,22 @@ def test_pool_exhaustion_truncates_not_deadlocks(model_and_params):
         assert b.pool.free_count() == b.pool.capacity
     finally:
         b.close()
+
+
+# ---------------------------------------------------------------- admission
+
+@pytest.mark.parametrize("retired", [{"admit_policy": "batch"},
+                                     {"admit_policy": "continuous"},
+                                     {"batch_window_s": 0.05}])
+def test_one_admission_policy(model_and_params, retired):
+    """Admission is continuous (a free slot, or a critical request at the
+    head of the backlog) and nothing selects another policy: the
+    run-to-completion baseline and its window went with the bench that
+    compared against it."""
+    m, params = model_and_params
+    with pytest.raises(TypeError, match="unexpected keyword"):
+        ContinuousBatcher(m, params, n_slots=2, page_size=4, max_seq_len=32,
+                          **retired)
 
 
 # -------------------------------------------------------------- determinism

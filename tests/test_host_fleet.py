@@ -235,6 +235,7 @@ def test_whole_host_kill_zero_loss_single_decision(tmp_path):
             while time.monotonic() < deadline and not fleet.host_failovers:
                 time.sleep(0.05)
             assert fleet.host_failovers == before + 1
+            assert fleet.requeued > 0, "the dead host held no claimed work"
             evs = [e for e in _ev.events(kind="fleet.host_failed")]
             assert len(evs) == 1
             ev = evs[-1]

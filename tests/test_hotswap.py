@@ -29,6 +29,8 @@ from analytics_zoo_tpu.engine.checkpoint import (CheckpointCorruptError,
                                                  save_checkpoint,
                                                  verify_checkpoint)
 from analytics_zoo_tpu.inference import InferenceModel
+from analytics_zoo_tpu.observability import events as _ev
+from analytics_zoo_tpu.observability import export_trace
 from analytics_zoo_tpu.serving import (ClusterServing, FleetSupervisor,
                                        InputQueue, ModelPublisher,
                                        ModelSwapper, OutputQueue,
@@ -522,6 +524,16 @@ def _publish(pub, tmp_path, it, b):
                                        iteration=it, epoch=0))
 
 
+def _assert_rollout_event(kind, version):
+    """The rollout's verdict on ``version`` is on the decision-event stream
+    and the trace it names exports whole (the rollout span is in it)."""
+    (ev,) = [e for e in _ev.events(kind=kind)
+             if e.fields.get("version") == version]
+    trace = export_trace(ev.trace_id)
+    assert any(s["name"] == "rollout" for s in trace["traceEvents"])
+    return ev
+
+
 def _versions_converged(fleet, version):
     mv = fleet.model_versions()
     return (mv and all(v == version for v in mv.values())
@@ -547,6 +559,7 @@ def test_rollout_canary_promotes_fleet_wide(tmp_path, zoo_ctx):
         assert n > 10
         assert ((rec["version"], "promoted")
                 in fleet.rollout.outcomes), fleet.rollout.outcomes
+        _assert_rollout_event("rollout.promoted", rec["version"])
         # operator surfaces: readiness + stats carry versions & phase
         ready, detail = fleet.readiness()
         assert ready
@@ -590,6 +603,8 @@ def test_poisoned_publish_rolls_back_zero_loss(tmp_path, zoo_ctx):
         rej = pub.check_rejections()
         assert any(r["version"] == poison["version"] and "nan" in r["reason"]
                    for r in rej), rej
+        ev = _assert_rollout_event("rollout.rejected", poison["version"])
+        assert ev.fields["outcome"] in ("rolled_back", "aborted")
         pub.close()
     finally:
         if fleet is not None:

@@ -194,7 +194,7 @@ def test_int8_imported_graph_falls_back_to_weight_only(zoo_ctx, np_rng):
 
 def test_device_apply_matches_predict_incl_int8(zoo_ctx, np_rng):
     """device_apply() is the public device-resident escape hatch (AOT export,
-    serving_bench's int8-vs-bf16 loop): it must expose exactly the predict
+    the fused-dispatch rule, chip_smoke.py): it must expose exactly the predict
     computation, before AND after quantize_int8 rewires apply/params."""
     import jax.numpy as jnp
 

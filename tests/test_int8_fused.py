@@ -4,10 +4,9 @@ Differential coverage: the fused int8 matmul/conv kernels
 (ops/int8_fused.py) vs the unfused lax oracle (ops/int8.py) and vs f32;
 the structural no-unfused-quantize-op invariant of the fused dispatch path
 (the ``fused-int8-dispatch`` rule of the shared analysis engine that the
-serving quick gate runs); the block-schedule tuning cache (ops/tuning.py);
-and the
-serving-engine startup warmup that moved int8 packing off the first
-request. All CPU-safe (pallas interpreter) — these run in tier-1.
+serving warm-up runs); and the serving-engine startup warmup that moved
+int8 packing off the first request. All CPU-safe (pallas interpreter) —
+these run in tier-1.
 """
 
 import os
@@ -18,7 +17,7 @@ import numpy as np
 import pytest
 
 from analytics_zoo_tpu.ops import int8 as int8_ops
-from analytics_zoo_tpu.ops import int8_fused, tuning
+from analytics_zoo_tpu.ops import int8_fused
 from analytics_zoo_tpu.ops.int8 import quantize_weight
 
 pytestmark = pytest.mark.pallas
@@ -32,16 +31,6 @@ def _packed(w):
 def fused_interpret(monkeypatch):
     """Force the router onto the fused kernels (interpreter on CPU)."""
     monkeypatch.setenv("ZOO_INT8_FUSED", "interpret")
-
-
-@pytest.fixture()
-def tuning_cache(tmp_path, monkeypatch):
-    """Isolated on-disk tuning cache per test."""
-    path = str(tmp_path / "tuning.json")
-    monkeypatch.setenv("ZOO_TPU_TUNING_CACHE", path)
-    tuning.invalidate()
-    yield path
-    tuning.invalidate()
 
 
 # ------------------------------------------------------------ matmul numerics
@@ -279,83 +268,6 @@ def test_fused_dispatch_structure_invariants(zoo_ctx, fused_interpret,
     assert st_off["quantize_ops_outside_kernels"] > 0
     assert st_off["int8_intermediates_outside_kernels"] > 0
     assert {f["rule"] for f in st_off["findings"]} == {"fused-int8-dispatch"}
-
-
-# -------------------------------------------------------------- tuning cache
-
-
-def test_tune_int8_matmul_persists_and_is_used(tuning_cache, np_rng):
-    best = tuning.tune_int8_matmul(
-        8, 32, 64, dtype=np.float32,
-        candidates=((8, 16, 32), (8, 32, 64)), interpret=True, iters=1)
-    assert best is not None and os.path.exists(tuning_cache)
-    looked = tuning.matmul_lookup(8, 32, 64, np.float32)
-    assert looked == (best["block_m"], best["block_n"], best["block_k"])
-    # same shape BUCKET (pow2 ladder) answers the lookup for m in (5..8]
-    assert tuning.matmul_lookup(5, 32, 64, np.float32) == looked
-    # resolve_blocks picks the tuned schedule up with no explicit blocks
-    blocks = int8_fused.resolve_blocks(8, 32, 64, np.float32,
-                                       interpret=True)
-    assert blocks == looked
-    # sweep details ride the cache entry (scored candidates + memory fields)
-    raw = tuning.lookup(tuning.MATMUL_OP,
-                        tuning.matmul_key(8, 32, 64, np.float32))
-    assert [e for e in raw["swept"] if "elapsed_ms" in e]
-
-
-def test_tuning_env_override_wins(tuning_cache, monkeypatch):
-    tuning.record(tuning.MATMUL_OP,
-                  tuning.matmul_key(8, 32, 64, np.float32),
-                  {"block_m": 8, "block_n": 16, "block_k": 32})
-    monkeypatch.setenv("ZOO_INT8_BLOCK_M", "4")
-    monkeypatch.setenv("ZOO_INT8_BLOCK_N", "32")
-    monkeypatch.setenv("ZOO_INT8_BLOCK_K", "64")
-    blocks = int8_fused.resolve_blocks(8, 32, 64, np.float32,
-                                       interpret=True)
-    assert blocks == (4, 32, 64)
-
-
-def test_tuning_counters_and_corrupt_cache(tuning_cache):
-    from analytics_zoo_tpu.common import telemetry as _tm
-
-    def counter_val(name, op):
-        fam = _tm.snapshot().get(name, {})
-        return fam.get("samples", {}).get(f'op="{op}"', 0)
-
-    tuning.matmul_lookup(8, 32, 64, np.float32)      # miss: nothing tuned
-    tuning.record(tuning.MATMUL_OP,
-                  tuning.matmul_key(8, 32, 64, np.float32),
-                  {"block_m": 8, "block_n": 16, "block_k": 32})
-    assert tuning.matmul_lookup(8, 32, 64, np.float32) == (8, 16, 32)
-    # corrupt cache file must read as empty, never raise
-    with open(tuning_cache, "w") as f:
-        f.write("{not json")
-    tuning.invalidate()
-    assert tuning.matmul_lookup(8, 32, 64, np.float32) is None
-
-
-def test_flash_default_blocks_consults_tuning_cache(tuning_cache,
-                                                    monkeypatch):
-    from analytics_zoo_tpu.ops.flash_attention import default_blocks
-
-    monkeypatch.delenv("ZOO_FLASH_BLOCK_Q", raising=False)
-    monkeypatch.delenv("ZOO_FLASH_BLOCK_K", raising=False)
-    assert default_blocks(1024, 1024) == (512, 512)     # adaptive default
-    tuning.record(tuning.FLASH_OP,
-                  tuning.flash_key(1024, 1024, np.dtype("bfloat16")),
-                  {"block_q": 256, "block_k": 128})
-    assert default_blocks(1024, 1024) == (256, 128)     # tuned wins
-    monkeypatch.setenv("ZOO_FLASH_BLOCK_Q", "128")
-    assert default_blocks(1024, 1024) == (128, 128)     # env wins over tuned
-
-
-def test_tune_flash_blocks_sweep(tuning_cache):
-    best = tuning.tune_flash_blocks(
-        128, 128, batch=1, heads=2, d=16, causal=True, with_backward=False,
-        candidates=((32, 32), (64, 64)), interpret=True, iters=1)
-    assert best is not None
-    assert tuning.flash_lookup(128, 128) == (best["block_q"],
-                                             best["block_k"])
 
 
 # -------------------------------------------------------- engine warmup path

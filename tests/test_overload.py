@@ -19,6 +19,8 @@ import pytest
 
 from analytics_zoo_tpu.common.resilience import RetryPolicy
 from analytics_zoo_tpu.inference import InferenceModel
+from analytics_zoo_tpu.observability import events as _ev
+from analytics_zoo_tpu.observability import export_trace
 from analytics_zoo_tpu.serving import (ClusterServing, FleetSupervisor,
                                        FrontEndApp, InputQueue, OutputQueue,
                                        ReplicaRouter, ServingConfig,
@@ -677,6 +679,12 @@ def test_autoscale_up_then_down_zero_loss(zoo_ctx):
             assert len(fleet._handles) == 1, fleet.scale_events
             downs = [e for e in fleet.scale_events if e[0] == "down"]
             assert downs
+            # both directions are decision events whose traces export whole
+            for kind in ("autoscale.up", "autoscale.down"):
+                evs = _ev.events(kind=kind)
+                assert evs, kind
+                assert all(export_trace(e.trace_id)["traceEvents"]
+                           for e in evs), kind
             # the survivors still serve
             iq = InputQueue(port=broker.port)
             oq = OutputQueue(port=broker.port)

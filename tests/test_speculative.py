@@ -16,8 +16,7 @@ import jax.numpy as jnp
 from analytics_zoo_tpu.models.transformer import TransformerLM
 from analytics_zoo_tpu.ops.kv_cache import (decode_attention_multi,
                                             paged_read, sample_tokens)
-from analytics_zoo_tpu.ops.paged_attention import (default_block_h,
-                                                   paged_attention,
+from analytics_zoo_tpu.ops.paged_attention import (paged_attention,
                                                    query_block,
                                                    synthetic_paged_case)
 from analytics_zoo_tpu.ops.speculative import (SpecDecodeConfig,
@@ -164,36 +163,6 @@ def test_query_block_fits_vmem_or_names_the_shape():
     with pytest.raises(ValueError, match="block_h=3"):
         paged_attention(q, kp, vp, table, lengths, page_size=8, block_h=3,
                         interpret=True)
-
-
-def test_default_block_h_env_and_divisibility(monkeypatch):
-    monkeypatch.setenv("ZOO_PAGED_BLOCK_H", "2")
-    assert default_block_h(8) == 2
-    # non-divisor env falls back to all heads rather than a broken grid
-    monkeypatch.setenv("ZOO_PAGED_BLOCK_H", "3")
-    assert default_block_h(8) == 8
-    monkeypatch.delenv("ZOO_PAGED_BLOCK_H")
-
-
-def test_paged_tuning_table(tmp_path, monkeypatch):
-    """The PAGED op rides the same autotuner cache as matmul/flash: a sweep
-    persists the winning block_h, lookups answer from it, and the kernel's
-    default consults it."""
-    from analytics_zoo_tpu.ops import tuning
-
-    monkeypatch.setenv("ZOO_TPU_TUNING_CACHE", str(tmp_path / "tuning.json"))
-    tuning.invalidate()
-    assert tuning.paged_lookup(4, 6, 8, 4, 16, np.float32) is None
-    best = tuning.tune_paged_attention(4, 6, 8, 4, 16, np.float32,
-                                       n_slots=2, candidates=(1, 2, 3),
-                                       iters=1)
-    assert best is not None and best["block_h"] in (1, 2)   # 3 can't divide
-    assert len([e for e in best["swept"] if "elapsed_ms" in e]) == 2
-    tuned = tuning.paged_lookup(4, 6, 8, 4, 16, np.float32)
-    assert tuned == best["block_h"]
-    assert default_block_h(4, q_len=4, pages_per_slot=6, page_size=8, d=16,
-                           dtype=np.dtype("float32")) == tuned
-    tuning.invalidate()
 
 
 # ----------------------------------------------- batcher: spec decode mode

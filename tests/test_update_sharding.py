@@ -11,8 +11,6 @@ only come from a structural bug (wrong scaling, dropped microbatch, slice
 misalignment), never from rounding.
 """
 
-import importlib.util
-import os
 import sys
 
 import jax
@@ -21,6 +19,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
+from analytics_zoo_tpu.analysis.memory import memory_fields
 from analytics_zoo_tpu.common import (MeshConfig, TrainConfig,
                                       init_zoo_context, reset_zoo_context)
 from analytics_zoo_tpu.common import telemetry as _tm
@@ -168,6 +167,15 @@ def test_flat_opt_state_is_one_over_dp(zoo_ctx):
     assert e_s._update_mode() == "flat"
     r, s = opt_bytes(e_r), opt_bytes(e_s)
     assert s <= r / 8 * 1.35 + 512, (r, s)
+
+    # and the sharded-update step costs no more device memory than the
+    # replicated one (arguments + temporaries of the compiled step)
+    def step_bytes(est):
+        return memory_fields(est._make_train_step().lower(
+            est.train_state, est._to_global((x, y))).compile()
+        )["hbm_peak_bytes"]
+
+    assert step_bytes(e_s) <= step_bytes(e_r) * 1.02
 
 
 def test_one_gradient_collective_per_global_step(zoo_ctx):
@@ -368,16 +376,6 @@ def test_bf16_checkpoint_roundtrip(zoo_ctx, tmp_path):
     assert m.dtype == jnp.float32
 
 
-# ------------------------------------------------------------ bench satellite
-def _load_bench():
-    spec = importlib.util.spec_from_file_location(
-        "zoo_bench", os.path.join(os.path.dirname(__file__), "..",
-                                  "bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 _OOM_DUMP = """RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of memory in memory space hbm. Used 17.54G of 15.48G hbm. Exceeded hbm capacity by 2.06G.
 
 Largest program allocations in hbm:
@@ -401,8 +399,9 @@ Largest program allocations in hbm:
 
 
 def test_parse_xla_memory_analysis_structured():
-    bench = _load_bench()
-    out = bench.parse_xla_memory_analysis(_OOM_DUMP)
+    from analytics_zoo_tpu.analysis.memory import parse_xla_memory_analysis
+
+    out = parse_xla_memory_analysis(_OOM_DUMP)
     assert out["hbm_peak_bytes"] == int(17.54 * 2 ** 30)
     assert out["hbm_capacity_bytes"] == int(15.48 * 2 ** 30)
     top = out["top_allocations"]
@@ -413,22 +412,7 @@ def test_parse_xla_memory_analysis_structured():
     assert top[1]["size_bytes"] == 8 * 2 ** 20
     assert top[1]["shape"].startswith("f32[2048,1024]")
     # no dump → None, not a half-filled dict
-    assert bench.parse_xla_memory_analysis("all good") is None
-
-
-def test_memory_parser_lives_in_analysis_and_bench_aliases_it():
-    """ISSUE 12 migration: the parser's home is the analysis subsystem;
-    the bench (and ops.tuning, which used to import FROM bench) alias the
-    same function — one implementation, three entry points."""
-    from analytics_zoo_tpu.analysis.memory import parse_xla_memory_analysis
-    from analytics_zoo_tpu.ops import tuning
-
-    bench = _load_bench()
-    assert bench.parse_xla_memory_analysis is parse_xla_memory_analysis
-    assert tuning.memory_fields.__module__ == \
-        "analytics_zoo_tpu.analysis.memory"
-    assert parse_xla_memory_analysis(_OOM_DUMP)["hbm_peak_bytes"] == \
-        int(17.54 * 2 ** 30)
+    assert parse_xla_memory_analysis("all good") is None
 
 
 def test_memory_fields_structured_vs_text_parity():
