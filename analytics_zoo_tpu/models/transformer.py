@@ -108,6 +108,18 @@ class TransformerLM(Layer, KerasNet):
         params["ln_f"] = lnf
         return params, {}
 
+    def cast_at_use(self, params):
+        # the head and what each block declares. Not the two embedding
+        # tables: their f32 rows are summed BEFORE the cast (apply_features,
+        # prefill, decode_step), so rounding the tables first is other
+        # arithmetic; not ln_f, which computes in f32
+        flags = {"token_embeddings": False, "pos_embeddings": False,
+                 "logits_kernel": True,
+                 "ln_f": self.ln_f.cast_at_use(params["ln_f"])}
+        for i, blk in enumerate(self.blocks):
+            flags[f"block{i}"] = blk.cast_at_use(params[f"block{i}"])
+        return flags
+
     def apply_features(self, params, x, *, training=False, rng=None):
         """Hidden states BEFORE the LM head: (B, T, hidden).
 

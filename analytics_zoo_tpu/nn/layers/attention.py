@@ -69,6 +69,10 @@ class MultiHeadAttention(Layer):
         }
         return params, {}
 
+    def cast_at_use(self, params):
+        # both projections' kernels and biases: qkv_proj / out_proj
+        return jax.tree_util.tree_map(lambda _: True, params)
+
     def qkv_proj(self, params, x):
         """Fused QKV projection → (q, k, v), each (B, T, n_head, head_dim).
         Shared by the batched forward and the KV-cache prefill/decode paths
@@ -185,6 +189,14 @@ class TransformerLayer(Layer):
             "mlp_down_bias": jnp.zeros((self.hidden_size,), param_dtype()),
         }
         return params, {}
+
+    def cast_at_use(self, params):
+        # the MLP's kernels and biases (_mlp) and the attention's own; the
+        # normalizations compute in f32 from f32 scales
+        flags = {k: True for k in params}
+        for name in ("attn", "ln1", "ln2"):
+            flags[name] = getattr(self, name).cast_at_use(params[name])
+        return flags
 
     def _mlp(self, params, x):
         """ln2 + MLP + residual — the block tail, shared by ``apply`` and the

@@ -57,6 +57,10 @@ class MoE(Layer):
             "expert_down_bias": jnp.zeros((self.n_experts, d), dt),
         }, {}
 
+    def cast_at_use(self, params):
+        # router, experts and their biases: every read in ``apply``
+        return jax.tree_util.tree_map(lambda _: True, params)
+
     def _ep_constraint(self, x, spec_with_expert_dim):
         """Pin the expert dim to the ep axis when running under a mesh.
 

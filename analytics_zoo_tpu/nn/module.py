@@ -20,6 +20,7 @@ Conventions
 from __future__ import annotations
 
 import contextlib
+import threading
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -51,7 +52,8 @@ def param_dtype():
 
 
 def compute_dtype():
-    return _POLICY["compute_dtype"]
+    pinned = getattr(_PINNED, "compute_dtype", None)
+    return _POLICY["compute_dtype"] if pinned is None else pinned
 
 
 @contextlib.contextmanager
@@ -68,6 +70,24 @@ def precision_policy(param_dtype=None, compute_dtype=None):
         with _POLICY_LOCK:
             _POLICY.clear()
             _POLICY.update(prev)
+
+
+_PINNED = threading.local()
+
+
+@contextlib.contextmanager
+def pinned_compute_dtype(dtype):
+    """Hold :func:`compute_dtype` to ``dtype`` on THIS thread for the block,
+    whatever the process-wide policy says or becomes. For an owner of jitted
+    executables that read the policy once and trace later, on a thread of
+    their own (the generation batcher): its traces see its dtype, and no
+    other thread's trace sees a change."""
+    prev = getattr(_PINNED, "compute_dtype", None)
+    _PINNED.compute_dtype = jnp.dtype(dtype)
+    try:
+        yield
+    finally:
+        _PINNED.compute_dtype = prev
 
 
 # ---------------------------------------------------------------------- initializers
@@ -180,6 +200,16 @@ class Layer:
 
     def compute_output_shape(self, input_shape: Shape) -> Shape:
         return input_shape
+
+    def cast_at_use(self, params: PyTree) -> PyTree:
+        """Which leaves of ``params`` every forward of this layer reads only
+        as ``jnp.asarray(leaf, compute_dtype)``: one bool per leaf, in the
+        structure of ``params``. Such a leaf may be handed over already in
+        the compute dtype (the cast is then the identity and the arithmetic
+        the same, bit for bit); generation serving holds them so. False
+        (the default) for a leaf that is also read at its own precision:
+        normalization scales, embedding tables summed before the cast."""
+        return jax.tree_util.tree_map(lambda _: False, params)
 
     def regularization(self, params: PyTree):
         """Regularization loss contribution for this layer's ``params``

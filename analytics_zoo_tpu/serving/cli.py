@@ -197,7 +197,9 @@ def do_info(args) -> int:
                       # the decode loop thread's seconds by exclusive phase
                       # since start, and the steps they bought: two readings
                       # and a subtraction say where the loop's time goes
-                      "steps", "loop_seconds", "model_version", "ts")}
+                      "steps", "loop_seconds", "model_version",
+                      # bytes of the served parameter tree by leaf dtype
+                      "param_bytes", "ts")}
             prefix = gen.get("prefix")
             if isinstance(prefix, dict):
                 # shared-prefix KV cache headline: fraction of prefills

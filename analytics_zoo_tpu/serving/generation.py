@@ -38,6 +38,7 @@ backlog), prefill (to the first token on the host; the two sum to
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import logging
 import queue
@@ -179,6 +180,14 @@ _tm.collector("zoo_gen_free_pages",
               "Free KV-cache pages summed over live continuous batchers",
               lambda: [((), float(sum(g.pool.free_count()
                                       for g in list(_LIVE_GENERATORS))))])
+_tm.collector("zoo_gen_param_bytes",
+              "Bytes of the parameter tree live continuous batchers serve "
+              "from (the served tree: leaves the forward casts at use held "
+              "in the compute dtype), by leaf dtype",
+              lambda: [((dtype,), float(n)) for dtype, n in sorted(sum(
+                  (g.param_bytes for g in list(_LIVE_GENERATORS)),
+                  collections.Counter()).items())],
+              labels=("dtype",))
 _tm.collector("zoo_gen_prefix_reclaimable_pages",
               "Prefix-cache pages whose only reference is the cache's own "
               "(no live stream attached) — HBM an eviction sweep would "
@@ -439,6 +448,22 @@ class ContinuousBatcher:
     killed loop is respawned by a supervisor with cache/slot state intact,
     so in-flight streams survive (kill-the-engine drill in
     tests/test_generation.py).
+
+    **What is held on the device.** The batcher reads the compute dtype of
+    the precision policy ONCE, when it is built, and every executable it owns
+    traces under that dtype on whatever thread and under whatever policy the
+    trace happens later. ``self.params`` is the *served tree* of the tree it
+    was given: each leaf the model declares as cast at use
+    (``model.cast_at_use(params)``: a block's matmul kernels and biases, the
+    head) and that is wider than the compute dtype is cast once, in one
+    jitted call; every other leaf is the given array itself. Under a bf16
+    policy a decode step so streams half the bytes of f32 weights, and its
+    arithmetic is the same bit for bit (the cast at use becomes the
+    identity). Where no leaf is wider than the compute dtype the served tree
+    IS the given tree and nothing is allocated. The batcher keeps no
+    reference to the given tree: it is the caller's to drop.
+    ``stats()["param_bytes"]`` (``{dtype: bytes}``; ``cli info``;
+    ``zoo_gen_param_bytes{dtype}``) says what is being served.
     """
 
     def __init__(self, model, params, *, n_slots: int = 8,
@@ -478,15 +503,22 @@ class ContinuousBatcher:
                              f"prefill_chunk(); "
                              f"{type(model).__name__} has none")
         import jax
+        import jax.numpy as jnp
+
+        from ..nn.module import compute_dtype
 
         self.model = model
-        self.params = jax.device_put(params)
+        # the ONE reading of the precision policy: the served tree, the page
+        # pool and every trace below are made for this dtype
+        self.compute_dtype = dtype = jnp.dtype(compute_dtype())
+        self._cast = jax.jit(lambda leaves: [x.astype(dtype) for x in leaves])
+        self.params = self._serve_view(params)
         self.n_slots = int(n_slots)
         # clamp to the vocabulary: lax.top_k with k > V fails at trace time
         self.top_k = min(int(top_k), getattr(model, "vocab", int(top_k)))
-        self.cfg, self.cache = model.init_kv_cache(
-            n_slots, page_size=page_size, max_seq_len=max_seq_len,
-            n_pages=n_pages)
+        self.cfg, self.cache = self._pinned(
+            model.init_kv_cache, n_slots, page_size=page_size,
+            max_seq_len=max_seq_len, n_pages=n_pages)
         self.pool = PagePool(self.cfg)
         # shared-prefix KV cache (ISSUE 17): 0 pages disables sharing
         # entirely (the cold baseline); the budget counts CACHE-held pages
@@ -537,8 +569,6 @@ class ContinuousBatcher:
         self.chunk_ema = _qos.ServiceTimeEMA()
         self._last_budget: Optional[Dict[str, Any]] = None
         # uris cancelled while still queued (bounded: unknown uris age out)
-        import collections
-
         self._cancelled_uris: "collections.deque[str]" = \
             collections.deque(maxlen=1024)
         self._wake = threading.Event()
@@ -589,19 +619,23 @@ class ContinuousBatcher:
         self.donate_cache = bool(donate_cache)
         self.hbm_budget_bytes = hbm_budget_bytes
         donate = (1,) if donate_cache else ()
+        pinned = self._pinned       # each trace under self.compute_dtype
         self._decode = jax.jit(
-            lambda p, c, ids, ln, tb, sd, ti, tp: model.decode_step(
+            lambda p, c, ids, ln, tb, sd, ti, tp: pinned(
+                model.decode_step,
                 p, c, ids, ln, tb, sd, ti, tp, page_size=cfg.page_size,
                 top_k=self.top_k), donate_argnums=donate)
         self._prefill = jax.jit(
-            lambda p, c, ids, ln, tb: model.prefill(
+            lambda p, c, ids, ln, tb: pinned(
+                model.prefill,
                 p, c, ids, ln, tb, page_size=cfg.page_size),
             donate_argnums=donate)
         # suffix prefill from the divergence point of a prefix hit (one
         # executable per pow2 suffix bucket, same ladder as _prefill) and
         # the COW boundary-page copy (ONE executable: src/dst are traced)
         self._prefill_from = jax.jit(
-            lambda p, c, ids, st, ln, tb: model.prefill_from(
+            lambda p, c, ids, st, ln, tb: pinned(
+                model.prefill_from,
                 p, c, ids, st, ln, tb, page_size=cfg.page_size),
             donate_argnums=donate)
         self._copy_page = jax.jit(
@@ -613,7 +647,8 @@ class ContinuousBatcher:
         self._prefill_chunk = None
         if self.prefill_chunk_tokens:
             self._prefill_chunk = jax.jit(
-                lambda p, c, ids, nd, nv, tb: model.prefill_chunk(
+                lambda p, c, ids, nd, nv, tb: pinned(
+                    model.prefill_chunk,
                     p, c, ids, nd, nv, tb, page_size=cfg.page_size),
                 donate_argnums=donate)
         # one compiled verify executable per k ever used (lazily jitted; a
@@ -632,6 +667,54 @@ class ContinuousBatcher:
         self._threads: List[threading.Thread] = []
         if autostart:
             self.start()
+
+    # ------------------------------------------------------------- served tree
+
+    def _pinned(self, fn, *args, **kw):
+        """``fn(*args, **kw)`` with this thread's compute dtype held to the
+        one the batcher was built with: what a jitted dispatch's trace, the
+        pool's dtype and the graph checks all go through, so a policy
+        changed afterwards cannot pair a served tree in one dtype with a
+        trace in another."""
+        from ..nn.module import pinned_compute_dtype
+
+        with pinned_compute_dtype(self.compute_dtype):
+            return fn(*args, **kw)
+
+    def _serve_view(self, params):
+        """The served tree of ``params`` (class docstring):
+        on the device, the leaves ``model.cast_at_use`` names that are wider
+        than the compute dtype cast in one jitted call, every other leaf the
+        array ``jax.device_put`` gave. A narrower leaf stays as it is:
+        casting it up once would only make the step read more."""
+        import jax
+        import jax.numpy as jnp
+
+        served = jax.device_put(params)
+        leaves, treedef = jax.tree_util.tree_flatten(served)
+        declare = getattr(self.model, "cast_at_use", None)
+        if declare is not None:
+            flags = treedef.flatten_up_to(declare(served))
+            wide = [i for i, (leaf, flag) in enumerate(zip(leaves, flags))
+                    if flag and jnp.issubdtype(leaf.dtype, jnp.floating)
+                    and leaf.dtype.itemsize > self.compute_dtype.itemsize]
+            if wide:
+                for i, cast in zip(wide, self._cast([leaves[i]
+                                                     for i in wide])):
+                    leaves[i] = cast
+                served = treedef.unflatten(leaves)
+        return served
+
+    @property
+    def param_bytes(self) -> "collections.Counter[str]":
+        """Bytes of the served tree by leaf dtype (``stats()``, ``cli info``,
+        ``zoo_gen_param_bytes{dtype}``)."""
+        import jax
+
+        nbytes: "collections.Counter[str]" = collections.Counter()
+        for leaf in jax.tree_util.tree_leaves(self.params):
+            nbytes[str(leaf.dtype)] += int(leaf.nbytes)
+        return nbytes
 
     # ------------------------------------------------------------------ control
 
@@ -1383,7 +1466,8 @@ class ContinuousBatcher:
 
             cfg = self.cfg
             fn = jax.jit(
-                lambda p, c, ids, ln, tb, sd, ti, tp: self.model.verify_step(
+                lambda p, c, ids, ln, tb, sd, ti, tp: self._pinned(
+                    self.model.verify_step,
                     p, c, ids, ln, tb, sd, ti, tp, page_size=cfg.page_size,
                     top_k=self.top_k), donate_argnums=self._donate)
             self._verify_fns[k] = fn
@@ -1794,9 +1878,11 @@ class ContinuousBatcher:
         old weights or vice versa. In-flight streams continue (their
         pending proposals are re-drafted; the k-gram corpus survives). A
         spec flip to a new ``k`` lazily compiles exactly one more verify
-        executable — the per-(k, slot-count) invariant holds."""
-        import jax
+        executable — the per-(k, slot-count) invariant holds.
 
+        ``params`` is the tree as published; what is flipped in is its
+        served tree (class docstring), built here, on the caller's (the
+        staging) thread, so the loop's ``swap`` phase stays a pointer flip."""
         if spec is not None:
             from ..ops.speculative import SpecDecodeConfig
 
@@ -1805,13 +1891,18 @@ class ContinuousBatcher:
             elif not isinstance(spec, SpecDecodeConfig):
                 raise TypeError(f"spec must be a SpecDecodeConfig or dict, "
                                 f"got {type(spec).__name__}")
-        self._pending_swap = (jax.device_put(params), version, spec)
+        self._pending_swap = (self._serve_view(params), version, spec)
         self._wake.set()
 
     def host_params(self):
-        """Current target params as host arrays — the retention hook
+        """The tree being served, as host arrays — the retention hook
         :class:`~.hotswap.ModelSwapper` snapshots before a swap so
-        ``rollback()`` can restore the pre-swap pair."""
+        ``rollback()`` can restore the pre-swap pair. It is the SERVED tree
+        (class docstring), not the tree that was given: under a bf16 policy
+        its matmul weights are bf16. Handing it back to :meth:`swap_params`
+        serves it as it is (no leaf is wider than the compute dtype any
+        more), so a swap then a rollback is stream-exact; it is not a copy
+        of the published f32 weights."""
         import jax
 
         return jax.device_get(self.params)
@@ -1837,7 +1928,8 @@ class ContinuousBatcher:
 
         budget = (hbm_budget_bytes if hbm_budget_bytes is not None
                   else self.hbm_budget_bytes)
-        findings = lint_decode_stability(
+        findings = self._pinned(
+            lint_decode_stability,
             self.model, self.params, self.cfg, self.cache,
             top_k=self.top_k, spec_k=self.spec_k,
             chunk_tokens=self.prefill_chunk_tokens,
@@ -1889,7 +1981,8 @@ class ContinuousBatcher:
         fields = memory_fields(self.lower_decode().compile())
         step = (self.model.verify_step if spec else self.model.decode_step)
         closed = jax.make_jaxpr(
-            lambda p, c, ids, ln, tb, sd, ti, tp: step(
+            lambda p, c, ids, ln, tb, sd, ti, tp: self._pinned(
+                step,
                 p, c, ids, ln, tb, sd, ti, tp, page_size=cfg.page_size,
                 top_k=self.top_k))(*args)
         n_params = len(jtu.tree_leaves(self.params))
@@ -1948,6 +2041,9 @@ class ContinuousBatcher:
             if self._occupied_slot_steps else 0.0,
             "model_version": self.version,
             "swaps": self.swaps,
+            # the served tree by leaf dtype: under a bf16 policy the matmul
+            # weights read bfloat16 here, the rest float32
+            "param_bytes": dict(self.param_bytes),
             # high-water mark of allocated (non-free) pool pages — the
             # sublinearity evidence for prefix sharing in the bench
             "peak_pages_in_use": self.peak_pages_in_use,
@@ -2014,6 +2110,13 @@ class GenerationEngine:
     Chunk writes ride a sink thread so the decode loop never blocks on a
     broker RTT; a request is XACKed only after its final frame is durably in
     the broker (at-least-once, like the one-shot engine).
+
+    ``params`` is handed to a :class:`ContinuousBatcher`, which serves from
+    its *served tree* (there: matmul weights cast once to the policy's
+    compute dtype) and keeps no reference to ``params`` itself: drop it after
+    construction and the device holds the served tree and the page pool.
+    ``stats()["param_bytes"]`` (republished to ``cli info``) says what that
+    is, by dtype.
     """
 
     def __init__(self, model, params=None,

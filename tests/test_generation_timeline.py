@@ -279,10 +279,13 @@ def test_ingress_once_a_request_and_egress_once_a_frame(model_and_params,
             capsys.readouterr()
             assert cli_main(["info", "--port", str(broker.port)]) == 0
             shown = json.loads(capsys.readouterr().out).get("generation", {})
-            if shown.get("loop_seconds", {}).get("decode_wait", 0) > 0:
+            # a snapshot taken mid-stream holds the first steps only
+            if (shown.get("loop_seconds", {}).get("decode_wait", 0) > 0
+                    and shown.get("steps", 0) >= max(n_new) - 1):
                 break
             time.sleep(0.2)
         assert set(shown["loop_seconds"]) == set(gen.LOOP_PHASES)
+        assert set(shown["param_bytes"]) == {"float32"}
         assert shown["steps"] >= max(n_new) - 1
     finally:
         client.close()

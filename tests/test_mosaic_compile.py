@@ -24,7 +24,8 @@ BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
 @pytest.fixture(scope="module")
 def v5e():
-    """``compile(fn, (shape, dtype), ...)`` for one device of a v5e 2x2."""
+    """``compile(fn, (shape, dtype), ..., **jit_kw)`` for one device of a
+    v5e 2x2: the compiled executable, which holds a Mosaic call."""
     from jax.experimental import topologies
 
     try:
@@ -34,16 +35,16 @@ def v5e():
         pytest.skip(f"cannot describe a v5e topology here: {e}")
     sharding = SingleDeviceSharding(topo.devices[0])
 
-    def compile(fn, *avals):
+    def compile(fn, *avals, **jit_kw):
         args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
                 for shape, dtype in avals]
         # the precision a TPU process runs at, not conftest's "highest":
         # Mosaic takes an fp32 contract precision on f32 operands only
         # ("Bad lhs type" on bf16 and int8)
         with jax.default_matmul_precision("default"):
-            text = jax.jit(fn).lower(*args).compile().as_text()
-        assert "tpu_custom_call" in text
-        return text
+            compiled = jax.jit(fn, **jit_kw).lower(*args).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        return compiled
 
     return compile
 
@@ -56,7 +57,7 @@ def test_flash_forward_and_backward_at_the_training_shape(v5e):
             *a, True, None, None, False).astype(F32).sum(),
             argnums=(0, 1, 2))(q, k, v)
 
-    assert v5e(grads, qkv, qkv, qkv).count("tpu_custom_call") == 3
+    assert v5e(grads, qkv, qkv, qkv).as_text().count("tpu_custom_call") == 3
 
 
 # decode, speculative verify, and the widest query the config admits: a
@@ -75,10 +76,51 @@ def test_paged_attention_at_the_serving_shapes(v5e, slots, q_len, table,
     text = v5e(lambda q, k, v, tb, ln: paged_attention(
         q, k, v, tb, ln, page_size=16, interpret=False),
         ((slots, q_len, heads, 128), dtype), pool, pool,
-        ((slots, table), I32), ((slots,), I32))
+        ((slots, table), I32), ((slots,), I32)).as_text()
     # one Mosaic call, under the name the benchmark's readers select by
     assert text.count("tpu_custom_call") == 1
     assert "zoo_paged_attention" in text
+
+
+def test_decode_step_of_the_serving_cell_reads_the_served_tree(v5e,
+                                                               monkeypatch):
+    """The whole decode step of the benchmark's serving cell, given the tree
+    a ``ContinuousBatcher`` serves under its bf16 policy: 3.05 GB of
+    parameters among the arguments where the f32 tree is 5.67, no temporary
+    copy of a weight, the pool aliased, one paged kernel a block."""
+    from analytics_zoo_tpu.models.transformer import TransformerLM
+    from analytics_zoo_tpu.nn.module import precision_policy
+
+    slots, page, pages, pps, blocks = 32, 16, 2304, 128, 24
+    m = TransformerLM(vocab=50257, hidden_size=2048, n_block=blocks,
+                      n_head=16, seq_len=2048, intermediate_size=8192)
+    given = jax.eval_shape(lambda: m.build(jax.random.PRNGKey(0))[0])
+    leaves, treedef = jax.tree_util.tree_flatten(given)
+    served = [(leaf.shape, BF16 if cast else leaf.dtype) for leaf, cast in
+              zip(leaves, jax.tree_util.tree_leaves(m.cast_at_use(given)))]
+    n = len(served)
+    pools = [((pages, page, 16, 128), BF16)] * (2 * blocks)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def step(*flat):
+        kv = flat[n:n + 2 * blocks]
+        with precision_policy(compute_dtype="bfloat16"):
+            return m.decode_step(
+                treedef.unflatten(flat[:n]),
+                {"k": kv[:blocks], "v": kv[blocks:]},
+                *flat[n + 2 * blocks:], page_size=page)
+
+    compiled = v5e(
+        step, *served, *pools, ((slots,), I32), ((slots,), I32),
+        ((slots, pps), I32), ((slots,), jnp.uint32), ((slots,), jnp.uint32),
+        ((slots,), F32), donate_argnums=tuple(range(n, n + 2 * blocks)))
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * blocks * pages * page * 16 * 128 * 2
+    param_bytes = mem.argument_size_in_bytes - pool_bytes
+    assert 3.05e9 < param_bytes < 3.06e9
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < 64e6
+    assert compiled.as_text().count("zoo_paged_attention") >= blocks
 
 
 @pytest.mark.parametrize("m", [1, 16, 512])
