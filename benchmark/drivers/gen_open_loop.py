@@ -2,20 +2,25 @@
 drawn from the seed, whether or not earlier ones have finished, and timed at
 the client from the instant each was due.
 
-End-to-end metric: ``itl_p95_ms`` (gaps between consecutive tokens of a stream
-that ended inside the window; a frame of k tokens is k gaps of 1/k of its
-wait; frames read back to back, within a millisecond, are one frame). Also
-computed, and recorded as a per-layer metric because some sixty requests a
-window do not pin it down: ``ttft_p90_ms`` (due instant to first token, over
-the requests due in the window; a request that failed or never got a token
-counts as the largest value).
+What the clients saw of the gaps between consecutive tokens of a stream that
+ended inside the window (a frame of k tokens is k gaps of 1/k of its wait;
+frames read back to back, within a millisecond, are one frame): the mean and
+the 50th, 90th, 95th and 99th percentiles, as ``itl_mean_ms`` and
+``itl_p<q>_ms``. Which of them a cell is judged on, and which it records as
+per-layer metrics, its own file says (``workloads/<cell>.json``); PERF.md,
+section 2, has the rule that chose and the runs it chose from. Also computed:
+``ttft_p90_ms`` (due instant to first token, over the requests due in the
+window; a request that failed or never got a token counts as the largest
+value), recorded and not judged.
 
-Why the 95th percentile of the gaps and not the 99th: every arrival's prefill
-stalls each live stream once, so at this cell's rate about 9% of the gaps are
-a decode step plus one prefill, and about 0.6% a step plus two. The 95th
-percentile lies well inside the first group and repeats to a percent; the
-99th lies on the edge between the two and jumped by a third in one run of six
-on the v5e (PR 22), with how often two arrivals fell into one step.
+Where the statistics lie: a plain pass of the decode loop is the body of the
+gaps; every arrival's prefill stalls each live stream once, so a few percent
+of the gaps are a pass plus one prefill, and the 99th percentile sits on the
+edge to "plus two" (it jumped by a third in one run of six on the v5e, PR 22).
+
+A traced run sends on through the seconds the profiler runs after the window
+(``serving_rig.ServingRig.measure``): a third part of the schedule, drawn
+like the others, so that the window's own requests are the untraced run's.
 """
 
 from __future__ import annotations
@@ -61,18 +66,19 @@ def reduce(obs, seconds: float):
     if ttft:
         metrics["ttft_p90_ms"] = 1e3 * harness.percentile(ttft, 90)
     if gaps:
-        metrics["itl_p95_ms"] = 1e3 * harness.percentile(gaps, 95)
+        metrics["itl_mean_ms"] = 1e3 * sum(gaps) / len(gaps)
+        for q in (50, 90, 95, 99):
+            metrics[f"itl_p{q}_ms"] = 1e3 * harness.percentile(gaps, q)
     obs["distribution_ms"] = {
         "ttft": {q: round(1e3 * harness.percentile(ttft, q), 1)
                  for q in (50, 90, 99)} if ttft else {},
-        "itl": {q: round(1e3 * harness.percentile(gaps, q), 1)
-                for q in (50, 90, 95, 99)} if gaps else {},
         "n_gaps": len(gaps)}
     return attempted, failed, metrics
 
 
-def jobs_for(rig: ServingRig, mix, seed: int, lead_s: float, seconds: float):
-    schedule = traffic.open_loop_schedule(mix, seed, lead_s, seconds)
+def jobs_for(rig: ServingRig, mix, seed: int, lead_s: float, seconds: float,
+             after_s: float = 0.0):
+    schedule = traffic.open_loop_schedule(mix, seed, lead_s, seconds, after_s)
     n = int(mix.get("generator_processes", 4))
     return [dict(rig.base_job(), requests=schedule[i::n]) for i in range(n)]
 
@@ -80,5 +86,5 @@ def jobs_for(rig: ServingRig, mix, seed: int, lead_s: float, seconds: float):
 def run(run: harness.Run) -> harness.Outcome:
     return serve_cell(
         run, lambda rig, lead_s: jobs_for(rig, run.traffic, run.seed, lead_s,
-                                          run.seconds),
+                                          run.seconds, run.trace_seconds()),
         reduce, stop_at_end=False)

@@ -108,7 +108,7 @@ def run(run: harness.Run) -> harness.Outcome:
 
     data = FeatureSet.from_numpy(x, y)
     # warm epochs over the real set, loader and shuffle included, past the
-    # first log point (which builds the communication probe on a dp mesh)
+    # first log point (where fit reads the loss back for the first time)
     while est.trainer_state.iteration <= est.config.log_every_n_steps:
         est.fit(data, batch_size=batch,
                 end_trigger=MaxEpoch(est.trainer_state.epoch + 1))
@@ -141,4 +141,6 @@ def run(run: harness.Run) -> harness.Outcome:
         failed=0 if finite else steps,
         end_to_end={"train_tokens_per_s": steps * batch * seq_len / (t1 - t0),
                     "setup_s": t0 - run.t_process_start},
-        observations=obs, notes=faults)
+        observations=obs, notes=faults,
+        checks={"first_step_loss_diff": [abs(got_loss - want_loss),
+                                         LOSS_ABS_TOL]})

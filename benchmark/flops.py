@@ -21,11 +21,28 @@ def _dims(shape: str):
 def train_flops_per_token(config: Dict[str, Any], seq_len: int) -> float:
     """Forward plus backward of the GPT-2 block stack and head, per token:
     6 x (parameters in the block matmuls and the head) plus causal attention
-    (the formula of ``bench.py:304-306`` with the MLP width as published)."""
+    (this file is the formula's home: the pre-chip ``bench.py`` it was copied
+    from was deleted in PR 28; the MLP width is the published one)."""
     h, layers = config["n_embd"], config["n_layer"]
     block = 4 * h * h + 2 * h * config["n_inner"]
     return (6.0 * block * layers + 6.0 * layers * seq_len * h
             + 6.0 * h * config["vocab_size"])
+
+
+def serve_flops(config: Dict[str, Any], context_from: int,
+                context_to: int) -> float:
+    """Forward of the block stack and head for the tokens at positions
+    ``context_from`` ... ``context_to - 1`` of one sequence, as serving has to
+    compute them once (a prefill, or decode steps): 2 x (parameters in the
+    block matmuls and the head) a token, plus QK^T and PV over the tokens
+    before it, 4 x hidden a cached token a layer."""
+    h, layers = config["n_embd"], config["n_layer"]
+    block = 4 * h * h + 2 * h * config["n_inner"]
+    n = context_to - context_from
+    attended = (context_to * (context_to + 1)
+                - context_from * (context_from + 1)) / 2
+    return (n * (2.0 * block * layers + 2.0 * h * config["vocab_size"])
+            + 4.0 * h * layers * attended)
 
 
 def flash_fwd_flops(shape: str) -> float:

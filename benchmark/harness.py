@@ -230,26 +230,44 @@ class Run:
     out_dir: str
     t_process_start: float                      # time.monotonic()
     compiles: CompileCounter = None
+    #: put the control of the correctness check in the program's place: the
+    #: reference in this precision below the configuration's, which has to
+    #: come out not correct; no run of the driver's does
+    control: Optional[str] = None
 
     def say(self, phase: str, **fields) -> None:
         body = " ".join(f"{k}={v}" for k, v in fields.items())
         print(f"[{self.cell['name']} +{time.monotonic() - self.t_process_start:6.1f}s"
               f" {phase}] {body}", flush=True)
 
-    def trace_window(self) -> Optional[TraceWindow]:
+    def trace_seconds(self) -> float:
+        """How long a traced run profiles (the mix's file says; 0 untraced)."""
+        if not self.trace:
+            return 0.0
+        return min(float(self.traffic.get("trace", {}).get("seconds", 4.0)),
+                   self.seconds)
+
+    def trace_window(self, start_after_s: Optional[float] = None
+                     ) -> Optional[TraceWindow]:
+        """The profiler's window of a traced run, started now: after the
+        mix's ``trace.start_after_s`` (inside the measured window), or after
+        ``start_after_s`` where the driver places it itself."""
         if not self.trace:
             return None
-        t = self.traffic.get("trace", {})
-        duration = min(float(t.get("seconds", 4.0)), self.seconds)
-        after = min(float(t.get("start_after_s", 2.0)),
-                    max(0.0, self.seconds - duration))
-        return TraceWindow(self.out_dir, after, duration).start()
+        duration = self.trace_seconds()
+        if start_after_s is None:
+            start_after_s = min(
+                float(self.traffic.get("trace", {}).get("start_after_s", 2.0)),
+                max(0.0, self.seconds - duration))
+        return TraceWindow(self.out_dir, start_after_s, duration).start()
 
 
 @dataclass
 class Outcome:
     """What a driver returns. ``observations`` is everything a per-layer
-    reader may want: counter snapshots, the trace, request records."""
+    reader may want: counter snapshots, the trace, request records, and
+    ``memory_peak_bytes`` where the driver read the peak itself (before a
+    reference ran on the chip)."""
 
     correct: bool
     attempted: int
@@ -257,6 +275,8 @@ class Outcome:
     end_to_end: Dict[str, float]
     observations: Dict[str, Any] = field(default_factory=dict)
     notes: List[str] = field(default_factory=list)
+    #: each number that decided ``correct``: name -> [value, limit]
+    checks: Dict[str, List[float]] = field(default_factory=dict)
 
 
 def percentile(values, q: float) -> float:

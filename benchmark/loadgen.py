@@ -6,7 +6,9 @@ it talks to the broker over its socket through ``GenerationClient``, as a
 user's clients do, so that generator and scheduler share no interpreter lock.
 Its first input line is one JSON object (the job); it answers ``READY``, waits
 for ``GO <monotonic zero>``, runs, and prints one JSON record per request and
-then ``DONE``. ``time.monotonic()`` is one clock for every process of a Linux
+then ``DONE``. A record holds the arrival instant and size of every frame and
+the tokens served, which the correctness check reads once the window has
+closed. ``time.monotonic()`` is one clock for every process of a Linux
 machine, so due instants and arrival times are comparable with the parent's.
 
 job: ``port``, ``mix`` (the traffic file), ``vocab``, ``timeout_s``, and
@@ -50,8 +52,11 @@ class Generator:
         """Send one request and read its stream to the end."""
         ids = traffic.prompt_tokens(self.mix, request, self.job["vocab"])
         record = {"prompt_len": request["prompt_len"],
-                  "output_len": request["output_len"], "t_due": t_due,
-                  "t_send": None, "frames": [], "outcome": "unsent"}
+                  "output_len": request["output_len"],
+                  "prefix": request["prefix"],
+                  "token_seed": request["token_seed"], "t_due": t_due,
+                  "t_send": None, "frames": [], "tokens": [],
+                  "outcome": "unsent"}
         client = self.client()
         try:
             record["t_send"] = time.monotonic()
@@ -59,6 +64,7 @@ class Generator:
             record["outcome"] = "unfinished"
             for chunk in client.stream(uri, timeout_s=self.job["timeout_s"]):
                 record["frames"].append([time.monotonic(), int(chunk.size)])
+                record["tokens"].extend(chunk.tolist())
             got = sum(k for _, k in record["frames"])
             record["outcome"] = "ok" if got == request["output_len"] \
                 else f"short:{got}"

@@ -14,9 +14,15 @@ structure). What it relies on:
 * a Mosaic kernel is an op whose instruction name starts with the kernel's
   ``name`` (``zoo_flash_fwd``, ``zoo_flash_bwd_dq``, ``zoo_flash_bwd_dkv``,
   ``zoo_paged_attention``);
-* host threads are lines of the plane ``/host:CPU``; the program's telemetry
-  spans (``serving.gen.prefill`` ...) enter a ``TraceAnnotation`` and land on
-  the lines named ``python``, on the same clock as the device lines.
+* host threads are lines of the plane ``/host:CPU``, on the same clock as the
+  device lines. A line is named after its thread, and a thread that Python
+  started after the process (``python`` under ``python run.py``, ``python3``
+  under the contract's ``python3 run.py``), so the lines of the program's own
+  threads are taken by what they hold: a telemetry span
+  (``serving.gen.loop.decode_wait`` ... enter a ``TraceAnnotation``) or an
+  event that only the interpreter's side of JAX emits (``PjitFunction(step)``,
+  ``np.asarray(jax.Array)``). The runtime's own threads (transfers, compile
+  passes) hold neither and stay out of ``idle_gaps``.
 
 Times are seconds from the start of the trace.
 """
@@ -26,8 +32,8 @@ from __future__ import annotations
 import bisect
 import collections
 import gzip
+import heapq
 import re
-import tempfile
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -38,6 +44,9 @@ _INSTRUCTION = re.compile(r"^%?([A-Za-z_][\w\-]*?)(?:\.\d+)*\s*=\s*\(?"
 _MODULE = re.compile(r"^(.*?)\((\d+)\)$")
 #: what this program's telemetry spans look like, among the host events
 _SPAN = re.compile(r"^[a-z_]+(\.[a-z_]+)+$")
+#: events that JAX emits from the thread of the Python caller alone
+_PYTHON_SIDE = re.compile(r"^(PjitFunction\(|np\.asarray\(|"
+                          r"PythonRefManager::|ParseArguments$)")
 COLLECTIVE = re.compile(r"^(all-gather|all-reduce|reduce-scatter|all-to-all|"
                         r"collective-permute|collective-broadcast)")
 
@@ -70,7 +79,7 @@ class Device:
 @dataclass
 class Trace:
     devices: List[Device]
-    host: List[Tuple[float, float, str]]        # events of the python lines
+    host: List[Tuple[float, float, str]]    # events of the program's threads
     #: first start and last end over every event of every plane and line:
     #: the part of the profiling session the trace itself vouches for
     span: Interval = (0.0, 0.0)
@@ -85,15 +94,15 @@ def _op(event) -> Op:
 
 
 def load(path: str) -> Trace:
+    with (gzip.open if path.endswith(".gz") else open)(path, "rb") as f:
+        return from_xspace(f.read())
+
+
+def from_xspace(serialized: bytes) -> Trace:
+    """A serialized ``XSpace`` (the content of an ``.xplane.pb``)."""
     import jax
 
-    if path.endswith(".gz"):
-        with gzip.open(path, "rb") as src, tempfile.NamedTemporaryFile(
-                suffix=".xplane.pb") as tmp:
-            tmp.write(src.read())
-            tmp.flush()
-            return load(tmp.name)
-    data = jax.profiler.ProfileData.from_file(path)
+    data = jax.profiler.ProfileData.from_serialized_xspace(serialized)
     devices, host = [], []
     first, last = float("inf"), 0.0
     for plane in data.planes:
@@ -127,11 +136,12 @@ def load(path: str) -> Trace:
             devices.append(Device(plane.name, modules, ops, async_ops))
         elif plane.name == "/host:CPU":
             for line in plane.lines:
-                if line.name == "python":
-                    for e in line.events:
-                        start = e.start_ns * 1e-9
-                        host.append((start, start + e.duration_ns * 1e-9,
-                                     e.name))
+                events = [(e.start_ns * 1e-9,
+                           (e.start_ns + e.duration_ns) * 1e-9, e.name)
+                          for e in line.events]
+                if any(_SPAN.match(name) or _PYTHON_SIDE.match(name)
+                       for _, _, name in events):
+                    host.extend(events)
     devices.sort(key=lambda d: d.name)
     host.sort()
     return Trace(devices, host,
@@ -201,8 +211,10 @@ def runs(device: Device, module: Optional[str] = None,
          has_op: Optional[str] = None,
          lacks_op: Optional[str] = None) -> List[ModuleRun]:
     """Runs of executables chosen by what can be observed of them: the HLO
-    module's name (a regular expression, matched whole) and an instruction
-    name they hold or lack (jitted lambdas all share one module name)."""
+    module's name (a regular expression, matched whole: an alternation
+    ``jit__lambda|jit_zoo_gen_decode_step`` takes a step under its old and its new
+    name) and an instruction name they hold or lack (jitted lambdas all share
+    one module name)."""
     out = []
     for run in device.modules:
         if module and not re.fullmatch(module, run.name):
@@ -247,35 +259,59 @@ def device_ops(trace: Trace, top: int = 10) -> List[List]:
     return [[name, seconds / len(trace.devices)] for name, seconds in ranked]
 
 
+def _innermost_at(events: Sequence[Tuple[float, float, str]],
+                  times: Sequence[float]) -> List[str]:
+    """For each instant of ``times`` (ascending), the name of the event open
+    then that began last (``none`` where none is open). ``events`` are
+    ``(start, end, name)`` in order of their starts."""
+    heap: List[Tuple[float, float, str]] = []
+    out, i = [], 0
+    for t in times:
+        while i < len(events) and events[i][0] <= t:
+            start, end, name = events[i]
+            heapq.heappush(heap, (-start, end, name))
+            i += 1
+        while heap and heap[0][1] < t:      # the latest to begin has ended
+            heapq.heappop(heap)
+        out.append(heap[0][2] if heap else "none")
+    return out
+
+
 def idle_gaps(trace: Trace, top: int = 10,
               short_s: float = 50e-6) -> List[List]:
     """Idle time of the first chip by what the host was doing: each gap
-    between ops goes to ``<telemetry span>/<innermost host event>`` open on a
-    python thread at the gap's middle (``none`` where there is none), gaps
-    under ``short_s`` to one entry of their own."""
+    between ops is cut where an event of the program's threads begins or
+    ends, and each piece goes to ``<telemetry span>/<innermost other event>``
+    open on a thread of the program during it (``none`` where there is none);
+    gaps under ``short_s`` go to one entry of their own. A gap is shared out
+    by time and not handed whole to what was open at its middle: a decode
+    loop's gap runs from the end of one phase through two or three short
+    ones into the next, and the short one in the middle would be given
+    milliseconds it never lasted (PR 34: ``emit`` read 0.62 s of a traced 4 s
+    in which the loop's own clock gave it 0.07)."""
     if not trace.devices:
         return []
     import numpy as np
 
     merged = union(spans(trace.devices[0].ops))
     acc: Dict[str, float] = collections.defaultdict(float)
-    starts = np.array([h[0] for h in trace.host])
-    ends = np.array([h[1] for h in trace.host])
-    names = [h[2] for h in trace.host]
-    is_span = np.array([bool(_SPAN.match(n)) for n in names], bool)
-
-    def innermost(open_now) -> str:
-        """Of the events open now, the one that began last."""
-        idx = np.flatnonzero(open_now)
-        return names[idx[np.argmax(starts[idx])]] if idx.size else "none"
-
+    cuts = np.sort(np.array([t for h in trace.host for t in h[:2]]))
+    pieces = []                                 # (middle, seconds)
     for (_, a), (b, _) in zip(merged, merged[1:]):
         if b - a < short_s:
             acc[f"gaps_under_{int(short_s * 1e6)}us"] += b - a
             continue
-        mid = (a + b) / 2
-        open_now = (starts <= mid) & (ends >= mid)
-        acc[f"{innermost(open_now & is_span)}/"
-            f"{innermost(open_now & ~is_span)}"] += b - a
+        inner = cuts[np.searchsorted(cuts, a, "right"):
+                     np.searchsorted(cuts, b, "left")]
+        edges = np.concatenate([[a], inner, [b]])
+        pieces.extend(zip((edges[:-1] + edges[1:]) / 2, np.diff(edges)))
+    pieces.sort()
+    middles = [m for m, _ in pieces]
+    in_span = _innermost_at(
+        [h for h in trace.host if _SPAN.match(h[2])], middles)
+    in_other = _innermost_at(
+        [h for h in trace.host if not _SPAN.match(h[2])], middles)
+    for (_, seconds), span, other in zip(pieces, in_span, in_other):
+        acc[f"{span}/{other}"] += float(seconds)
     ranked = sorted(acc.items(), key=lambda kv: -kv[1])[:top]
     return [[name, seconds] for name, seconds in ranked]

@@ -8,8 +8,10 @@ and the traffic mix that file names, hands them to ``drivers/<driver>.py``,
 and prints as the last line of standard output one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics with
 ``--trace 0``, its per-layer metrics, each read by ``readers/<reader>.py`` as
-``metrics/<metric>.json`` says, with ``--trace 1``), ``device`` and, traced,
-``breakdown``. It measures on a TPU or not at all: off one it exits 2 and
+``metrics/<metric>.json`` says, with ``--trace 1``), ``device``, traced
+``breakdown``, and last ``checks``: each number that decided ``correct``
+beside its limit, which are also the last lines of standard error. It
+measures on a TPU or not at all: off one it exits 2 and
 prints no result. ``--rehearse-on-cpu`` is the development rehearsal (tiny
 sizes from the files' ``rehearse`` entries, interpreted kernels): it proves
 the control flow, says ``platform=cpu`` and reports no device metric.
@@ -43,6 +45,11 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=None)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse-on-cpu", action="store_true")
+    ap.add_argument("--control", choices=("fp8", "int8"), default=None,
+                    help="put the correctness check's control in the "
+                         "program's place (the reference in this precision "
+                         "below the configuration's): the run has to come "
+                         "out not correct")
     ap.add_argument("--out", default=None,
                     help="directory for the run's files (default "
                          "<checkout>/benchmark_out/<cell>)")
@@ -57,6 +64,11 @@ def main(argv=None) -> int:
 
     with open(os.path.join(CHECKOUT, "BENCHMARK.json")) as f:
         contract = json.load(f)
+    listed = sorted(w["name"] for w in contract["workloads"])
+    if args.workload not in listed:
+        print(f"benchmark/run.py: BENCHMARK.json lists no cell "
+              f"{args.workload!r}; it lists {listed}", file=sys.stderr)
+        return 2
     seconds = args.seconds if args.seconds is not None \
         else float(contract["run_seconds"])
     rehearse = args.rehearse_on_cpu
@@ -84,7 +96,7 @@ def main(argv=None) -> int:
     run = harness.Run(cell=cell, config=config, traffic=mix, seed=args.seed,
                       seconds=seconds, trace=bool(args.trace), out_dir=out_dir,
                       t_process_start=T_PROCESS_START,
-                      compiles=harness.CompileCounter())
+                      compiles=harness.CompileCounter(), control=args.control)
     run.say("device", platform=platform, kind=repr(kind), count=len(devices),
             compile_cache=enable_compile_cache(), seed=args.seed,
             seconds=seconds, trace=args.trace)
@@ -94,9 +106,10 @@ def main(argv=None) -> int:
     for note in outcome.notes:
         run.say("FAULT", what=note)
 
-    used = devices[:cell["chips"]]
-    peak_bytes = max(((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-                      for d in used), default=0)
+    # a driver that runs a reference on the chip reads the peak before it
+    peak_bytes = outcome.observations.get("memory_peak_bytes") or max(
+        ((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+         for d in devices[:cell["chips"]]), default=0)
     device = {"platform": platform, "kind": kind, "count": len(devices),
               "memory_peak_bytes": peak_bytes}
     declared = {m["name"]: m for m in
@@ -139,7 +152,12 @@ def main(argv=None) -> int:
             reader = importlib.import_module(
                 "benchmark.readers." + spec["reader"])
             put(name, reader.read(obs, spec.get("params", {})))
+    result["checks"] = {name: {"value": value, "limit": limit}
+                        for name, (value, limit) in outcome.checks.items()}
     print(json.dumps(result), flush=True)
+    for name, (value, limit) in outcome.checks.items():
+        print(f"check {name} {value!r} limit {limit!r}", file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

@@ -1,6 +1,9 @@
-"""A generation-serving deployment under load, as the serving drivers and
-``tools/find_knee.py`` set it up: broker, engine and scheduler in this process
-(which holds the chip), the load generator in child processes.
+"""A generation-serving deployment under load, as the serving drivers,
+``tools/find_knee.py`` and ``tools/loop_phases.py`` set it up, which is how
+``python -m analytics_zoo_tpu.serving.cli start`` deploys it: the broker in a
+process of its own (``python -m analytics_zoo_tpu.serving.broker``, no chip),
+engine, scheduler, source and sink in this process (which holds the chip), the
+load generator in child processes. There is one way to deploy and no switch.
 
 The way context, engine and client are built, the Mosaic-call check and the
 logit arithmetic follow ``chip_smoke.py`` phase 2.
@@ -12,25 +15,72 @@ import collections
 import json
 import os
 import re
+import select
+import socket
 import subprocess
 import sys
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Tuple
 
 import numpy as np
 
 from . import harness
 
-# logits of the bf16 serving path (f32 weights cast at use, bf16 activations
-# and KV pages) against the float32 reference at "highest" precision, over
-# prefill and three decode steps: root-mean-square error relative to the
-# reference's spread, and the largest single error. The v5e measured 0.009
-# and 0.010 at 8 blocks of 1024 (PR 21); the limits are five times that, and
-# a wrong page, position or mask is off by the spread itself (rel_rms ~ 1).
+# Four numbers decide ``correct``, each with a limit of its own (PERF.md,
+# section 2, has the readings each limit was set from).
+#
+# ``served_gap``: over a sample, drawn from the seed, of the requests the
+# window finished (the longest among them), the reference runs once over each
+# prompt with its served tokens, and the number is the widest gap by which a
+# served token's logit lies below the reference's best at its position. The
+# traffic is greedy, so a sound deployment serves the reference's best token
+# or one that bf16 rounding cannot tell from it. On the v5e at the cells'
+# size it read at most 0.0154 over 27 seeds of the chat cell and 0.0101 over
+# 23 of the docs cell, and the float8 control at least 0.0625 and 0.0701 over
+# six seeds each (PR 34); the limit lies between, with more of the room
+# above the lower reading.
+#
+# ``logit_rel_rms`` / ``logit_max_abs``: logits of the executables that just
+# served (f32 weights cast once to bf16, bf16 activations and KV pages)
+# against the float32 reference at "highest" precision, over one prefill and
+# three teacher-forced decode steps at one slot: root-mean-square error
+# relative to the reference's spread, and the largest single error. The v5e
+# measured 0.009 and 0.010 at 8 blocks of 1024 (PR 21) and 0.0112-0.0124 and
+# 0.0142-0.0182 at the cell's size (PRs 22-34); the float8 control over the
+# same sequence at least 0.106 and 0.137, the int8 one 0.023-0.026 and
+# 0.030-0.033 (PR 34); a wrong page, position or mask is off by the spread
+# itself (rel_rms ~ 1).
+#
+# ``narrow_operands``: tensors narrower than 16 bits (int8, int4, float8; not
+# booleans) among the served tree's and the page pool's leaves and in the
+# lowered decode step. The configuration states bfloat16, so a sound
+# deployment has none and the limit is 0, an exact comparison. It is there
+# because int8 with a scale per row and column is within twice of bfloat16
+# on these weights: no number read from outputs parts it from the stated
+# precision by the three times a limit needs (PERF.md, section 2), and it is
+# the step down that a v5e tempts.
+#
+# The control (``run.py --control fp8|int8``) is the reference computed in
+# that precision and put in the program's place: its first token at each
+# served position, its logits over the probe's sequence and its lowered block
+# go through the same comparison, and the run has to come out not correct.
+SERVED_GAP_TOL = 0.04
 LOGIT_REL_RMS_TOL = 0.05
 LOGIT_MAX_ABS_TOL = 0.05
+NARROW_OPERANDS_TOL = 0
 N_DECODE_CHECKED = 3
+#: requests in the served sample, and the multiple the reference pads to
+SERVED_SAMPLE = 6
+REFERENCE_PAD = 512
+#: a tensor type narrower than 16 bits in StableHLO text (``i1`` is a mask)
+_NARROW = re.compile(r"[<x](u?i[248]|f[468]E\w+)>")
+
+
+def narrow_types(lowered_text: str) -> Dict[str, int]:
+    """Tensor element types narrower than 16 bits in a lowered program's
+    text, each with how often it occurs."""
+    return dict(collections.Counter(_NARROW.findall(lowered_text)))
 
 
 def _next_pow2(n: int) -> int:
@@ -58,34 +108,105 @@ def prefill_buckets(mix: Dict[str, Any], sizes: Dict[str, Any]) -> List[int]:
     return [b for b in (first << i for i in range(32)) if b <= last]
 
 
+def rel_errors(got: np.ndarray, want: np.ndarray) -> Dict[str, float]:
+    """Root-mean-square error relative to the reference's spread, and the
+    largest single error; infinite where ``got`` is not finite."""
+    if not np.isfinite(got).all():
+        return {"rel_rms": float("inf"), "max_abs": float("inf")}
+    return {"rel_rms": float(np.sqrt(np.mean((got - want) ** 2)) / want.std()),
+            "max_abs": float(np.abs(got - want).max())}
+
+
+class BrokerProcess:
+    """``python -m analytics_zoo_tpu.serving.broker`` on this machine, in a
+    process of its own that never sees the chip: what ``serving/cli.py``
+    ``do_start`` launches. It is given port 0, binds whichever the kernel
+    hands it and says which on its first line, so no other run on the
+    machine (the driver runs parent and change side by side) can take the
+    port between a probe and the bind, and what answers there is this
+    process and no other checkout's."""
+
+    HOST = "127.0.0.1"
+
+    def __init__(self):
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONUNBUFFERED="1")
+        env.pop("XLA_FLAGS", None)
+        self.port = None
+        self.proc = subprocess.Popen(
+            [sys.executable, "-m", "analytics_zoo_tpu.serving.broker",
+             "--host", self.HOST, "--port", "0"],
+            cwd=harness.CHECKOUT, env=env, stdout=subprocess.PIPE, text=True)
+
+    def wait_until_it_answers(self, timeout_s: float = 60.0) -> None:
+        """Read the port the broker bound from its first line, then connect
+        to it once; the broker still has to be alive after that."""
+        try:
+            ready, _, _ = select.select([self.proc.stdout], [], [], timeout_s)
+            line = self.proc.stdout.readline() if ready else ""
+            said = re.search(r"listening on \S+:(\d+)\s*$", line)
+            if not said:
+                raise RuntimeError(
+                    f"the broker did not say its port within {timeout_s} s "
+                    f"(it said {line!r}, exit code {self.proc.poll()})")
+            self.port = int(said.group(1))
+            socket.create_connection((self.HOST, self.port), 5.0).close()
+            if self.proc.poll() is not None:
+                raise RuntimeError(f"the broker exited with code "
+                                   f"{self.proc.returncode} after it answered")
+        except BaseException:
+            self.stop()
+            raise
+
+    def stop(self) -> None:
+        """Kill and wait: a broker left behind is a process the driver has
+        to end."""
+        if self.proc.poll() is None:
+            self.proc.kill()
+        self.proc.wait()
+        self.proc.stdout.close()
+
+
 class ServingRig:
     def __init__(self, run: harness.Run):
         import jax
 
-        from analytics_zoo_tpu.serving import ServingConfig, start_broker
+        from analytics_zoo_tpu.serving import ServingConfig
         from analytics_zoo_tpu.serving.generation import (GenerationClient,
                                                           GenerationEngine)
 
         self.run = run
         self.mix = run.traffic
         self.sizes = run.config["serving"]["ServingConfig"]
-        harness.make_context(run.config)
-        self.model = harness.build_model(run.config)
-        self.params = harness.make_params(self.model, run.seed)
-        jax.block_until_ready(self.params)
-        run.say("weights", leaves=len(jax.tree_util.tree_leaves(self.params)))
-        self.broker = start_broker()
-        self.engine = GenerationEngine(
-            self.model, self.params, config=ServingConfig(
-                queue_port=self.broker.port, **self.sizes)).start()
-        self.batcher = self.engine.batcher
-        self.client = GenerationClient(port=self.broker.port)
         self.children: List[subprocess.Popen] = []
         self.records: List[Dict[str, Any]] = []
         self._readers: List[threading.Thread] = []
         self._ready = threading.Semaphore(0)
         self._done = threading.Semaphore(0)
         self._stopped = False
+        self.checks: Dict[str, List[float]] = {}
+        self.engine = self.client = None
+        self.broker = BrokerProcess()       # it imports while weights are made
+        try:
+            harness.make_context(run.config)
+            self.model = harness.build_model(run.config)
+            params = harness.make_params(self.model, run.seed)
+            jax.block_until_ready(params)
+            run.say("weights", leaves=len(jax.tree_util.tree_leaves(params)))
+            self.broker.wait_until_it_answers()
+            self.engine = GenerationEngine(
+                self.model, params, config=ServingConfig(
+                    queue_port=self.broker.port, **self.sizes)).start()
+            # the engine serves from a tree of its own and keeps no reference
+            # to this one: the rig lets go of it, as a deployment does, and
+            # makes it again from the seed for the reference check
+            del params
+            self.batcher = self.engine.batcher
+            self.client = GenerationClient(port=self.broker.port)
+            run.say("deployed", broker_pid=self.broker.proc.pid,
+                    broker_port=self.broker.port)
+        except BaseException:
+            self.close()
+            raise
 
     # ---------------------------------------------------------------- warm-up
 
@@ -187,7 +308,11 @@ class ServingRig:
     def measure(self, lead_s: float, seconds: float,
                 stop_at_end: bool) -> Dict[str, Any]:
         """Run the spawned children's load and observe the window
-        ``[zero + lead_s, zero + lead_s + seconds]``."""
+        ``[zero + lead_s, zero + lead_s + seconds]``. A traced run profiles
+        the seconds that follow the window, under the same load: what the
+        clients and the counters report is then read where no profiler runs
+        (under one the sink falls behind and time to first token read
+        thirtyfold, PRs 27-33), and the device metrics where one does."""
         run = self.run
         self.records = []
         for _ in self.children:
@@ -201,15 +326,15 @@ class ServingRig:
         obs["counters0"] = harness.counters()
         obs["stats0"] = self.batcher.stats()
         compiles0 = run.compiles.count
-        tracer = run.trace_window()
         time.sleep(max(0.0, w1 - time.monotonic()))
         obs["counters1"] = harness.counters()
         obs["stats1"] = self.batcher.stats()
+        tracer = run.trace_window(start_after_s=0.0)
+        if tracer is not None:
+            obs.update(tracer.finish())
         obs["compiles_in_window"] = run.compiles.count - compiles0
         if stop_at_end:
             self.tell("STOP")
-        if tracer is not None:
-            obs.update(tracer.finish())
         obs["generators_clean"] = self.reap(
             float(self.mix.get("drain_s", 45)))
         obs["records"] = self.records
@@ -219,26 +344,32 @@ class ServingRig:
     # ----------------------------------------------------------- correctness
 
     def stop_serving(self) -> None:
+        """End the engine and the broker's process (kill and wait)."""
         if self._stopped:
             return
         self._stopped = True
-        self.client.close()
-        self.engine.stop()
-        self.broker.shutdown()
+        try:
+            if self.client is not None:
+                self.client.close()
+            if self.engine is not None:
+                self.engine.stop()
+        finally:
+            self.broker.stop()
 
-    def mosaic_kernels(self) -> Dict[str, int]:
-        """Mosaic custom calls in the lowered decode step, by kernel name."""
+    @staticmethod
+    def mosaic_kernels(lowered_text: str) -> Dict[str, int]:
+        """Mosaic custom calls in a lowered program's text, by kernel name."""
         names: collections.Counter = collections.Counter()
-        for line in self.batcher.lower_decode().as_text().splitlines():
+        for line in lowered_text.splitlines():
             if "tpu_custom_call" in line:
                 m = re.search(r'kernel_name = "([^"]+)"', line)
                 names[m.group(1) if m else "?"] += 1
         return dict(names)
 
-    def check_logits(self) -> Dict[str, float]:
+    def program_logits(self):
         """Prefill, then teacher-forced decode steps, through the executables
-        that just served, against the plain reference over the whole
-        sequence. Call after ``stop_serving``: it consumes the cache."""
+        that just served: ``(sequence, prefill length, bucket, logits)``.
+        Call after ``stop_serving``: it consumes the cache."""
         from analytics_zoo_tpu.ops.kv_cache import SCRATCH_PAGE
 
         batcher, cfg = self.batcher, self.batcher.cfg
@@ -248,9 +379,6 @@ class ServingRig:
         rng = np.random.default_rng([self.run.seed, 7])
         seq = rng.integers(1, self.model.vocab,
                            size=n_prefill + N_DECODE_CHECKED).astype(np.int32)
-        reference, kwargs = harness.reference_of(self.run.config)
-        want_all = np.asarray(reference.logits(self.params, seq[None],
-                                               **kwargs))[0]
         ids = np.zeros((1, bucket), np.int32)
         ids[0, :n_prefill] = seq[:n_prefill]
         n_pages = -(-len(seq) // cfg.page_size)
@@ -260,7 +388,7 @@ class ServingRig:
         logits, cache = batcher._prefill(
             batcher.params, batcher.cache, ids,
             np.array([n_prefill], np.int32), table[:1])
-        got, want = [np.asarray(logits)[0]], [want_all[n_prefill - 1]]
+        got = [np.asarray(logits)[0]]
         zeros = np.zeros(cfg.n_slots, np.uint32)
         for pos in range(n_prefill, len(seq)):
             step_ids = np.zeros(cfg.n_slots, np.int32)
@@ -270,18 +398,90 @@ class ServingRig:
                 batcher.params, cache, step_ids, lengths, table, zeros,
                 zeros, np.zeros(cfg.n_slots, np.float32))
             got.append(np.asarray(logits)[0])
-            want.append(want_all[pos])
-        got, want = np.stack(got), np.stack(want)
-        finite = bool(np.isfinite(got).all())
-        return {"bucket": bucket, "n_prefill": n_prefill, "finite": finite,
-                "rel_rms": float(np.sqrt(np.mean((got - want) ** 2))
-                                 / want.std()) if finite else float("inf"),
-                "max_abs": float(np.abs(got - want).max())
-                if finite else float("inf")}
+        batcher.cache = cache
+        return seq, n_prefill, bucket, np.stack(got)
+
+    def release_program_state(self) -> int:
+        """Free what the engine held on the device (served tree, page pool),
+        so that the reference runs beside nothing; peak bytes before that."""
+        import jax
+
+        chips = jax.devices()[:self.run.cell["chips"]]
+        stats = [d.memory_stats() or {} for d in chips]
+        self.run.say("memory", held_bytes=max(
+            (m.get("bytes_in_use", 0) for m in stats), default=0))
+        for leaf in jax.tree_util.tree_leaves((self.batcher.params,
+                                               self.batcher.cache)):
+            if hasattr(leaf, "delete") and not leaf.is_deleted():
+                leaf.delete()
+        return max((m.get("peak_bytes_in_use", 0) for m in stats), default=0)
+
+    def served_sample(self, records) -> List[Dict[str, Any]]:
+        """Requests the window finished, drawn from the seed, the longest
+        among them first."""
+        done = sorted((r for r in records if r.get("in_window")
+                       and r["outcome"] == "ok" and r.get("tokens")),
+                      key=lambda r: tuple(r["token_seed"]))
+        if not done:
+            return []
+        longest = max(done, key=lambda r: r["prompt_len"] + r["output_len"])
+        rng = np.random.default_rng([self.run.seed, 11])
+        drawn = [done[i] for i in rng.permutation(len(done))
+                 if done[i] is not longest]
+        return [longest] + drawn[:SERVED_SAMPLE - 1]
+
+    def narrow_operands(self, lowered_text: str) -> Dict[str, int]:
+        """What the deployment holds or computes in under 16 bits: leaves of
+        the served tree and the page pool by their type, and the tensor
+        types of the lowered decode step. Call before the state is freed."""
+        import jax
+
+        found = collections.Counter(narrow_types(lowered_text))
+        for leaf in jax.tree_util.tree_leaves((self.batcher.params,
+                                               self.batcher.cache)):
+            dtype = np.dtype(leaf.dtype)
+            if dtype.itemsize < 2 and dtype != np.bool_:
+                found[f"leaf:{dtype.name}"] += 1
+        return dict(found)
+
+    def served_gaps(self, sample, params, control) -> Dict[str, Any]:
+        """The reference, once over each sampled prompt with its served
+        tokens: the widest gap by which a served token's logit lies below the
+        reference's best at its position. With a ``control`` also the same
+        reading of the token that the reference computed in that lower
+        precision puts first there."""
+        from benchmark import traffic_gen as traffic
+
+        reference, kwargs = harness.reference_of(self.run.config)
+        cap = int(self.sizes["gen_max_seq_len"])
+        out = {"requests": len(sample), "tokens": 0, "served_gap": 0.0}
+        if control:
+            out["control_gap"] = 0.0
+        for r in sample:
+            tokens = np.asarray(r["tokens"], np.int32)
+            seq = np.concatenate([traffic.prompt_tokens(
+                self.mix, r, self.model.vocab), tokens[:-1]])
+            padded = min(-(-len(seq) // REFERENCE_PAD) * REFERENCE_PAD, cap)
+            ids = np.zeros((1, padded), np.int32)
+            ids[0, :len(seq)] = seq
+            at = r["prompt_len"] - 1 + np.arange(len(tokens))
+            rows = np.asarray(reference.logits(params, ids, **kwargs))[0][at]
+            best, each = rows.max(-1), np.arange(len(at))
+            out["tokens"] += len(tokens)
+            out["served_gap"] = max(out["served_gap"], float(
+                (best - rows[each, tokens]).max()))
+            if control:
+                low = np.asarray(reference.logits(
+                    params, ids, precision=control, **kwargs))[0][at]
+                out["control_gap"] = max(out["control_gap"], float(
+                    (best - rows[each, low.argmax(-1)]).max()))
+        return out
 
     def verdict(self, obs: Dict[str, Any], on_tpu: bool) -> List[str]:
         """Everything that makes this run's outputs wrong, in words; empty
-        when it is correct. Ends serving."""
+        when it is correct. Ends serving, frees its state, and only then runs
+        the reference. ``self.checks`` holds each number compared beside its
+        limit."""
         faults = []
         stats = obs["stats_end"]
         self.stop_serving()
@@ -292,20 +492,50 @@ class ServingRig:
             faults.append(f"decode ran {stats['distinct_decode_shapes']} shapes")
         if not obs["generators_clean"]:
             faults.append("a load generator had to be killed")
-        kernels = self.mosaic_kernels()
+        decode_step = self.batcher.lower_decode().as_text()
+        kernels = self.mosaic_kernels(decode_step)
         if on_tpu and not kernels.get("zoo_paged_attention"):
             faults.append(f"the decode step holds no zoo_paged_attention "
                           f"Mosaic call: {kernels}")
-        check = self.check_logits()
-        self.run.say("logits", **check, tol_rel_rms=LOGIT_REL_RMS_TOL,
-                     tol_max_abs=LOGIT_MAX_ABS_TOL, mosaic=kernels)
-        if not (check["rel_rms"] <= LOGIT_REL_RMS_TOL
-                and check["max_abs"] <= LOGIT_MAX_ABS_TOL):
-            faults.append(f"logits off the reference: {check}")
+        seq, n_prefill, bucket, got = self.program_logits()
+        narrow = self.narrow_operands(decode_step)
+        obs["memory_peak_bytes"] = self.release_program_state()
+
+        control = self.run.control
+        params = harness.make_params(self.model, self.run.seed)
+        reference, kwargs = harness.reference_of(self.run.config)
+        want = np.asarray(reference.logits(params, seq[None], **kwargs))[
+            0][n_prefill - 1:]
+        served = self.served_gaps(self.served_sample(obs["records"]), params,
+                                  control)
+        gap = served["served_gap"] if served["requests"] else float("inf")
+        if control:     # the reference in that precision, in the program's place
+            self.run.say("program", served_gap=gap, narrow=narrow, **rel_errors(
+                got, want))
+            got = np.asarray(reference.logits(
+                params, seq[None], precision=control, **kwargs))[
+                0][n_prefill - 1:]
+            narrow = narrow_types(reference.lowered_block(
+                params, seq[None], precision=control, **kwargs))
+            gap = served["control_gap"] if served["requests"] else gap
+        del params
+        errors = rel_errors(got, want)
+        self.run.say("logits", bucket=bucket, n_prefill=n_prefill,
+                     mosaic=kernels, narrow=narrow, **errors)
+        self.run.say("served", **served)
+        self.checks = {
+            "served_gap": [gap, SERVED_GAP_TOL],
+            "logit_rel_rms": [errors["rel_rms"], LOGIT_REL_RMS_TOL],
+            "logit_max_abs": [errors["max_abs"], LOGIT_MAX_ABS_TOL],
+            "narrow_operands": [sum(narrow.values()), NARROW_OPERANDS_TOL]}
+        for name, (value, limit) in self.checks.items():
+            if not value <= limit:
+                faults.append(f"{name} {value} is over its limit {limit}")
         return faults
 
     def close(self) -> None:
-        """End every process and thread this rig started."""
+        """End every process and thread this rig started, also when the run
+        failed: the load generators, the engine, the broker."""
         for child in self.children:
             child.kill()
             child.wait()
@@ -342,4 +572,4 @@ def serve_cell(run: harness.Run, make_jobs, reduce,
     metrics["setup_s"] = obs["window"][0] - run.t_process_start
     return harness.Outcome(correct=not faults, attempted=attempted,
                            failed=failed, end_to_end=metrics,
-                           observations=obs, notes=faults)
+                           observations=obs, notes=faults, checks=rig.checks)

@@ -7,8 +7,10 @@ of it, written into its traffic file as a number.
     python benchmark/tools/find_knee.py --workload gen-chat-steady \
         --rates 4 6 8 10 12 --seconds 30 --seed 0
 
-One process: the deployment is set up and warmed once, then offered each rate
-in turn, lowest first, each with its own lead-in, window and drain. A rate is
+One process: the deployment (``serving_rig.ServingRig``: the broker in a
+process of its own, as the cells run it) is set up and warmed once, then
+offered each rate in turn, lowest first, each with its own lead-in, window and
+drain, up to the first rate that is not sustained. A rate is
 sustained when at least 99% of the requests due in its window ended ``ok``
 and the backlog (requests due that have no first token yet: a request being
 served is no backlog) at the end of the window is no larger than at its
@@ -67,7 +69,8 @@ def main(argv=None) -> int:
 
     enable_compile_cache()
     run = harness.Run(cell=cell, config=config, traffic=mix, seed=args.seed,
-                      seconds=args.seconds, trace=False, out_dir="", t_process_start=T_PROCESS_START,
+                      seconds=args.seconds, trace=False, out_dir="",
+                      t_process_start=T_PROCESS_START,
                       compiles=harness.CompileCounter())
     lead_s = float(mix.get("lead_in_s", 6))
     rig = ServingRig(run)
@@ -98,8 +101,9 @@ def main(argv=None) -> int:
                 "compiles_in_window": obs["compiles_in_window"],
                 "generator_late_p99_ms": generator_late.read(obs, {})}),
                 flush=True)
-            if sustained:
-                knee = rate
+            if not sustained:
+                break       # its backlog would be offered to the next rate
+            knee = rate
     finally:
         rig.close()
     print(json.dumps({"knee_rate_per_s": knee,

@@ -130,12 +130,15 @@ def prompt_tokens(mix: Dict[str, Any], request: Dict[str, Any],
 
 
 def open_loop_schedule(mix: Dict[str, Any], seed: int, lead_s: float,
-                       seconds: float) -> List[Dict[str, Any]]:
+                       seconds: float,
+                       after_s: float = 0.0) -> List[Dict[str, Any]]:
     """Requests with their due instants (seconds from the schedule's zero):
-    a lead-in of ``lead_s`` and then the window, each a schedule of its own,
-    so that the window's amount of work does not depend on the lead-in's."""
+    a lead-in of ``lead_s``, then the window, then ``after_s`` more seconds
+    (a traced run's profiler follows its window), each a schedule of its own,
+    so that the window's amount of work depends on neither of the others."""
     out = []
-    for part, (start, span) in enumerate(((0.0, lead_s), (lead_s, seconds))):
+    for part, (start, span) in enumerate(((0.0, lead_s), (lead_s, seconds),
+                                          (lead_s + seconds, after_s))):
         due = start + arrival_times(mix["arrivals"],
                                     _rng(seed, _ARRIVALS, part), span)
         reqs = requests(mix, seed, len(due), stream=part, stratified=True)
