@@ -1,9 +1,10 @@
-"""Collective-budget rules: the ZeRO-1 one-collective-per-global-step gate.
+"""Collective-budget rules: the ZeRO-1 one-exchange-per-bucket gate.
 
-The flat update-sharding path (PR 5, ``parallel/update_sharding.py``) is
-structurally ONE grad-sized reduce-scatter + one params all-gather per global
-step, with counts constant in ``grad_accum_steps``. This module owns both
-counters that guard it:
+The flat update-sharding path (``parallel/update_sharding.py``) is
+structurally one exchange per bucket per global step — a bucket-sized
+reduce-scatter and a params all-gather for each bucket of the flat meta —
+with counts constant in ``grad_accum_steps``. This module owns both counters
+that guard it:
 
 * :func:`collective_counts` — the compiled-HLO instruction counter
   (migrated here from ``parallel.update_sharding``; the bench's
@@ -52,7 +53,9 @@ _PRIMITIVE_TO_HLO = {
 def collective_counts(hlo_text: str) -> Dict[str, int]:
     """Count collective *instruction definitions* in compiled HLO (or
     lowered StableHLO) text, e.g. ``{"reduce-scatter": 1, "all-gather": 1}``
-    (ignores mentions in operand positions)."""
+    (ignores mentions in operand positions). Lowered text defines a jitted
+    function once however often it is called, so the buckets of the flat
+    exchange count once there and ``n_buckets`` times once compiled."""
     out: Counter = Counter()
     for line in hlo_text.splitlines():
         if "=" not in line:
@@ -119,8 +122,9 @@ class CollectiveBudgetRule(Rule):
     layer = "jaxpr"
     severity = "error"
     doc = ("Collective census of the traced step vs an expected budget "
-           "(e.g. ZeRO-1 flat: exactly 1 reduce-scatter + 1 all-gather per "
-           "global step, none inside the accumulation scan)")
+           "(e.g. ZeRO-1 flat: one exchange per bucket per global step — "
+           "n_buckets reduce-scatters + n_buckets all-gathers, constant in "
+           "grad_accum_steps, none inside the accumulation scan)")
 
     def check(self, closed_jaxpr, ctx: RuleContext) -> Iterable[Finding]:
         if ctx.expect_collectives is None:

@@ -55,6 +55,9 @@ logger = logging.getLogger("analytics_zoo_tpu.estimator")
 # synced at each log point by the loss transfer). The same numbers flush to
 # TrainSummary (TensorBoard + metrics.jsonl) and land here for /metrics.
 _STEPS = _tm.counter("zoo_train_steps_total", "Optimizer steps run")
+_UPDATE_BUCKETS = _tm.gauge("zoo_train_update_buckets",
+                            "Buckets of the ZeRO-1 flat exchange (one "
+                            "reduce-scatter + one all-gather each per step)")
 _DATA_WAIT = _tm.histogram("zoo_train_data_wait_seconds",
                            "Per-step host wait on the input pipeline")
 _COMPUTE = _tm.histogram("zoo_train_compute_seconds",
@@ -252,8 +255,8 @@ class Estimator:
         if mode == "flat":
             if (in_opt and self._flat_meta is not None
                     and tuple(getattr(leaf, "shape", ()))
-                    == (self._flat_meta.npad,)):
-                return P("dp")
+                    == self._flat_meta.bucket_shape):
+                return P(None, "dp")     # replica i owns column block i
             return P()       # flat mode implies no base rules (pure-dp mesh)
         if in_opt and upd_rule is not None:
             return upd_rule(path, leaf)
@@ -325,11 +328,17 @@ class Estimator:
                         if self._mp_dtype is not None else params)
         mode = self._update_mode()
         if mode == "flat":
-            self._flat_meta = upd.flat_meta(model_params,
-                                            self.mesh.shape["dp"])
-            opt_state = upd.flat_opt_init(
-                self._base_tx, params, self._flat_meta,
-                keep_master=self._mp_dtype is not None)
+            meta = self._flat_meta = upd.flat_meta(model_params,
+                                                   self.mesh.shape["dp"])
+            # one executable for all buckets: built op by op, every bucket's
+            # pieces compile (or load from the cache) on their own shapes
+            opt_state = jax.jit(lambda p: upd.flat_opt_init(
+                self._base_tx, p, meta,
+                keep_master=self._mp_dtype is not None))(params)
+            _UPDATE_BUCKETS.set(meta.n_buckets)
+            logger.info("update sharding: flat over dp=%d, %d parameters in "
+                        "%d bucket(s) of %d x %d", meta.n_shards, meta.n,
+                        meta.n_buckets, *meta.bucket_shape)
         else:
             opt_state = self.tx.init(params)
         state = {
@@ -471,8 +480,8 @@ class Estimator:
     def _flat_step_fn(self):
         """Pure-dp weight-update sharding: the whole step runs inside
         ``shard_map`` (manual over the mesh), so per-replica grads stay local
-        through the accumulation scan and the exchange is structurally ONE
-        reduce-scatter + one params all-gather per global step —
+        through the accumulation scan and the exchange is structurally one
+        reduce-scatter + one params all-gather per BUCKET per global step —
         BigDL ``AllReduceParameter``'s slice-owner update, TPU-native."""
         from jax import shard_map
 
@@ -633,11 +642,9 @@ class Estimator:
             if cfg.checkpoint_dir:
                 latest = ckpt.latest_checkpoint(cfg.checkpoint_dir)
                 if latest:
-                    restored, meta = ckpt.load_checkpoint(latest, self.train_state)
-                    self.train_state = self._place_state(restored)
-                    self.trainer_state.iteration = meta["iteration"]
-                    self.trainer_state.epoch = meta["epoch"]
-                    logger.info("resumed from %s (iter %d)", latest, meta["iteration"])
+                    self._restore(latest)
+                    logger.info("resumed from %s (iter %d)", latest,
+                                self.trainer_state.iteration)
         if self._train_step is None:    # after the state: it fixes the layout
             self._train_step = self._make_train_step()
 
@@ -701,10 +708,7 @@ class Estimator:
                                    cfg.retry_times, latest, delay)
                     if delay > 0:
                         (retry_policy.sleep or time.sleep)(delay)
-                    restored, meta = ckpt.load_checkpoint(latest, self.train_state)
-                    self.train_state = self._place_state(restored)
-                    self.trainer_state.iteration = meta["iteration"]
-                    self.trainer_state.epoch = meta["epoch"]
+                    self._restore(latest)
                     continue
 
                 if validation_data is not None and validation_metrics:
@@ -1028,10 +1032,11 @@ class Estimator:
         graph-layer lint rules against it per ``TrainConfig.graph_checks``.
 
         Expectations are derived from the config: the flat update-sharding
-        path must show exactly one reduce-scatter + one all-gather per global
-        step (and none inside the accumulation scan); a declared bf16 policy
-        must actually reach the contraction ops; no host callbacks or large
-        closure-captured constants may ride the step. The memory tier rides
+        path must show exactly one reduce-scatter + one all-gather per bucket
+        of the flat meta per global step (and none inside the accumulation
+        scan); a declared bf16 policy must actually reach the contraction
+        ops; no host callbacks or large closure-captured constants may ride
+        the step. The memory tier rides
         the same trace: the train state is rebound every step, so an
         un-donated state (``donate_state=False``) is ``donation-missed``; a
         declared ``hbm_budget_mb`` bounds the static live-range peak; and
@@ -1039,8 +1044,10 @@ class Estimator:
         from ..analysis import RuleContext, enforce, lint_jaxpr, profile_jaxpr
         from ..analysis.rules.memory import lint_memory
 
-        expect = ({"reduce-scatter": 1, "all-gather": 1}
-                  if self._update_mode() == "flat" else None)
+        expect = None
+        if self._update_mode() == "flat":
+            n_buckets = self._flat_meta.n_buckets
+            expect = {"reduce-scatter": n_buckets, "all-gather": n_buckets}
         cfg = self.config
         n_state = len(jax.tree_util.tree_leaves(self.train_state))
         batch = self._to_global(sample_batch)
@@ -1342,9 +1349,30 @@ class Estimator:
         if self.train_state is None:
             assert sample_batch is not None, "need sample_batch to build state"
             self.train_state = self._init_state(sample_batch)
-        path = ckpt.latest_checkpoint(directory) or directory
-        restored, meta = ckpt.load_checkpoint(path, self.train_state)
+        self._restore(ckpt.latest_checkpoint(directory) or directory)
+        return self
+
+    def _restore(self, path: str):
+        """Load the snapshot at ``path`` into the current state's structure
+        and placement, and take its iteration/epoch. Under flat update
+        sharding the optimizer state must be in this build's bucket layout:
+        a one-bucket state in the older ``(npad,)`` layout is re-padded, any
+        other layout is refused rather than read as this one."""
+        flat = self._update_mode() == "flat"
+        try:
+            restored, meta = ckpt.load_checkpoint(path, self.train_state)
+            if flat:
+                restored["opt_state"] = upd.adopt_flat_layout(
+                    restored["opt_state"], self.train_state["opt_state"],
+                    self._flat_meta)
+        except ValueError as e:
+            if not flat:
+                raise
+            raise ValueError(
+                f"{path} does not fit the flat update-sharding state "
+                f"({self._flat_meta.n_buckets} bucket(s) of "
+                f"{self._flat_meta.bucket_shape}): {e}. Resume it with the "
+                f"version that wrote it, or load the params alone.") from e
         self.train_state = self._place_state(restored)
         self.trainer_state.iteration = meta["iteration"]
         self.trainer_state.epoch = meta["epoch"]
-        return self

@@ -9,19 +9,29 @@ On a pure data-parallel mesh the equivalent exchange is
     reduce-scatter(grads) → shard-local optimizer update → all-gather(params)
 
 ("Automatic Cross-Replica Sharding of Weight Update in Data-Parallel Training",
-Xu et al. 2020): per-step gradient communication stays one collective round,
-and optimizer state (plus the f32 master weights of the mixed-precision path)
-shrinks to ``1/dp`` per device.
+Xu et al. 2020): per-step gradient communication moves each gradient byte
+once, and optimizer state (plus the f32 master weights of the mixed-precision
+path) shrinks to ``1/dp`` per device.
 
 Two implementations, selected by the training engine:
 
-* **flat** (pure-dp mesh) — the BigDL layout, literally: the gradient pytree is
-  flattened to one padded f32 vector inside ``shard_map``; ``psum_scatter``
-  hands each replica its slice, the optimizer updates that slice against a
-  flat (sharded) optimizer state, and one tiled ``all_gather`` rebuilds the
-  replicated params. The collective count per *global* step is structural —
-  gradient accumulation scans microbatches over device-local grads, so K
-  microbatches still cost exactly one reduce-scatter + one all-gather.
+* **flat** (pure-dp mesh) — the BigDL layout, literally: inside ``shard_map``
+  the gradient pytree is viewed as one f32 matrix, the raveled leaves in
+  tree-flatten order each padded to whole rows, cut into equal **buckets** of
+  rows (:func:`flat_meta`; about one transformer block each, exactly one for
+  a model that fits one). Per bucket, ``psum_scatter`` hands each replica
+  its column block, the optimizer updates that shard against the bucket's
+  (sharded) optimizer state, and one tiled ``all_gather`` rebuilds the
+  bucket's replicated params. A bucket is stacked from the row blocks of the
+  leaves it covers, never sliced out of the whole view, so it depends on
+  those gradients alone and its all-gather can run under the next bucket's
+  reduction and update; one whole-vector exchange could start only after
+  the LAST gradient and ran wholly exposed (three tenths of the step on four
+  v5e chips). All buckets go through the same two jitted functions, so the
+  traced step defines each collective once. The collective count per
+  *global* step is structural — gradient accumulation scans microbatches
+  over device-local grads, so K microbatches still cost exactly one
+  reduce-scatter + one all-gather per bucket.
 * **gspmd** (meshes that also shard params over ``fsdp``/``tp``) —
   :func:`make_update_sharding` extends the per-leaf
   :func:`~analytics_zoo_tpu.parallel.sharding.make_param_sharding` specs with a
@@ -45,9 +55,9 @@ from jax.sharding import PartitionSpec as P
 
 __all__ = [
     "FlatParamMeta", "FlatUpdateState", "MasterWeightsState",
-    "collective_counts", "flat_exchange", "flat_meta", "flatten_tree",
-    "make_update_sharding", "shard_spec_over_axis",
-    "unflatten_tree", "with_master_weights",
+    "adopt_flat_layout", "collective_counts", "flat_bucket", "flat_exchange",
+    "flat_meta", "flat_opt_init", "flatten_tree", "make_update_sharding",
+    "shard_spec_over_axis", "unflatten_buckets", "with_master_weights",
 ]
 
 
@@ -122,29 +132,86 @@ def make_update_sharding(mesh, base_rule: Optional[Callable] = None,
 
 
 # ------------------------------------------------------------- flat exchange
+#: Target length of one bucket of the flat exchange, in elements: about one
+#: transformer block at hidden size 2048 (12 * 2048**2). The bucket length a
+#: model gets is derived from its parameter count (``flat_meta``): a model that
+#: fits in one target gets exactly one bucket, a larger one gets equal buckets
+#: of at most about this length.
+BUCKET_TARGET_LEN = 48 * 2 ** 20
+#: Most columns of one replica's shard of a bucket (a power of two). A bucket
+#: travels as a ``(rows, n_shards * cols)`` matrix scattered over its MINOR
+#: dimension: the v5e compiler emits a real reduce-scatter for that (128-4096
+#: columns a shard), and rewrites a scatter over the major or only dimension,
+#: where a shard is one contiguous block, to a whole-operand all-reduce.
+SHARD_COLS = 1024
+#: A large shard's row count is rounded up to a multiple of this: the same
+#: compiler falls back to the all-reduce when it cannot cut the rows into
+#: chunks (11,512 = 8 x 1,439 rows, 1,439 prime, did; 11,520 did not).
+SHARD_ROWS_MULTIPLE = 128
+
+
 class FlatParamMeta(NamedTuple):
     """Static flattening layout of a param pytree (BigDL AllReduceParameter's
-    flat-vector view): leaf order/shapes/dtypes + dp-padded total length."""
+    flat-vector view): leaf order/shapes/dtypes, and the cut of the flat view
+    into ``n_buckets`` equal buckets. The flat view is a matrix of
+    ``n_shards * cols`` columns in which leaf ``i``, raveled and zero-padded
+    to whole rows, takes ``leaf_rows[i]`` rows, leaves following one another
+    in tree-flatten order; a bucket is ``shard_shape[0]`` consecutive rows
+    (the last one zero-padded), exchanged and its state held as that
+    ``bucket_shape`` matrix; replica ``i`` owns column block ``i``
+    (``shard_shape``) of every bucket. Every leaf starts on a row, so a
+    bucket is stacked from whole row blocks of the leaves it covers; a
+    bucket concatenated from 1-D pieces had to be re-tiled into its matrix,
+    which cost the four-chip cell a tenth of its exchange and half the
+    step's compile time."""
 
     treedef: Any
     shapes: Tuple[Tuple[int, ...], ...]
     sizes: Tuple[int, ...]
     dtypes: Tuple[Any, ...]
-    n: int
-    npad: int
+    n: int                          # parameters (no padding counted)
     n_shards: int
+    n_buckets: int
+    shard_shape: Tuple[int, int]
+    leaf_rows: Tuple[int, ...]
 
     @property
-    def shard_size(self) -> int:
-        return self.npad // self.n_shards
+    def bucket_shape(self) -> Tuple[int, int]:
+        rows, cols = self.shard_shape
+        return rows, self.n_shards * cols
+
+    @property
+    def bucket_len(self) -> int:
+        rows, width = self.bucket_shape
+        return rows * width
+
+    @property
+    def npad(self) -> int:
+        """Elements of the whole flat view, padding included."""
+        return self.n_buckets * self.bucket_len
+
+    def pieces(self, b: int) -> Tuple[Tuple[int, int, int], ...]:
+        """``(leaf index, first row, end row)`` of the row blocks of the
+        leaves' row matrices that fall in bucket ``b``, in flat order."""
+        rows = self.shard_shape[0]
+        lo, hi = b * rows, (b + 1) * rows
+        out, off = [], 0
+        for i, n_rows in enumerate(self.leaf_rows):
+            s, e = max(lo, off), min(hi, off + n_rows)
+            if s < e:
+                out.append((i, s - off, e - off))
+            off += n_rows
+        return tuple(out)
 
 
 class FlatUpdateState(NamedTuple):
-    """Optimizer state of the flat exchange: the inner transformation's state
-    over the flat (npad,) vector — dp-sharded — plus the f32 master-weight
-    shard of the mixed-precision path (``None`` when params are already f32,
+    """Optimizer state of the flat exchange, one entry per bucket: the inner
+    transformation's state over that bucket's ``bucket_shape`` matrix —
+    dp-sharded over its columns — plus the f32 master weights of the
+    mixed-precision path (``master`` is ``None`` when params are already f32,
     in which case the master shard is re-sliced from the replicated params
-    each step instead of stored)."""
+    each step instead of stored). The buckets' matrices, stacked, are the
+    flat view of :class:`FlatParamMeta` in its natural order."""
 
     inner_state: Any
     master: Any
@@ -158,41 +225,120 @@ class MasterWeightsState(NamedTuple):
     master: Any
 
 
-def flat_meta(params, n_shards: int) -> FlatParamMeta:
+def flat_meta(params, n_shards: int,
+              bucket_len: Optional[int] = None) -> FlatParamMeta:
+    """Layout of ``params`` for the flat exchange over ``n_shards`` replicas.
+    ``bucket_len`` is the TARGET bucket length in elements (default
+    :data:`BUCKET_TARGET_LEN`): the flat view is cut into equal buckets of
+    about that length, as few as that takes (one for a model that fits)."""
     leaves, treedef = jax.tree_util.tree_flatten(params)
     shapes = tuple(tuple(l.shape) for l in leaves)
     sizes = tuple(int(np.prod(s)) if s else 1 for s in shapes)
-    dtypes = tuple(jnp.asarray(l).dtype for l in leaves)
+    dtypes = tuple(jnp.dtype(l.dtype) for l in leaves)
     n = int(sum(sizes))
-    npad = ((n + n_shards - 1) // n_shards) * n_shards
-    return FlatParamMeta(treedef, shapes, sizes, dtypes, n, npad, n_shards)
+    # a shard's columns: the most, up to SHARD_COLS, at which padding every
+    # leaf to whole rows wastes under 1/64 of the parameters
+    cols = SHARD_COLS
+    while cols > 1 and 64 * sum(-z % (n_shards * cols) for z in sizes) > n:
+        cols //= 2
+    width = n_shards * cols
+    leaf_rows = tuple(-(-z // width) for z in sizes)
+    total = sum(leaf_rows)
+    target = BUCKET_TARGET_LEN if bucket_len is None else int(bucket_len)
+    rows = -(-total // max(1, -(-total * width // target)))
+    # the largest power of two, at most SHARD_ROWS_MULTIPLE, that pads the
+    # rows by under 1/64
+    mult = min(SHARD_ROWS_MULTIPLE, 1 << max(0, (rows // 64).bit_length() - 1))
+    rows = -(-rows // mult) * mult
+    return FlatParamMeta(treedef, shapes, sizes, dtypes, n, n_shards,
+                         -(-total // rows), (rows, cols), leaf_rows)
+
+
+def flat_bucket(tree, meta: FlatParamMeta, b: int, dtype=jnp.float32):
+    """Bucket ``b`` of the flat view of ``tree`` as a ``bucket_shape`` matrix
+    in ``dtype``, stacked from the row blocks of the leaves it covers
+    (zero rows below the last leaf) — never sliced out of the whole view, so
+    it depends on those leaves alone."""
+    leaves = jax.tree_util.tree_leaves(tree)
+    rows, width = meta.bucket_shape
+    parts, have = [], 0
+    for i, r0, r1 in meta.pieces(b):
+        n_rows = meta.leaf_rows[i]
+        flat = jnp.ravel(leaves[i])
+        if flat.size < n_rows * width:
+            flat = jnp.pad(flat, (0, n_rows * width - flat.size))
+        block = flat.reshape(n_rows, width)
+        if (r0, r1) != (0, n_rows):
+            block = jax.lax.slice_in_dim(block, r0, r1)
+        parts.append(block.astype(dtype))
+        have += r1 - r0
+    if have < rows:
+        parts.append(jnp.zeros((rows - have, width), dtype))
+    return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
 
 
 def flatten_tree(tree, meta: FlatParamMeta, dtype=jnp.float32):
-    """Pytree → one (npad,) vector in ``dtype`` (zero-padded tail)."""
-    leaves = jax.tree_util.tree_leaves(tree)
-    vec = jnp.concatenate([jnp.ravel(l).astype(dtype) for l in leaves])
-    if meta.npad > meta.n:
-        vec = jnp.pad(vec, (0, meta.npad - meta.n))
-    return vec
+    """Pytree → the whole flat view as one (npad,) vector in ``dtype``."""
+    return jnp.concatenate([jnp.ravel(flat_bucket(tree, meta, b, dtype))
+                            for b in range(meta.n_buckets)])
 
 
-def unflatten_tree(vec, meta: FlatParamMeta):
-    """(npad,) vector → pytree with the meta's original shapes/dtypes."""
-    out, off = [], 0
-    for shape, size, dt in zip(meta.shapes, meta.sizes, meta.dtypes):
-        out.append(jax.lax.slice_in_dim(vec, off, off + size)
-                   .reshape(shape).astype(dt))
-        off += size
+def unflatten_buckets(buckets: Sequence[Any], meta: FlatParamMeta):
+    """Per-bucket ``bucket_shape`` matrices → pytree with the meta's original
+    shapes/dtypes. A leaf that spans buckets is joined from its row blocks."""
+    blocks = [[] for _ in meta.sizes]
+    for b, bucket in enumerate(buckets):
+        off = 0
+        for i, r0, r1 in meta.pieces(b):
+            blocks[i].append(jax.lax.slice_in_dim(bucket, off, off + r1 - r0))
+            off += r1 - r0
+    out = []
+    for rows, size, shape, dt in zip(blocks, meta.sizes, meta.shapes,
+                                     meta.dtypes):
+        flat = jnp.ravel(jnp.concatenate(rows) if len(rows) > 1 else rows[0])
+        if flat.size > size:
+            flat = jax.lax.slice_in_dim(flat, 0, size)
+        out.append(flat.reshape(shape).astype(dt))
     return jax.tree_util.tree_unflatten(meta.treedef, out)
 
 
 def flat_opt_init(tx: optax.GradientTransformation, params,
                   meta: FlatParamMeta, keep_master: bool) -> FlatUpdateState:
-    """Global-view init (arrays are full (npad,) vectors; the engine places
-    them dp-sharded). ``params`` may be any float dtype — masters are f32."""
-    flat32 = flatten_tree(params, meta, jnp.float32)
-    return FlatUpdateState(tx.init(flat32), flat32 if keep_master else None)
+    """Global-view init (each bucket's arrays are whole ``bucket_shape``
+    matrices; the engine places them dp-sharded over their columns).
+    ``params`` may be any float dtype — masters are f32."""
+    flat32 = tuple(flat_bucket(params, meta, b, jnp.float32)
+                   for b in range(meta.n_buckets))
+    return FlatUpdateState(tuple(tx.init(m) for m in flat32),
+                           flat32 if keep_master else None)
+
+
+def adopt_flat_layout(restored: FlatUpdateState, template: FlatUpdateState,
+                      meta: FlatParamMeta) -> FlatUpdateState:
+    """Bring a checkpoint's flat optimizer state (already unflattened into
+    ``template``'s structure) into ``meta``'s layout. A one-bucket state
+    written as plain ``(npad,)`` vectors — the layout before bucketing: the
+    raveled leaves end to end — holds the same values in the same order and
+    is re-padded leaf by leaf; any other shape mismatch is refused in words,
+    never reinterpreted."""
+    width = meta.bucket_shape[1]
+
+    def adopt(path, got, want):
+        got, shape = np.asarray(got), tuple(want.shape)
+        if got.shape == shape:
+            return got
+        if (shape == meta.bucket_shape and meta.n_buckets == 1
+                and got.ndim == 1 and got.size >= meta.n):
+            leaves = np.split(got[:meta.n], np.cumsum(meta.sizes)[:-1])
+            flat = np.concatenate([np.pad(l, (0, r * width - l.size))
+                                   for l, r in zip(leaves, meta.leaf_rows)])
+            return np.pad(flat, (0, meta.npad - flat.size)).reshape(shape)
+        raise ValueError(
+            f"optimizer-state leaf {jax.tree_util.keystr(path)} is "
+            f"{got.shape} in the checkpoint and {shape} here: it was written "
+            f"under another flat update-sharding layout")
+
+    return jax.tree_util.tree_map_with_path(adopt, restored, template)
 
 
 def flat_exchange(params, grads, opt_state: FlatUpdateState,
@@ -204,44 +350,57 @@ def flat_exchange(params, grads, opt_state: FlatUpdateState,
     ``axis``). ``grads`` are this replica's local-mean grads.
 
     Returns ``(new_params, new_opt_state, grad_norm)``; ``grad_norm`` is the
-    f32 global (pre-clip) gradient L2 norm. Exactly one grad-sized collective
-    round per call: ``psum_scatter`` in, tiled ``all_gather`` out (the norm
-    rides a scalar psum).
+    f32 global (pre-clip) gradient L2 norm. One grad-sized collective round
+    per BUCKET per call: a reduce-scatter in, a tiled ``all_gather`` out (the
+    norm rides one scalar psum over all buckets' shards). Every bucket goes
+    through the same two jitted functions, so the traced step defines each
+    collective once and calls it ``meta.n_buckets`` times; a bucket's
+    reduction depends only on the gradients it covers, and its update and
+    gather only on its own reduction (and, under ``clip_norm``, on the norm).
     """
     n = axis_size(axis)
-    shard = meta.shard_size
-    idx = jax.lax.axis_index(axis)
-
-    gflat = flatten_tree(grads, meta, jnp.float32)
-    # mean over replicas: local grads are means over the local micro/batch
-    gshard = jax.lax.psum_scatter(gflat, axis, scatter_dimension=0,
-                                  tiled=True) / n
-    gnorm = jnp.sqrt(jax.lax.psum(jnp.sum(gshard * gshard), axis))
-    if clip_norm is not None:
-        # f32 global-norm clipping computed across the scattered shards —
-        # optax.clip_by_global_norm would only see one shard here
-        gshard = gshard * jnp.minimum(1.0, clip_norm / (gnorm + 1e-12))
-    if clip_value is not None:
-        lo, hi = clip_value
-        gshard = jnp.clip(gshard, lo, hi)
-
-    if opt_state.master is not None:
-        master = opt_state.master        # persistent f32 shard (bf16 params)
-    else:                                # f32 params: re-slice, store nothing
-        pflat = flatten_tree(params, meta, jnp.float32)
-        master = jax.lax.dynamic_slice_in_dim(pflat, idx * shard, shard)
-
-    updates, inner2 = tx.update(gshard, opt_state.inner_state, master)
-    master2 = optax.apply_updates(master, updates)
-
+    cols = meta.shard_shape[1]
+    keep_master = opt_state.master is not None
     # all-gather in the MODEL dtype: under bf16 params the param broadcast
     # costs half the bytes of the f32 masters
     gather_dt = meta.dtypes[0] if len(set(meta.dtypes)) == 1 else jnp.float32
-    new_flat = jax.lax.all_gather(master2.astype(gather_dt), axis, axis=0,
-                                  tiled=True)
-    new_params = unflatten_tree(new_flat, meta)
-    new_opt = FlatUpdateState(inner2,
-                              master2 if opt_state.master is not None else None)
+
+    @jax.jit
+    def reduce_bucket(gbucket):
+        # mean over replicas: local grads are means over the local micro/batch
+        return jax.lax.psum_scatter(gbucket, axis, scatter_dimension=1,
+                                    tiled=True) / n
+
+    @jax.jit
+    def update_bucket(gshard, inner, master):
+        if not keep_master:              # f32 params: ``master`` is the whole
+            master = jax.lax.dynamic_slice_in_dim(    # bucket, re-slice it
+                master, jax.lax.axis_index(axis) * cols, cols, axis=1)
+        updates, inner2 = tx.update(gshard, inner, master)
+        master2 = optax.apply_updates(master, updates)
+        gathered = jax.lax.all_gather(master2.astype(gather_dt), axis,
+                                      axis=1, tiled=True)
+        return gathered, inner2, master2
+
+    buckets = range(meta.n_buckets)
+    gshards = [reduce_bucket(flat_bucket(grads, meta, b)) for b in buckets]
+    gnorm = jnp.sqrt(jax.lax.psum(sum(jnp.sum(g * g) for g in gshards), axis))
+    if clip_norm is not None:
+        # f32 global-norm clipping computed across the scattered shards —
+        # optax.clip_by_global_norm would only see one shard here
+        scale = jnp.minimum(1.0, clip_norm / (gnorm + 1e-12))
+        gshards = [g * scale for g in gshards]
+    if clip_value is not None:
+        lo, hi = clip_value
+        gshards = [jnp.clip(g, lo, hi) for g in gshards]
+
+    gathered, inner2, master2 = zip(*(
+        update_bucket(gshards[b], opt_state.inner_state[b],
+                      opt_state.master[b] if keep_master     # f32 shard
+                      else flat_bucket(params, meta, b))     # f32 bucket
+        for b in buckets))
+    new_params = unflatten_buckets(gathered, meta)
+    new_opt = FlatUpdateState(inner2, master2 if keep_master else None)
     return new_params, new_opt, gnorm
 
 

@@ -61,6 +61,25 @@ def np_rng():
 
 
 @pytest.hookimpl(trylast=True)
+@pytest.fixture()
+def bucket_target(monkeypatch):
+    """``bucket_target(48)``: every ``flat_meta`` the engine builds from here
+    on is handed ``bucket_len=48`` (the function's own argument — there is no
+    config field), so a toy model exchanges several buckets of the ZeRO-1
+    flat exchange instead of one."""
+    from analytics_zoo_tpu.parallel import update_sharding as upd
+
+    real = upd.flat_meta
+
+    def force(bucket_len):
+        monkeypatch.setattr(
+            upd, "flat_meta",
+            lambda params, n_shards: real(params, n_shards,
+                                          bucket_len=bucket_len))
+
+    return force
+
+
 def pytest_sessionfinish(session, exitstatus):
     """Shutdown-hang watchdog: full-suite runs have intermittently printed
     their summary and then hung forever in ``threading._shutdown`` joining a

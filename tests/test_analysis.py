@@ -355,16 +355,31 @@ def test_graph_checks_clean_fit_passes(zoo_ctx):
     assert est.trainer_state.iteration == 2
 
 
-def test_graph_checks_flat_sharding_passes(zoo_ctx):
+@pytest.fixture(params=[None, 48], ids=["one-bucket", "several-buckets"])
+def flat_buckets(request, bucket_target):
+    """Run a flat update-sharding test at the default bucket target (the toy
+    fits one bucket) and with a 48-element target (the toy's 172 parameters,
+    22 rows of 8, then exchange 4 buckets). Returns the count."""
+    if request.param is None:
+        return 1
+    bucket_target(request.param)
+    return 4
+
+
+def test_graph_checks_flat_sharding_passes(zoo_ctx, flat_buckets):
+    """The collective budget is one reduce-scatter + one all-gather per
+    bucket of the flat meta: a clean exchange passes with one and several."""
     est = _toy_fit("raise", update_sharding=True)
     assert est._update_mode() == "flat"
+    assert est._flat_meta.n_buckets == flat_buckets
     assert est.trainer_state.iteration == 2
 
 
-def test_graph_checks_catch_broken_flat_exchange(zoo_ctx, monkeypatch):
+def test_graph_checks_catch_broken_flat_exchange(zoo_ctx, monkeypatch,
+                                                 flat_buckets):
     """Deliberately break the ZeRO-1 exchange (psum instead of the
     reduce-scatter/all-gather pair): graph_checks='raise' fails fit()
-    BEFORE the first step compiles."""
+    BEFORE the first step compiles, whatever the bucket count."""
     from analytics_zoo_tpu.parallel import update_sharding as upd
 
     def broken_exchange(params, grads, opt_state, meta, tx, *, axis="dp",
@@ -375,7 +390,8 @@ def test_graph_checks_catch_broken_flat_exchange(zoo_ctx, monkeypatch):
         return params, opt_state, gnorm
 
     monkeypatch.setattr(upd, "flat_exchange", broken_exchange)
-    with pytest.raises(GraphLintError, match="reduce-scatter"):
+    with pytest.raises(GraphLintError,
+                       match=f"expected {flat_buckets} reduce-scatter"):
         _toy_fit("raise", update_sharding=True)
 
 

@@ -66,6 +66,20 @@ def _leaves(est):
                 est.train_state["params"]))]
 
 
+# ``bucket_target`` (conftest): with a 48-element target the 192-parameter toy
+# (three rows of 64: two of the first leaf, one of the second) exchanges 3
+# buckets
+
+
+def _mesh2():
+    return Mesh(np.array(jax.devices()[:2]).reshape((2,) + (1,) * 5), AXES)
+
+
+def _compiled_step(est, x, y):
+    return est._make_train_step().lower(
+        est.train_state, est._to_global((x, y))).compile()
+
+
 # ------------------------------------------------------- accumulation equiv
 @pytest.mark.parametrize("shuffle", [False, True])
 def test_grad_accum_matches_big_batch_byte_exact_f32(zoo_ctx, shuffle):
@@ -127,14 +141,13 @@ def test_sharded_update_bit_parity_two_devices(zoo_ctx):
     update/all-gather exchange must be bit-identical to the replicated
     update (on 2 devices both reduce orders are the single add x0+x1; with
     exact-arithmetic data the whole step is deterministic)."""
-    mesh2 = Mesh(np.array(jax.devices()[:2]).reshape((2,) + (1,) * 5), AXES)
     x, y = _dyadic_data(B=32)
     ests = {}
     for sharded in (False, True):
         cfg = TrainConfig(shuffle=False, log_every_n_steps=10 ** 9,
                           update_sharding=sharded)
         est = _dyadic_estimator(cfg, x, y, optimizer=Adam(lr=1e-2),
-                                mesh=mesh2)
+                                mesh=_mesh2())
         est.fit((x, y), batch_size=32, epochs=1)      # exactly one step
         ests[sharded] = est
     assert ests[True]._update_mode() == "flat"
@@ -148,10 +161,14 @@ def test_sharded_update_bit_parity_two_devices(zoo_ctx):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
-def test_flat_opt_state_is_one_over_dp(zoo_ctx):
+@pytest.mark.parametrize("bucket_len", [None, 512])
+def test_flat_opt_state_is_one_over_dp(zoo_ctx, bucket_target, bucket_len):
     """ZeRO-1 memory claim on the 8-way dp mesh: per-device optimizer-state
-    bytes ≈ replicated/8 (within padding + replicated scalar count leaves)."""
+    bytes ≈ replicated/8 (within padding + replicated scalar count leaves),
+    with one bucket and with three."""
     x, y = _dyadic_data(B=64, D=16)
+    if bucket_len:
+        bucket_target(bucket_len)
 
     def opt_bytes(est):
         return sum(l.addressable_shards[0].data.nbytes
@@ -165,36 +182,223 @@ def test_flat_opt_state_is_one_over_dp(zoo_ctx):
     e_s = _dyadic_estimator(TrainConfig(update_sharding=True, **base), x, y,
                             optimizer=Adam(1e-3), D=16, H=64, O=4)
     assert e_s._update_mode() == "flat"
+    assert e_s._flat_meta.n_buckets == (3 if bucket_len else 1)
     r, s = opt_bytes(e_r), opt_bytes(e_s)
     assert s <= r / 8 * 1.35 + 512, (r, s)
 
     # and the sharded-update step costs no more device memory than the
     # replicated one (arguments + temporaries of the compiled step)
     def step_bytes(est):
-        return memory_fields(est._make_train_step().lower(
-            est.train_state, est._to_global((x, y))).compile()
-        )["hbm_peak_bytes"]
+        return memory_fields(_compiled_step(est, x, y))["hbm_peak_bytes"]
 
     assert step_bytes(e_s) <= step_bytes(e_r) * 1.02
 
 
-def test_one_gradient_collective_per_global_step(zoo_ctx):
+@pytest.mark.parametrize("bucket_len", [None, 48])
+def test_one_gradient_collective_per_bucket_per_global_step(
+        zoo_ctx, bucket_target, bucket_len):
     """The flat path's structural guarantee: compiled HLO has exactly one
-    grad-sized reduce-scatter and collective counts do NOT grow with
-    grad_accum_steps (the K-microbatch scan accumulates device-local grads)."""
+    grad-sized reduce-scatter and one all-gather per BUCKET, the counts do
+    NOT grow with grad_accum_steps (the K-microbatch scan accumulates
+    device-local grads, so none sits inside it), and no reduction runs as an
+    all-reduce of a bucket's or the vector's length."""
+    import re
+
+    from analytics_zoo_tpu.analysis.rules.collectives import (
+        jaxpr_collective_counts)
+
     x, y = _dyadic_data(B=64)
+    if bucket_len:
+        bucket_target(bucket_len)
     counts = {}
     for K in (1, 4):
         cfg = TrainConfig(shuffle=False, log_every_n_steps=10 ** 9,
                           update_sharding=True, grad_accum_steps=K)
         est = _dyadic_estimator(cfg, x, y)
-        step = est._make_train_step()
-        batch = est._to_global((x, y))
-        compiled = step.lower(est.train_state, batch).compile()
-        counts[K] = upd.collective_counts(compiled.as_text())
+        meta = est._flat_meta
+        assert meta.n_buckets == (3 if bucket_len else 1)
+        hlo = _compiled_step(est, x, y).as_text()
+        counts[K] = upd.collective_counts(hlo)
+        for shape in re.findall(r"\[([\d,]*)\][^ ]* all-reduce(?:-start)?\(",
+                                hlo):
+            n_elem = int(np.prod([int(d) for d in shape.split(",") if d]))
+            assert n_elem < meta.bucket_len // meta.n_shards, (shape, hlo)
+        census = jaxpr_collective_counts(jax.make_jaxpr(
+            est._with_policy(est._step_fn()))(est.train_state,
+                                              est._to_global((x, y))))
+        assert census["in_loop"] == {}, census
+        assert census["counts"]["reduce-scatter"] == meta.n_buckets
+        assert census["counts"]["all-gather"] == meta.n_buckets
     assert counts[1] == counts[4], counts
-    assert counts[4].get("reduce-scatter", 0) == 1, counts
-    assert counts[4].get("all-gather", 0) >= 1, counts
+    assert counts[4].get("reduce-scatter", 0) == meta.n_buckets, counts
+    assert counts[4].get("all-gather", 0) == meta.n_buckets, counts
+
+
+@pytest.mark.parametrize("bucket_len", [None, 48])
+def test_lowered_step_defines_each_collective_once(zoo_ctx, bucket_target,
+                                                   bucket_len):
+    """The benchmark's check (benchmark/drivers/train_fit.py) counts TEXT
+    occurrences in the lowered step and wants one reduce_scatter and one
+    all_gather: every bucket goes through the same two jitted functions, so
+    each collective is defined once however many buckets call it. A refactor
+    that inlines the buckets fails here before it fails on the chip."""
+    x, y = _dyadic_data(B=64)
+    if bucket_len:
+        bucket_target(bucket_len)
+    est = _dyadic_estimator(
+        TrainConfig(shuffle=False, log_every_n_steps=10 ** 9,
+                    update_sharding=True), x, y)
+    assert est._flat_meta.n_buckets == (3 if bucket_len else 1)
+    text = est.lower_train_step((x, y)).as_text()
+    assert text.count('stablehlo.reduce_scatter"') == 1
+    assert text.count('stablehlo.all_gather"') == 1
+
+
+def test_small_model_is_one_bucket_one_exchange(zoo_ctx):
+    """A model smaller than one bucket target gets exactly one bucket, with
+    no padding when its leaves fill whole rows: the one-reduce-scatter,
+    one-all-gather exchange it had before there were buckets."""
+    from analytics_zoo_tpu.analysis.rules.collectives import (
+        jaxpr_collective_counts)
+
+    x, y = _dyadic_data(B=64)
+    est = _dyadic_estimator(
+        TrainConfig(shuffle=False, log_every_n_steps=10 ** 9,
+                    update_sharding=True), x, y)
+    meta = est._flat_meta
+    assert (meta.n_buckets, meta.n, meta.npad) == (1, 192, 192)
+    assert meta.bucket_shape == (3, 64) and meta.shard_shape == (3, 8)
+    census = jaxpr_collective_counts(jax.make_jaxpr(est._step_fn())(
+        est.train_state, est._to_global((x, y))))
+    assert census["counts"]["reduce-scatter"] == 1
+    assert census["counts"]["all-gather"] == 1
+
+
+def test_flat_meta_cuts_equal_buckets_from_the_leaves():
+    """Bucket geometry: every leaf starts on a row of the flat view (padded
+    to whole rows), buckets are equal runs of rows, a bucket is stacked from
+    exactly the row blocks it covers, and flatten and unflatten are inverses
+    across leaves that straddle buckets and leaves that need padding."""
+    rng = np.random.default_rng(0)
+    params = {"a": rng.normal(size=(8, 16)).astype(np.float32),
+              "b": rng.normal(size=(7,)).astype(np.float32),
+              "c": rng.normal(size=(16, 4)).astype(np.float32)}
+    meta = upd.flat_meta(params, 2, bucket_len=40)
+    # 16-column rows waste 9 of 199 elements on "b"; 8-column rows waste 1
+    assert meta.shard_shape == (5, 4) and meta.bucket_shape == (5, 8)
+    assert meta.leaf_rows == (16, 1, 8) and meta.n == 199
+    assert (meta.n_buckets, meta.bucket_len, meta.npad) == (5, 40, 200)
+    assert meta.pieces(0) == ((0, 0, 5),)
+    assert meta.pieces(3) == ((0, 15, 16), (1, 0, 1), (2, 0, 3))
+    assert meta.pieces(4) == ((2, 3, 8),)
+    view = np.concatenate([params["a"].ravel(), np.pad(params["b"], (0, 1)),
+                           params["c"].ravel()])
+    for b in range(meta.n_buckets):
+        got = np.asarray(upd.flat_bucket(params, meta, b))
+        np.testing.assert_array_equal(
+            got, view[b * 40:(b + 1) * 40].reshape(5, 8))
+    np.testing.assert_array_equal(
+        np.asarray(upd.flatten_tree(params, meta)), view)
+    back = upd.unflatten_buckets(
+        [upd.flat_bucket(params, meta, b) for b in range(meta.n_buckets)],
+        meta)
+    for k in params:
+        np.testing.assert_array_equal(np.asarray(back[k]), params[k])
+    # a large model: rows of SHARD_COLS columns a shard, the row count
+    # rounded so the chip's compiler can chunk it; a model under one target:
+    # one bucket, and fewer columns where whole rows would waste too much
+    big = {"w": jax.ShapeDtypeStruct((612_917_248,), jnp.bfloat16)}
+    meta = upd.flat_meta(big, 4)
+    assert meta.n_buckets == 13 and meta.shard_shape == (11520, 1024)
+    one = upd.flat_meta({"w": jax.ShapeDtypeStruct((1000, 1000),
+                                                    jnp.float32)}, 4)
+    assert one.n_buckets == 1 and one.shard_shape == (246, 1024)
+    odd = upd.flat_meta({"w": jax.ShapeDtypeStruct((300, 300), jnp.float32),
+                         "b": jax.ShapeDtypeStruct((300,), jnp.float32)}, 4)
+    assert odd.n_buckets == 1 and odd.shard_shape == (89, 256)
+    assert odd.npad <= odd.n * (1 + 1 / 64)
+
+
+@pytest.mark.parametrize("precision", ["f32", "bf16"])
+@pytest.mark.parametrize("optimizer", ["sgd_momentum", "adam"])
+def test_bucketed_update_bit_parity_two_devices(zoo_ctx, bucket_target,
+                                                optimizer, precision):
+    """Three buckets on a 2-device dp mesh. Bucketing changes which collective
+    carries an element, never its arithmetic: one step and six more are
+    bit-identical to the one-bucket exchange, f32 params or bf16 params with
+    f32 masters. Against the REPLICATED update one f32 step is bit-identical
+    and six more stay within 1e-5; under bf16 compute the replicated path sums
+    the bf16 gradients across replicas before the cast where the flat path
+    casts to f32 first, so there the comparison is at bf16's grain."""
+    make = {"sgd_momentum": lambda: SGD(lr=0.5, momentum=0.5),
+            "adam": lambda: Adam(lr=1e-2)}[optimizer]
+    extra = {"compute_dtype": "bfloat16"} if precision == "bf16" else {}
+    x, y = _dyadic_data(B=32)
+    ests = {}
+    for name, sharded in (("replicated", False), ("one", True),
+                          ("three", True)):
+        if name == "three":
+            bucket_target(48)
+        cfg = TrainConfig(shuffle=False, log_every_n_steps=10 ** 9,
+                          update_sharding=sharded, **extra)
+        ests[name] = _dyadic_estimator(cfg, x, y, optimizer=make(),
+                                       mesh=_mesh2())
+    assert ests["one"]._flat_meta.n_buckets == 1
+    assert ests["three"]._flat_meta.n_buckets == 3
+    bf16_grain = dict(rtol=0, atol=2 ** -7)
+
+    def compare(steps):
+        got = {k: [l.astype(np.float32) for l in _leaves(e)]
+               for k, e in ests.items()}
+        for a, b in zip(got["one"], got["three"]):
+            np.testing.assert_array_equal(a, b, err_msg=f"{steps} step(s)")
+        for a, b in zip(got["replicated"], got["three"]):
+            if precision == "bf16":
+                np.testing.assert_allclose(a, b, **bf16_grain)
+            elif steps == 1:
+                np.testing.assert_array_equal(a, b)
+            else:
+                np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+    for est in ests.values():
+        est.fit((x, y), batch_size=32, epochs=1)      # exactly one step
+    compare(1)
+    for est in ests.values():
+        est.fit((x, y), batch_size=32, epochs=7)      # six more
+    compare(7)
+    if precision == "bf16":
+        # the f32 masters, read back in flat order from the three buckets,
+        # are the one-bucket masters bit for bit
+        flat = {k: np.concatenate([np.asarray(m).ravel() for m in
+                                   jax.device_get(ests[k].train_state[
+                                       "opt_state"]).master])
+                for k in ("one", "three")}
+        np.testing.assert_array_equal(flat["one"], flat["three"])
+
+
+def test_bucketed_clip_norm_matches_replicated(zoo_ctx, bucket_target):
+    """gradient_clip_norm with several buckets: the norm is one scalar psum
+    over all buckets' shards, so the clipped update equals the replicated
+    clipped update (and the reported norm is the global one)."""
+    x, y = _dyadic_data(B=32)
+    bucket_target(48)
+    ests, norms = {}, {}
+    for sharded in (False, True):
+        cfg = TrainConfig(shuffle=False, log_every_n_steps=1,
+                          update_sharding=sharded, gradient_clip_norm=0.25)
+        est = _dyadic_estimator(cfg, x, y, optimizer=SGD(lr=0.5),
+                                mesh=_mesh2())
+        snap0 = _tm.snapshot().get("zoo_train_grad_norm", {}).get(
+            "samples", {}).get("", {"sum": 0.0})["sum"]
+        est.fit((x, y), batch_size=32, epochs=1)
+        norms[sharded] = _tm.snapshot()["zoo_train_grad_norm"][
+            "samples"][""]["sum"] - snap0
+        ests[sharded] = est
+    assert ests[True]._flat_meta.n_buckets == 3
+    assert norms[True] > 0.25                  # the clip engaged
+    np.testing.assert_allclose(norms[True], norms[False], rtol=1e-6)
+    for a, b in zip(_leaves(ests[False]), _leaves(ests[True])):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
 
 
 # ----------------------------------------------------------- mixed precision
@@ -219,9 +423,9 @@ def test_mixed_precision_trains_with_f32_masters(zoo_ctx):
     # model params are bf16; the f32 values live only in the sharded masters
     p0 = jax.tree_util.tree_leaves(est.train_state["params"])[0]
     assert p0.dtype == jnp.bfloat16
-    master = est.train_state["opt_state"].master
-    assert master is not None and master.dtype == jnp.float32
-    assert master.sharding.spec == P("dp")
+    (master,) = est.train_state["opt_state"].master     # one bucket
+    assert master.dtype == jnp.float32
+    assert master.sharding.spec == P(None, "dp")
     snap1 = _tm.snapshot()
 
     def count(snap):
@@ -332,8 +536,12 @@ def test_sanitize_raises_on_overdividing_tuple_axes(zoo_ctx):
 
 
 # ---------------------------------------------------------------- durability
-def test_flat_mode_checkpoint_roundtrip(zoo_ctx, tmp_path):
+@pytest.mark.parametrize("bucket_len", [None, 48])
+def test_flat_mode_checkpoint_roundtrip(zoo_ctx, tmp_path, bucket_target,
+                                        bucket_len):
     x, y = _dyadic_data(B=64)
+    if bucket_len:
+        bucket_target(bucket_len)
     cfg = TrainConfig(shuffle=False, log_every_n_steps=10 ** 9,
                       update_sharding=True, checkpoint_dir=str(tmp_path))
     est = _dyadic_estimator(cfg, x, y, optimizer=Adam(1e-2))
@@ -349,10 +557,49 @@ def test_flat_mode_checkpoint_roundtrip(zoo_ctx, tmp_path):
     # the flat-layout state (FlatUpdateState + dp-sharded vectors) round-trips
     assert est2.trainer_state.iteration == it
     assert isinstance(est2.train_state["opt_state"], upd.FlatUpdateState)
+    assert est2._flat_meta.n_buckets == (3 if bucket_len else 1)
     for a, b in zip(_leaves(est), _leaves(est2)):
         np.testing.assert_array_equal(a, b)
+    for a, b in zip(*(jax.tree_util.tree_leaves(jax.device_get(
+            e.train_state["opt_state"])) for e in (est, est2))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     est2.fit((x, y), batch_size=32, epochs=3)         # resumes, 1 more epoch
     assert est2.trainer_state.iteration == it + 2
+
+
+@pytest.mark.parametrize("bucket_len", [None, 48])
+def test_old_flat_layout_checkpoint_is_repadded_or_refused(
+        zoo_ctx, tmp_path, bucket_target, bucket_len):
+    """A checkpoint whose flat optimizer state is one ``(npad,)`` vector per
+    slot (the layout before bucketing): a one-bucket estimator re-pads it
+    into its ``bucket_shape`` (same flat order); a several-bucket one refuses
+    it in words. It is never read as if it were the new layout."""
+    import optax
+
+    from analytics_zoo_tpu.engine import checkpoint as ckpt
+
+    x, y = _dyadic_data(B=64)
+    if bucket_len:
+        bucket_target(bucket_len)
+    cfg = TrainConfig(shuffle=False, log_every_n_steps=10 ** 9,
+                      update_sharding=True, compute_dtype="bfloat16")
+    est = _dyadic_estimator(cfg, x, y, optimizer=Adam(1e-2))
+    state = jax.device_get(est.train_state)
+    flat = np.arange(192, dtype=np.float32) / 8          # npad = n = 192
+    old = dict(state, opt_state=upd.FlatUpdateState(
+        optax.adam(1e-2).init(jnp.asarray(flat)), flat))
+    assert len(jax.tree_util.tree_leaves(old)) == len(
+        jax.tree_util.tree_leaves(state)) or bucket_len
+    ckpt.save_checkpoint(str(tmp_path), old, iteration=7, epoch=1)
+    if bucket_len:
+        with pytest.raises(ValueError, match=r"3 bucket\(s\) of \(1, 64\)"):
+            est.load(str(tmp_path))
+        return
+    est.load(str(tmp_path))
+    assert est.trainer_state.iteration == 7
+    (master,) = est.train_state["opt_state"].master
+    assert master.shape == (3, 64) and master.sharding.spec == P(None, "dp")
+    np.testing.assert_array_equal(np.asarray(master).ravel(), flat)
 
 
 def test_bf16_checkpoint_roundtrip(zoo_ctx, tmp_path):
@@ -372,7 +619,7 @@ def test_bf16_checkpoint_roundtrip(zoo_ctx, tmp_path):
     for a, b in zip(_leaves(est), _leaves(est2)):
         assert a.dtype == b.dtype == jnp.bfloat16
         np.testing.assert_array_equal(a, b)
-    m = est2.train_state["opt_state"].master
+    (m,) = est2.train_state["opt_state"].master
     assert m.dtype == jnp.float32
 
 

@@ -4,12 +4,12 @@ Each process: pick up the launcher-threaded backend config (cpu + gloo
 collectives), join the jax.distributed job via init_zoo_context, then run
 flat ZeRO-1 weight-update sharding (PR 5, parallel/update_sharding.py) as
 genuine 2-process training: the optimizer state lives dp-sharded, every
-step is one ``psum_scatter`` in + one tiled ``all_gather`` out across the
-two processes over gloo.
+step is one ``psum_scatter`` in + one tiled ``all_gather`` out per bucket
+(this model fits one) across the two processes over gloo.
 
 Before training, the worker runs the collective-budget lint on the jitted
-step (jaxpr layer — trace only) and asserts the budget "exactly one
-reduce-scatter and one all-gather per step" holds; the finding count lands
+step (jaxpr layer — trace only) and asserts the budget "one reduce-scatter
+and one all-gather per bucket per step" holds; the finding count lands
 in result-<rank>.json together with a post-training parameter digest so the
 test can assert both ranks hold identical weights.
 """
@@ -73,23 +73,24 @@ def main():
             params, grads, opt_state, meta, tx, axis="dp")
         return new_params, new_opt, jax.lax.pmean(loss, "dp"), gnorm
 
-    # ZeRO-1 layout: the (npad,)-sized optimizer vectors (masters, adam
-    # moments) live dp-sharded, scalars (step counts) replicated — the same
-    # rule Estimator._state_spec applies in flat mode
+    # ZeRO-1 layout: each bucket's optimizer matrices (masters, adam
+    # moments) live dp-sharded over their columns, scalars (step counts)
+    # replicated — the same rule Estimator._state_spec applies in flat mode
     opt_specs = jax.tree_util.tree_map(
-        lambda l: (P("dp") if tuple(getattr(l, "shape", ()))
-                   == (meta.npad,) else P()), opt_state)
+        lambda l: (P(None, "dp") if tuple(getattr(l, "shape", ()))
+                   == meta.bucket_shape else P()), opt_state)
     sharded_step = shard_map(
         step, mesh=mesh,
         in_specs=(P(), opt_specs, P("dp"), P("dp")),
         out_specs=(P(), opt_specs, P(), P()), check_vma=False)
 
-    # -- collective-budget lint: exactly ONE reduce-scatter and ONE
-    # all-gather per step (trace-only; the incidental scalar psums for the
+    # -- collective-budget lint: ONE reduce-scatter and ONE all-gather per
+    # bucket per step (trace-only; the incidental scalar psums for the
     # loss/grad-norm are all-reduces and not part of the budget)
     lint_ctx = RuleContext(where="zero1_worker.step",
-                           expect_collectives={"reduce-scatter": 1,
-                                               "all-gather": 1})
+                           expect_collectives={
+                               "reduce-scatter": meta.n_buckets,
+                               "all-gather": meta.n_buckets})
     findings = lint_traced(
         sharded_step, params, opt_state,
         jax.ShapeDtypeStruct((64, 6), jnp.float32),
