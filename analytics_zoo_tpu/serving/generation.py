@@ -84,7 +84,16 @@ _GEN_REQS = _tm.counter("zoo_gen_requests_total",
                         "Generation requests finished, by outcome",
                         labels=("outcome",))
 _GEN_STEPS = _tm.counter("zoo_gen_decode_steps_total",
-                         "Multi-slot decode steps executed")
+                         "Multi-slot decode steps executed (counted when the "
+                         "step is collected: read and emitted)")
+_GEN_LAUNCHES = _tm.counter(
+    "zoo_gen_decode_launches_total",
+    "Single-token decode steps dispatched, by order: ahead = launched from "
+    "the ids the step before it left on the device, before that step was "
+    "read (the host's work of the pass runs under a step); drained = "
+    "launched from ids on the host, the step in flight collected first, "
+    "with the reason (ahead has reason=\"\")",
+    labels=("order", "reason"))
 _GEN_ITL = _tm.histogram("zoo_gen_inter_token_seconds",
                          "Per-stream time between consecutive emitted tokens",
                          buckets=_tm.LATENCY_LADDER)
@@ -437,6 +446,26 @@ class _Slot:
         self.prefix_keys: List[str] = prefix_keys or []
 
 
+class _Flight:
+    """A decode step that was dispatched and has not been read: the step in
+    flight. ``next_ids`` is the device array the step returns (the next
+    launch takes it in the place of ``ids``; the collect reads it), ``rows``
+    the ``(row, slot)`` pairs it stepped: slot OBJECTS, so a row retired and
+    refilled between the launch and the collect gets nothing of it."""
+
+    __slots__ = ("next_ids", "rows", "t_launch")
+
+    def __init__(self, next_ids, rows: List[Tuple[int, _Slot]]):
+        self.next_ids = next_ids
+        self.rows = rows
+        self.t_launch = time.monotonic()
+
+
+#: why a decode launch took its ids from the host (the ``reason`` label of
+#: ``zoo_gen_decode_launches_total{order="drained"}``)
+DRAIN_REASONS = ("first", "admit", "chunk", "preempt", "swap", "spec")
+
+
 class ContinuousBatcher:
     """Continuous micro-batching decode loop over a paged KV cache.
 
@@ -448,6 +477,24 @@ class ContinuousBatcher:
     killed loop is respawned by a supervisor with cache/slot state intact,
     so in-flight streams survive (kill-the-engine drill in
     tests/test_generation.py).
+
+    **One decode step stays in flight.** A pass of plain decode is *launch,
+    then collect* (:meth:`_launch`, :meth:`_collect`): step n+1 is dispatched
+    with the ids step n left ON THE DEVICE in the place of ``ids``, and only
+    then is step n read, emitted and finished, while the device runs n+1.
+    What step n+1 needs besides, the host knows before it has read step n
+    (lengths and token ordinals are step n's plus one; a row that ends with
+    step n by ``max_new_tokens`` or ``max_seq_len`` is masked to scratch).
+    What is found only at the read (EOS, a cancellation) retires the slot
+    then, and the token step n+1 computed for that row is thrown away.
+    ``self._flight`` is that step: the one piece of state between the two.
+    A pass *drains* (collects the step in flight, then launches from ids on
+    the host: the serial order) when a row it has to step was not stepped by
+    the step in flight, so its token is on the host (an admission, a resume,
+    a chunked prefill finalised), and before what needs every slot's true
+    state: a preemption, a hot swap, speculation's steps, the loop's end.
+    Nothing selects the order; ``stats()["decode_launches"]`` and
+    ``zoo_gen_decode_launches_total{order,reason}`` say how often each ran.
 
     **What is held on the device.** The batcher reads the compute dtype of
     the precision policy ONCE, when it is built, and every executable it owns
@@ -516,9 +563,25 @@ class ContinuousBatcher:
         self.n_slots = int(n_slots)
         # clamp to the vocabulary: lax.top_k with k > V fails at trace time
         self.top_k = min(int(top_k), getattr(model, "vocab", int(top_k)))
-        self.cfg, self.cache = self._pinned(
+        self.cfg, pool = self._pinned(
             model.init_kv_cache, n_slots, page_size=page_size,
             max_seq_len=max_seq_len, n_pages=n_pages)
+        # the pool is COMMITTED to the device the served tree lies on (the
+        # same buffers, no copy). An executable that holds a shard_map (the
+        # flash prefill) returns committed arrays, and everything the pool is
+        # then threaded through does: a pool uncommitted until its first such
+        # prefill made that bucket lower a second time on its second use, and
+        # would make the decode step lower once for ids put from the host and
+        # once for the committed ids a step returns
+        device = next(iter(
+            jax.tree_util.tree_leaves(self.params)[0].devices()))
+        self.cache = jax.device_put(pool, device)
+        del pool
+        # a decode step's ids from the host, as the kind of array a step
+        # returns (committed, as the pool is): _decode sees one signature
+        # whichever it is given
+        self._put_ids = lambda ids: jax.device_put(ids, device)
+        ids_on_device = jax.sharding.SingleDeviceSharding(device)
         self.pool = PagePool(self.cfg)
         # shared-prefix KV cache (ISSUE 17): 0 pages disables sharing
         # entirely (the cold baseline); the budget counts CACHE-held pages
@@ -607,6 +670,14 @@ class ContinuousBatcher:
         self._occupied_slot_steps = 0
         self._decode_tokens = 0          # decode-phase tokens (excl prefill)
         self._clock = _LoopClock()
+        # the decode step that is dispatched and not read (class docstring).
+        # State of the batcher, not of a pass: a loop killed between a launch
+        # and its collect leaves it here for the respawned loop
+        self._flight: Optional[_Flight] = None
+        self._drained_for: Optional[str] = None   # why, until the next launch
+        self._collected_t = 0.0                   # time.monotonic() of a collect
+        self.launches_ahead = 0
+        self.launches_drained: Dict[str, int] = dict.fromkeys(DRAIN_REASONS, 0)
 
         cfg = self.cfg
         # Donate the KV page pool into both dispatches (the cache-alias
@@ -620,11 +691,16 @@ class ContinuousBatcher:
         self.hbm_budget_bytes = hbm_budget_bytes
         donate = (1,) if donate_cache else ()
         pinned = self._pinned       # each trace under self.compute_dtype
+        # ids are declared where every form of them lies, so that host ids a
+        # caller passes as NumPy (the benchmark's logit probe) run the
+        # executable that serves, not a copy lowered for unplaced ids
         self._decode = jax.jit(
             lambda p, c, ids, ln, tb, sd, ti, tp: pinned(
                 model.decode_step,
                 p, c, ids, ln, tb, sd, ti, tp, page_size=cfg.page_size,
-                top_k=self.top_k), donate_argnums=donate)
+                top_k=self.top_k),
+            in_shardings=(None, None, ids_on_device) + (None,) * 5,
+            donate_argnums=donate)
         self._prefill = jax.jit(
             lambda p, c, ids, ln, tb: pinned(
                 model.prefill,
@@ -867,6 +943,10 @@ class ContinuousBatcher:
                                      "active streams")
                     self._fail_all_active(f"decode step failed: {e}")
                 clock.close_pass()
+            try:
+                self._drain()           # its tokens belong to the streams
+            except Exception:
+                logger.exception("the last decode step could not be read")
         except WorkerKilled:
             logger.warning("generation decode loop killed mid-stream; "
                            "slots/cache intact, awaiting respawn")
@@ -885,7 +965,7 @@ class ContinuousBatcher:
                 # running streams advance every loop pass no matter how deep
                 # the prefill backlog (starvation-free by construction)
                 self._prefill_chunks()
-        if self.active_slots() == 0:
+        if self._flight is None and self.active_slots() == 0:
             if (self._pending.empty() and not self._backlog
                     and not self._preempted):
                 with clock.phase("idle"):
@@ -895,6 +975,9 @@ class ContinuousBatcher:
         self._step()
 
     def _fail_all_active(self, error: str):
+        # a step that failed takes the one chained on it with it: whether the
+        # launch raised or the collect, the streams below fail here, once
+        self._flight = None
         with self._lock:
             finishes = [self._retire_locked(i, "error", error=error)
                         for i, s in enumerate(self._slots) if s is not None]
@@ -966,6 +1049,14 @@ class ContinuousBatcher:
         if req.priority != "critical":
             return False
         with self._lock:
+            if not any(s is not None and s.request.priority == "bulk"
+                       for s in self._slots):
+                return False
+        # the victim parks with its true last_token, length and ordinal
+        self._drain("preempt")
+        with self._lock:
+            if any(s is None for s in self._slots):
+                return True             # the collect retired a stream
             victims = [(s.request.order_key, i) for i, s in
                        enumerate(self._slots)
                        if s is not None and s.request.priority == "bulk"]
@@ -1481,6 +1572,7 @@ class ContinuousBatcher:
         pend = self._pending_swap
         if pend is None:
             return
+        self._drain("swap")     # no step spans the flip of (params, schedule)
         self._pending_swap = None
         params, version, spec = pend
         self.params = params
@@ -1515,45 +1607,108 @@ class ContinuousBatcher:
         self._step_plain()
 
     def _step_plain(self, rows: Optional[List[int]] = None):
-        """One single-token decode dispatch. ``rows=None`` steps every
-        occupied slot (classic mode); a row subset steps only those slots,
-        with every other row masked to scratch in the dispatched table copy
-        — speculative mode's tail regime (slots within k of the cache cap,
-        or squeezed out of the k-page lookahead by a dry pool) rides the
-        SAME single-token executable plain decode uses, so those streams
-        emit and truncate exactly as the non-speculative loop would."""
+        """One pass of single-token decode: launch a step, then collect the
+        one launched a pass ago, which the device finished while the host
+        emitted, admitted and built this one (class docstring). The pass
+        drains first when the step in flight cannot give the next one its
+        ids. ``rows=None`` steps every occupied slot (classic mode); a row
+        subset steps only those slots, with every other row masked to
+        scratch in the dispatched table copy — speculative mode's tail
+        regime (slots within k of the cache cap, or squeezed out of the
+        k-page lookahead by a dry pool) rides the SAME single-token
+        executable plain decode uses, in the serial order (launched from the
+        host, collected at once), so those streams emit and truncate exactly
+        as the non-speculative loop would."""
+        stepped = self._flight
+        if stepped is not None:
+            why = self._why_drain(stepped, rows)
+            if why is not None:
+                self._drain(why)
+                stepped = None
+        self._flight = self._launch(rows, stepped)
+        if rows is not None:
+            stepped, self._flight = self._flight, None
+        if stepped is not None:
+            self._collect(stepped)
+
+    def _why_drain(self, flight: _Flight,
+                   rows: Optional[List[int]]) -> Optional[str]:
+        """None when the next step can take its ids from ``flight`` on the
+        device: every row it has to step was stepped by ``flight``. Else the
+        reason its ids have to come from the host: a row joined whose token
+        is there (admitted, resumed, or left out of a step for want of a
+        page: ``admit``; its prefill finalised in chunks: ``chunk``), or the
+        rows are speculation's tail."""
+        if rows is not None:
+            return "spec"
+        stepped = dict(flight.rows)
+        with self._lock:
+            for i, slot in enumerate(self._slots):
+                if (slot is None or slot.prefilling or slot.request.cancelled
+                        or stepped.get(i) is slot):
+                    continue
+                return "chunk" if slot.chunks else "admit"
+        return None
+
+    def _drain(self, reason: Optional[str] = None) -> None:
+        """Collect the step in flight, if there is one, before something that
+        needs every slot's true state; the next launch is ``reason``'s."""
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            self._drained_for = reason
+            self._collect(flight)
+
+    def _launch(self, rows: Optional[List[int]],
+                chain: Optional[_Flight]) -> Optional[_Flight]:
+        """Dispatch one single-token decode step and return it unread, or
+        None when no row has a token to compute. With ``chain`` (the step in
+        flight, which stepped every row this one steps: :meth:`_why_drain`)
+        the step's ``ids`` are ``chain``'s on the device and every row is one
+        step ahead of its slot's host state; without, they are the slots'
+        ``last_token``, put on the device the same way (one executable
+        whichever it is)."""
         clock = self._clock
         with clock.phase("decode_host"):
             cfg = self.cfg
             b = self.n_slots
-            ids = np.zeros(b, np.int32)
+            d = 0 if chain is None else 1   # steps each row has in flight
+            ids = np.zeros(b, np.int32)     # unused under a chain
             lengths = np.zeros(b, np.int32)
             seeds = np.zeros(b, np.uint32)
             tok_idx = np.zeros(b, np.uint32)
             temps = np.zeros(b, np.float32)
+            masked = np.ones(b, bool)
             finishes = []
-            live: List[int] = []
-            prefilling: List[int] = []
+            live: List[Tuple[int, _Slot]] = []
             with self._lock:
                 for i in (range(b) if rows is None else rows):
                     slot = self._slots[i]
                     if slot is None:
                         continue
-                    if slot.request.cancelled:
+                    req = slot.request
+                    if req.cancelled:
                         finishes.append(self._retire_locked(i, "cancelled"))
                         continue
                     if slot.prefilling:
                         # mid-prefill: masked out of the dispatch below — an
                         # unmasked row would take a position-0 K/V write into
                         # its REAL first page (silent prompt corruption)
-                        prefilling.append(i)
                         continue
+                    length = slot.length + d
+                    generated = slot.generated + d
+                    if d and (generated >= req.max_new_tokens
+                              or length + 1 > cfg.max_seq_len):
+                        continue    # ends with the step in flight: its collect
                     # grow: the position being written this step needs its page
-                    p = slot.length // cfg.page_size
+                    p = length // cfg.page_size
                     if self._table[i, p] == SCRATCH_PAGE:
                         try:
                             (pg,) = self._alloc_pages(1)
                         except OutOfPages:
+                            if d:
+                                # its token in flight is still owed: sit this
+                                # step out; the next pass drains and decides
+                                continue
                             finishes.append(self._retire_locked(
                                 i, "truncated",
                                 error="kv page pool exhausted"))
@@ -1562,47 +1717,73 @@ class ContinuousBatcher:
                         slot.pages.append(pg)
                         self._note_pool_peak()
                     ids[i] = slot.last_token
-                    lengths[i] = slot.length
-                    seeds[i] = slot.request.seed
-                    tok_idx[i] = slot.generated
-                    temps[i] = slot.request.temperature
-                    live.append(i)
+                    lengths[i] = length
+                    seeds[i] = req.seed
+                    tok_idx[i] = generated
+                    temps[i] = req.temperature
+                    masked[i] = False
+                    live.append((i, slot))
                 table = self._table.copy()
-            if rows is not None:
-                for i in range(b):
-                    if i not in live:  # mask non-members (incl. spec-active)
-                        table[i, :] = SCRATCH_PAGE
-            else:
-                for i in prefilling:
-                    table[i, :] = SCRATCH_PAGE
+            # every row not stepped writes to scratch: free rows are scratch
+            # already; prefilling, ending and non-member rows are not
+            table[masked] = SCRATCH_PAGE
             for fin in finishes:       # final-frame callbacks OUTSIDE the lock
                 self._finish_cb(*fin)
             if not live:
-                return
+                return None
+            if chain is not None:
+                order, reason = "ahead", ""
+                self.launches_ahead += 1
+            else:
+                order = "drained"
+                reason = ("spec" if rows is not None
+                          else self._drained_for or "first")
+                self.launches_drained[reason] += 1
+            self._drained_for = None
+            _GEN_LAUNCHES.labels(order, reason).inc()
             self.decode_shapes.add((b, cfg.pages_per_slot, cfg.page_size))
-            t0 = time.monotonic()
             next_ids, _logits, self.cache = self._decode(
-                self.params, self.cache, ids, lengths, table, seeds, tok_idx,
-                temps)
+                self.params, self.cache,
+                self._put_ids(ids) if chain is None else chain.next_ids,
+                lengths, table, seeds, tok_idx, temps)
+            # the copy to the host starts when the step ends, not when the
+            # collect asks: the read finds the ids there (a step wrapped by a
+            # test may hand back host ids, which have nothing to start)
+            start_copy = getattr(next_ids, "copy_to_host_async", None)
+            if start_copy is not None:
+                start_copy()
+            return _Flight(next_ids, live)
+
+    def _collect(self, flight: _Flight) -> None:
+        """Read a launched step and give its rows their tokens: emit, finish.
+        A row whose slot was retired since the launch (EOS or a cancellation
+        found at the collect before, a failure) gets nothing, whoever holds
+        the row now."""
+        clock = self._clock
+        with clock.phase("decode_host"):
             with clock.phase("decode_wait"):
-                next_ids = np.asarray(next_ids)
-            self.step_ema.observe(time.monotonic() - t0)
+                next_ids = np.asarray(flight.next_ids).tolist()
+            # what a stream waits for a token: since the collect before, or
+            # since the launch where the loop had nothing in flight
+            now = time.monotonic()
+            self.step_ema.observe(now - max(flight.t_launch,
+                                            self._collected_t))
+            self._collected_t = now
             self.steps += 1
-            self._occupied_slot_steps += len(live)
             _GEN_STEPS.inc()
             _mw.sample("serving.decode")
         with clock.phase("emit"):
-            for i in live:
+            for i, slot in flight.rows:
                 with self._lock:
-                    slot = self._slots[i]
-                if slot is None:
-                    continue
-                tok = int(next_ids[i])
+                    if self._slots[i] is not slot:
+                        continue
+                tok = next_ids[i]
                 slot.length += 1           # last_token is now cached
                 slot.last_token = tok
                 slot.generated += 1
                 slot.history.append(tok)
                 self._decode_tokens += 1
+                self._occupied_slot_steps += 1
                 self._emit(slot, [tok])
                 self._maybe_finish(i)
 
@@ -2020,6 +2201,14 @@ class ContinuousBatcher:
             "free_pages": self.pool.free_count(),
             "page_capacity": self.pool.capacity,
             "steps": self.steps,
+            # single-token decode launches: ahead = from the step in flight's
+            # ids on the device, drained = from the host, by reason;
+            # ahead / (ahead + drained) is the share that hid the host
+            "decode_launches": {
+                "ahead": self.launches_ahead,
+                "drained": sum(self.launches_drained.values()),
+                "drained_by": {r: n for r, n in self.launches_drained.items()
+                               if n}},
             "tokens_generated": self.tokens_generated,
             "requests": dict(self.requests_finished),
             "loop_respawns": self.loop_respawns,

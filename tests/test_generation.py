@@ -365,26 +365,33 @@ def test_stream_cleanup_and_remote_cancel(model_and_params, broker, np_rng):
 def test_chaos_kill_engine_mid_stream(model_and_params, broker, np_rng):
     """Kill the decode loop mid-stream (seeded chaos at the
     ``serving.generate`` site): the supervisor respawns it with slot/cache
-    state intact and every stream still completes with its full token
-    count."""
+    state intact, the step in flight included, and every stream still
+    completes with its full token count: no token lost, none sent twice
+    (tests/test_generation_inflight.py kills it with a step known to be in
+    flight)."""
     from analytics_zoo_tpu.common.chaos import ChaosSchedule
 
     m, params = model_and_params
     cfg = ServingConfig(queue_port=broker.port, gen_slots=2, gen_page_size=4,
                         gen_max_seq_len=32)
+    prompts = [np_rng.integers(1, VOCAB, size=4).tolist() for _ in range(3)]
     sched = ChaosSchedule(seed=7).kill("serving.generate", at=4)
     with sched:
         eng = GenerationEngine(m, params, config=cfg).start()
         try:
             cl = GenerationClient(port=broker.port)
-            uris = [cl.submit(np_rng.integers(1, VOCAB, size=4).tolist(),
-                              max_new_tokens=8, temperature=0.3,
-                              seed=100 + i) for i in range(3)]
+            uris = [cl.submit(p, max_new_tokens=8, temperature=0.3,
+                              seed=100 + i) for i, p in enumerate(prompts)]
             outs = [[t for c in cl.stream(u, timeout_s=60)
                      for t in c.tolist()] for u in uris]
             assert all(len(o) == 8 for o in outs)
             assert eng.batcher.loop_respawns >= 1
             assert sched.occurrences("serving.generate") >= 4
+            # what a loop nobody killed streams for the same requests
+            assert outs == [eng.batcher.generate(p, max_new_tokens=8,
+                                                 temperature=0.3,
+                                                 seed=100 + i)
+                            for i, p in enumerate(prompts)]
             cl.close()
         finally:
             eng.stop()

@@ -302,30 +302,37 @@ def test_preempt_parks_pending_drafts_and_resumes_exact(model_and_params,
     b = ContinuousBatcher(m, params, n_slots=1, page_size=4, max_seq_len=64,
                           n_pages=33, spec_k=4)
     try:
-        got, got_lock = [], threading.Lock()
-        first_chunk = threading.Event()
+        first_chunk, critical_in = threading.Event(), threading.Event()
+        parked, parked_drafts = threading.Event(), []
 
         def on_chunk(tokens, final, meta):
-            with got_lock:
-                got.extend(tokens)
+            # the loop's thread stops at the bulk stream's first token until
+            # the critical request is queued: its next pass finds it at the
+            # head of the backlog with the one slot taken, whatever the
+            # order and the speed of the passes before
             first_chunk.set()
+            assert critical_in.wait(30)
 
+        preempt_for = b._preempt_for
+
+        def parking(req):
+            freed = preempt_for(req)
+            if freed and b._preempted:
+                parked_drafts.extend(b._preempted[0].pending_drafts or [])
+                parked.set()
+            return freed
+
+        b._preempt_for = parking
         h = b.submit(prompt, max_new_tokens=24, temperature=0.6, seed=9,
                      priority="bulk", on_chunk=on_chunk)
         assert first_chunk.wait(30)
         hc = b.submit(np_rng.integers(1, VOCAB, size=3).tolist(),
                       max_new_tokens=4, priority="critical")
+        critical_in.set()
         # the critical request must preempt the only slot; the parked bulk
         # slot carries its pending (drafted, un-verified) proposals
-        deadline = time.time() + 30
-        parked_drafts = None
-        while time.time() < deadline:
-            with b._lock:
-                if b._preempted:
-                    parked_drafts = list(b._preempted[0].pending_drafts or [])
-                    break
-            time.sleep(0.001)
-        assert parked_drafts, "bulk slot never parked with pending drafts"
+        assert parked.wait(30), "bulk slot never parked"
+        assert parked_drafts, "bulk slot parked without pending drafts"
         assert hc.result(timeout_s=60)           # critical completes
         assert h.result(timeout_s=60) == ref     # bulk resumes token-exact
         assert b.stats()["free_pages"] == b.stats()["page_capacity"]
