@@ -876,22 +876,40 @@ class region:
     :class:`SpanRecord`, no histogram: a loop that opens several of these
     eighty times a second must not push request traces out of the recorder.
     ``seconds`` holds the elapsed time after exit. Name it like a span
-    (``area.sub.what``, lower case) so that trace readers treat it as one."""
+    (``area.sub.what``, lower case) so that trace readers treat it as one.
 
-    __slots__ = ("name", "_child", "_annot", "_t0", "seconds")
+    With a ``cpu_child`` the calling thread's own CPU seconds
+    (``time.thread_time``) over the region are added to it as well, and
+    ``cpu_seconds`` holds them after exit: wall less CPU of a stretch that
+    makes no blocking call is time the thread was runnable and did not run.
+    ``annotate=False`` feeds the counters and enters no annotation: for a
+    thread's waits, which a trace reader that names a device's idle gap
+    after the span that began last must not see."""
 
-    def __init__(self, name: str, seconds_child):
+    __slots__ = ("name", "_child", "_cpu_child", "_annotate", "_annot",
+                 "_t0", "_c0", "seconds", "cpu_seconds")
+
+    def __init__(self, name: str, seconds_child, cpu_child=None,
+                 annotate: bool = True):
         self.name = name
         self._child = seconds_child
+        self._cpu_child = cpu_child
+        self._annotate = annotate
         self.seconds = 0.0
+        self.cpu_seconds = 0.0
 
     def __enter__(self) -> "region":
-        self._annot = _annotation(self.name)
+        self._annot = _annotation(self.name) if self._annotate else None
+        if self._cpu_child is not None:
+            self._c0 = time.thread_time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.seconds = dt = time.perf_counter() - self._t0
+        if self._cpu_child is not None:
+            self.cpu_seconds = cpu = time.thread_time() - self._c0
+            self._cpu_child.inc(cpu)
         if self._annot is not None:
             self._annot.__exit__(exc_type, exc, tb)
         self._child.inc(dt)

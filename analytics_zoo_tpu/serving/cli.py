@@ -197,7 +197,11 @@ def do_info(args) -> int:
                       # the decode loop thread's seconds by exclusive phase
                       # since start, and the steps they bought: two readings
                       # and a subtraction say where the loop's time goes
-                      "steps", "loop_seconds", "model_version",
+                      "steps", "loop_seconds",
+                      # the sink thread's seconds by phase, the frames they
+                      # wrote, its queue's depth and the seconds a full
+                      # queue blocked the loop's emit
+                      "sink", "model_version",
                       # bytes of the served parameter tree by leaf dtype
                       "param_bytes", "ts")}
             prefix = gen.get("prefix")

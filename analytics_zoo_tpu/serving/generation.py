@@ -28,12 +28,17 @@ active-slots / free-pages gauges.
 The path accounts for its own time (docs/observability.md has the tables):
 ``zoo_gen_loop_seconds_total{phase}`` splits every second of the decode loop's
 thread into exclusive phases (:class:`_LoopClock`; each phase is also a
-``serving.gen.loop.<phase>`` profiler region, on the device trace's clock),
-and a request's legs are histograms on one fine ladder: ingress (client
-``submit`` to the engine's source), queue wait (``submit`` to leaving the
-backlog), prefill (to the first token on the host; the two sum to
+``serving.gen.loop.<phase>`` profiler region, on the device trace's clock);
+the engine's sink and source threads keep the same books
+(``zoo_gen_sink_seconds_total``, ``zoo_gen_source_seconds_total``; their
+working phases are regions too, their waits are not), and
+``zoo_gen_cpu_seconds_total{thread,phase}`` holds each thread's own CPU time
+beside its wall time. A request's legs are histograms on one fine ladder:
+ingress (client ``submit`` to the engine's source), queue wait (``submit`` to
+leaving the backlog), prefill (to the first token on the host; the two sum to
 ``zoo_gen_ttft_seconds``) and egress (a frame handed to the sink until its
-``XADD`` returned).
+``XADD`` returned; ``zoo_gen_egress_queued_seconds{frame}`` is the part of it
+spent in the sink's queue).
 """
 
 from __future__ import annotations
@@ -124,6 +129,20 @@ _GEN_EGRESS = _tm.histogram(
     "zoo_gen_egress_seconds",
     "A frame handed to the engine's sink queue until its XADD to the "
     "broker returned", buckets=_tm.LATENCY_LADDER)
+_GEN_EGRESS_QUEUED = _tm.histogram(
+    "zoo_gen_egress_queued_seconds",
+    "The first leg of zoo_gen_egress_seconds: a frame handed to the engine's "
+    "sink queue until the sink thread took it off, by kind of frame (first = "
+    "a stream's seq 0, final = its last frame, next = the others; one "
+    "observation a frame, as zoo_gen_egress_seconds)",
+    labels=("frame",), buckets=_tm.LATENCY_LADDER)
+_EGRESS_QUEUED = {kind: _GEN_EGRESS_QUEUED.labels(frame=kind)
+                  for kind in ("first", "next", "final")}
+_GEN_EMIT_BLOCKED = _tm.counter(
+    "zoo_gen_emit_blocked_seconds_total",
+    "Seconds the decode loop's emit stood blocked because the engine's sink "
+    "queue was full (0 while the sink keeps up; rising: the sink is the "
+    "knee)")
 #: the exclusive phases of the decode loop's thread (docs/observability.md)
 LOOP_PHASES = ("swap", "admit", "prefill_host", "prefill_wait", "decode_host",
                "decode_wait", "emit", "idle", "other")
@@ -133,6 +152,31 @@ _GEN_LOOP_SECONDS = _tm.counter(
     "the phases sum to the thread's wall time (the *_wait phases are the "
     "host waiting for device work, idle is the wait for a request)",
     labels=("phase",))
+#: ... of the engine's sink thread: idle is the wait for a frame
+SINK_PHASES = ("idle", "build", "xadd", "ack", "other")
+_GEN_SINK_SECONDS = _tm.counter(
+    "zoo_gen_sink_seconds_total",
+    "Seconds of the generation engine's sink thread by exclusive phase, "
+    "summing to the thread's wall time: idle (blocked on its queue), build "
+    "(the frame), xadd and ack (the broker calls), other",
+    labels=("phase",))
+#: ... and of its source thread: poll is the blocking read for a request
+SOURCE_PHASES = ("poll", "admit", "stats", "other")
+_GEN_SOURCE_SECONDS = _tm.counter(
+    "zoo_gen_source_seconds_total",
+    "Seconds of the generation engine's source thread by exclusive phase, "
+    "summing to the thread's wall time: poll (blocked in XREADGROUP), admit "
+    "(an entry parsed and submitted to the batcher), stats (the HSET once a "
+    "second), other",
+    labels=("phase",))
+_GEN_CPU_SECONDS = _tm.counter(
+    "zoo_gen_cpu_seconds_total",
+    "The thread's own CPU seconds (time.thread_time) by thread (loop, sink, "
+    "source) and by the phase of its zoo_gen_<thread>_seconds_total, "
+    "estimated from one pass in seventeen. Wall less CPU of a phase that makes "
+    "no blocking call is time the thread was runnable and did not run: the "
+    "wait for the interpreter or the host",
+    labels=("thread", "phase"))
 _GEN_PREFILL_CHUNKS = _tm.counter(
     "zoo_gen_prefill_chunks_total",
     "Chunked-prefill dispatches executed (each fills at most "
@@ -197,6 +241,13 @@ _tm.collector("zoo_gen_param_bytes",
                   (g.param_bytes for g in list(_LIVE_GENERATORS)),
                   collections.Counter()).items())],
               labels=("dtype",))
+_LIVE_ENGINES: "weakref.WeakSet[GenerationEngine]" = weakref.WeakSet()
+_tm.collector("zoo_gen_sink_queue_depth",
+              "Frames waiting in the sink queues of live generation engines "
+              "(a queue holds 1,024; a full one blocks the decode loop's "
+              "emit)",
+              lambda: [((), float(sum(e._sink_q.qsize()
+                                      for e in list(_LIVE_ENGINES))))])
 _tm.collector("zoo_gen_prefix_reclaimable_pages",
               "Prefix-cache pages whose only reference is the cache's own "
               "(no live stream attached) — HBM an eviction sweep would "
@@ -235,46 +286,93 @@ class _Phase:
 _NO_PHASE = contextlib.nullcontext()
 
 
+class _Sampled:
+    """A CPU child that is fed on one pass in ``clock.CPU_EVERY``: what it is
+    given counts that many times (see :class:`_LoopClock`)."""
+
+    __slots__ = ("_child", "_clock")
+
+    def __init__(self, child, clock: "_LoopClock"):
+        self._child = child
+        self._clock = clock
+
+    def inc(self, seconds: float) -> None:
+        self._child.inc(seconds * self._clock.CPU_EVERY)
+
+
 class _LoopClock:
-    """Closed accounting of the decode loop thread's wall time.
+    """Closed accounting of one thread's wall time: the decode loop's (the
+    defaults), the engine's sink's, its source's.
 
     The thread is in exactly one phase at a time: entering a phase suspends
     the one around it and leaving resumes it, so the phases are exclusive,
-    and what no phase claims of a loop pass goes to ``other`` when the pass
+    and what no phase claims of a pass goes to ``other`` when the pass
     closes. Their sum is the thread's wall time, to the clock's resolution.
-    Each stretch is a :func:`telemetry.region` ``serving.gen.loop.<phase>``:
+    Each stretch is a :func:`telemetry.region` ``<prefix><phase>``:
     flat, never nested, so a profiler trace shows them side by side on the
-    loop thread's line. A thread other than the loop's (``close()`` failing
-    the streams left) gets a no-op."""
+    thread's line; the phases of ``quiet`` (a thread's waits) feed their
+    counters and enter no annotation. A thread other than the clock's
+    (``close()`` failing the streams left) gets a no-op.
 
-    def __init__(self):
-        self._children = {p: _GEN_LOOP_SECONDS.labels(phase=p)
-                          for p in LOOP_PHASES}
-        #: this batcher's own seconds by phase (the counter family is shared
-        #: by every batcher of the process); read by ``stats()``
-        self.seconds: Dict[str, float] = dict.fromkeys(LOOP_PHASES, 0.0)
+    The thread's CPU seconds by the same phases
+    (``zoo_gen_cpu_seconds_total{thread,phase}``) are an estimate from one
+    pass in :attr:`CPU_EVERY`, counted that many times: ``time.thread_time``
+    is a system call, 5.7 us where the benchmark runs against 0.09 for
+    ``perf_counter`` (PERF.md, PR 41), and a pass of the decode loop would
+    make thirteen of them, a frame of the sink seven, with the interpreter
+    held."""
+
+    #: the thread's CPU clock is read on every pass of this many: a prime,
+    #: so that the passes read do not fall in step with a period of the work
+    #: (a stream takes a new page every ``page_size`` = 16 steps)
+    CPU_EVERY = 17
+
+    def __init__(self, family=_GEN_LOOP_SECONDS,
+                 phases: Sequence[str] = LOOP_PHASES,
+                 prefix: str = "serving.gen.loop.", thread: str = "loop",
+                 quiet: Sequence[str] = ()):
+        self._children = {p: family.labels(phase=p) for p in phases}
+        self._cpu_children = {
+            p: _Sampled(_GEN_CPU_SECONDS.labels(thread=thread, phase=p), self)
+            for p in phases}
+        # resolved once: a phase is entered thousands of times a second
+        self._phases = {p: _Phase(self, p) for p in phases}
+        self._regions = {p: (prefix + p, self._children[p],
+                             self._cpu_children[p], p not in quiet)
+                         for p in phases}
+        #: this clock's own seconds by phase (the counter family is shared
+        #: by every batcher or engine of the process); read by ``stats()``
+        self.seconds: Dict[str, float] = dict.fromkeys(phases, 0.0)
         self._tid: Optional[int] = None
         self._stack: List[str] = []     # phases entered, innermost last
         self._open = None               # the innermost phase's running region
-        self._pass_t0 = 0.0
-        self._accounted = 0.0           # seconds of this pass in some phase
+        self._passes = 0                # closed since begin()
+        self._cpu_pass = False          # this pass reads the CPU clock
+        self._pass_t0 = self._pass_c0 = 0.0
+        # seconds of this pass in some phase: wall, and the thread's CPU
+        self._accounted = self._accounted_cpu = 0.0
 
     def begin(self) -> None:
         """The calling thread is the loop from now on (a respawn too)."""
         self._tid = threading.get_ident()
         self._stack.clear()
         self._open = None
-        self._accounted = 0.0
+        self._accounted = self._accounted_cpu = 0.0
+        self._passes = 0
+        self._cpu_pass = True           # the first pass is one of them
+        self._pass_c0 = time.thread_time()
         self._pass_t0 = time.perf_counter()
 
     def phase(self, name: str):
         if threading.get_ident() != self._tid:
             return _NO_PHASE
-        return _Phase(self, name)
+        return self._phases[name]
 
     def _start(self, name: str) -> None:
-        self._open = _tm.region("serving.gen.loop." + name,
-                                self._children[name])
+        region_name, child, cpu_child, annotate = self._regions[name]
+        self._open = _tm.region(region_name, child,
+                                cpu_child if self._cpu_pass else None,
+                                annotate)
         self._open.__enter__()
 
     def _stop(self, name: str) -> None:
@@ -282,6 +380,7 @@ class _LoopClock:
         dt = self._open.seconds
         self.seconds[name] += dt
         self._accounted += dt
+        self._accounted_cpu += self._open.cpu_seconds
 
     def _push(self, name: str) -> None:
         outer = self._stack[-1] if self._stack else None
@@ -302,14 +401,21 @@ class _LoopClock:
             self._start(outer)
 
     def close_pass(self) -> None:
-        """End of a loop pass, no phase open: the pass's remainder is
-        ``other``, and the next pass starts at the same reading."""
+        """End of a pass, no phase open: the pass's remainder is ``other``,
+        and the next pass starts at the same readings."""
         now = time.perf_counter()
         other = max(0.0, now - self._pass_t0 - self._accounted)
         self._children["other"].inc(other)
         self.seconds["other"] += other
+        if self._cpu_pass:
+            self._cpu_children["other"].inc(max(
+                0.0, time.thread_time() - self._pass_c0 - self._accounted_cpu))
+        self._passes += 1
+        self._cpu_pass = self._passes % self.CPU_EVERY == 0
+        if self._cpu_pass:
+            self._pass_c0 = time.thread_time()
         self._pass_t0 = now
-        self._accounted = 0.0
+        self._accounted = self._accounted_cpu = 0.0
 
 
 class _Request:
@@ -2347,7 +2453,16 @@ class GenerationEngine:
         self._stop = threading.Event()
         self._threads: List[threading.Thread] = []
         self._sink_q: "queue.Queue" = queue.Queue(maxsize=1024)
+        self._sink_clock = _LoopClock(_GEN_SINK_SECONDS, SINK_PHASES,
+                                      "serving.gen.sink.", "sink",
+                                      quiet=("idle",))
+        self._source_clock = _LoopClock(_GEN_SOURCE_SECONDS, SOURCE_PHASES,
+                                        "serving.gen.source.", "source",
+                                        quiet=("poll", "stats"))
         self.served_streams = 0
+        self.frames_written = 0
+        self.emit_blocked_s = 0.0
+        _LIVE_ENGINES.add(self)
 
     def _connect(self, tag: str) -> _Conn:
         policy = RetryPolicy(max_attempts=None, base_delay_s=0.05,
@@ -2400,26 +2515,30 @@ class GenerationEngine:
     def _source_loop(self):
         conn = self._connect("gen.source")
         hb = self.registry.register("serving.gen.source")
+        clock = self._source_clock
+        clock.begin()
         stats_pub = 0.0
         try:
             while not self._stop.is_set():
                 hb.beat()
                 now = time.time()
-                if now - stats_pub >= 1.0:
-                    stats_pub = now
-                    try:
-                        conn.call("HSET", GEN_STATS_PREFIX + self.group,
-                                  dict(self.stats(), ts=now))
-                    except RetryAbortedError:
-                        break
                 try:
-                    entries = conn.call("XREADGROUP", self.stream, self.group,
-                                        8, 200)
+                    if now - stats_pub >= 1.0:
+                        stats_pub = now
+                        with clock.phase("stats"):
+                            conn.call("HSET", GEN_STATS_PREFIX + self.group,
+                                      dict(self.stats(), ts=now))
+                    with clock.phase("poll"):
+                        entries = conn.call("XREADGROUP", self.stream,
+                                            self.group, 8, 200)
                 except RetryAbortedError:
                     break
                 for entry_id, payload in entries or ():
-                    self._admit_entry(entry_id, payload)
+                    with clock.phase("admit"):
+                        self._admit_entry(entry_id, payload)
+                clock.close_pass()
         finally:
+            clock.close_pass()
             hb.stop()
             conn.close()
 
@@ -2478,9 +2597,15 @@ class GenerationEngine:
                                 n_tokens=meta.get("n_tokens", 0))
             # the last field is the instant the frame was handed over:
             # zoo_gen_egress_seconds runs from here to its XADD returning
-            self._sink_q.put(("chunk", _eid, _uri, seq, list(tokens),
-                              meta if final else {}, final, _ctx,
-                              time.perf_counter()))
+            item = ("chunk", _eid, _uri, seq, list(tokens),
+                    meta if final else {}, final, _ctx, time.perf_counter())
+            try:
+                self._sink_q.put_nowait(item)
+            except queue.Full:          # back-pressure: the sink is behind
+                self._sink_q.put(item)
+                blocked = time.perf_counter() - item[-1]
+                _GEN_EMIT_BLOCKED.inc(blocked)
+                self.emit_blocked_s += blocked
 
         try:
             self.batcher.submit(prompt, uri=uri, on_chunk=on_chunk,
@@ -2493,44 +2618,79 @@ class GenerationEngine:
     def _sink_loop(self):
         conn = self._connect("gen.sink")
         hb = self.registry.register("serving.gen.sink")
+        clock = self._sink_clock
+        clock.begin()
         try:
             while True:
                 hb.beat()
                 try:
-                    item = self._sink_q.get(timeout=0.1)
+                    try:
+                        item = self._sink_q.get_nowait()
+                    except queue.Empty:     # only a wait is idle time
+                        with clock.phase("idle"):
+                            item = self._sink_q.get(timeout=0.1)
+                    self._write(conn, item)
                 except queue.Empty:
                     if self._stop.is_set():
                         break
-                    continue
-                (kind, entry_id, uri, seq, tokens, meta, final, ctx,
-                 t_handed) = item
-                try:
-                    if kind == "ack":   # cancel frames carry no reply
-                        conn.call("XACK", self.stream, self.group, [entry_id])
-                        continue
-                    frame = {"sid": uri, "seq": seq,
-                             "tokens": np.asarray(tokens, np.int32),
-                             "final": bool(final)}
-                    if final:
-                        frame.update({k: v for k, v in meta.items()
-                                      if k in ("outcome", "error",
-                                               "n_tokens",
-                                               "retry_after_s")})
-                    if ctx is not None:
-                        frame[TRACE_KEY] = ctx
-                    conn.call("XADD", GEN_OUT_PREFIX + uri, frame)
-                    _GEN_EGRESS.observe(time.perf_counter() - t_handed)
-                    if final:
-                        conn.call("XACK", self.stream, self.group, [entry_id])
-                        self.served_streams += 1
                 except RetryAbortedError:
                     break
+                clock.close_pass()
         finally:
+            clock.close_pass()
             hb.stop()
             conn.close()
 
+    def _write(self, conn: _Conn, item: Tuple) -> None:
+        """One turn of the sink: the frame of ``item``, just taken off the
+        queue, to its reply stream, and the request acknowledged once its
+        final frame is in the broker."""
+        t_taken = time.perf_counter()
+        clock = self._sink_clock
+        kind, entry_id, uri, seq, tokens, meta, final, ctx, t_handed = item
+        if kind == "ack":               # cancel frames carry no reply
+            with clock.phase("ack"):
+                conn.call("XACK", self.stream, self.group, [entry_id])
+            return
+        with clock.phase("build"):
+            frame = {"sid": uri, "seq": seq,
+                     "tokens": np.asarray(tokens, np.int32),
+                     "final": bool(final)}
+            if final:
+                frame.update({k: v for k, v in meta.items()
+                              if k in ("outcome", "error", "n_tokens",
+                                       "retry_after_s")})
+            if ctx is not None:
+                frame[TRACE_KEY] = ctx
+        with clock.phase("xadd"):
+            conn.call("XADD", GEN_OUT_PREFIX + uri, frame)
+        t_done = time.perf_counter()
+        which = "first" if seq == 0 else "final" if final else "next"
+        _GEN_EGRESS.observe(t_done - t_handed)
+        _EGRESS_QUEUED[which].observe(t_taken - t_handed)
+        self.frames_written += 1
+        if which != "next":
+            # the two frames a client waits for close the request's trace;
+            # a span a token would push the traces out of the recorder
+            _tm.record_span("serving.gen.egress", t_handed, t_done,
+                            remote=ctx, uri=uri, frame=which,
+                            queued_s=round(t_taken - t_handed, 6))
+        if final:
+            with clock.phase("ack"):
+                conn.call("XACK", self.stream, self.group, [entry_id])
+            self.served_streams += 1
+
     def stats(self) -> Dict[str, Any]:
-        out = {"served_streams": self.served_streams}
+        out = {"served_streams": self.served_streams,
+               # the sink thread's seconds by exclusive phase since start
+               # (this engine's share of zoo_gen_sink_seconds_total), the
+               # frames they wrote, and the back-pressure pair: frames
+               # waiting now, and how long a full queue has blocked emit
+               "sink": {"seconds": {p: round(v, 6) for p, v in
+                                    self._sink_clock.seconds.items()},
+                        "frames": self.frames_written,
+                        "queue_depth": self._sink_q.qsize(),
+                        "emit_blocked_s": round(self.emit_blocked_s, 6)}}
         out.update(self.batcher.stats())
         return out
 

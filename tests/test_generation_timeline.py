@@ -1,6 +1,9 @@
 """The generation path accounts for its own time (ISSUE 23): the decode
 loop's closed phase accounting, a request's server-side timeline and spans,
-the broker hops, the fine latency ladder, and the loop's profiler regions.
+the broker hops, the fine latency ladder, and the loop's profiler regions;
+and the engine's sink and source threads keep the same books (ISSUE 41):
+phases that sum to each thread's wall time, CPU beside wall, a frame's wait
+in the sink's queue by kind of frame, back-pressure where it happens.
 Tiny model, CPU; every test carries a time limit of its own.
 """
 
@@ -8,6 +11,7 @@ import functools
 import glob
 import json
 import os
+import queue
 import re
 import signal
 import threading
@@ -241,6 +245,8 @@ def test_ingress_once_a_request_and_egress_once_a_frame(model_and_params,
     try:
         ingress0 = _hist("zoo_gen_ingress_seconds")
         egress0 = _hist("zoo_gen_egress_seconds")
+        queued0 = {k: _hist("zoo_gen_egress_queued_seconds", k)
+                   for k in ("first", "next", "final")}
         n_new = (3, 5, 4)
         uris = [client.submit(list(range(1, 10)), max_new_tokens=n, seed=i)
                 for i, n in enumerate(n_new)]
@@ -252,11 +258,17 @@ def test_ingress_once_a_request_and_egress_once_a_frame(model_and_params,
             "max_new_tokens": 2})
         assert sum(c.size for c in client.stream("old-client",
                                                  timeout_s=120)) == 2
+        # a malformed request is a stream of one frame, final and seq 0
+        client._conn.call("XADD", gen.GEN_STREAM, {
+            "uri": "one-frame", "prompt": np.arange(1, 6, dtype=np.int32),
+            "max_new_tokens": "abc"})
+        with pytest.raises(RuntimeError, match="malformed request"):
+            list(client.stream("one-frame", timeout_s=120))
         ingress = np.subtract(_hist("zoo_gen_ingress_seconds"), ingress0)
         assert ingress[1] == len(uris)
         # a frame a token (plain decode) and a final frame a request; the
         # client may read the last frame before the sink has timed its XADD
-        frames = sum(n_new) + len(uris) + 2 + 1
+        frames = sum(n_new) + len(uris) + 2 + 1 + 1
         deadline = time.monotonic() + 10
         while time.monotonic() < deadline:
             egress = np.subtract(_hist("zoo_gen_egress_seconds"), egress0)
@@ -265,16 +277,36 @@ def test_ingress_once_a_request_and_egress_once_a_frame(model_and_params,
             time.sleep(0.01)
         assert egress[1] == frames
         assert 0 <= ingress[0] < 5 * len(uris) and 0 < egress[0] < 60
+        # the wait in the sink's queue is observed with it, once a frame and
+        # by kind: a first and a final frame a stream, a one-frame stream a
+        # first alone, and the wait is a part of the whole
+        queued = {k: np.subtract(_hist("zoo_gen_egress_queued_seconds", k),
+                                 queued0[k]) for k in queued0}
+        assert sum(q[1] for q in queued.values()) == frames
+        assert queued["first"][1] == len(uris) + 2
+        assert queued["final"][1] == len(uris) + 1
+        assert 0 < sum(q[0] for q in queued.values()) < egress[0]
+        sink = engine.stats()["sink"]
+        assert sink["frames"] == frames and sink["emit_blocked_s"] == 0
+        assert sink["seconds"]["xadd"] > 0 and sink["seconds"]["ack"] > 0
         assert engine.stats()["loop_seconds"]["decode_wait"] > 0
         # one request, one trace: the client's send span parents the
-        # server's queue, prefill and stream spans
+        # server's queue, prefill and stream spans, and the egress of its
+        # first and of its final frame (no other frame's)
         (send,) = [sp for sp in tm.spans(name="serving.gen.send")
                    if sp.tags.get("uri") == uris[0]]
-        legs = {sp.name: sp for sp in tm.spans(trace_id=send.trace_id)
-                if sp.name.startswith("serving.gen.") and sp is not send}
-        assert set(legs) == {"serving.gen.queue", "serving.gen.prefill",
-                             "serving.gen.stream"}
-        assert all(sp.parent_id == send.span_id for sp in legs.values())
+        legs = [sp for sp in tm.spans(trace_id=send.trace_id)
+                if sp.name.startswith("serving.gen.") and sp is not send]
+        assert sorted(sp.name for sp in legs) == [
+            "serving.gen.egress", "serving.gen.egress", "serving.gen.prefill",
+            "serving.gen.queue", "serving.gen.stream"]
+        assert all(sp.parent_id == send.span_id for sp in legs)
+        egress_legs = {sp.tags["frame"]: sp for sp in legs
+                       if sp.name == "serving.gen.egress"}
+        assert set(egress_legs) == {"first", "final"}
+        for sp in egress_legs.values():
+            assert sp.tags["uri"] == uris[0]
+            assert 0 <= sp.tags["queued_s"] <= sp.duration_s
         # the source republishes stats() to the gen:stats: hash once a
         # second, and `cli info` prints the loop's accounting from it
         from analytics_zoo_tpu.serving.cli import main as cli_main
@@ -290,12 +322,226 @@ def test_ingress_once_a_request_and_egress_once_a_frame(model_and_params,
                 break
             time.sleep(0.2)
         assert set(shown["loop_seconds"]) == set(gen.LOOP_PHASES)
+        assert set(shown["sink"]["seconds"]) == set(gen.SINK_PHASES)
+        assert shown["sink"]["queue_depth"] == 0
         assert set(shown["param_bytes"]) == {"float32"}
         assert shown["steps"] >= max(n_new) - 1
     finally:
         client.close()
         engine.stop()
         broker.shutdown()
+
+
+# ------------------------------------------- the sink's and source's books
+
+def _engine(model_and_params, broker, **kw):
+    m, params = model_and_params
+    return GenerationEngine(m, params, config=ServingConfig(
+        queue_port=broker.port, gen_slots=2, gen_page_size=4,
+        gen_max_seq_len=32, **kw))
+
+
+def _stamped(clock):
+    """``{"t0", "t1"}``: the instants ``clock``'s thread began and last
+    closed a pass (its end, once the thread has ended)."""
+    marks = {}
+    begin, close_pass = clock.begin, clock.close_pass
+
+    def stamped_begin():
+        marks["t0"] = time.perf_counter()
+        begin()
+
+    def stamped_close_pass():
+        close_pass()
+        marks["t1"] = time.perf_counter()
+
+    clock.begin, clock.close_pass = stamped_begin, stamped_close_pass
+    return marks
+
+
+@time_limit(180)
+def test_sink_and_source_phases_sum_to_their_threads_wall_time(
+        model_and_params):
+    """Over a served run, from each thread's start to its end: the exclusive
+    phases add up to the wall time that passed, every phase that had work
+    holds some, and the thread's CPU seconds by phase stay under its wall
+    seconds (a wait burns next to none)."""
+    def family(name):
+        return dict(tm.snapshot().get(name, {}).get("samples", {}))
+
+    before = {n: family(n) for n in (
+        "zoo_gen_sink_seconds_total", "zoo_gen_source_seconds_total",
+        "zoo_gen_cpu_seconds_total")}
+    broker = start_broker()
+    engine = _engine(model_and_params, broker)
+    marks = {"sink": _stamped(engine._sink_clock),
+             "source": _stamped(engine._source_clock)}
+    # every pass reads the thread's CPU clock, where serving reads it on
+    # one in seventeen: the books are then exact, not an estimate
+    for clock in (engine._sink_clock, engine._source_clock,
+                  engine.batcher._clock):
+        clock.CPU_EVERY = 1
+    engine.start()
+    client = GenerationClient(port=broker.port)
+    try:
+        uris = [client.submit(list(range(1, 10)), max_new_tokens=6, seed=i)
+                for i in range(4)]
+        client.cancel("nobody")         # an entry that is acknowledged only
+        for uri in uris:
+            assert sum(c.size for c in client.stream(uri, timeout_s=120)) == 6
+        time.sleep(1.2)                 # idle turns, and a second stats tick
+    finally:
+        client.close()
+        engine.stop()
+        broker.shutdown()
+    assert not any(t.is_alive() for t in engine._threads)
+    clocks = {"sink": (engine._sink_clock, gen.SINK_PHASES),
+              "source": (engine._source_clock, gen.SOURCE_PHASES)}
+    for thread, (clock, phases) in clocks.items():
+        wall = marks[thread]["t1"] - marks[thread]["t0"]
+        assert set(clock.seconds) == set(phases)
+        assert wall > 1.2
+        assert sum(clock.seconds.values()) == pytest.approx(wall, rel=0.02)
+        assert all(v > 0 for v in clock.seconds.values()), clock.seconds
+        after = family(f"zoo_gen_{thread}_seconds_total")
+        cpu = family("zoo_gen_cpu_seconds_total")
+        for name, seconds in clock.seconds.items():
+            # other engines of this process may be feeding the families too
+            moved = after[name] - before[
+                f"zoo_gen_{thread}_seconds_total"].get(name, 0.0)
+            assert moved >= seconds - 1e-4
+            burnt = cpu[f"{thread},{name}"] - before[
+                "zoo_gen_cpu_seconds_total"].get(f"{thread},{name}", 0.0)
+            assert 0 <= burnt <= moved + 0.01, (thread, name)
+    assert engine.stats()["sink"]["seconds"]["idle"] >= 1.0
+    assert engine._source_clock.seconds["poll"] >= 1.0
+    # the loop's clock feeds the same CPU family under its own thread
+    assert family("zoo_gen_cpu_seconds_total")["loop,decode_host"] > 0
+
+
+@time_limit(30)
+def test_the_cpu_clock_is_read_on_one_pass_in_seventeen_and_counted_so(
+        monkeypatch):
+    """``time.thread_time`` is a system call (5.7 us where the benchmark
+    runs), so a clock reads it on one pass in ``CPU_EVERY`` and counts what
+    it read that many times: an estimate of the thread's CPU seconds at a
+    seventeenth of the cost."""
+    reads = []
+    thread_time = time.thread_time
+    monkeypatch.setattr(time, "thread_time",
+                        lambda: reads.append(1) or thread_time())
+    fam = tm.counter("zoo_test_clock_seconds_total", "test",
+                     labels=("phase",))
+    clock = gen._LoopClock(fam, ("busy", "other"), "test.clock.", "test")
+    every = clock.CPU_EVERY
+    assert every == 17
+    cpu = gen._GEN_CPU_SECONDS.labels(thread="test", phase="busy")
+    clock.begin()
+    burnt = 0.0
+    for _ in range(2 * every):
+        with clock.phase("busy"):
+            c0 = thread_time()
+            while thread_time() - c0 < 0.002:
+                pass
+            burnt += thread_time() - c0
+        clock.close_pass()
+    # begin; passes 0 and 17: a pair a phase and one at each end of the
+    # pass; and the start of pass 34
+    assert len(reads) == 1 + 3 + 4 + 1
+    assert cpu.value() == pytest.approx(burnt, rel=0.25)
+    assert clock.seconds["busy"] >= burnt - 1e-3
+
+
+class _SlowXadd:
+    """The sink's connection with an ``XADD`` that takes ``delay_s``."""
+
+    def __init__(self, conn, delay_s):
+        self._conn, self._delay_s = conn, delay_s
+
+    def call(self, verb, *args):
+        if verb == "XADD":
+            time.sleep(self._delay_s)
+        return self._conn.call(verb, *args)
+
+    def close(self):
+        self._conn.close()
+
+
+@time_limit(180)
+def test_a_slow_sink_blocks_emit_and_the_loop_holds_that_time(
+        model_and_params):
+    """Back-pressure where it happens: with a queue of two frames and an
+    ``XADD`` of 30 ms the decode loop's ``emit`` stands blocked on the full
+    queue; the counter, ``stats()["sink"]`` and the loop's own ``emit`` phase
+    all say so."""
+    broker = start_broker()
+    engine = _engine(model_and_params, broker)
+    engine._sink_q = queue.Queue(maxsize=2)
+    connect = engine._connect
+    engine._connect = lambda tag: (_SlowXadd(connect(tag), 0.03)
+                                   if tag == "gen.sink" else connect(tag))
+    blocked0 = tm.snapshot()["zoo_gen_emit_blocked_seconds_total"][
+        "samples"].get("", 0.0)
+    engine.start()
+    client = GenerationClient(port=broker.port)
+    try:
+        assert engine.stats()["sink"]["queue_depth"] == 0
+        uri = client.submit(list(range(1, 10)), max_new_tokens=16)
+        depth, n = 0, 0
+        for chunk in client.stream(uri, timeout_s=120):
+            n += chunk.size
+            depth = max(depth, engine.stats()["sink"]["queue_depth"],
+                        int(tm.snapshot()["zoo_gen_sink_queue_depth"][
+                            "samples"][""]))
+        assert n == 16 and depth == 2
+        stats = engine.stats()
+        blocked = stats["sink"]["emit_blocked_s"]
+        # 18 frames of 30 ms through a queue of two: most of them waited
+        assert blocked > 0.2
+        assert tm.snapshot()["zoo_gen_emit_blocked_seconds_total"][
+            "samples"][""] - blocked0 == pytest.approx(blocked, abs=1e-3)
+        assert stats["loop_seconds"]["emit"] >= blocked
+        assert stats["sink"]["seconds"]["xadd"] >= 17 * 0.03
+    finally:
+        client.close()
+        engine.stop()
+        broker.shutdown()
+
+
+@time_limit(60)
+def test_a_thousand_next_frames_record_no_span(model_and_params):
+    """A stream's first and final frame close its trace; the frames between
+    them feed counters alone, or a long stream would push every request's
+    trace out of the recorder."""
+    class Broker:
+        port = 1                        # nothing connects to it
+
+        def call(self, *_args):
+            return None
+
+    engine = _engine(model_and_params, Broker)
+    try:
+        engine._sink_clock.begin()      # this thread is the sink now
+        n_spans = len(tm.spans())
+        queued0 = _hist("zoo_gen_egress_queued_seconds", "next")
+        ctx = {"t": "ab" * 16, "s": "cd" * 8}
+        for seq in range(1, 1001):
+            engine._write(Broker(), ("chunk", "1-0", "u", seq, [seq], {},
+                                     False, ctx, time.perf_counter()))
+            engine._sink_clock.close_pass()
+        assert len(tm.spans()) == n_spans
+        assert engine.stats()["sink"]["frames"] == 1000
+        assert np.subtract(_hist("zoo_gen_egress_queued_seconds", "next"),
+                           queued0)[1] == 1000
+        engine._write(Broker(), ("chunk", "1-0", "u", 1001, [], {
+            "outcome": "ok", "n_tokens": 1000}, True, ctx,
+            time.perf_counter()))
+        (span,) = tm.spans()[n_spans:]
+        assert span.name == "serving.gen.egress"
+        assert span.tags["frame"] == "final" and span.trace_id == "ab" * 16
+        assert engine.served_streams == 1
+    finally:
+        engine.batcher.close()
 
 
 # -------------------------------------------------------------- the ladder
@@ -334,7 +580,8 @@ def test_the_fine_ladder_reads_a_p95_within_15_percent():
     assert abs(_quantile(coarse.snapshot(), 0.95) / float(
         np.quantile(gaps, 0.95)) - 1) > 0.15
     for fam in (gen._GEN_TTFT, gen._GEN_ITL, gen._GEN_QUEUE_WAIT,
-                gen._GEN_PREFILL, gen._GEN_INGRESS, gen._GEN_EGRESS):
+                gen._GEN_PREFILL, gen._GEN_INGRESS, gen._GEN_EGRESS,
+                gen._GEN_EGRESS_QUEUED):
         assert fam.buckets == ladder
 
 
@@ -354,6 +601,86 @@ def test_a_region_times_into_its_child_and_records_no_span():
             raise KeyError("passes through, and is timed")
     assert child.value() > r.seconds
     assert len(tm.spans()) == n_spans and tm.current_span() is None
+
+
+@time_limit(30)
+def test_a_region_with_a_cpu_child_adds_the_cpu_the_thread_burnt():
+    fam = tm.counter("zoo_test_region_seconds_total", "test",
+                     labels=("phase",))
+    wall, cpu = fam.labels(phase="wall"), fam.labels(phase="cpu")
+    with tm.region("test.region.busy", wall, cpu_child=cpu) as r:
+        c0 = time.thread_time()
+        while time.thread_time() - c0 < 0.05:
+            pass
+    assert 0.05 <= r.cpu_seconds < 0.07 and r.cpu_seconds <= r.seconds + 1e-3
+    assert cpu.value() == pytest.approx(r.cpu_seconds)
+    assert wall.value() == pytest.approx(r.seconds)
+    # a wait costs wall time and next to no CPU
+    with tm.region("test.region.asleep", wall, cpu_child=cpu,
+                   annotate=False) as r:
+        time.sleep(0.05)
+    assert r.seconds >= 0.05 and r.cpu_seconds < 0.005
+    assert cpu.value() < 0.075
+    # without a child the thread's clock is not read
+    with tm.region("test.region.plain", wall) as r:
+        pass
+    assert r.cpu_seconds == 0.0
+
+
+@time_limit(180)
+def test_a_cpu_profile_holds_the_working_phases_of_sink_and_source_only(
+        model_and_params, tmp_path):
+    """``sink.xadd`` (and ``build``, ``ack``) on the sink thread's line and
+    ``source.admit`` on the source's, flat and side by side; the threads'
+    waits (``sink.idle``, ``source.poll``, the ``stats`` tick) nowhere: a
+    reader that names a device's idle gap after the span that began last
+    must go on reading ``serving.gen.loop.idle`` while there is no request."""
+    broker = start_broker()
+    engine = _engine(model_and_params, broker).start()
+    client = GenerationClient(port=broker.port)
+    try:
+        client.generate(list(range(1, 9)), max_new_tokens=3)     # compiled
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            for i in range(3):
+                client.generate(list(range(1, 9 + i)), max_new_tokens=6,
+                                seed=i)
+            time.sleep(1.2)             # idle turns, polls, a stats tick
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        client.close()
+        engine.stop()
+        broker.shutdown()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    lines = []                          # both may be called "python"
+    for plane in data.planes:
+        for line in plane.lines:
+            events = sorted((e.start_ns, e.start_ns + e.duration_ns, e.name)
+                            for e in line.events
+                            if e.name.startswith(("serving.gen.sink.",
+                                                  "serving.gen.source.")))
+            if events:
+                lines.append(events)
+    assert len(lines) == 2
+    by_thread = {events[0][2].split(".")[2]: events for events in lines}
+    names = {t: {name for _, _, name in events}
+             for t, events in by_thread.items()}
+    assert names["sink"] == {"serving.gen.sink." + p
+                             for p in ("build", "xadd", "ack")}
+    assert names["source"] == {"serving.gen.source.admit"}
+    assert all(SPAN_PATTERN.match(n) for t in names for n in names[t])
+    for events in by_thread.values():
+        for (_, end, a), (start, _, b_name) in zip(events, events[1:]):
+            assert end <= start, (a, b_name)
+    assert engine._sink_clock.seconds["idle"] > 1.0
+    assert engine._source_clock.seconds["poll"] > 1.0
+    assert engine._source_clock.seconds["stats"] > 0
 
 
 @time_limit(180)
