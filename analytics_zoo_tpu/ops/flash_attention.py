@@ -5,8 +5,19 @@ Forward: tiled online-softmax. Grid (B·H, T_q/block_q, T_kv/block_k); each
 program folds one K/V tile into fp32 VMEM accumulators (m, l, acc), writing the
 normalized output on the last K tile. Q·Kᵀ and P·V hit the MXU per tile; scores
 never materialize in HBM — peak memory O(block_q · block_k) per core instead of
-O(T²). Causal masking skips fully-future K tiles (no wasted tiles beyond the
-diagonal).
+O(T²). What a grid step does follows from the tile's position and the static
+``causal`` alone (:func:`forward_tile_plan` counts the kinds): in a causal call
+a K tile wholly in the future is skipped (an empty grid step; its K/V copy
+hides under the working tiles) and every other tile builds the mask, as the
+backward kernels do; a non-causal call (a ring's off-diagonal step) builds
+none. Masking only the tiles the diagonal crosses, and index maps that fetch
+nothing for a skipped tile, were measured on a v5e and refused (PR 42,
+``PERF.md``: the tile is bound by its stores, the copies were hidden, neither
+was faster). The first tile of a q tile sets the statistics, so nothing
+resets them. ``m`` and ``l`` stay (block_q, 128) with the row's value in
+every lane, the exponent is ``exp2`` with the softmax scale folded into its
+one multiply, and the saved ``lse`` is converted back to the NATURAL log on
+the last tile: the backward kernels and the ring merges read it.
 
 Backward: tiled pallas kernels recomputing probabilities from the saved
 log-sum-exp (standard flash recompute: P = exp(S − lse)). Two passes:
@@ -25,6 +36,7 @@ compare against. Off TPU the kernels run in the Pallas interpreter
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -37,6 +49,8 @@ from jax.experimental.pallas import tpu as pltpu
 from .backend import interpret_default
 
 NEG_INF = -1e30
+_LOG2E = math.log2(math.e)
+_LN2 = math.log(2.0)
 
 #: Flash-aware rematerialization policy: under ``jax.checkpoint`` save ONLY the
 #: flash kernel's output + log-sum-exp (tagged in ``_flash_attention_fwd_res``),
@@ -47,76 +61,137 @@ FLASH_REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
     "flash_out", "flash_lse")
 
 
+def _reaches_diagonal(q_lo, k_lo, block_q):
+    """A K tile some row of the q tile attends to; the others are skipped."""
+    return k_lo <= q_lo + block_q - 1
+
+
+def forward_tile_plan(t_q: int, t_k: int, block_q: int, block_k: int,
+                      causal: bool) -> tuple:
+    """``(skipped, working)`` (q tile, K tile) pairs of one head in the
+    forward kernel, by the predicate the kernel itself branches on: a skipped
+    pair runs an empty grid step, a working pair folds its scores (behind the
+    causal mask when ``causal``)."""
+    nq, nk = t_q // block_q, t_k // block_k
+    working = nq * nk if not causal else sum(
+        _reaches_diagonal(qi * block_q, kb * block_k, block_q)
+        for qi in range(nq) for kb in range(nk))
+    return nq * nk - working, working
+
+
+def _lanes(x, n: int):
+    """``x`` holds one value a row in each of its lanes; the same in ``n``
+    lanes, by reusing registers where ``n`` is a multiple (no broadcast)."""
+    w = x.shape[1]
+    if n == w:
+        return x
+    if n < w:
+        return x[:, :n]
+    if w > 1 and n % w == 0:
+        return jnp.tile(x, (1, n // w))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _lane_sums(p, w: int):
+    """Row sums of ``p`` left spread over ``w`` lanes (lane j holds the sum
+    of columns j, j + w, ...): elementwise adds of whole registers, the one
+    cross-lane reduction is made once, on the last tile."""
+    if w == 1:
+        return jnp.sum(p, axis=1, keepdims=True)
+    ps = p[:, :w]
+    for c in range(w, p.shape[1], w):
+        ps = ps + p[:, c:c + w]
+    return ps
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale: float, causal: bool,
                 block_q: int, block_k: int):
     qi = pl.program_id(1)
     kb = pl.program_id(2)
     nk = pl.num_programs(2)
+    w = m_scr.shape[1]
+    # the exponent is taken in base 2 with the softmax scale folded into its
+    # one multiply: exp(scale * s - m) = exp2(s * c2 - m2), m2 = m * log2(e)
+    c2 = scale * _LOG2E
 
-    @pl.when(kb == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    def body():
-        # operands STAY in their storage dtype: a bf16×bf16→f32 dot runs the
-        # MXU at full rate, an f32 upcast would halve it; all softmax
-        # statistics accumulate in f32 via preferred_element_type
-        q = q_ref[0]                                # (block_q, D)
-        k = k_ref[0]                                # (block_k, D)
+    def fold(first: bool):
+        # operands STAY in their storage dtype: a bf16 x bf16 -> f32 dot runs
+        # the MXU at full rate, an f32 upcast would halve it; scores,
+        # statistics and accumulator are f32
         v = v_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
         if causal:
             q_pos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m_prev = m_scr[:, 0:1]                      # (block_q, 1)
-        l_prev = l_scr[:, 0:1]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                      # (block_q, block_k)
-        l_new = l_prev * corr + p.sum(axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        # m and l live as (block_q, w) with the row's value in every lane
+        # (l: spread over the lanes), so nothing below slices a one-lane
+        # column out of them or broadcasts one back
+        m_new = jnp.max(s, axis=1, keepdims=True) * c2
+        if first:
+            m_new = jnp.broadcast_to(m_new, (block_q, w))
+        else:
+            m_prev = m_scr[...]
+            m_new = jnp.maximum(m_prev, m_new)
+            alpha = jnp.exp2(m_prev - m_new)
+        p = jnp.exp2(s * c2 - _lanes(m_new, block_k))   # (block_q, block_k)
+        pv = jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        if first:       # the first tile of a q tile sets the state: no reset
+            l_scr[...] = _lane_sums(p, w)
+            acc_scr[...] = pv
+        else:
+            l_scr[...] = alpha * l_scr[...] + _lane_sums(p, w)
+            acc_scr[...] = acc_scr[...] * _lanes(alpha, pv.shape[1]) + pv
+        m_scr[...] = m_new
 
+    # one online softmax, two tile bodies: the first tile of a q tile (never
+    # skipped: column 0 is in every row's past) and the working tiles after it
+    first = kb == 0
+    later = jnp.logical_not(first)
     if causal:
-        # skip K tiles strictly in the future of every query in this Q tile
-        @pl.when(kb * block_k <= qi * block_q + block_q - 1)
-        def _():
-            body()
-    else:
-        body()
+        later = jnp.logical_and(
+            later, _reaches_diagonal(qi * block_q, kb * block_k, block_q))
+    pl.when(first)(functools.partial(fold, True))
+    pl.when(later)(functools.partial(fold, False))
 
     @pl.when(kb == nk - 1)
     def _finish():
-        l = l_scr[:, 0:1]
+        l = jnp.sum(l_scr[...], axis=1, keepdims=True)      # (block_q, 1)
         safe_l = jnp.where(l == 0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / safe_l).astype(o_ref.dtype)
+        # the saved lse is the natural log whatever base the tiles used;
         # lse block spans the FULL row (TPU tiling: last-two block dims must
-        # divide (8,128) or equal the array dims); each q-tile writes its slice
-        lse_ref[0, 0, pl.ds(qi * block_q, block_q)] = \
-            m_scr[:, 0] + jnp.log(safe_l[:, 0])
+        # divide (8,128) or equal the array dims); each q-tile writes its
+        # slice, moved from rows to lanes by one transpose
+        lse = (m_scr[...] + jnp.log2(jnp.broadcast_to(safe_l, m_scr.shape))
+               ) * _LN2
+        lse_ref[0, :, pl.ds(qi * block_q, block_q)] = lse.T[0:1, :]
 
 
+@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
+                                             "interpret"))
 def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_k: int,
                interpret: bool):
+    """``(out, lse)``: (B, T_q, H, D) in the storage dtype and the natural-log
+    (B, H, T_q) f32. Jitted so that a model's blocks, which call it with the
+    same shapes, trace and lower the kernel once between them and not once
+    each (seconds of a 24-block prefill's set-up: PR 42, ``PERF.md``)."""
     b, t_q, h, d = q.shape
     t_k = k.shape[1]
-    scale = 1.0 / float(np.sqrt(d))
+    scale = 1.0 / math.sqrt(d)
     # (B, T, H, D) -> (B*H, T, D)
     qh = q.transpose(0, 2, 1, 3).reshape(b * h, t_q, d)
     kh = k.transpose(0, 2, 1, 3).reshape(b * h, t_k, d)
     vh = v.transpose(0, 2, 1, 3).reshape(b * h, t_k, d)
     nq = t_q // block_q
     nk = t_k // block_k
+    # statistics in whole registers where a K tile is whole registers wide
+    stat_lanes = 128 if block_k % 128 == 0 else 1
 
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k)
@@ -137,8 +212,8 @@ def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_k: int,
             jax.ShapeDtypeStruct((b * h, 1, t_q), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, stat_lanes), jnp.float32),
+            pltpu.VMEM((block_q, stat_lanes), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         # qi is NOT parallel: the lse out-block (one full row per bh) is

@@ -7,22 +7,63 @@ import numpy as np
 import pytest
 
 from analytics_zoo_tpu.ops.attention import full_attention
-from analytics_zoo_tpu.ops.flash_attention import flash_attention
+from analytics_zoo_tpu.ops.flash_attention import (_flash_fwd,
+                                                   flash_attention,
+                                                   forward_tile_plan)
 
 
-def make_qkv(b=2, t=64, h=2, d=16, seed=0, dtype=jnp.float32):
+def make_qkv(b=2, t=64, h=2, d=16, seed=0, dtype=jnp.float32, t_k=None):
     rng = np.random.default_rng(seed)
-    mk = lambda: jnp.asarray(rng.standard_normal((b, t, h, d)), dtype)
-    return mk(), mk(), mk()
+    mk = lambda t: jnp.asarray(rng.standard_normal((b, t, h, d)), dtype)
+    return mk(t), mk(t_k or t), mk(t_k or t)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_matches_full(causal):
-    q, k, v = make_qkv()
+def reference_lse(q, k, causal):
+    """Natural-log log-sum-exp of the scaled scores, (B, H, T_q), f32."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32)) / np.sqrt(q.shape[-1])
+    if causal:
+        t_q, t_k = s.shape[-2:]
+        s = jnp.where(jnp.arange(t_q)[:, None] >= jnp.arange(t_k)[None, :],
+                      s, -jnp.inf)
+    return jax.scipy.special.logsumexp(s, axis=-1)
+
+
+#: (causal, T_q, T_k, block_q, block_k): the 16 x 16 cases keep the
+#: statistics one lane wide; at 1,024 tokens every kind of tile (skipped,
+#: wholly below the diagonal, crossed by it; first of its q tile or not)
+#: occurs in one call, with block_q == block_k and != both ways; T_q != T_k
+#: is the ring's off-diagonal step
+TILINGS = [(False, 64, 64, 16, 16), (True, 64, 64, 16, 16),
+           (True, 1024, 1024, 256, 256), (True, 1024, 1024, 128, 256),
+           (True, 1024, 1024, 256, 128), (False, 256, 512, 128, 256)]
+_tiling_id = lambda c: "{}-q{}-k{}-{}x{}".format(
+    "causal" if c[0] else "dense", *c[1:])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("tiling", TILINGS, ids=_tiling_id)
+def test_flash_matches_full(tiling, dtype):
+    """The output against ``full_attention`` and the saved ``lse`` against
+    the reference log-sum-exp (natural log, whatever base a tile works in)."""
+    causal, t_q, t_k, bq, bk = tiling
+    if t_q == 1024:     # skipped and working tiles, or the case tests nothing
+        assert all(forward_tile_plan(t_q, t_k, bq, bk, causal))
+    q, k, v = make_qkv(b=1, t=t_q, t_k=t_k, seed=1)
     want = full_attention(q, k, v, causal=causal)
-    got = flash_attention(q, k, v, causal, 16, 16, True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=2e-5, rtol=2e-5)
+    want_lse = reference_lse(q, k, causal)
+    q, k, v = (a.astype(dtype) for a in (q, k, v))
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    got = flash_attention(q, k, v, causal, bq, bk, True)
+    assert got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
+                               atol=tol, rtol=tol)
+    _, lse = _flash_fwd(q, k, v, causal=causal, block_q=bq, block_k=bk,
+                        interpret=True)
+    assert lse.dtype == jnp.float32 and lse.shape == want_lse.shape
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               atol=tol, rtol=tol)
 
 
 def test_flash_single_tile_and_uneven_block_clamp():
@@ -177,13 +218,17 @@ def test_flash_backward_no_quadratic_memory(causal):
         f"(T^2 scale would be {b*h*t*t})")
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_tiled_backward_matches_oracle_multi_tile(causal):
-    # multiple q AND k tiles so cross-tile accumulation paths are exercised
-    q, k, v = make_qkv(b=1, t=128, h=2, d=16, seed=3)
+@pytest.mark.parametrize("tiling", [(False, 128, 128, 32, 32),
+                                    (True, 128, 128, 32, 32)] + TILINGS[2:],
+                         ids=_tiling_id)
+def test_flash_tiled_backward_matches_oracle_multi_tile(tiling):
+    # multiple q AND k tiles so cross-tile accumulation paths are exercised;
+    # the backward recomputes P from the lse the forward saved
+    causal, t_q, t_k, bq, bk = tiling
+    q, k, v = make_qkv(b=1, t=t_q, t_k=t_k, h=2, d=16, seed=3)
 
     def f_flash(q, k, v):
-        return (flash_attention(q, k, v, causal, 32, 32, True) ** 2).sum()
+        return (flash_attention(q, k, v, causal, bq, bk, True) ** 2).sum()
 
     def f_full(q, k, v):
         return (full_attention(q, k, v, causal=causal) ** 2).sum()

@@ -68,6 +68,17 @@ SCHEDULES = {
     # train-cgpt-zero1-x4 and the 2048 prefill bucket of gen-chat-steady
     "flash-t2048-16x128": (lambda: _flash(2048, 16, 128),
                            ((512, 512), (32, 4, 4))),
+    # what the forward's 16 grid steps of a head do at those tiles, by the
+    # kernel's own predicate (PR 42): (skipped, working). A causal call masks
+    # every working tile: masking only the four the diagonal crosses was
+    # measured on the v5e and refused (PERF.md section 6, PR 42)
+    "flash-plan-t2048-512x512": (
+        lambda: fa.forward_tile_plan(2048, 2048, 512, 512, True), (6, 10)),
+    "flash-plan-t1024-512x512": (
+        lambda: fa.forward_tile_plan(1024, 1024, 512, 512, True), (1, 3)),
+    # a ring's off-diagonal step: nothing skipped
+    "flash-plan-t2048-dense": (
+        lambda: fa.forward_tile_plan(2048, 2048, 512, 512, False), (0, 16)),
     # gen-chat-steady's decode step: all 16 heads in one program
     "paged-32x128x16-16x128": (lambda: _paged(32, 128, 16, 16, 128),
                                (32, 1, 1)),

@@ -65,6 +65,26 @@ def test_flash_forward_and_backward_at_the_training_shape(v5e):
     assert v5e(grads, qkv, qkv, qkv).as_text().count("tpu_custom_call") == 3
 
 
+# the serving prefill of gen-docs-batch (one 2,048-token prompt, 16 heads of
+# 128; forward alone) and an explicit flash call at GPT-2 medium's widths
+# (head 64: the statistics' lanes are cut to the accumulator's 64)
+@pytest.mark.parametrize("shape,calls", [((1, 2048, 16, 128), 1),
+                                         ((4, 1024, 16, 64), 3)])
+def test_flash_at_the_serving_shape_and_at_head_64(v5e, shape, calls):
+    qkv = (shape, BF16)
+
+    def forward(q, k, v):
+        return flash_attention(q, k, v, True, None, None, False)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: forward(*a).astype(F32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    text = v5e(forward if calls == 1 else grads, qkv, qkv, qkv).as_text()
+    assert text.count("tpu_custom_call") == calls
+    assert "zoo_flash_fwd" in text
+
+
 # decode, speculative verify, and the widest query the config admits: a
 # prefix-hit suffix bucket as long as gen_max_seq_len (one slot, table one
 # chunk wider than the slot's pages); then the benchmark's serving cell as it
