@@ -101,3 +101,26 @@ class LayerNormalization(Layer):
         y = (xf - mean) * jax.lax.rsqrt(var + self.epsilon)
         y = y * params["gamma"] + params["beta"]
         return y.astype(x.dtype), state
+
+
+class RMSNorm(Layer):
+    """Root-mean-square norm over the last axis, in float32, with a learned
+    scale and no bias: ``x / sqrt(mean(x^2) + epsilon) * scale``."""
+
+    def __init__(self, epsilon: float = 1e-6, name=None, input_shape=None):
+        super().__init__(name=name, input_shape=input_shape)
+        self.epsilon = epsilon
+
+    def build(self, rng, input_shape):
+        return {"scale": jnp.ones((input_shape[-1],), param_dtype())}, {}
+
+    def apply(self, params, state, x, *, training=False, rng=None):
+        return rms_norm(x, params["scale"], self.epsilon), state
+
+
+def rms_norm(x, scale, epsilon: float = 1e-6):
+    """``x / sqrt(mean(x^2) + epsilon) * scale`` over the last axis, computed
+    in float32 and handed back in ``x``'s dtype."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + epsilon)
+    return (y * scale.astype(jnp.float32)).astype(x.dtype)

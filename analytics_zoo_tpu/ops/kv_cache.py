@@ -1,10 +1,25 @@
-"""Paged KV cache for autoregressive decode serving.
+"""The decode-side cache of autoregressive serving: K/V pages, and per-slot
+state for the layers that keep no keys.
 
 The serving stack's one-shot predict path recomputes the whole sequence per
 request; an autoregressive decode loop doing that would pay O(T²) attention
 per EMITTED token. This module is the TPU-native fix — the decode-side state
-store behind ``TransformerLM.prefill()``/``decode_step()`` and the continuous
-batcher (:mod:`analytics_zoo_tpu.serving.generation`):
+store behind ``prefill()``/``decode_step()`` of ``TransformerLM`` and
+``HybridLM`` and the continuous batcher
+(:mod:`analytics_zoo_tpu.serving.generation`).
+
+**Two kinds of state, one description.** :class:`KVCacheConfig` names, layer
+by layer, what a layer keeps between steps (``layer_kinds``): ``"pages"``, K
+and V of every cached token in the paged pools below, addressed through the
+slot's row of the page table; or ``"slot"``, a fixed-size state a slot (a
+linear-attention layer's matrix state and the tail of its short convolution:
+``slot_state`` names the leaves), addressed by the slot's index, of a size
+that does not grow with the sequence. :func:`init_cache` builds both: pools
+for the page layers only, and for each leaf of ``slot_state`` one
+``(n_slots, ...)`` array a slot layer. Every leaf is donated into the
+dispatches and updated where it lies. A model whose layers all hold pages
+(``TransformerLM``) leaves ``layer_kinds`` empty and gets the pytree it always
+got. What follows is about the pages:
 
 * **Pages, not ragged buffers.** K/V live in preallocated pools of
   fixed-size pages, ``(n_pages, page_size, n_heads, head_dim)``.
@@ -58,9 +73,15 @@ NEG_INF = -1e30
 SCRATCH_PAGE = 0
 
 
+#: what a layer keeps between decode steps (``KVCacheConfig.layer_kinds``)
+PAGES, SLOT = "pages", "slot"
+
+
 @dataclasses.dataclass(frozen=True)
 class KVCacheConfig:
-    """Static geometry of one paged cache (fixes every traced shape)."""
+    """Static geometry of one decode cache (fixes every traced shape): the
+    paged K/V pools of the layers that attend to cached keys, and the
+    per-slot state of the layers that do not (module docstring)."""
 
     n_layers: int
     n_heads: int
@@ -70,12 +91,57 @@ class KVCacheConfig:
     pages_per_slot: int = 16           # max_seq_len = page_size * pages_per_slot
     n_pages: Optional[int] = None      # pool size incl. scratch (None = full)
     dtype: Any = jnp.float32
+    #: per layer, ``PAGES`` or ``SLOT``; empty = every layer holds pages
+    layer_kinds: Tuple[str, ...] = ()
+    #: the leaves a ``SLOT`` layer keeps for each slot: ``(name, shape,
+    #: dtype)``, the name being the leaf's key in the cache pytree and its
+    #: ``kind`` in the byte accounting
+    slot_state: Tuple[Tuple[str, Tuple[int, ...], Any], ...] = ()
 
     def __post_init__(self):
         if self.page_size < 1 or self.pages_per_slot < 1:
             raise ValueError("page_size and pages_per_slot must be >= 1")
         if self.n_pages is not None and self.n_pages < 2:
             raise ValueError("n_pages must leave room for scratch + 1 page")
+        if self.layer_kinds and (
+                len(self.layer_kinds) != self.n_layers
+                or set(self.layer_kinds) - {PAGES, SLOT}):
+            raise ValueError(f"layer_kinds must name {PAGES!r} or {SLOT!r} "
+                             f"for each of {self.n_layers} layers, got "
+                             f"{self.layer_kinds}")
+        if (SLOT in self.layer_kinds) != bool(self.slot_state):
+            raise ValueError("slot_state names the leaves of the layers "
+                             "that layer_kinds marks as holding slot state: "
+                             "one without the other")
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        """``layer_kinds``, spelled out for a model that left it empty."""
+        return self.layer_kinds or (PAGES,) * self.n_layers
+
+    @property
+    def n_page_layers(self) -> int:
+        return self.kinds.count(PAGES)
+
+    @property
+    def n_slot_layers(self) -> int:
+        return self.kinds.count(SLOT)
+
+    def index_in_kind(self, layer: int) -> int:
+        """Which of its kind's leaves is ``layer``'s: ``cache["k"][i]`` of a
+        page layer, ``cache[name][i]`` of a slot layer."""
+        return self.kinds[:layer].count(self.kinds[layer])
+
+    def bytes_by_kind(self) -> Dict[str, int]:
+        """Bytes the cache holds on the device: ``pages`` (K and V pools) and
+        one entry a leaf of ``slot_state``."""
+        pool = (self.total_pages * self.page_size * self.n_heads
+                * self.head_dim * jnp.dtype(self.dtype).itemsize)
+        out = {PAGES: 2 * pool * self.n_page_layers}
+        for name, shape, dtype in self.slot_state:
+            out[name] = (self.n_slot_layers * self.n_slots
+                         * int(np.prod(shape)) * jnp.dtype(dtype).itemsize)
+        return out
 
     @property
     def max_seq_len(self) -> int:
@@ -89,19 +155,28 @@ class KVCacheConfig:
         return self.n_slots * self.pages_per_slot + 1
 
 
-#: ``{"k": one pool per layer, "v": one pool per layer}`` — see the module
-#: docstring; ``cache["k"][i]`` is layer ``i``'s K pool.
+#: ``{"k": one pool per page layer, "v": one pool per page layer}`` and, for
+#: a model with slot layers, one entry a leaf of ``slot_state``: ``(n_slots,
+#: ...)`` arrays, one per slot layer — see the module docstring;
+#: ``cache["k"][i]`` is the K pool of the ``i``-th layer that holds pages.
 KVCache = Dict[str, Tuple[jax.Array, ...]]
 
 
 def init_cache(cfg: KVCacheConfig) -> KVCache:
     """Preallocate the K/V page pools, one ``(n_pages, page_size, n_heads,
-    head_dim)`` array per layer (zeros; contents only ever read through a
-    length mask, so stale pages are invisible)."""
+    head_dim)`` array per page layer (zeros; contents only ever read through
+    a length mask, so stale pages are invisible), and the slot layers'
+    leaves, ``(n_slots,) + shape`` each (zeros: the state a sequence starts
+    from; a prefill writes its slot's whole state, so a reused slot starts
+    from its prompt and from nothing else)."""
     shape = (cfg.total_pages, cfg.page_size, cfg.n_heads, cfg.head_dim)
-    return {name: tuple(jnp.zeros(shape, cfg.dtype)
-                        for _ in range(cfg.n_layers))
-            for name in ("k", "v")}
+    cache = {name: tuple(jnp.zeros(shape, cfg.dtype)
+                         for _ in range(cfg.n_page_layers))
+             for name in ("k", "v")}
+    for name, leaf_shape, dtype in cfg.slot_state:
+        cache[name] = tuple(jnp.zeros((cfg.n_slots,) + tuple(leaf_shape), dtype)
+                            for _ in range(cfg.n_slot_layers))
+    return cache
 
 
 class PagePool:
@@ -515,7 +590,7 @@ class PrefixCache:
 # ---------------------------------------------------------------------------
 
 def copy_page(cache: KVCache, src, dst) -> KVCache:
-    """Copy one page's K and V in every layer's pool, ``src`` -> ``dst`` —
+    """Copy one page's K and V in every page layer's pool, ``src`` -> ``dst`` —
     the copy-on-write op for the one partially-shared boundary page of a
     full-prompt prefix hit. ``src``/``dst`` are traced int32 scalars, so
     every (src, dst) pair rides ONE compiled executable; jit with the cache
@@ -523,8 +598,9 @@ def copy_page(cache: KVCache, src, dst) -> KVCache:
     a second pool."""
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
-    return jax.tree_util.tree_map(
-        lambda pages: pages.at[dst].set(pages[src]), cache)
+    return {**cache, **{name: tuple(pages.at[dst].set(pages[src])
+                                    for pages in cache[name])
+                        for name in ("k", "v")}}
 
 
 def paged_write(pages: jax.Array, table: jax.Array, pos: jax.Array,
@@ -687,8 +763,8 @@ def sample_tokens(logits: jax.Array, seeds: jax.Array, token_idx: jax.Array,
 
 
 __all__ = [
-    "KVCacheConfig", "OutOfPages", "PagePool", "PrefixCache", "PrefixMatch",
-    "SCRATCH_PAGE", "copy_page", "decode_attention",
+    "KVCacheConfig", "OutOfPages", "PAGES", "PagePool", "PrefixCache",
+    "PrefixMatch", "SCRATCH_PAGE", "SLOT", "copy_page", "decode_attention",
     "decode_attention_multi", "init_cache", "paged_read", "paged_write",
     "paged_write_multi", "prefill_write", "prefix_block_key",
     "sample_tokens",

@@ -12,6 +12,7 @@ length-prefixed JSON, and both interoperate on one connection
 (docs/serving_protocol.md):
 
     XADD stream payload              -> id
+    XADDM [[stream, payload], ...]   -> [id, ...]   (in order, one wake-up)
     XREADGROUP stream group n block  -> [(id, payload), ...]   (each entry to ONE consumer)
     HSET key mapping / HGET key / HDEL key
     LEN stream / PING / SHUTDOWN / INFO
@@ -56,10 +57,10 @@ from .wire import (MAX_MSG, VERSION as WIRE_VERSION,  # noqa: F401
                    received_trace_context, recv_msg, send_msg,
                    set_wire_model_version, wire_stats)
 
-_KNOWN_CMDS = frozenset({"XADD", "XGROUPCREATE", "XREADGROUP", "XREAD",
-                         "XLAST", "XDELSTREAM", "XTRANSFER", "XACK", "HSET",
-                         "HSETNX", "HGET", "HDEL", "LEN", "PING", "SHMOPEN",
-                         "INFO", "SHUTDOWN"})
+_KNOWN_CMDS = frozenset({"XADD", "XADDM", "XGROUPCREATE", "XREADGROUP",
+                         "XREAD", "XLAST", "XDELSTREAM", "XTRANSFER", "XACK",
+                         "HSET", "HSETNX", "HGET", "HDEL", "LEN", "PING",
+                         "SHMOPEN", "INFO", "SHUTDOWN"})
 # unknown verbs collapse to one label value: client-supplied strings must not
 # mint unbounded counter children in the process-wide registry
 _CMDS = _tm.counter("zoo_broker_commands_total",
@@ -329,6 +330,25 @@ class _Store:
             self._log("A", stream, entry_id, payload)
             self.cond.notify_all()
             return entry_id
+
+    def xadd_many(self, entries: List[Tuple[str, Any]]) -> List[str]:
+        """``xadd`` of every ``(stream, payload)`` in order, under one hold
+        of the lock and with ONE wake-up at the end: the generation sink
+        hands over the frames of a whole decode step (one a live stream) in
+        one round trip, and each blocked ``xread`` wakes once to find its
+        frame, where an ``xadd`` a frame woke every reader of every stream
+        each time."""
+        with self.cond:
+            ids = []
+            for stream, payload in entries:
+                self._seq += 1
+                entry_id = f"{self._seq}-0"
+                self._append(stream, entry_id, payload)
+                self._log("A", stream, entry_id, payload)
+                ids.append(entry_id)
+            if ids:
+                self.cond.notify_all()
+            return ids
 
     def xgroupcreate(self, stream: str, group: str, start: str = "$") -> None:
         """Register a consumer group. ``start='$'`` = only entries added after
@@ -729,6 +749,9 @@ class _Handler(socketserver.BaseRequestHandler):
         SHUTDOWN) return sentinels for :meth:`handle` to act on."""
         if cmd == "XADD":
             return store.xadd(req[1], _stamp_qos(req[2]))
+        if cmd == "XADDM":
+            return store.xadd_many([(stream, _stamp_qos(payload))
+                                    for stream, payload in req[1]])
         if cmd == "XGROUPCREATE":
             store.xgroupcreate(req[1], req[2],
                                req[3] if len(req) > 3 else "$")

@@ -15,7 +15,7 @@ KV cache (:mod:`analytics_zoo_tpu.ops.kv_cache`) and
   requests from ``generation_stream`` (XREADGROUP, same consumer-group
   semantics as the one-shot engine) and streams frame-per-chunk token deltas
   onto a per-request broker stream (``genout:<uri>``) with a final-frame
-  marker, over the binary wire protocol.
+  marker (token ids as plain ints: small frames are cheapest as JSON).
 * :class:`GenerationClient` — ``submit()`` + ``stream()``: the token-delta
   consumer (XREAD cursor reads; broker.py grew the verb for this).
 
@@ -36,8 +36,8 @@ working phases are regions too, their waits are not), and
 beside its wall time. A request's legs are histograms on one fine ladder:
 ingress (client ``submit`` to the engine's source), queue wait (``submit`` to
 leaving the backlog), prefill (to the first token on the host; the two sum to
-``zoo_gen_ttft_seconds``) and egress (a frame handed to the sink until its
-``XADD`` returned; ``zoo_gen_egress_queued_seconds{frame}`` is the part of it
+``zoo_gen_ttft_seconds``) and egress (a frame handed to the sink until the
+round trip that carried it, one ``XADDM`` for all that waited, returned; ``zoo_gen_egress_queued_seconds{frame}`` is the part of it
 spent in the sink's queue).
 """
 
@@ -91,6 +91,16 @@ _GEN_REQS = _tm.counter("zoo_gen_requests_total",
 _GEN_STEPS = _tm.counter("zoo_gen_decode_steps_total",
                          "Multi-slot decode steps executed (counted when the "
                          "step is collected: read and emitted)")
+_GEN_SLOT_STEPS = _tm.counter(
+    "zoo_gen_decode_slot_steps_total",
+    "Live slots summed over the single-token decode steps dispatched (a "
+    "step of 38 live slots adds 38): the rows a step computed, which is what "
+    "a per-slot state update costs by")
+_GEN_LINEAR_PREFILL = _tm.counter(
+    "zoo_gen_linear_prefill_tokens_total",
+    "True prompt tokens (no bucket padding) prefilled into the per-slot "
+    "recurrent state of a model that has such layers (HybridLM's chunked "
+    "scan); stays 0 for a model whose layers all hold pages")
 _GEN_LAUNCHES = _tm.counter(
     "zoo_gen_decode_launches_total",
     "Single-token decode steps dispatched, by order: ahead = launched from "
@@ -241,6 +251,16 @@ _tm.collector("zoo_gen_param_bytes",
                   (g.param_bytes for g in list(_LIVE_GENERATORS)),
                   collections.Counter()).items())],
               labels=("dtype",))
+_tm.collector("zoo_gen_cache_bytes",
+              "Bytes of decode cache live continuous batchers hold on the "
+              "device, by kind: pages (the K and V pools), and for a model "
+              "with per-slot state recurrent (the linear-attention layers' "
+              "matrix states) and conv (their convolution tails)",
+              lambda: [((kind,), float(n)) for kind, n in sorted(sum(
+                  (collections.Counter(g.cfg.bytes_by_kind())
+                   for g in list(_LIVE_GENERATORS)),
+                  collections.Counter()).items())],
+              labels=("kind",))
 _LIVE_ENGINES: "weakref.WeakSet[GenerationEngine]" = weakref.WeakSet()
 _tm.collector("zoo_gen_sink_queue_depth",
               "Frames waiting in the sink queues of live generation engines "
@@ -617,6 +637,25 @@ class ContinuousBatcher:
     reference to the given tree: it is the caller's to drop.
     ``stats()["param_bytes"]`` (``{dtype: bytes}``; ``cli info``;
     ``zoo_gen_param_bytes{dtype}``) says what is being served.
+
+    **A model with per-slot state** (``HybridLM``: its cache description,
+    :class:`~analytics_zoo_tpu.ops.kv_cache.KVCacheConfig`, names layers that
+    keep a fixed-size recurrent state a slot instead of pages). The loop is
+    the same; three things follow from the state being addressed by slot and
+    not through the page table. A prefill is told the slot it fills
+    (``model.prefill(..., slots=)``) and writes that slot's whole state, so a
+    reused slot starts from its prompt and from nothing of the stream before;
+    that write is dispatched after any decode step still in flight on the
+    row, so a step launched ahead for a stream that has since ended cannot
+    reach the next stream's state. A row a step does not step (it ended, it
+    sits a step out for want of a page) is masked to scratch in the
+    dispatched table, and the model leaves such a row's state as it was.
+    And what would need a snapshot or a resume of that state is refused, in
+    words, when the batcher is built: a prefix cache, speculation, chunked
+    prefill; a critical request does not preempt a bulk slot (parked pages
+    would resume in another slot, without the state) and waits for a
+    retirement. ``stats()["cache_bytes"]`` and ``zoo_gen_cache_bytes{kind}``
+    count the state beside the pages.
     """
 
     def __init__(self, model, params, *, n_slots: int = 8,
@@ -672,6 +711,11 @@ class ContinuousBatcher:
         self.cfg, pool = self._pinned(
             model.init_kv_cache, n_slots, page_size=page_size,
             max_seq_len=max_seq_len, n_pages=n_pages)
+        # per-slot state (class docstring): what cannot snapshot it is refused
+        self.slot_state = bool(self.cfg.slot_state)
+        if self.slot_state:
+            self._refuse_for_slot_state(
+                prefix_cache_pages=prefix_cache_pages, spec_k=spec_k)
         # the pool is COMMITTED to the device the served tree lies on (the
         # same buffers, no copy). An executable that holds a shard_map (the
         # flash prefill) returns committed arrays, and everything the pool is
@@ -755,6 +799,7 @@ class ContinuousBatcher:
         if self.spec_k == 1:
             self.spec_k = 0             # k=1 is definitionally plain decode
         self._pending_swap: Optional[Tuple] = None
+        self._preempt_refused = False
         self.version: Optional[str] = None
         self.swaps = 0
         # accounting
@@ -812,6 +857,19 @@ class ContinuousBatcher:
                 model.prefill,
                 p, c, ids, ln, tb, page_size=cfg.page_size),
             donate_argnums=donate)
+        if self.slot_state:
+            # the same, told which slots it fills; without them (the
+            # benchmark's logit probe) slots 0 .. B-1, through the one
+            # executable a bucket that serves
+            prefill_at = jax.jit(
+                lambda p, c, ids, ln, tb, slots: pinned(
+                    model.prefill, p, c, ids, ln, tb, slots=slots,
+                    page_size=cfg.page_size),
+                donate_argnums=donate)
+            self._prefill = lambda p, c, ids, ln, tb, slots=None: prefill_at(
+                p, c, ids, ln, tb,
+                np.arange(len(ln), dtype=np.int32) if slots is None
+                else slots)
         # suffix prefill from the divergence point of a prefix hit (one
         # executable per pow2 suffix bucket, same ladder as _prefill) and
         # the COW boundary-page copy (ONE executable: src/dst are traced)
@@ -849,6 +907,27 @@ class ContinuousBatcher:
         self._threads: List[threading.Thread] = []
         if autostart:
             self.start()
+
+    def _refuse_for_slot_state(self, *, prefix_cache_pages=0,
+                               spec_k=0) -> None:
+        """Raise for an option that assumes the whole cache is pages, for a
+        model that also keeps a recurrent state a slot (chunked prefill is
+        refused before, with every model that has no ``prefill_chunk``)."""
+        kind = type(self.model).__name__
+        state = ", ".join(name for name, _, _ in self.cfg.slot_state)
+        if int(prefix_cache_pages) > 0:
+            raise ValueError(
+                f"prefix_cache_pages={prefix_cache_pages}: {kind} keeps "
+                f"per-slot state ({state}) beside its pages, and a shared "
+                f"prefix's pages do not hold the recurrent state at the "
+                f"prefix's end; prefix reuse needs a snapshot of that state "
+                f"a block, which is not built. Serve it with "
+                f"prefix_cache_pages=0")
+        if int(spec_k) >= 2:
+            raise ValueError(
+                f"spec_k={spec_k}: {kind} keeps per-slot state ({state}); a "
+                f"rejected draft would have to roll that state back, and "
+                f"the model has no verify_step. Serve it with spec_k=0")
 
     # ------------------------------------------------------------- served tree
 
@@ -1154,6 +1233,18 @@ class ContinuousBatcher:
         was freed."""
         if req.priority != "critical":
             return False
+        if self.slot_state:
+            # a parked stream keeps its pages and resumes in ANOTHER slot,
+            # where its recurrent state is not: the request waits for a
+            # retirement (class docstring)
+            if not self._preempt_refused:
+                self._preempt_refused = True
+                logger.warning(
+                    "generation: %s keeps per-slot state; a critical "
+                    "request does not preempt a bulk slot (its state cannot "
+                    "be parked) and waits for a retirement",
+                    type(self.model).__name__)
+            return False
         with self._lock:
             if not any(s is not None and s.request.priority == "bulk"
                        for s in self._slots):
@@ -1356,7 +1447,8 @@ class ContinuousBatcher:
                         "prefix-share write isolation violated: "
                         + "; ".join(f.message for f in findings))
             with _tm.span("serving.gen.prefill", remote=req.ctx, uri=req.uri,
-                          bucket=bucket, cached_tokens=start), \
+                          bucket=bucket, cached_tokens=start,
+                          slot=slot_idx), \
                     clock.phase("prefill_host"):
                 ids = np.zeros((1, bucket), np.int32)
                 ids[0, :n_suffix] = req.prompt[start:]
@@ -1371,7 +1463,9 @@ class ContinuousBatcher:
                 else:
                     logits, self.cache = self._prefill(
                         self.params, self.cache, ids,
-                        np.array([n_prompt], np.int32), table)
+                        np.array([n_prompt], np.int32), table,
+                        *([np.array([slot_idx], np.int32)]
+                          if self.slot_state else []))
                 first = self._sample(
                     logits, np.array([req.seed], np.uint32),
                     np.array([0], np.uint32),
@@ -1404,6 +1498,8 @@ class ContinuousBatcher:
         self.prefill_buckets.add(bucket)
         req.prefill_bucket = str(bucket)
         _GEN_TOKENS.labels(phase="prefill").inc(n_suffix)
+        if self.slot_state:
+            _GEN_LINEAR_PREFILL.inc(n_prompt)
         if start:
             req.cached_prefix_tokens = start
             self.prefix_tokens_saved += start
@@ -1847,6 +1943,7 @@ class ContinuousBatcher:
                 self.launches_drained[reason] += 1
             self._drained_for = None
             _GEN_LAUNCHES.labels(order, reason).inc()
+            _GEN_SLOT_STEPS.inc(len(live))
             self.decode_shapes.add((b, cfg.pages_per_slot, cfg.page_size))
             next_ids, _logits, self.cache = self._decode(
                 self.params, self.cache,
@@ -2178,6 +2275,8 @@ class ContinuousBatcher:
             elif not isinstance(spec, SpecDecodeConfig):
                 raise TypeError(f"spec must be a SpecDecodeConfig or dict, "
                                 f"got {type(spec).__name__}")
+            if self.slot_state:
+                self._refuse_for_slot_state(spec_k=spec.k)
         self._pending_swap = (self._serve_view(params), version, spec)
         self._wake.set()
 
@@ -2339,6 +2438,9 @@ class ContinuousBatcher:
             # the served tree by leaf dtype: under a bf16 policy the matmul
             # weights read bfloat16 here, the rest float32
             "param_bytes": dict(self.param_bytes),
+            # the decode cache by kind: pages, and a model's per-slot state
+            # (recurrent, conv) where it keeps one
+            "cache_bytes": self.cfg.bytes_by_kind(),
             # high-water mark of allocated (non-free) pool pages — the
             # sublinearity evidence for prefix sharing in the bench
             "peak_pages_in_use": self.peak_pages_in_use,
@@ -2397,14 +2499,16 @@ class GenerationEngine:
     Consumes request payloads from ``generation_stream`` and streams token
     deltas as frame-per-chunk entries on ``genout:<uri>``:
 
-        {"sid": uri, "seq": n, "tokens": int32[...], "final": false}
+        {"sid": uri, "seq": n, "tokens": [id, ...], "final": false}
         ...
         {"sid": uri, "seq": n, "tokens": [], "final": true,
          "outcome": "ok"|"error"|"cancelled"|"truncated", "n_tokens": N}
 
     Chunk writes ride a sink thread so the decode loop never blocks on a
-    broker RTT; a request is XACKed only after its final frame is durably in
-    the broker (at-least-once, like the one-shot engine).
+    broker RTT; the sink sends all the frames that wait (a decode step hands
+    over one a live stream) in one round trip (``XADDM``); a request is
+    XACKed only after its final frame is durably in the broker
+    (at-least-once, like the one-shot engine).
 
     ``params`` is handed to a :class:`ContinuousBatcher`, which serves from
     its *served tree* (there: matmul weights cast once to the policy's
@@ -2625,11 +2729,18 @@ class GenerationEngine:
                 hb.beat()
                 try:
                     try:
-                        item = self._sink_q.get_nowait()
+                        items = [self._sink_q.get_nowait()]
                     except queue.Empty:     # only a wait is idle time
                         with clock.phase("idle"):
-                            item = self._sink_q.get(timeout=0.1)
-                    self._write(conn, item)
+                            items = [self._sink_q.get(timeout=0.1)]
+                    # a decode step hands over a frame a live stream at
+                    # once: whatever waits goes out in the same round trip
+                    while len(items) < self._sink_q.maxsize:
+                        try:
+                            items.append(self._sink_q.get_nowait())
+                        except queue.Empty:
+                            break
+                    self._write(conn, items)
                 except queue.Empty:
                     if self._stop.is_set():
                         break
@@ -2641,44 +2752,59 @@ class GenerationEngine:
             hb.stop()
             conn.close()
 
-    def _write(self, conn: _Conn, item: Tuple) -> None:
-        """One turn of the sink: the frame of ``item``, just taken off the
-        queue, to its reply stream, and the request acknowledged once its
-        final frame is in the broker."""
+    def _write(self, conn: _Conn, items: List[Tuple]) -> None:
+        """One turn of the sink: the frames of ``items``, just taken off the
+        queue in order, to their reply streams in ONE round trip (``XADDM``),
+        and the requests acknowledged whose final frame is then in the
+        broker. A round trip a frame is a millisecond of the interpreter a
+        token a stream, and a wake-up of every blocked reader: with most
+        slots live that, and not the chip, bounds what a deployment carries
+        (PERF.md section 6, PR 43)."""
         t_taken = time.perf_counter()
         clock = self._sink_clock
-        kind, entry_id, uri, seq, tokens, meta, final, ctx, t_handed = item
-        if kind == "ack":               # cancel frames carry no reply
-            with clock.phase("ack"):
-                conn.call("XACK", self.stream, self.group, [entry_id])
-            return
+        frames, acks = [], []
         with clock.phase("build"):
-            frame = {"sid": uri, "seq": seq,
-                     "tokens": np.asarray(tokens, np.int32),
-                     "final": bool(final)}
-            if final:
-                frame.update({k: v for k, v in meta.items()
-                              if k in ("outcome", "error", "n_tokens",
-                                       "retry_after_s")})
-            if ctx is not None:
-                frame[TRACE_KEY] = ctx
-        with clock.phase("xadd"):
-            conn.call("XADD", GEN_OUT_PREFIX + uri, frame)
-        t_done = time.perf_counter()
-        which = "first" if seq == 0 else "final" if final else "next"
-        _GEN_EGRESS.observe(t_done - t_handed)
-        _EGRESS_QUEUED[which].observe(t_taken - t_handed)
-        self.frames_written += 1
-        if which != "next":
-            # the two frames a client waits for close the request's trace;
-            # a span a token would push the traces out of the recorder
-            _tm.record_span("serving.gen.egress", t_handed, t_done,
-                            remote=ctx, uri=uri, frame=which,
-                            queued_s=round(t_taken - t_handed, 6))
-        if final:
+            for kind, entry_id, uri, seq, tokens, meta, final, ctx, _ in items:
+                if kind == "ack":       # cancel frames carry no reply
+                    acks.append(entry_id)
+                    continue
+                # token ids as plain ints: a frame without arrays rides the
+                # wire as JSON, which costs the broker and the reader far
+                # less than a binary frame of a token; the client makes the
+                # array
+                frame = {"sid": uri, "seq": seq,
+                         "tokens": np.asarray(tokens, np.int32).tolist(),
+                         "final": bool(final)}
+                if final:
+                    frame.update({k: v for k, v in meta.items()
+                                  if k in ("outcome", "error", "n_tokens",
+                                           "retry_after_s")})
+                    acks.append(entry_id)
+                if ctx is not None:
+                    frame[TRACE_KEY] = ctx
+                frames.append((GEN_OUT_PREFIX + uri, frame))
+        if frames:
+            with clock.phase("xadd"):
+                conn.call("XADDM", frames)
+            t_done = time.perf_counter()
+            for kind, _, uri, seq, _, _, final, ctx, t_handed in items:
+                if kind == "ack":
+                    continue
+                which = "first" if seq == 0 else "final" if final else "next"
+                _GEN_EGRESS.observe(t_done - t_handed)
+                _EGRESS_QUEUED[which].observe(t_taken - t_handed)
+                if which != "next":
+                    # the two frames a client waits for close the request's
+                    # trace; a span a token would push the traces out of the
+                    # recorder
+                    _tm.record_span("serving.gen.egress", t_handed, t_done,
+                                    remote=ctx, uri=uri, frame=which,
+                                    queued_s=round(t_taken - t_handed, 6))
+                self.served_streams += bool(final)
+            self.frames_written += len(frames)
+        if acks:
             with clock.phase("ack"):
-                conn.call("XACK", self.stream, self.group, [entry_id])
-            self.served_streams += 1
+                conn.call("XACK", self.stream, self.group, acks)
 
     def stats(self) -> Dict[str, Any]:
         out = {"served_streams": self.served_streams,
@@ -2761,9 +2887,8 @@ class GenerationClient:
 
     def stream(self, uri: str, timeout_s: float = 60.0):
         """Yield token chunks (int32 ndarrays) for ``uri`` until the final
-        frame; raises on an errored stream. Frame-per-chunk over the binary
-        wire protocol; chunks reassemble in ``seq`` order (the broker stream
-        is ordered). The per-request broker stream is deleted after its
+        frame; raises on an errored stream. Frame-per-chunk; chunks
+        reassemble in ``seq`` order (the broker stream is ordered). The per-request broker stream is deleted after its
         terminal frame is consumed (the streaming twin of OutputQueue's
         HDEL-after-query), so finished streams don't accumulate broker
         state."""

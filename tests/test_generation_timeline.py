@@ -318,7 +318,8 @@ def test_ingress_once_a_request_and_egress_once_a_frame(model_and_params,
             shown = json.loads(capsys.readouterr().out).get("generation", {})
             # a snapshot taken mid-stream holds the first steps only
             if (shown.get("loop_seconds", {}).get("decode_wait", 0) > 0
-                    and shown.get("steps", 0) >= max(n_new) - 1):
+                    and shown.get("steps", 0) >= max(n_new) - 1
+                    and shown["sink"]["queue_depth"] == 0):
                 break
             time.sleep(0.2)
         assert set(shown["loop_seconds"]) == set(gen.LOOP_PHASES)
@@ -453,13 +454,14 @@ def test_the_cpu_clock_is_read_on_one_pass_in_seventeen_and_counted_so(
 
 
 class _SlowXadd:
-    """The sink's connection with an ``XADD`` that takes ``delay_s``."""
+    """The sink's connection with a round trip of frames (``XADDM``) that
+    takes ``delay_s``."""
 
     def __init__(self, conn, delay_s):
         self._conn, self._delay_s = conn, delay_s
 
     def call(self, verb, *args):
-        if verb == "XADD":
+        if verb == "XADDM":
             time.sleep(self._delay_s)
         return self._conn.call(verb, *args)
 
@@ -470,15 +472,15 @@ class _SlowXadd:
 @time_limit(180)
 def test_a_slow_sink_blocks_emit_and_the_loop_holds_that_time(
         model_and_params):
-    """Back-pressure where it happens: with a queue of two frames and an
-    ``XADD`` of 30 ms the decode loop's ``emit`` stands blocked on the full
+    """Back-pressure where it happens: with a queue of two frames and a
+    round trip of 50 ms the decode loop's ``emit`` stands blocked on the full
     queue; the counter, ``stats()["sink"]`` and the loop's own ``emit`` phase
     all say so."""
     broker = start_broker()
     engine = _engine(model_and_params, broker)
     engine._sink_q = queue.Queue(maxsize=2)
     connect = engine._connect
-    engine._connect = lambda tag: (_SlowXadd(connect(tag), 0.03)
+    engine._connect = lambda tag: (_SlowXadd(connect(tag), 0.05)
                                    if tag == "gen.sink" else connect(tag))
     blocked0 = tm.snapshot()["zoo_gen_emit_blocked_seconds_total"][
         "samples"].get("", 0.0)
@@ -496,12 +498,14 @@ def test_a_slow_sink_blocks_emit_and_the_loop_holds_that_time(
         assert n == 16 and depth == 2
         stats = engine.stats()
         blocked = stats["sink"]["emit_blocked_s"]
-        # 18 frames of 30 ms through a queue of two: most of them waited
-        assert blocked > 0.2
+        # 18 frames, two at most a round trip of 50 ms, through a queue of
+        # two: the loop stood blocked for most of the sink's 0.45 s
+        assert blocked > 0.1
         assert tm.snapshot()["zoo_gen_emit_blocked_seconds_total"][
             "samples"][""] - blocked0 == pytest.approx(blocked, abs=1e-3)
         assert stats["loop_seconds"]["emit"] >= blocked
-        assert stats["sink"]["seconds"]["xadd"] >= 17 * 0.03
+        # a round trip carries what the queue held, two frames at most here
+        assert stats["sink"]["seconds"]["xadd"] >= 9 * 0.05
     finally:
         client.close()
         engine.stop()
@@ -526,20 +530,73 @@ def test_a_thousand_next_frames_record_no_span(model_and_params):
         queued0 = _hist("zoo_gen_egress_queued_seconds", "next")
         ctx = {"t": "ab" * 16, "s": "cd" * 8}
         for seq in range(1, 1001):
-            engine._write(Broker(), ("chunk", "1-0", "u", seq, [seq], {},
-                                     False, ctx, time.perf_counter()))
+            engine._write(Broker(), [("chunk", "1-0", "u", seq, [seq], {},
+                                      False, ctx, time.perf_counter())])
             engine._sink_clock.close_pass()
         assert len(tm.spans()) == n_spans
         assert engine.stats()["sink"]["frames"] == 1000
         assert np.subtract(_hist("zoo_gen_egress_queued_seconds", "next"),
                            queued0)[1] == 1000
-        engine._write(Broker(), ("chunk", "1-0", "u", 1001, [], {
+        engine._write(Broker(), [("chunk", "1-0", "u", 1001, [], {
             "outcome": "ok", "n_tokens": 1000}, True, ctx,
-            time.perf_counter()))
+            time.perf_counter())])
         (span,) = tm.spans()[n_spans:]
         assert span.name == "serving.gen.egress"
         assert span.tags["frame"] == "final" and span.trace_id == "ab" * 16
         assert engine.served_streams == 1
+    finally:
+        engine.batcher.close()
+
+
+@time_limit(60)
+def test_a_pass_of_the_sink_is_one_round_trip_for_all_that_waits(
+        model_and_params):
+    """The frames that wait when the sink turns to its queue (a decode step
+    hands over one a live stream) reach the broker in ONE ``XADDM``, in the
+    order they were handed over; the finals among them and a cancel's
+    acknowledgement share one ``XACK`` after it; each frame is observed
+    once, with the round trip it rode as its egress."""
+    calls = []
+
+    class Conn:
+        def call(self, verb, *args):
+            calls.append((verb,) + args)
+
+    class Broker:
+        port = 1                        # nothing connects to it
+
+    engine = _engine(model_and_params, Broker)
+    try:
+        engine._sink_clock.begin()      # this thread is the sink now
+        egress0 = _hist("zoo_gen_egress_seconds")
+        now = time.perf_counter()
+        engine._write(Conn(), [
+            ("chunk", "1-0", "a", 0, [5], {}, False, None, now),
+            ("chunk", "2-0", "b", 7, [6, 7], {}, False, None, now),
+            ("ack", "9-0", "c", 0, [], {}, False, None, None),
+            ("chunk", "1-0", "a", 1, [8], {}, False, None, now),
+            ("chunk", "2-0", "b", 8, [], {"outcome": "ok", "n_tokens": 9,
+                                         "left": "out"}, True, None, now)])
+        engine._sink_clock.close_pass()
+        (xaddm, frames), (xack, stream, group, ids) = calls
+        assert (xaddm, xack) == ("XADDM", "XACK")
+        assert [(key, f["seq"], f["tokens"], f["final"])
+                for key, f in frames] == [
+            ("genout:a", 0, [5], False), ("genout:b", 7, [6, 7], False),
+            ("genout:a", 1, [8], False), ("genout:b", 8, [], True)]
+        assert frames[3][1]["n_tokens"] == 9 and "left" not in frames[3][1]
+        # plain ints: the frames ride the wire as JSON
+        json.dumps(frames)
+        assert (stream, group, ids) == (engine.stream, engine.group,
+                                        ["9-0", "2-0"])
+        assert np.subtract(_hist("zoo_gen_egress_seconds"), egress0)[1] == 4
+        assert engine.stats()["sink"]["frames"] == 4
+        assert engine.served_streams == 1
+        # nothing but acknowledgements: no empty XADDM
+        del calls[:]
+        engine._write(Conn(), [("ack", "3-0", "d", 0, [], {}, False, None,
+                                None)])
+        assert [c[0] for c in calls] == ["XACK"]
     finally:
         engine.batcher.close()
 

@@ -148,6 +148,48 @@ def test_decode_step_of_the_serving_cell_reads_the_served_tree(v5e,
     assert compiled.as_text().count("zoo_paged_attention") >= blocks
 
 
+# Olmo-Hybrid's serving cell (gen-olmoh-reason-steady): 48 slots, 30 linear
+# heads of key 96 and value 192 (a slot's state is one 96 x 5,760 float32
+# block, aliased in to out), prompts of 32 to 1,024 tokens in chunks of 64;
+# and the paged kernel at the 32 head slots its full layers' pools hold (the
+# 30 heads themselves Mosaic refuses: a slice of 30 on an axis tiled by 8)
+def test_gated_delta_decode_at_the_serving_shape(v5e):
+    from analytics_zoo_tpu.ops.gated_delta import gdn_decode
+
+    slots, h, dk, dv = 48, 30, 96, 192
+    compiled = v5e(
+        lambda *a: gdn_decode(*a, interpret=False),
+        ((slots, dk, h * dv), F32), ((slots, h, dk), F32),
+        ((slots, h, dk), F32), ((slots, h, dv), F32), ((slots, h), F32),
+        ((slots, h), F32), ((slots,), jnp.bool_), donate_argnums=(0,))
+    assert "zoo_gdn_decode" in compiled.as_text()
+    # the state is updated where it lies: no second copy of it
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == slots * dk * h * dv * 4
+
+
+@pytest.mark.parametrize("tokens", [32, 1024])
+def test_gated_delta_chunk_scan_at_the_serving_buckets(v5e, tokens):
+    from analytics_zoo_tpu.ops.gated_delta import gated_delta_chunked
+
+    h, dk, dv = 30, 96, 192
+    qk, v, gate = (((1, tokens, h, dk), F32), ((1, tokens, h, dv), F32),
+                   ((1, tokens, h), F32))
+    text = v5e(lambda *a: gated_delta_chunked(*a, kernel=True,
+                                              interpret=False),
+               qk, qk, v, gate, gate).as_text()
+    assert "zoo_gdn_chunk_fwd" in text
+
+
+def test_paged_attention_at_the_hybrid_cells_pool(v5e):
+    pool = ((3584, 16, 32, 128), BF16)
+    text = v5e(lambda q, k, v, tb, ln: paged_attention(
+        q, k, v, tb, ln, page_size=16, interpret=False),
+        ((48, 1, 32, 128), BF16), pool, pool, ((48, 256), I32),
+        ((48,), I32)).as_text()
+    assert "zoo_paged_attention" in text
+
+
 @pytest.mark.parametrize("m", [1, 16, 512])
 def test_fused_int8_matmul_at_the_mlp_shapes(v5e, m):
     v5e(lambda x, q, s: int8_fused.int8_matmul_fused(

@@ -67,6 +67,36 @@ def test_broker_stream_and_hash(broker):
     c.close()
 
 
+def test_broker_xaddm_appends_in_order_with_one_wake_up(broker):
+    """``XADDM``: every ``(stream, payload)`` appended in order under ids
+    that rise, readers of each stream see their own entries, and a reader
+    blocked in ``XREAD`` wakes for the batch that holds its entry."""
+    from analytics_zoo_tpu.serving.client import _Conn
+
+    c = _Conn("127.0.0.1", broker.port)
+    woke = []
+
+    def blocked():
+        r = _Conn("127.0.0.1", broker.port)
+        woke.append(r.call("XREAD", "m:b", 0, 64, 5000))
+        r.close()
+
+    t = threading.Thread(target=blocked)
+    t.start()
+    ids = c.call("XADDM", [["m:a", {"v": 1}], ["m:b", {"v": 2}],
+                           ["m:a", {"v": 3}]])
+    t.join(timeout=10)
+    assert len(ids) == 3 and \
+        [int(i.split("-")[0]) for i in ids] == sorted(
+            int(i.split("-")[0]) for i in ids)
+    assert woke == [[1, [[ids[1], {"v": 2}]]]]
+    cursor, got = c.call("XREAD", "m:a", 0, 64, 0)
+    assert cursor == 2 and [p["v"] for _, p in got] == [1, 3]
+    assert c.call("XADDM", []) == []
+    assert c.call("INFO")["commands"]["XADDM"] == 2
+    c.close()
+
+
 def test_serving_end_to_end(zoo_ctx, broker, fitted):
     model, x = fitted
     cfg = ServingConfig(batch_size=8, concurrent_num=2,

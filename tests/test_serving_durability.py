@@ -256,6 +256,22 @@ def test_store_idle_reclaim_never_double_delivers_redeliver_entries(tmp_path):
     assert s2.xreadgroup("in", "g", 10, 0) == []
 
 
+def test_store_xadd_many_is_logged_an_entry_a_record(tmp_path):
+    """A batch of appends is as durable as the same appends one by one: the
+    log holds a record an entry, and a restart replays them in order."""
+    from analytics_zoo_tpu.serving.broker import _Store
+
+    aof = str(tmp_path / "s.aof")
+    s = _Store(aof_path=aof)
+    ids = s.xadd_many([("a", {"v": 0}), ("b", {"v": 1}), ("a", {"v": 2})])
+    one = s.xadd("a", {"v": 3})
+    s2 = _Store(aof_path=aof)
+    assert s2.xread("a", 0, 10, 0) == (3, [
+        (ids[0], {"v": 0}), (ids[2], {"v": 2}), (one, {"v": 3})])
+    assert s2.xread("b", 0, 10, 0) == (1, [(ids[1], {"v": 1})])
+    assert s2.xadd("a", {"v": 4}) not in ids + [one]
+
+
 def test_store_pending_payload_survives_maxlen_trim_and_rewrite(tmp_path):
     """ADVICE r3: a delivered-but-unacked entry trimmed out of the live stream
     by maxlen overflow must still be redeliverable after a restart (its payload
