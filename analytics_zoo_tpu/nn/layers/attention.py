@@ -20,7 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...ops.attention import full_attention, sharded_attention
+from ...ops.attention import (count_route, full_attention,
+                              prefer_flash_single_device, sharded_attention)
 from ..activations import get_activation
 from ..module import Layer, as_compute, get_initializer, param_dtype
 from .normalization import LayerNormalization
@@ -90,19 +91,24 @@ class MultiHeadAttention(Layer):
         return o @ jnp.asarray(params["out_kernel"], dtype) + jnp.asarray(
             params["out_bias"], dtype)
 
-    def _attend(self, q, k, v, t):
-        """Strategy dispatch shared by ``apply`` and ``apply_with_kv``."""
+    def _attend(self, q, k, v, t, training=False):
+        """Strategy dispatch shared by ``apply`` and ``apply_with_kv``.
+        ``training`` says that a backward pass follows the call."""
         mesh = self._mesh()
         if mesh is not None and self.attn_strategy != "full":
             return sharded_attention(q, k, v, mesh,
                                      strategy=self.attn_strategy,
-                                     causal=self.causal)
-        if self._flash_single_device(t):
+                                     causal=self.causal, backward=training)
+        flash = self._flash_single_device(t, training,
+                                          q.shape[0] * q.shape[2])
+        count_route(flash, training)
+        if flash:
             # no mesh context: an explicit 'flash' means the kernel (a
             # length its tiles cannot cover is an error naming the shape),
-            # and 'auto' prefers it on TPU from 2k tokens, where a
-            # pre-PR-1 measurement had it level with XLA full attention,
-            # faster from 4k up, and the only option past 16k where the
+            # and 'auto' prefers it on TPU from the length at which it beats
+            # XLA full attention, which is shorter when a backward follows
+            # and the batch is large (ops.attention.FLASH_FROM_TOKENS,
+            # FLASH_BACKWARD_*); past 16k it is the only option, where the
             # (H, T, T) scores OOM
             from ...ops.flash_attention import flash_attention
 
@@ -112,7 +118,7 @@ class MultiHeadAttention(Layer):
     def apply(self, params, state, x, *, training=False, rng=None):
         x = as_compute(x)
         q, k, v = self.qkv_proj(params, x)
-        o = self._attend(q, k, v, x.shape[1])
+        o = self._attend(q, k, v, x.shape[1], training)
         return self.out_proj(params, o, x.dtype), state
 
     def apply_with_kv(self, params, x):
@@ -124,7 +130,8 @@ class MultiHeadAttention(Layer):
         o = self._attend(q, k, v, x.shape[1])
         return self.out_proj(params, o, x.dtype), k, v
 
-    def _flash_single_device(self, t: int) -> bool:
+    def _flash_single_device(self, t: int, training: bool = False,
+                             batch_heads: int = 1) -> bool:
         if t <= 1:
             # single-query decode step: flash tiling is pure overhead at
             # query length 1 — plain dot attention regardless of strategy
@@ -132,9 +139,7 @@ class MultiHeadAttention(Layer):
         if self.attn_strategy == "flash":
             return True
         if self.attn_strategy == "auto":
-            from ...ops.attention import prefer_flash_single_device
-
-            return prefer_flash_single_device(t)
+            return prefer_flash_single_device(t, training, batch_heads)
         return False
 
     def _mesh(self):

@@ -103,8 +103,9 @@ class QKNormAttention(Layer):
         self.head_dim = hidden_size // n_head
         self.pool_heads = -(-n_head // 8) * 8
         self.epsilon = epsilon
-        # the prefill's routing (XLA full attention, the flash kernel from 2k
-        # tokens on a TPU, sequence-parallel forms under a mesh) is
+        # the routing (XLA full attention, the flash kernel on a TPU from 2k
+        # tokens in a prefill and from fewer when a backward follows a large
+        # batch, sequence-parallel forms under a mesh) is
         # MultiHeadAttention's, used and not copied
         self._route = MultiHeadAttention(hidden_size, n_head, causal=True,
                                          attn_strategy=attn_strategy,
@@ -144,8 +145,8 @@ class QKNormAttention(Layer):
     def apply(self, params, state, x, *, training=False, rng=None):
         x = as_compute(x)
         q, k, v = self._qkv(params, x)
-        return self._out(params, self._route._attend(q, k, v, x.shape[1])), \
-            state
+        o = self._route._attend(q, k, v, x.shape[1], training)
+        return self._out(params, o), state
 
     def prefill(self, params, x, cache, at: StepContext):
         from ...ops.kv_cache import prefill_write

@@ -35,6 +35,7 @@ from jax import shard_map
 from jax.lax import axis_size
 from jax.sharding import PartitionSpec as P
 
+from ..common import telemetry as _tm
 from .backend import interpret_default
 
 NEG_INF = -1e30
@@ -499,14 +500,40 @@ def ulysses_attention_local(q, k, v, *, axis_name: str = "sp",
     return a2a(o, 1, 2)
 
 
-def prefer_flash_single_device(t: int) -> bool:
+#: ``auto`` on one TPU device takes the pallas kernel from this many tokens,
+#: whatever the batch and whether or not a backward pass follows ...
+FLASH_FROM_TOKENS = 2048
+#: ... and, when a backward pass follows, at a shorter length too if the
+#: kernel's largest tile divides it and the (B, H, T, T) scores have at least
+#: this many elements a device. XLA's form writes, keeps and re-reads that
+#: tensor for the backward: under 2**26 elements (128 MiB in bf16, a v5e's
+#: VMEM) it does so faster than the kernel recomputes the scores, from there
+#: on slower by a fifth or more, at head 64 and at head 128 alike; on tiles
+#: of 128 or 256 (256, 640, 768, 896 tokens) the kernel loses at any batch.
+#: Read from ``scripts/attention_routes.py`` on a v5e (PERF.md
+#: section 6, PR 44).
+FLASH_BACKWARD_TILE = 512
+FLASH_BACKWARD_SCORES = 2 ** 26
+
+_ROUTES = _tm.counter(
+    "zoo_attention_route_total",
+    "Attention calls traced on one device (no mesh, or sp == 1), by the "
+    "route taken and whether a backward pass follows",
+    labels=("route", "backward"))
+
+
+def prefer_flash_single_device(t: int, backward: bool = False,
+                               batch_heads: int = 1) -> bool:
     """Auto-dispatch rule shared by the layer (mesh-less) and
-    :func:`sharded_attention` (sp==1) paths, so both resolve identically:
-    on TPU the pallas kernel beat XLA full attention from 4k up and matched
-    it at 2k at the model level (a sweep made before PR 1; not measured on
-    the current code), and is the only option once the (H, T, T) score
-    tensor would OOM. A length the flash tiles do not divide stays on full
-    attention.
+    :func:`sharded_attention` (sp==1) paths, so both resolve identically.
+    On TPU the pallas kernel from :data:`FLASH_FROM_TOKENS` tokens up; a call
+    that will be differentiated (``backward``: the layer passes its
+    ``training`` flag) takes it at any multiple of
+    :data:`FLASH_BACKWARD_TILE` tokens if its ``batch_heads`` (batch x heads
+    on one device) make the score tensor :data:`FLASH_BACKWARD_SCORES`
+    elements or more. Past 16k the kernel is the only option, since the
+    (H, T, T) score tensor would OOM. A length the flash tiles do not divide
+    stays on full attention.
 
     Query length 1 — the KV-cache decode step — is excluded UNCONDITIONALLY
     (not just by the threshold): a single query row has nothing to tile, so
@@ -516,17 +543,42 @@ def prefer_flash_single_device(t: int) -> bool:
         return False
     from .flash_attention import tiles_ok
 
-    return jax.default_backend() == "tpu" and t >= 2048 and tiles_ok(t, t)
+    if jax.default_backend() != "tpu" or not tiles_ok(t, t):
+        return False
+    return t >= FLASH_FROM_TOKENS or (
+        backward and t % FLASH_BACKWARD_TILE == 0
+        and batch_heads * t * t >= FLASH_BACKWARD_SCORES)
+
+
+def count_route(flash: bool, backward: bool) -> None:
+    """One traced single-device attention call took this route (the route is
+    chosen while tracing, so this counts traces, not runs)."""
+    _ROUTES.labels(route="flash" if flash else "full",
+                   backward="1" if backward else "0").inc()
+
+
+def _rows_a_device(n: int, mesh, axes) -> int:
+    """How many of ``n`` rows split over the mesh ``axes`` one device holds.
+    An axis that is Manual in the context (the caller is inside a
+    ``shard_map`` over it) has split them already."""
+    manual = jax.sharding.get_abstract_mesh().manual_axes
+    for a in axes:
+        if a not in manual:
+            n = -(-n // mesh.shape[a])
+    return n
 
 
 def sharded_attention(q, k, v, mesh, *, strategy: str = "auto",
                       causal: bool = False, seq_axis: str = "sp",
-                      batch_axes=("dp", "fsdp"), head_axis: str = "tp"):
+                      batch_axes=("dp", "fsdp"), head_axis: str = "tp",
+                      backward: bool = False):
     """Dispatch attention under the global mesh (called inside jit).
 
     With ``sp > 1`` wraps the chosen sequence-parallel kernel in a shard_map whose
     specs shard batch over dp/fsdp, sequence over sp, heads over tp — so tensor and
-    sequence parallelism compose.
+    sequence parallelism compose. ``backward`` says that the call will be
+    differentiated: with ``sp == 1`` ``auto`` then takes the flash kernel from
+    a shorter sequence (:func:`prefer_flash_single_device`).
     """
     if strategy not in ("auto", "full", "flash", "ring", "zigzag", "ulysses"):
         raise ValueError(f"unknown attention strategy {strategy!r}; "
@@ -540,8 +592,13 @@ def sharded_attention(q, k, v, mesh, *, strategy: str = "auto",
             strategy = ("zigzag" if causal and _zigzag_ok(q.shape[1], sp)
                         else "ring")
         else:
-            strategy = ("flash" if prefer_flash_single_device(q.shape[1])
-                        else "full")
+            strategy = ("flash" if prefer_flash_single_device(
+                q.shape[1], backward,
+                _rows_a_device(q.shape[0], mesh, batch_axes)
+                * _rows_a_device(q.shape[2], mesh, (head_axis,)))
+                else "full")
+    if sp == 1:
+        count_route(strategy == "flash", backward)
     if strategy == "flash":
         if sp > 1:
             raise ValueError(
@@ -555,11 +612,15 @@ def sharded_attention(q, k, v, mesh, *, strategy: str = "auto",
         # (without it GSPMD would all-gather q/k/v and replicate the work).
         # shard_map needs exact divisibility; shapes that don't split fall
         # back to the unwrapped kernel (GSPMD handles them, possibly with
-        # gathers — correct, just not maximally parallel).
+        # gathers — correct, just not maximally parallel). Inside a caller's
+        # shard_map over these axes (the ZeRO-1 flat path) q/k/v are this
+        # device's shard already, and a second shard_map does not trace.
         batch_div = 1
         for a in batch_axes:
             batch_div *= mesh.shape[a]
-        if q.shape[0] % batch_div or q.shape[2] % mesh.shape[head_axis]:
+        manual = jax.sharding.get_abstract_mesh().manual_axes
+        if (all(a in manual for a in (*batch_axes, head_axis))
+                or q.shape[0] % batch_div or q.shape[2] % mesh.shape[head_axis]):
             return flash_attention(q, k, v, causal)
         spec = P(batch_axes, None, head_axis, None)
         wrapped = shard_map(

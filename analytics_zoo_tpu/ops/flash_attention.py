@@ -41,7 +41,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -329,12 +328,17 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
+                                             "interpret"))
 def _flash_bwd(q, k, v, o, lse, g, *, causal: bool, block_q: int,
                block_k: int, interpret: bool):
-    """Tiled flash backward: dq/dk/dv pallas kernels from the saved lse."""
+    """Tiled flash backward: dq/dk/dv pallas kernels from the saved lse.
+    Jitted as :func:`_flash_fwd` is, and for its reason: a 24-block model's
+    training step held 48 copies of the two kernels and started 10 s later
+    on a warm compile cache (PR 44, ``PERF.md``)."""
     b, t_q, h, d = q.shape
     t_k = k.shape[1]
-    scale = 1.0 / float(np.sqrt(d))
+    scale = 1.0 / math.sqrt(d)
     qh = q.transpose(0, 2, 1, 3).reshape(b * h, t_q, d)
     kh = k.transpose(0, 2, 1, 3).reshape(b * h, t_k, d)
     vh = v.transpose(0, 2, 1, 3).reshape(b * h, t_k, d)

@@ -481,13 +481,15 @@ def test_auto_routes_single_query_to_plain_dot(monkeypatch):
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert attn_ops.prefer_flash_single_device(1) is False
+    assert attn_ops.prefer_flash_single_device(1, backward=True) is False
     assert attn_ops.prefer_flash_single_device(4096) is True
     mha_auto = MultiHeadAttention(8, 2, attn_strategy="auto")
     mha_flash = MultiHeadAttention(8, 2, attn_strategy="flash")
     # decode step (T=1): plain dot regardless of strategy — flash tiling is
     # pure overhead at query length 1
-    assert mha_auto._flash_single_device(1) is False
-    assert mha_flash._flash_single_device(1) is False
+    for training in (False, True):
+        assert mha_auto._flash_single_device(1, training) is False
+        assert mha_flash._flash_single_device(1, training) is False
 
 
 def test_auto_prefill_still_prefers_flash_at_long_t(monkeypatch):
@@ -501,8 +503,14 @@ def test_auto_prefill_still_prefers_flash_at_long_t(monkeypatch):
     assert mha._flash_single_device(4096) is True
     assert mha._flash_single_device(2048) is True
     assert mha._flash_single_device(512) is False      # below the threshold
+    # a prefill is a forward alone: at 1,024 tokens it stays on XLA full
+    # attention, where a training call takes the kernel
+    assert mha._flash_single_device(1024, batch_heads=64) is False
+    assert mha._flash_single_device(1024, training=True,
+                                    batch_heads=64) is True
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     assert attn_ops.prefer_flash_single_device(4096) is False
+    assert attn_ops.prefer_flash_single_device(4096, backward=True) is False
 
 
 # ------------------------------------------------ satellites: decode lint

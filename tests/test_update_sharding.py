@@ -274,6 +274,29 @@ def test_small_model_is_one_bucket_one_exchange(zoo_ctx):
     assert census["counts"]["all-gather"] == 1
 
 
+def test_flat_step_traces_the_flash_kernel_at_a_batch_dp_divides(zoo_ctx):
+    """Inside the flat step's ``shard_map`` a device holds its rows of the
+    batch already: when dp divides that many rows too (8 a chip on dp=8),
+    ``sharded_attention`` must call the kernel on them as they are and not
+    wrap it in a second ``shard_map``, which does not trace (ROADMAP C2)."""
+    from analytics_zoo_tpu.data import FeatureSet
+    from analytics_zoo_tpu.models.transformer import TransformerLM, lm_loss
+    from analytics_zoo_tpu.ops.attention import _ROUTES
+
+    model = TransformerLM(vocab=64, hidden_size=32, n_block=2, n_head=2,
+                          seq_len=128, attn_strategy="flash")
+    est = Estimator(model, optimizer=Adam(lr=1e-3), loss=lm_loss,
+                    mesh=zoo_ctx.mesh,
+                    config=TrainConfig(update_sharding=True,
+                                       log_every_n_steps=1))
+    ids = np.random.default_rng(0).integers(0, 64, (64, 128)).astype(np.int32)
+    before = _ROUTES.labels(route="flash", backward="1").value()
+    est.fit(FeatureSet.from_numpy(ids, ids), batch_size=64, epochs=1)
+    assert est._update_mode() == "flat"
+    assert np.isfinite(float(est.trainer_state.last_loss))
+    assert _ROUTES.labels(route="flash", backward="1").value() - before == 2
+
+
 def test_flat_meta_cuts_equal_buckets_from_the_leaves():
     """Bucket geometry: every leaf starts on a row of the flat view (padded
     to whole rows), buckets are equal runs of rows, a bucket is stacked from
