@@ -11,6 +11,10 @@ that guard it:
   ``--update-sharding`` gate and the HLO-layer rule run on it). Counts
   *instruction definitions* only, so operand mentions don't double-count;
   also recognizes lowered StableHLO spellings.
+* :func:`entry_ops` — the compiled program's top-level ops by kind, with
+  the bytes each kind writes: what a layout costs in copies of its own
+  (standalone ``reshape``, ``concatenate``, ``slice``, ``convert``) beside
+  the collectives it asked for.
 * :func:`jaxpr_collective_counts` — the trace-time counter
   (``TrainConfig.graph_checks`` runs before anything compiles). Primitive
   names are normalized to the HLO spellings so one ``expect_collectives``
@@ -21,6 +25,7 @@ that guard it:
 
 from __future__ import annotations
 
+import math
 import re
 from collections import Counter
 from typing import Dict, Iterable, List
@@ -69,6 +74,38 @@ def collective_counts(hlo_text: str) -> Dict[str, int]:
         if m:
             out[m.group(1).replace("_", "-")] += 1
     return dict(out)
+
+
+_ENTRY_OP_RE = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = (.+?) ([\w\-]+)\(")
+_SHAPE_RE = re.compile(r"\b(pred|[suf]\d+|bf16)\[([\d,]*)\]")
+
+
+def shape_bytes(shape_text: str) -> int:
+    """Bytes an HLO result type holds, e.g. ``f32[8,128]{1,0}`` or a tuple
+    of such (every array shape in the text is summed)."""
+    size = 0
+    for dtype, dims in _SHAPE_RE.findall(shape_text):
+        bits = 8 if dtype == "pred" else int(dtype.lstrip("sufb"))
+        size += bits // 8 * math.prod(int(d) for d in dims.split(",") if d)
+    return size
+
+
+def entry_ops(hlo_text: str) -> Dict[str, List[int]]:
+    """``{kind: [count, output bytes]}`` of the ops of compiled HLO's
+    ``ENTRY`` computation (a fusion counts as ``fusion``, its body is not
+    opened). The bytes are what the ops' result shapes hold, tuples summed;
+    they are not a time, and an op that updates a buffer in place counts
+    the whole buffer."""
+    entry = hlo_text[hlo_text.index("ENTRY"):]
+    out: Dict[str, List[int]] = {}
+    for line in entry[:entry.index("\n}")].splitlines():
+        m = _ENTRY_OP_RE.match(line)
+        if m:
+            tally = out.setdefault(m.group(2), [0, 0])
+            tally[0] += 1
+            tally[1] += shape_bytes(m.group(1))
+    return out
 
 
 def jaxpr_collective_counts(closed_jaxpr) -> Dict[str, Dict[str, int]]:

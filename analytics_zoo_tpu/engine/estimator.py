@@ -58,6 +58,10 @@ _STEPS = _tm.counter("zoo_train_steps_total", "Optimizer steps run")
 _UPDATE_BUCKETS = _tm.gauge("zoo_train_update_buckets",
                             "Buckets of the ZeRO-1 flat exchange (one "
                             "reduce-scatter + one all-gather each per step)")
+_UPDATE_OWN_ROWS = _tm.gauge("zoo_train_update_own_rows_share",
+                             "Share of the parameters that enter the flat "
+                             "exchange's buckets by their own rows (cut "
+                             "into column blocks, not raveled and re-cut)")
 _DATA_WAIT = _tm.histogram("zoo_train_data_wait_seconds",
                            "Per-step host wait on the input pipeline")
 _COMPUTE = _tm.histogram("zoo_train_compute_seconds",
@@ -336,9 +340,11 @@ class Estimator:
                 self._base_tx, p, meta,
                 keep_master=self._mp_dtype is not None))(params)
             _UPDATE_BUCKETS.set(meta.n_buckets)
+            _UPDATE_OWN_ROWS.set(meta.own_rows_share)
             logger.info("update sharding: flat over dp=%d, %d parameters in "
-                        "%d bucket(s) of %d x %d", meta.n_shards, meta.n,
-                        meta.n_buckets, *meta.bucket_shape)
+                        "%d bucket(s) of %d x %d, %.3f of them by their own "
+                        "rows", meta.n_shards, meta.n, meta.n_buckets,
+                        *meta.bucket_shape, meta.own_rows_share)
         else:
             opt_state = self.tx.init(params)
         state = {
@@ -1357,7 +1363,8 @@ class Estimator:
         and placement, and take its iteration/epoch. Under flat update
         sharding the optimizer state must be in this build's bucket layout:
         a one-bucket state in the older ``(npad,)`` layout is re-padded, any
-        other layout is refused rather than read as this one."""
+        other layout (other buckets, or a view in which other leaves enter
+        by their own rows) is refused rather than read as this one."""
         flat = self._update_mode() == "flat"
         try:
             restored, meta = ckpt.load_checkpoint(path, self.train_state)
@@ -1368,11 +1375,13 @@ class Estimator:
         except ValueError as e:
             if not flat:
                 raise
+            own = ("" if self._flat_meta.layout is None
+                   else ", matrices by their own rows")
             raise ValueError(
                 f"{path} does not fit the flat update-sharding state "
                 f"({self._flat_meta.n_buckets} bucket(s) of "
-                f"{self._flat_meta.bucket_shape}): {e}. Resume it with the "
-                f"version that wrote it, or load the params alone.") from e
+                f"{self._flat_meta.bucket_shape}{own}): {e}. Resume it with "
+                f"the version that wrote it, or load the params alone.") from e
         self.train_state = self._place_state(restored)
         self.trainer_state.iteration = meta["iteration"]
         self.trainer_state.epoch = meta["epoch"]

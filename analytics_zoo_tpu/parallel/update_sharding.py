@@ -16,22 +16,28 @@ path) shrinks to ``1/dp`` per device.
 Two implementations, selected by the training engine:
 
 * **flat** (pure-dp mesh) — the BigDL layout, literally: inside ``shard_map``
-  the gradient pytree is viewed as one f32 matrix, the raveled leaves in
-  tree-flatten order each padded to whole rows, cut into equal **buckets** of
-  rows (:func:`flat_meta`; about one transformer block each, exactly one for
-  a model that fits one). Per bucket, ``psum_scatter`` hands each replica
-  its column block, the optimizer updates that shard against the bucket's
-  (sharded) optimizer state, and one tiled ``all_gather`` rebuilds the
-  bucket's replicated params. A bucket is stacked from the row blocks of the
-  leaves it covers, never sliced out of the whole view, so it depends on
-  those gradients alone and its all-gather can run under the next bucket's
-  reduction and update; one whole-vector exchange could start only after
-  the LAST gradient and ran wholly exposed (three tenths of the step on four
-  v5e chips). All buckets go through the same two jitted functions, so the
-  traced step defines each collective once. The collective count per
-  *global* step is structural — gradient accumulation scans microbatches
-  over device-local grads, so K microbatches still cost exactly one
-  reduce-scatter + one all-gather per bucket.
+  the gradient pytree is viewed as one f32 matrix, every leaf taking whole
+  rows of it, cut into equal **buckets** of rows (:func:`flat_meta`; about
+  one transformer block each, exactly one for a model that fits one). Per
+  bucket, ``psum_scatter`` hands each replica its column block, the
+  optimizer updates that shard against the bucket's (sharded) optimizer
+  state, and one tiled ``all_gather`` rebuilds the bucket's replicated
+  params. A bucket is stacked from the row blocks of the leaves it covers,
+  never sliced out of the whole view, so it depends on those gradients alone
+  and its all-gather can run under the next bucket's reduction and update;
+  one whole-vector exchange could start only after the LAST gradient and ran
+  wholly exposed (three tenths of the step on four v5e chips). In a model of
+  several buckets the view is as wide as the parameter matrices' own minor
+  dimension (or a divisor of it), so a matrix enters by **its own rows**:
+  whole column blocks of whole lane tiles, cut and stacked, where a raveled
+  and re-cut matrix is re-tiled element by element on its way into the
+  bucket and again on its way out (a matrix of an odd width, a head of
+  50,257 columns, still is: row by row, in a ``while`` loop). All buckets
+  have one shape and go through the same two jitted
+  functions, so the traced step defines each collective once. The
+  collective count per *global* step is structural — gradient accumulation
+  scans microbatches over device-local grads, so K microbatches still cost
+  exactly one reduce-scatter + one all-gather per bucket.
 * **gspmd** (meshes that also shard params over ``fsdp``/``tp``) —
   :func:`make_update_sharding` extends the per-leaf
   :func:`~analytics_zoo_tpu.parallel.sharding.make_param_sharding` specs with a
@@ -148,22 +154,36 @@ SHARD_COLS = 1024
 #: compiler falls back to the all-reduce when it cannot cut the rows into
 #: chunks (11,512 = 8 x 1,439 rows, 1,439 prime, did; 11,520 did not).
 SHARD_ROWS_MULTIPLE = 128
+#: Lanes of one tile of the chip's memory: a matrix enters the view by its
+#: own rows only where a shard of one of its column blocks is whole tiles.
+LANES = 128
+#: Rows of one tile at the narrowest parameter dtype (bf16: 16 rows): the
+#: matrices whose row count is a multiple take the view's first rows, so
+#: every block of theirs starts on a tile.
+ROW_TILE = 16
 
 
 class FlatParamMeta(NamedTuple):
     """Static flattening layout of a param pytree (BigDL AllReduceParameter's
     flat-vector view): leaf order/shapes/dtypes, and the cut of the flat view
     into ``n_buckets`` equal buckets. The flat view is a matrix of
-    ``n_shards * cols`` columns in which leaf ``i``, raveled and zero-padded
-    to whole rows, takes ``leaf_rows[i]`` rows, leaves following one another
-    in tree-flatten order; a bucket is ``shard_shape[0]`` consecutive rows
-    (the last one zero-padded), exchanged and its state held as that
-    ``bucket_shape`` matrix; replica ``i`` owns column block ``i``
-    (``shard_shape``) of every bucket. Every leaf starts on a row, so a
-    bucket is stacked from whole row blocks of the leaves it covers; a
-    bucket concatenated from 1-D pieces had to be re-tiled into its matrix,
-    which cost the four-chip cell a tenth of its exchange and half the
-    step's compile time."""
+    ``n_shards * cols`` columns in which leaf ``i`` takes ``leaf_rows[i]``
+    rows, the leaves following one another in ``order``; a bucket is
+    ``shard_shape[0]`` consecutive rows (the last one zero-padded), exchanged
+    and its state held as that ``bucket_shape`` matrix; replica ``i`` owns
+    column block ``i`` (``shard_shape``) of every bucket.
+
+    A leaf's rows are its **row matrix**. Where ``col_blocks[i]`` is 0 that
+    is the raveled leaf, zero-padded to whole rows. Where it is ``k`` the
+    leaf (of two or more dimensions, seen as the matrix of its minor one) is
+    ``k`` times as wide as the view and enters by its own rows: its ``k``
+    column blocks one below the other, each row of the view a piece of one
+    row of the leaf, so nothing is re-tiled on the way into a bucket or out
+    of it. Every leaf starts on a row, so a bucket is stacked from whole row
+    blocks of the leaves it covers; a bucket concatenated from 1-D pieces
+    had to be re-tiled into its matrix, which cost the four-chip cell a
+    tenth of its exchange and half the step's compile time, and matrices
+    raveled into rows of another width 6 ms more of its 76."""
 
     treedef: Any
     shapes: Tuple[Tuple[int, ...], ...]
@@ -174,6 +194,8 @@ class FlatParamMeta(NamedTuple):
     n_buckets: int
     shard_shape: Tuple[int, int]
     leaf_rows: Tuple[int, ...]
+    order: Tuple[int, ...]          # leaves in the order they take rows
+    col_blocks: Tuple[int, ...]     # 0: raveled; k: by its own rows
 
     @property
     def bucket_shape(self) -> Tuple[int, int]:
@@ -190,18 +212,45 @@ class FlatParamMeta(NamedTuple):
         """Elements of the whole flat view, padding included."""
         return self.n_buckets * self.bucket_len
 
+    @property
+    def own_rows_share(self) -> float:
+        """Share of the parameters that enter the view by their own rows."""
+        return sum(z for z, k in zip(self.sizes, self.col_blocks) if k) / max(
+            1, self.n)
+
+    @property
+    def layout(self) -> Optional[np.ndarray]:
+        """What tells this view from another of the same bucket shape (its
+        width and rows, the leaves' order and column blocks), or ``None``
+        for the plain view: every leaf raveled, in tree order."""
+        if not any(self.col_blocks):
+            return None
+        return np.asarray(self.bucket_shape + self.order + self.col_blocks,
+                          np.int32)
+
     def pieces(self, b: int) -> Tuple[Tuple[int, int, int], ...]:
         """``(leaf index, first row, end row)`` of the row blocks of the
         leaves' row matrices that fall in bucket ``b``, in flat order."""
         rows = self.shard_shape[0]
         lo, hi = b * rows, (b + 1) * rows
         out, off = [], 0
-        for i, n_rows in enumerate(self.leaf_rows):
+        for i in self.order:
+            n_rows = self.leaf_rows[i]
             s, e = max(lo, off), min(hi, off + n_rows)
             if s < e:
                 out.append((i, s - off, e - off))
             off += n_rows
         return tuple(out)
+
+    def blocks(self, i: int, r0: int, r1: int):
+        """Rows ``[r0, r1)`` of leaf ``i``'s row matrix as ``(column block,
+        first row, end row)`` runs of the leaf's own rows."""
+        rows = self.sizes[i] // self.shapes[i][-1]
+        while r0 < r1:
+            j = r0 // rows
+            end = min(r1, (j + 1) * rows)
+            yield j, r0 - j * rows, end - j * rows
+            r0 = end
 
 
 class FlatUpdateState(NamedTuple):
@@ -211,10 +260,14 @@ class FlatUpdateState(NamedTuple):
     mixed-precision path (``master`` is ``None`` when params are already f32,
     in which case the master shard is re-sliced from the replicated params
     each step instead of stored). The buckets' matrices, stacked, are the
-    flat view of :class:`FlatParamMeta` in its natural order."""
+    flat view of :class:`FlatParamMeta`. ``layout`` is that meta's
+    ``layout``: ``None`` for the plain view, else the small vector that a
+    snapshot carries so that it is never read under another view whose
+    buckets happen to have the same shape."""
 
     inner_state: Any
     master: Any
+    layout: Any = None
 
 
 class MasterWeightsState(NamedTuple):
@@ -230,28 +283,52 @@ def flat_meta(params, n_shards: int,
     """Layout of ``params`` for the flat exchange over ``n_shards`` replicas.
     ``bucket_len`` is the TARGET bucket length in elements (default
     :data:`BUCKET_TARGET_LEN`): the flat view is cut into equal buckets of
-    about that length, as few as that takes (one for a model that fits)."""
+    about that length, as few as that takes (one for a model that fits, in
+    the plain view: every leaf raveled, in tree order)."""
     leaves, treedef = jax.tree_util.tree_flatten(params)
     shapes = tuple(tuple(l.shape) for l in leaves)
     sizes = tuple(int(np.prod(s)) if s else 1 for s in shapes)
     dtypes = tuple(jnp.dtype(l.dtype) for l in leaves)
     n = int(sum(sizes))
+    target = BUCKET_TARGET_LEN if bucket_len is None else int(bucket_len)
     # a shard's columns: the most, up to SHARD_COLS, at which padding every
     # leaf to whole rows wastes under 1/64 of the parameters
     cols = SHARD_COLS
     while cols > 1 and 64 * sum(-z % (n_shards * cols) for z in sizes) > n:
         cols //= 2
+
+    def own_rows(c):
+        return tuple(s[-1] // (n_shards * c)
+                     if len(s) >= 2 and s[-1] % (n_shards * c) == 0 else 0
+                     for s in shapes)
+
+    col_blocks = (0,) * len(shapes)          # the plain view
+    if n > target:
+        # several buckets: of the widths a shard of which is whole lane
+        # tiles, the one (the widest) at which the matrices that can enter
+        # by their own rows hold the most parameters
+        most, c = 0, cols
+        while c >= LANES:
+            share = sum(z for z, k in zip(sizes, own_rows(c)) if k)
+            if share > most:
+                most, cols, col_blocks = share, c, own_rows(c)
+            c //= 2
     width = n_shards * cols
     leaf_rows = tuple(-(-z // width) for z in sizes)
+    # matrices of whole row tiles first, then the other matrices, then the
+    # raveled leaves: each group in tree order
+    order = tuple(sorted(range(len(shapes)), key=lambda i: (
+        2 if not col_blocks[i]
+        else int(sizes[i] // shapes[i][-1] % ROW_TILE > 0))))
     total = sum(leaf_rows)
-    target = BUCKET_TARGET_LEN if bucket_len is None else int(bucket_len)
     rows = -(-total // max(1, -(-total * width // target)))
     # the largest power of two, at most SHARD_ROWS_MULTIPLE, that pads the
     # rows by under 1/64
     mult = min(SHARD_ROWS_MULTIPLE, 1 << max(0, (rows // 64).bit_length() - 1))
     rows = -(-rows // mult) * mult
     return FlatParamMeta(treedef, shapes, sizes, dtypes, n, n_shards,
-                         -(-total // rows), (rows, cols), leaf_rows)
+                         -(-total // rows), (rows, cols), leaf_rows, order,
+                         col_blocks)
 
 
 def flat_bucket(tree, meta: FlatParamMeta, b: int, dtype=jnp.float32):
@@ -263,14 +340,20 @@ def flat_bucket(tree, meta: FlatParamMeta, b: int, dtype=jnp.float32):
     rows, width = meta.bucket_shape
     parts, have = [], 0
     for i, r0, r1 in meta.pieces(b):
-        n_rows = meta.leaf_rows[i]
-        flat = jnp.ravel(leaves[i])
-        if flat.size < n_rows * width:
-            flat = jnp.pad(flat, (0, n_rows * width - flat.size))
-        block = flat.reshape(n_rows, width)
-        if (r0, r1) != (0, n_rows):
-            block = jax.lax.slice_in_dim(block, r0, r1)
-        parts.append(block.astype(dtype))
+        if meta.col_blocks[i]:
+            matrix = leaves[i].reshape(-1, meta.shapes[i][-1])
+            parts += [jax.lax.slice(matrix, (a0, j * width),
+                                    (a1, (j + 1) * width)).astype(dtype)
+                      for j, a0, a1 in meta.blocks(i, r0, r1)]
+        else:
+            n_rows = meta.leaf_rows[i]
+            flat = jnp.ravel(leaves[i])
+            if flat.size < n_rows * width:
+                flat = jnp.pad(flat, (0, n_rows * width - flat.size))
+            block = flat.reshape(n_rows, width)
+            if (r0, r1) != (0, n_rows):
+                block = jax.lax.slice_in_dim(block, r0, r1)
+            parts.append(block.astype(dtype))
         have += r1 - r0
     if have < rows:
         parts.append(jnp.zeros((rows - have, width), dtype))
@@ -285,20 +368,30 @@ def flatten_tree(tree, meta: FlatParamMeta, dtype=jnp.float32):
 
 def unflatten_buckets(buckets: Sequence[Any], meta: FlatParamMeta):
     """Per-bucket ``bucket_shape`` matrices → pytree with the meta's original
-    shapes/dtypes. A leaf that spans buckets is joined from its row blocks."""
-    blocks = [[] for _ in meta.sizes]
+    shapes/dtypes. A leaf that spans buckets is joined from its row blocks,
+    one that entered by its own rows from its column blocks."""
+    blocks = [[[] for _ in range(max(1, k))] for k in meta.col_blocks]
     for b, bucket in enumerate(buckets):
         off = 0
         for i, r0, r1 in meta.pieces(b):
-            blocks[i].append(jax.lax.slice_in_dim(bucket, off, off + r1 - r0))
-            off += r1 - r0
+            runs = (meta.blocks(i, r0, r1) if meta.col_blocks[i]
+                    else ((0, r0, r1),))
+            for j, a0, a1 in runs:
+                blocks[i][j].append(
+                    jax.lax.slice_in_dim(bucket, off, off + a1 - a0))
+                off += a1 - a0
     out = []
-    for rows, size, shape, dt in zip(blocks, meta.sizes, meta.shapes,
-                                     meta.dtypes):
-        flat = jnp.ravel(jnp.concatenate(rows) if len(rows) > 1 else rows[0])
-        if flat.size > size:
-            flat = jax.lax.slice_in_dim(flat, 0, size)
-        out.append(flat.reshape(shape).astype(dt))
+    for cols, k, size, shape, dt in zip(blocks, meta.col_blocks, meta.sizes,
+                                        meta.shapes, meta.dtypes):
+        cols = [jnp.concatenate(rows) if len(rows) > 1 else rows[0]
+                for rows in cols]
+        if k:
+            leaf = jnp.concatenate(cols, axis=1) if k > 1 else cols[0]
+        else:
+            leaf = jnp.ravel(cols[0])
+            if leaf.size > size:
+                leaf = jax.lax.slice_in_dim(leaf, 0, size)
+        out.append(leaf.reshape(shape).astype(dt))
     return jax.tree_util.tree_unflatten(meta.treedef, out)
 
 
@@ -309,8 +402,10 @@ def flat_opt_init(tx: optax.GradientTransformation, params,
     ``params`` may be any float dtype — masters are f32."""
     flat32 = tuple(flat_bucket(params, meta, b, jnp.float32)
                    for b in range(meta.n_buckets))
+    layout = meta.layout
     return FlatUpdateState(tuple(tx.init(m) for m in flat32),
-                           flat32 if keep_master else None)
+                           flat32 if keep_master else None,
+                           None if layout is None else jnp.asarray(layout))
 
 
 def adopt_flat_layout(restored: FlatUpdateState, template: FlatUpdateState,
@@ -319,9 +414,16 @@ def adopt_flat_layout(restored: FlatUpdateState, template: FlatUpdateState,
     ``template``'s structure) into ``meta``'s layout. A one-bucket state
     written as plain ``(npad,)`` vectors — the layout before bucketing: the
     raveled leaves end to end — holds the same values in the same order and
-    is re-padded leaf by leaf; any other shape mismatch is refused in words,
-    never reinterpreted."""
+    is re-padded leaf by leaf; any other shape mismatch, and a state whose
+    ``layout`` is another view's, is refused in words, never reinterpreted
+    (a snapshot with or without a ``layout`` where this view has none or
+    one does not reach here: it has another count of leaves)."""
     width = meta.bucket_shape[1]
+    if restored.layout is not None and not np.array_equal(
+            np.asarray(restored.layout), meta.layout):
+        raise ValueError(
+            "its optimizer state was written under another flat view of "
+            "the same bucket shape (other leaves enter by their own rows)")
 
     def adopt(path, got, want):
         got, shape = np.asarray(got), tuple(want.shape)
@@ -400,7 +502,8 @@ def flat_exchange(params, grads, opt_state: FlatUpdateState,
                       else flat_bucket(params, meta, b))     # f32 bucket
         for b in buckets))
     new_params = unflatten_buckets(gathered, meta)
-    new_opt = FlatUpdateState(inner2, master2 if keep_master else None)
+    new_opt = FlatUpdateState(inner2, master2 if keep_master else None,
+                              opt_state.layout)
     return new_params, new_opt, gnorm
 
 

@@ -205,15 +205,44 @@ def test_fused_int8_conv_at_resnet_shapes(v5e, hw, cin, cout, k, dtype):
         ((2, hw, hw, cin), dtype), ((k, k, cin, cout), I8), ((cout,), F32))
 
 
-def test_flat_exchange_reaches_the_chips_as_reduce_scatters(v5e_2x2):
+def _cell_params():
+    """The four-chip training cell's own parameter tree, in bf16."""
+    from analytics_zoo_tpu.models.transformer import TransformerLM
+
+    model = TransformerLM(vocab=50257, hidden_size=2048, n_block=8, n_head=16,
+                          seq_len=2048, intermediate_size=8192)
+    tree = jax.eval_shape(lambda key: model.build(key)[0],
+                          jax.random.PRNGKey(0))
+    return jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, BF16), tree)
+
+
+_SYNTHETIC = {"blocks": jax.ShapeDtypeStruct((8, 24576, 2048), BF16),
+              "head": jax.ShapeDtypeStruct((50257, 2048), BF16),
+              "tokens": jax.ShapeDtypeStruct((50257, 2048), BF16),
+              "positions": jax.ShapeDtypeStruct((2048, 2048), BF16),
+              "norm": jax.ShapeDtypeStruct((2048,), BF16)}
+
+
+@pytest.mark.parametrize("tree,own_rows,reshape_gb,concatenate_gb", [
+    (lambda: _SYNTHETIC, 1.0, 0.1, 0.4), (_cell_params, 0.8317, 0.6, 0.4)],
+    ids=["synthetic", "cell"])
+def test_flat_exchange_reaches_the_chips_as_reduce_scatters(
+        v5e_2x2, tree, own_rows, reshape_gb, concatenate_gb):
     """The ZeRO-1 flat exchange at the four-chip training cell's size (613 M
-    bf16 parameters, dp=4, Adam with f32 masters), compiled for the 2x2:
-    every bucket's reduction is a real ``reduce-scatter`` and every gather an
+    bf16 parameters, dp=4, Adam with f32 masters), compiled for the 2x2, on
+    a tree of a few large leaves and on the cell's own 101: every bucket's
+    reduction is a real ``reduce-scatter`` and every gather an
     ``all-gather``. This compiler rewrites a reduce-scatter whose shard is
     one contiguous block (a 1-D operand, or a scatter over the major
     dimension), or whose rows it cannot chunk, to an all-reduce of the whole
     operand: twice the wire bytes, and what the cell paid until its exchange
-    was bucketed."""
+    was bucketed. And the matrices enter their buckets by their own rows:
+    the program re-tiles (a standalone ``reshape``) and stacks (a standalone
+    ``concatenate``) little more than the head's 50,257 columns, where the
+    cell's tree raveled into rows of 4,096 read 2.86 and 1.36 GB. (23,040
+    rows a bucket is a count this compiler reduces quickly; 23,168, 128 x
+    181, took it half as long again on the chips: ``PERF.md`` section 6.)"""
     import re
 
     import numpy as np
@@ -221,16 +250,14 @@ def test_flat_exchange_reaches_the_chips_as_reduce_scatters(v5e_2x2):
     from jax import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+    from analytics_zoo_tpu.analysis.rules.collectives import entry_ops
     from analytics_zoo_tpu.parallel import update_sharding as upd
 
     mesh = Mesh(np.array(v5e_2x2.devices[:4]), ("dp",))
-    tree = {"blocks": jax.ShapeDtypeStruct((8, 24576, 2048), BF16),
-            "head": jax.ShapeDtypeStruct((50257, 2048), BF16),
-            "tokens": jax.ShapeDtypeStruct((50257, 2048), BF16),
-            "positions": jax.ShapeDtypeStruct((2048, 2048), BF16),
-            "norm": jax.ShapeDtypeStruct((2048,), BF16)}
+    tree = tree()
     meta = upd.flat_meta(tree, 4)
-    assert meta.n_buckets == 13 and meta.shard_shape == (11520, 1024)
+    assert meta.n_buckets == 13 and meta.shard_shape == (23040, 512)
+    assert round(meta.own_rows_share, 4) == own_rows
     tx = optax.adam(1e-4)
     opt = jax.eval_shape(lambda: upd.flat_opt_init(
         tx, jax.tree_util.tree_map(lambda l: jnp.zeros(l.shape, l.dtype),
@@ -267,3 +294,6 @@ def test_flat_exchange_reaches_the_chips_as_reduce_scatters(v5e_2x2):
     big = [shape for shape in re.findall(
         r"= (\S+) all-reduce(?:-start)?\(", entry) if "[]" not in shape]
     assert not big, big
+    ops = entry_ops(hlo)
+    assert ops.get("reshape", [0, 0])[1] < reshape_gb * 1e9, ops
+    assert ops.get("concatenate", [0, 0])[1] < concatenate_gb * 1e9, ops

@@ -68,7 +68,11 @@ def _leaves(est):
 
 # ``bucket_target`` (conftest): with a 48-element target the 192-parameter toy
 # (three rows of 64: two of the first leaf, one of the second) exchanges 3
-# buckets
+# buckets in the plain view. ``_OWN_ROWS``: a hidden layer as wide as one
+# lane tile a shard, under a target the toy exceeds, so the first kernel
+# enters the view by its own rows and the second is raveled: (hidden size,
+# bucket target) for the 2-device mesh and for the 8-device one
+_OWN_ROWS = {2: (256, 1024), 8: (1024, 4096)}
 
 
 def _mesh2():
@@ -161,11 +165,14 @@ def test_sharded_update_bit_parity_two_devices(zoo_ctx):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.parametrize("bucket_len", [None, 512])
-def test_flat_opt_state_is_one_over_dp(zoo_ctx, bucket_target, bucket_len):
+@pytest.mark.parametrize("bucket_len,hidden", [
+    (None, 64), (512, 64), (_OWN_ROWS[8][1], _OWN_ROWS[8][0])])
+def test_flat_opt_state_is_one_over_dp(zoo_ctx, bucket_target, bucket_len,
+                                       hidden):
     """ZeRO-1 memory claim on the 8-way dp mesh: per-device optimizer-state
     bytes ≈ replicated/8 (within padding + replicated scalar count leaves),
-    with one bucket and with three."""
+    with one bucket, with three, and with a kernel that enters its buckets
+    by its own rows."""
     x, y = _dyadic_data(B=64, D=16)
     if bucket_len:
         bucket_target(bucket_len)
@@ -178,11 +185,15 @@ def test_flat_opt_state_is_one_over_dp(zoo_ctx, bucket_target, bucket_len):
 
     base = dict(shuffle=False, log_every_n_steps=10 ** 9)
     e_r = _dyadic_estimator(TrainConfig(update_sharding=False, **base), x, y,
-                            optimizer=Adam(1e-3), D=16, H=64, O=4)
+                            optimizer=Adam(1e-3), D=16, H=hidden, O=4)
     e_s = _dyadic_estimator(TrainConfig(update_sharding=True, **base), x, y,
-                            optimizer=Adam(1e-3), D=16, H=64, O=4)
+                            optimizer=Adam(1e-3), D=16, H=hidden, O=4)
     assert e_s._update_mode() == "flat"
-    assert e_s._flat_meta.n_buckets == (3 if bucket_len else 1)
+    meta = e_s._flat_meta
+    if hidden == 64:
+        assert (meta.n_buckets, meta.layout) == (3 if bucket_len else 1, None)
+    else:
+        assert meta.n_buckets == 5 and meta.col_blocks == (1, 0)
     r, s = opt_bytes(e_r), opt_bytes(e_s)
     assert s <= r / 8 * 1.35 + 512, (r, s)
 
@@ -234,21 +245,26 @@ def test_one_gradient_collective_per_bucket_per_global_step(
     assert counts[4].get("all-gather", 0) == meta.n_buckets, counts
 
 
-@pytest.mark.parametrize("bucket_len", [None, 48])
+@pytest.mark.parametrize("bucket_len,hidden", [
+    (None, 16), (48, 16), (_OWN_ROWS[8][1], _OWN_ROWS[8][0])])
 def test_lowered_step_defines_each_collective_once(zoo_ctx, bucket_target,
-                                                   bucket_len):
+                                                   bucket_len, hidden):
     """The benchmark's check (benchmark/drivers/train_fit.py) counts TEXT
     occurrences in the lowered step and wants one reduce_scatter and one
-    all_gather: every bucket goes through the same two jitted functions, so
-    each collective is defined once however many buckets call it. A refactor
-    that inlines the buckets fails here before it fails on the chip."""
+    all_gather (``benchmark/traffic/fit-2k-zero1.json``): every bucket has
+    the one bucket shape and goes through the same two jitted functions, so
+    each collective is defined once however many buckets call it and however
+    the leaves enter them. A refactor that inlines the buckets, or gives a
+    leaf a bucket in a shape of its own, fails here before it fails on the
+    chip."""
     x, y = _dyadic_data(B=64)
     if bucket_len:
         bucket_target(bucket_len)
     est = _dyadic_estimator(
         TrainConfig(shuffle=False, log_every_n_steps=10 ** 9,
-                    update_sharding=True), x, y)
+                    update_sharding=True), x, y, H=hidden)
     assert est._flat_meta.n_buckets == (3 if bucket_len else 1)
+    assert (est._flat_meta.own_rows_share > 0) == (hidden > 16)
     text = est.lower_train_step((x, y)).as_text()
     assert text.count('stablehlo.reduce_scatter"') == 1
     assert text.count('stablehlo.all_gather"') == 1
@@ -342,11 +358,93 @@ def test_flat_meta_cuts_equal_buckets_from_the_leaves():
     assert odd.npad <= odd.n * (1 + 1 / 64)
 
 
+def _cell_tree():
+    """The four-chip training cell's parameter tree, by shape."""
+    from analytics_zoo_tpu.models.transformer import TransformerLM
+
+    model = TransformerLM(vocab=50257, hidden_size=2048, n_block=8, n_head=16,
+                          seq_len=2048, intermediate_size=8192)
+    return jax.eval_shape(lambda key: model.build(key)[0],
+                          jax.random.PRNGKey(0))
+
+
+def test_flat_meta_lays_matrices_in_by_their_own_rows():
+    """In a model of several buckets the view is as wide as the matrices'
+    own minor dimension allows (a shard of whole lane tiles): a matrix that
+    many columns wide, or a multiple, enters by its own rows, its column
+    blocks one below the other, the matrices of whole row tiles first;
+    every other leaf is raveled as in the plain view, after them. Bucket
+    then unflatten is the identity, and a tree under one target keeps the
+    plain view in one bucket."""
+    rng = np.random.default_rng(0)
+    params = {"a": rng.normal(size=(16, 512)).astype(np.float32),
+              "b": rng.normal(size=(256,)).astype(np.float32),
+              "c": rng.normal(size=(256, 4)).astype(np.float32),
+              "d": rng.normal(size=(3, 256)).astype(np.float32),
+              "e": rng.normal(size=(32, 256)).astype(np.float32),
+              "f": rng.normal(size=(16, 384)).astype(np.float32),
+              "g": rng.normal(size=(4, 4, 512)).astype(np.float32)}
+    meta = upd.flat_meta(params, 2, bucket_len=3072)
+    assert meta.shard_shape == (12, 128) and meta.bucket_shape == (12, 256)
+    # "a" is two column blocks of 16 rows, "d" and "e" one, "g" (seen as
+    # sixteen rows of 512) two; "f" is a matrix of one and a half widths
+    # and is raveled like "b" and "c"
+    assert meta.col_blocks == (2, 0, 0, 1, 1, 0, 2)
+    assert meta.leaf_rows == (32, 1, 4, 3, 32, 24, 32)
+    assert meta.order == (0, 4, 6, 3, 1, 2, 5) and meta.n_buckets == 11
+    assert meta.own_rows_share == (8192 + 768 + 8192 + 8192) / meta.n
+    # bucket 1 holds the last four rows of "a"'s first column block and the
+    # first eight of its second
+    assert meta.pieces(1) == ((0, 12, 24),)
+    assert list(meta.blocks(0, 12, 24)) == [(0, 12, 16), (1, 0, 8)]
+    assert meta.pieces(8) == ((3, 0, 3), (1, 0, 1), (2, 0, 4), (5, 0, 4))
+    a, g = params["a"], params["g"].reshape(16, 512)
+    view = np.concatenate([
+        a[:, :256], a[:, 256:], params["e"], g[:, :256], g[:, 256:],
+        params["d"], params["b"].reshape(1, 256),
+        params["c"].reshape(4, 256), params["f"].reshape(24, 256)])
+    view = np.pad(view, ((0, 11 * 12 - len(view)), (0, 0)))
+    buckets = [upd.flat_bucket(params, meta, b) for b in range(meta.n_buckets)]
+    for b, got in enumerate(buckets):
+        np.testing.assert_array_equal(np.asarray(got),
+                                      view[b * 12:(b + 1) * 12])
+    back = upd.unflatten_buckets(buckets, meta)
+    for k in params:
+        np.testing.assert_array_equal(np.asarray(back[k]), params[k])
+    # the raveled leaves take the rows they take in the plain view of that
+    # width, one after the other
+    assert [meta.leaf_rows[i] for i in (1, 2, 5)] == [
+        -(-params[k].size // 256) for k in "bcf"]
+    # under one target: the plain view, one bucket, nothing to tell it by
+    one = upd.flat_meta(params, 2)
+    assert one.n_buckets == 1 and one.layout is None
+    assert one.col_blocks == (0,) * 7 and one.order == tuple(range(7))
+    whole = np.asarray(upd.flatten_tree(params, one))
+    np.testing.assert_array_equal(whole[:8192 + 256], np.concatenate(
+        [params["a"].ravel(), params["b"]]))      # raveled, in tree order
+    # the four-chip cell: 2,048 columns (512 a shard) take every matrix but
+    # the head, whose 50,257 columns are raveled with the vectors
+    cell = upd.flat_meta(_cell_tree(), 4)
+    assert cell.n_buckets == 13 and cell.shard_shape == (23040, 512)
+    assert round(cell.own_rows_share, 4) == 0.8317
+    names = [jax.tree_util.keystr(p) for p, _ in
+             jax.tree_util.tree_leaves_with_path(_cell_tree())]
+    assert [names[i] for i in cell.order[32:35]] == [
+        "['pos_embeddings']", "['token_embeddings']",
+        "['block0']['attn']['out_bias']"]
+    assert cell.col_blocks[names.index("['logits_kernel']")] == 0
+    assert cell.npad <= cell.n * (1 + 1 / 64)
+
+
+@pytest.mark.parametrize("hidden,target", [(16, 48), _OWN_ROWS[2]],
+                         ids=["plain", "own_rows"])
 @pytest.mark.parametrize("precision", ["f32", "bf16"])
 @pytest.mark.parametrize("optimizer", ["sgd_momentum", "adam"])
 def test_bucketed_update_bit_parity_two_devices(zoo_ctx, bucket_target,
-                                                optimizer, precision):
-    """Three buckets on a 2-device dp mesh. Bucketing changes which collective
+                                                optimizer, precision, hidden,
+                                                target):
+    """Three buckets on a 2-device dp mesh, in the plain view and with the
+    first kernel entering by its own rows. The view changes which collective
     carries an element, never its arithmetic: one step and six more are
     bit-identical to the one-bucket exchange, f32 params or bf16 params with
     f32 masters. Against the REPLICATED update one f32 step is bit-identical
@@ -361,13 +459,15 @@ def test_bucketed_update_bit_parity_two_devices(zoo_ctx, bucket_target,
     for name, sharded in (("replicated", False), ("one", True),
                           ("three", True)):
         if name == "three":
-            bucket_target(48)
+            bucket_target(target)
         cfg = TrainConfig(shuffle=False, log_every_n_steps=10 ** 9,
                           update_sharding=sharded, **extra)
         ests[name] = _dyadic_estimator(cfg, x, y, optimizer=make(),
-                                       mesh=_mesh2())
-    assert ests["one"]._flat_meta.n_buckets == 1
-    assert ests["three"]._flat_meta.n_buckets == 3
+                                       mesh=_mesh2(), H=hidden)
+    one, three = ests["one"]._flat_meta, ests["three"]._flat_meta
+    assert (one.n_buckets, one.layout) == (1, None)
+    assert three.n_buckets == 3
+    assert three.col_blocks == ((1, 0) if hidden > 16 else (0, 0))
     bf16_grain = dict(rtol=0, atol=2 ** -7)
 
     def compare(steps):
@@ -390,27 +490,33 @@ def test_bucketed_update_bit_parity_two_devices(zoo_ctx, bucket_target,
         est.fit((x, y), batch_size=32, epochs=7)      # six more
     compare(7)
     if precision == "bf16":
-        # the f32 masters, read back in flat order from the three buckets,
+        # the f32 masters, read back leaf by leaf from the three buckets,
         # are the one-bucket masters bit for bit
-        flat = {k: np.concatenate([np.asarray(m).ravel() for m in
-                                   jax.device_get(ests[k].train_state[
-                                       "opt_state"]).master])
-                for k in ("one", "three")}
-        np.testing.assert_array_equal(flat["one"], flat["three"])
+        masters = {k: jax.tree_util.tree_leaves(upd.unflatten_buckets(
+            [jnp.asarray(m) for m in jax.device_get(
+                ests[k].train_state["opt_state"]).master],
+            ests[k]._flat_meta._replace(
+                dtypes=(jnp.dtype("float32"),) * 2)))
+            for k in ("one", "three")}
+        for a, b in zip(masters["one"], masters["three"]):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_bucketed_clip_norm_matches_replicated(zoo_ctx, bucket_target):
+@pytest.mark.parametrize("hidden,target", [(16, 48), _OWN_ROWS[2]],
+                         ids=["plain", "own_rows"])
+def test_bucketed_clip_norm_matches_replicated(zoo_ctx, bucket_target,
+                                               hidden, target):
     """gradient_clip_norm with several buckets: the norm is one scalar psum
     over all buckets' shards, so the clipped update equals the replicated
     clipped update (and the reported norm is the global one)."""
     x, y = _dyadic_data(B=32)
-    bucket_target(48)
+    bucket_target(target)
     ests, norms = {}, {}
     for sharded in (False, True):
         cfg = TrainConfig(shuffle=False, log_every_n_steps=1,
                           update_sharding=sharded, gradient_clip_norm=0.25)
         est = _dyadic_estimator(cfg, x, y, optimizer=SGD(lr=0.5),
-                                mesh=_mesh2())
+                                mesh=_mesh2(), H=hidden)
         snap0 = _tm.snapshot().get("zoo_train_grad_norm", {}).get(
             "samples", {}).get("", {"sum": 0.0})["sum"]
         est.fit((x, y), batch_size=32, epochs=1)
@@ -418,6 +524,7 @@ def test_bucketed_clip_norm_matches_replicated(zoo_ctx, bucket_target):
             "samples"][""]["sum"] - snap0
         ests[sharded] = est
     assert ests[True]._flat_meta.n_buckets == 3
+    assert (ests[True]._flat_meta.layout is not None) == (hidden > 16)
     assert norms[True] > 0.25                  # the clip engaged
     np.testing.assert_allclose(norms[True], norms[False], rtol=1e-6)
     for a, b in zip(_leaves(ests[False]), _leaves(ests[True])):
@@ -559,21 +666,22 @@ def test_sanitize_raises_on_overdividing_tuple_axes(zoo_ctx):
 
 
 # ---------------------------------------------------------------- durability
-@pytest.mark.parametrize("bucket_len", [None, 48])
+@pytest.mark.parametrize("bucket_len,hidden", [
+    (None, 16), (48, 16), (_OWN_ROWS[8][1], _OWN_ROWS[8][0])])
 def test_flat_mode_checkpoint_roundtrip(zoo_ctx, tmp_path, bucket_target,
-                                        bucket_len):
+                                        bucket_len, hidden):
     x, y = _dyadic_data(B=64)
     if bucket_len:
         bucket_target(bucket_len)
     cfg = TrainConfig(shuffle=False, log_every_n_steps=10 ** 9,
                       update_sharding=True, checkpoint_dir=str(tmp_path))
-    est = _dyadic_estimator(cfg, x, y, optimizer=Adam(1e-2))
+    est = _dyadic_estimator(cfg, x, y, optimizer=Adam(1e-2), H=hidden)
     est.fit((x, y), batch_size=32, epochs=2)
     it = est.trainer_state.iteration
     # fresh estimator resumes from the flat-layout checkpoint
     cfg2 = TrainConfig(shuffle=False, log_every_n_steps=10 ** 9,
                        update_sharding=True, checkpoint_dir=str(tmp_path))
-    model = Sequential([L.Dense(16, use_bias=False, input_shape=(8,)),
+    model = Sequential([L.Dense(hidden, use_bias=False, input_shape=(8,)),
                         L.Dense(4, use_bias=False)])
     est2 = Estimator(model, optimizer=Adam(1e-2), loss="mse", config=cfg2)
     est2.load(str(tmp_path), sample_batch=(x, y))
@@ -581,6 +689,7 @@ def test_flat_mode_checkpoint_roundtrip(zoo_ctx, tmp_path, bucket_target,
     assert est2.trainer_state.iteration == it
     assert isinstance(est2.train_state["opt_state"], upd.FlatUpdateState)
     assert est2._flat_meta.n_buckets == (3 if bucket_len else 1)
+    assert (est2.train_state["opt_state"].layout is not None) == (hidden > 16)
     for a, b in zip(_leaves(est), _leaves(est2)):
         np.testing.assert_array_equal(a, b)
     for a, b in zip(*(jax.tree_util.tree_leaves(jax.device_get(
@@ -590,13 +699,20 @@ def test_flat_mode_checkpoint_roundtrip(zoo_ctx, tmp_path, bucket_target,
     assert est2.trainer_state.iteration == it + 2
 
 
-@pytest.mark.parametrize("bucket_len", [None, 48])
+@pytest.mark.parametrize("bucket_len,hidden,written_as", [
+    (None, 16, "vector"), (48, 16, "vector"),
+    (_OWN_ROWS[8][1], _OWN_ROWS[8][0], "plain_buckets"),
+    (_OWN_ROWS[8][1], _OWN_ROWS[8][0], "another_view")])
 def test_old_flat_layout_checkpoint_is_repadded_or_refused(
-        zoo_ctx, tmp_path, bucket_target, bucket_len):
+        zoo_ctx, tmp_path, bucket_target, bucket_len, hidden, written_as):
     """A checkpoint whose flat optimizer state is one ``(npad,)`` vector per
     slot (the layout before bucketing): a one-bucket estimator re-pads it
     into its ``bucket_shape`` (same flat order); a several-bucket one refuses
-    it in words. It is never read as if it were the new layout."""
+    it in words. So does an estimator whose first kernel enters its buckets
+    by its own rows when the snapshot's buckets, of the very same shape,
+    were stacked in the plain view (every leaf raveled, in tree order: what
+    the version before wrote) or in a view of other leaves. It is never read
+    as if it were the new layout."""
     import optax
 
     from analytics_zoo_tpu.engine import checkpoint as ckpt
@@ -606,8 +722,22 @@ def test_old_flat_layout_checkpoint_is_repadded_or_refused(
         bucket_target(bucket_len)
     cfg = TrainConfig(shuffle=False, log_every_n_steps=10 ** 9,
                       update_sharding=True, compute_dtype="bfloat16")
-    est = _dyadic_estimator(cfg, x, y, optimizer=Adam(1e-2))
+    est = _dyadic_estimator(cfg, x, y, optimizer=Adam(1e-2), H=hidden)
     state = jax.device_get(est.train_state)
+    if written_as != "vector":
+        mine = state["opt_state"]
+        assert mine.master[0].shape == (4, 1024) and len(mine.master) == 3
+        other = (None if written_as == "plain_buckets"
+                 else np.asarray(mine.layout)[::-1].copy())
+        old = dict(state, opt_state=mine._replace(layout=other))
+        ckpt.save_checkpoint(str(tmp_path), old, iteration=7, epoch=1)
+        with pytest.raises(ValueError, match=(
+                r"3 bucket\(s\) of \(4, 1024\), matrices by their own rows.*"
+                + ("16 leaves, template has 17"
+                   if written_as == "plain_buckets" else "another flat view")
+                + r".*Resume it with the version that wrote it")):
+            est.load(str(tmp_path))
+        return
     flat = np.arange(192, dtype=np.float32) / 8          # npad = n = 192
     old = dict(state, opt_state=upd.FlatUpdateState(
         optax.adam(1e-2).init(jnp.asarray(flat)), flat))
