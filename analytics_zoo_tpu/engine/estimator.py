@@ -60,8 +60,9 @@ _UPDATE_BUCKETS = _tm.gauge("zoo_train_update_buckets",
                             "reduce-scatter + one all-gather each per step)")
 _UPDATE_OWN_ROWS = _tm.gauge("zoo_train_update_own_rows_share",
                              "Share of the parameters that enter the flat "
-                             "exchange's buckets by their own rows (cut "
-                             "into column blocks, not raveled and re-cut)")
+                             "exchange's buckets by their own rows or their "
+                             "transpose's (cut into column blocks, not "
+                             "raveled and re-cut)")
 _DATA_WAIT = _tm.histogram("zoo_train_data_wait_seconds",
                            "Per-step host wait on the input pipeline")
 _COMPUTE = _tm.histogram("zoo_train_compute_seconds",
@@ -342,8 +343,8 @@ class Estimator:
             _UPDATE_BUCKETS.set(meta.n_buckets)
             _UPDATE_OWN_ROWS.set(meta.own_rows_share)
             logger.info("update sharding: flat over dp=%d, %d parameters in "
-                        "%d bucket(s) of %d x %d, %.3f of them by their own "
-                        "rows", meta.n_shards, meta.n, meta.n_buckets,
+                        "%d bucket(s) of %d x %d, %.4f of them by their own "
+                        "rows or their transpose's", meta.n_shards, meta.n, meta.n_buckets,
                         *meta.bucket_shape, meta.own_rows_share)
         else:
             opt_state = self.tx.init(params)
@@ -1364,7 +1365,8 @@ class Estimator:
         sharding the optimizer state must be in this build's bucket layout:
         a one-bucket state in the older ``(npad,)`` layout is re-padded, any
         other layout (other buckets, or a view in which other leaves enter
-        by their own rows) is refused rather than read as this one."""
+        by their own rows or their transpose's) is refused rather than read
+        as this one."""
         flat = self._update_mode() == "flat"
         try:
             restored, meta = ckpt.load_checkpoint(path, self.train_state)

@@ -31,10 +31,14 @@ Two implementations, selected by the training engine:
   dimension (or a divisor of it), so a matrix enters by **its own rows**:
   whole column blocks of whole lane tiles, cut and stacked, where a raveled
   and re-cut matrix is re-tiled element by element on its way into the
-  bucket and again on its way out (a matrix of an odd width, a head of
-  50,257 columns, still is: row by row, in a ``while`` loop). All buckets
-  have one shape and go through the same two jitted
-  functions, so the traced step defines each collective once. The
+  bucket and again on its way out. A matrix of an odd width whose ROWS are
+  a whole number of view widths (a head of 2,048 x 50,257) enters by the
+  rows of its **transpose**: as many rows of the view as its ravel took,
+  at one transposing pass each way (on a v5e a bitcast: the compiler keeps
+  such a parameter with its rows minor, which is why its ravel was walked
+  row by row, in a ``while`` loop). All buckets have one shape and go
+  through the same two jitted functions, so the traced step defines each
+  collective once. The
   collective count per *global* step is structural — gradient accumulation
   scans microbatches over device-local grads, so K microbatches still cost
   exactly one reduce-scatter + one all-gather per bucket.
@@ -174,16 +178,24 @@ class FlatParamMeta(NamedTuple):
     column block ``i`` (``shard_shape``) of every bucket.
 
     A leaf's rows are its **row matrix**. Where ``col_blocks[i]`` is 0 that
-    is the raveled leaf, zero-padded to whole rows. Where it is ``k`` the
+    is the raveled leaf, zero-padded to whole rows. Where it is ``k > 0`` the
     leaf (of two or more dimensions, seen as the matrix of its minor one) is
     ``k`` times as wide as the view and enters by its own rows: its ``k``
     column blocks one below the other, each row of the view a piece of one
     row of the leaf, so nothing is re-tiled on the way into a bucket or out
-    of it. Every leaf starts on a row, so a bucket is stacked from whole row
-    blocks of the leaves it covers; a bucket concatenated from 1-D pieces
-    had to be re-tiled into its matrix, which cost the four-chip cell a
-    tenth of its exchange and half the step's compile time, and matrices
-    raveled into rows of another width 6 ms more of its 76."""
+    of it. Where it is ``-k`` the leaf is a matrix whose columns do not fit
+    the view but whose rows are ``k`` view widths, and it enters by the rows
+    of its **transpose**, exactly as an own-rows leaf of the transposed
+    shape would: as many rows as its ravel takes, none of them padding, at
+    one transposing pass each way (the ravel of the four-chip cell's head,
+    2,048 x 50,257, was walked row by row in two ``while`` loops, 12 ms of
+    an exchange of 70; its transposes are bitcasts there, because the
+    chip's compiler keeps that parameter with its rows minor). Every leaf
+    starts on a row, so a bucket is stacked from whole row blocks of the
+    leaves it covers; a bucket concatenated from 1-D pieces had to be
+    re-tiled into its matrix, which cost the four-chip cell a tenth of its
+    exchange and half the step's compile time, and matrices raveled into
+    rows of another width 6 ms more of its 76."""
 
     treedef: Any
     shapes: Tuple[Tuple[int, ...], ...]
@@ -195,7 +207,7 @@ class FlatParamMeta(NamedTuple):
     shard_shape: Tuple[int, int]
     leaf_rows: Tuple[int, ...]
     order: Tuple[int, ...]          # leaves in the order they take rows
-    col_blocks: Tuple[int, ...]     # 0: raveled; k: by its own rows
+    col_blocks: Tuple[int, ...]     # 0: raveled; k: own rows; -k: transpose's
 
     @property
     def bucket_shape(self) -> Tuple[int, int]:
@@ -214,15 +226,16 @@ class FlatParamMeta(NamedTuple):
 
     @property
     def own_rows_share(self) -> float:
-        """Share of the parameters that enter the view by their own rows."""
+        """Share of the parameters that enter the view by their own rows or
+        their transpose's: everything that is not raveled."""
         return sum(z for z, k in zip(self.sizes, self.col_blocks) if k) / max(
             1, self.n)
 
     @property
     def layout(self) -> Optional[np.ndarray]:
         """What tells this view from another of the same bucket shape (its
-        width and rows, the leaves' order and column blocks), or ``None``
-        for the plain view: every leaf raveled, in tree order."""
+        width and rows, the leaves' order and signed column blocks), or
+        ``None`` for the plain view: every leaf raveled, in tree order."""
         if not any(self.col_blocks):
             return None
         return np.asarray(self.bucket_shape + self.order + self.col_blocks,
@@ -244,8 +257,9 @@ class FlatParamMeta(NamedTuple):
 
     def blocks(self, i: int, r0: int, r1: int):
         """Rows ``[r0, r1)`` of leaf ``i``'s row matrix as ``(column block,
-        first row, end row)`` runs of the leaf's own rows."""
-        rows = self.sizes[i] // self.shapes[i][-1]
+        first row, end row)`` runs of the rows of the leaf (of its transpose
+        where ``col_blocks[i]`` is negative)."""
+        rows = self.leaf_rows[i] // abs(self.col_blocks[i])
         while r0 < r1:
             j = r0 // rows
             end = min(r1, (j + 1) * rows)
@@ -284,7 +298,16 @@ def flat_meta(params, n_shards: int,
     ``bucket_len`` is the TARGET bucket length in elements (default
     :data:`BUCKET_TARGET_LEN`): the flat view is cut into equal buckets of
     about that length, as few as that takes (one for a model that fits, in
-    the plain view: every leaf raveled, in tree order)."""
+    the plain view: every leaf raveled, in tree order).
+
+    How a leaf enters (``col_blocks``) is decided by its shape alone. That a
+    matrix entering by its transpose's rows is cheaper than its ravel is
+    measured for one layout only: a v5e's compiler keeps a ``[k * width,
+    odd]`` bf16 parameter with its rows minor (``{0,1}``), so both
+    transposes are bitcasts (the four-chip cell's head on the chip; a
+    2,048 x 1,000 one compiled in ``tests/test_mosaic_compile.py``). Where
+    a compiler kept such a matrix with its columns minor, the ravel was the
+    free operation and each transpose is a pass over the leaf."""
     leaves, treedef = jax.tree_util.tree_flatten(params)
     shapes = tuple(tuple(l.shape) for l in leaves)
     sizes = tuple(int(np.prod(s)) if s else 1 for s in shapes)
@@ -298,15 +321,17 @@ def flat_meta(params, n_shards: int,
         cols //= 2
 
     def own_rows(c):
-        return tuple(s[-1] // (n_shards * c)
-                     if len(s) >= 2 and s[-1] % (n_shards * c) == 0 else 0
-                     for s in shapes)
+        # k: by its own rows; -k: a matrix that cannot, by its transpose's
+        w = n_shards * c
+        return tuple(s[-1] // w if len(s) >= 2 and s[-1] % w == 0
+                     else -(s[0] // w) if len(s) == 2 and s[0] % w == 0
+                     else 0 for s in shapes)
 
     col_blocks = (0,) * len(shapes)          # the plain view
     if n > target:
         # several buckets: of the widths a shard of which is whole lane
         # tiles, the one (the widest) at which the matrices that can enter
-        # by their own rows hold the most parameters
+        # by their own rows or their transpose's hold the most parameters
         most, c = 0, cols
         while c >= LANES:
             share = sum(z for z, k in zip(sizes, own_rows(c)) if k)
@@ -319,7 +344,7 @@ def flat_meta(params, n_shards: int,
     # raveled leaves: each group in tree order
     order = tuple(sorted(range(len(shapes)), key=lambda i: (
         2 if not col_blocks[i]
-        else int(sizes[i] // shapes[i][-1] % ROW_TILE > 0))))
+        else int(leaf_rows[i] // abs(col_blocks[i]) % ROW_TILE > 0))))
     total = sum(leaf_rows)
     rows = -(-total // max(1, -(-total * width // target)))
     # the largest power of two, at most SHARD_ROWS_MULTIPLE, that pads the
@@ -342,6 +367,8 @@ def flat_bucket(tree, meta: FlatParamMeta, b: int, dtype=jnp.float32):
     for i, r0, r1 in meta.pieces(b):
         if meta.col_blocks[i]:
             matrix = leaves[i].reshape(-1, meta.shapes[i][-1])
+            if meta.col_blocks[i] < 0:
+                matrix = matrix.T
             parts += [jax.lax.slice(matrix, (a0, j * width),
                                     (a1, (j + 1) * width)).astype(dtype)
                       for j, a0, a1 in meta.blocks(i, r0, r1)]
@@ -369,8 +396,9 @@ def flatten_tree(tree, meta: FlatParamMeta, dtype=jnp.float32):
 def unflatten_buckets(buckets: Sequence[Any], meta: FlatParamMeta):
     """Per-bucket ``bucket_shape`` matrices → pytree with the meta's original
     shapes/dtypes. A leaf that spans buckets is joined from its row blocks,
-    one that entered by its own rows from its column blocks."""
-    blocks = [[[] for _ in range(max(1, k))] for k in meta.col_blocks]
+    one that entered by its own rows from its column blocks, one that
+    entered by its transpose's likewise and transposed back."""
+    blocks = [[[] for _ in range(max(1, abs(k)))] for k in meta.col_blocks]
     for b, bucket in enumerate(buckets):
         off = 0
         for i, r0, r1 in meta.pieces(b):
@@ -386,7 +414,9 @@ def unflatten_buckets(buckets: Sequence[Any], meta: FlatParamMeta):
         cols = [jnp.concatenate(rows) if len(rows) > 1 else rows[0]
                 for rows in cols]
         if k:
-            leaf = jnp.concatenate(cols, axis=1) if k > 1 else cols[0]
+            leaf = jnp.concatenate(cols, axis=1) if len(cols) > 1 else cols[0]
+            if k < 0:
+                leaf = leaf.T
         else:
             leaf = jnp.ravel(cols[0])
             if leaf.size > size:
@@ -423,7 +453,8 @@ def adopt_flat_layout(restored: FlatUpdateState, template: FlatUpdateState,
             np.asarray(restored.layout), meta.layout):
         raise ValueError(
             "its optimizer state was written under another flat view of "
-            "the same bucket shape (other leaves enter by their own rows)")
+            "the same bucket shape (other leaves enter by their own rows, or "
+            "by their transpose's)")
 
     def adopt(path, got, want):
         got, shape = np.asarray(got), tuple(want.shape)

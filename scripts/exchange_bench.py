@@ -6,7 +6,9 @@
 ``benchmark/configs`` by shape, lays it out with
 ``parallel.update_sharding.flat_meta`` over the configuration's ``dp`` and
 runs ``flat_exchange`` with its optimizer: gradients in, parameters out,
-no forward and no backward pass.
+no forward and no backward pass. Either way it prints the view (buckets,
+their shape, ``own_rows_share``) and every leaf over 1% of the parameters with
+how it enters the view: by its own rows, by its transpose's, or raveled.
 
 * On the chips (as many as ``dp``): the device time of one exchange, the
   median of ``--runs`` traced runs, split into the reductions, the gathers
@@ -99,6 +101,20 @@ def describe(meta) -> dict:
             "own_rows_share": getattr(meta, "own_rows_share", 0.0)}
 
 
+def large_leaves(meta, tree) -> list:
+    """Every leaf of ``tree`` over 1% of the parameters with how it enters
+    the view, so that the listing names what is still raveled."""
+    import jax
+
+    names = [jax.tree_util.keystr(path) for path, _ in
+             jax.tree_util.tree_leaves_with_path(tree)]
+    blocks = getattr(meta, "col_blocks", (0,) * len(meta.sizes))
+    return [[name, list(shape),
+             "own rows" if k > 0 else "transpose" if k else "raveled"]
+            for name, shape, size, k in zip(names, meta.shapes, meta.sizes,
+                                            blocks) if 100 * size > meta.n]
+
+
 def listing(config_name: str) -> dict:
     """No chip: compile for a described v5e 2x2 and list the program."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -127,6 +143,7 @@ def listing(config_name: str) -> dict:
     return {
         "compiled_for": "v5e:2x2 (described, nothing ran)",
         "layout": describe(meta),
+        "large_leaves": large_leaves(meta, args[0]),
         "lowered_text": {k: text.count(f'stablehlo.{k}"')
                          for k in ("reduce_scatter", "all_gather",
                                    "all_reduce")},
@@ -220,6 +237,7 @@ def measure(config_name: str, runs: int) -> dict:
         "device_kind": device.device_kind, "platform": device.platform,
         "chips": len(trace.devices), "jax": jax.__version__, "runs": runs,
         "layout": describe(meta), "grad_norm": float(norm),
+        "large_leaves": large_leaves(meta, args[0]),
         "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
         # medians over the runs, then the mean over the chips
         "ms": {k: statistics.fmean(c[k] for c in per_chip)

@@ -224,11 +224,22 @@ _SYNTHETIC = {"blocks": jax.ShapeDtypeStruct((8, 24576, 2048), BF16),
               "norm": jax.ShapeDtypeStruct((2048,), BF16)}
 
 
-@pytest.mark.parametrize("tree,own_rows,reshape_gb,concatenate_gb", [
-    (lambda: _SYNTHETIC, 1.0, 0.1, 0.4), (_cell_params, 0.8317, 0.6, 0.4)],
-    ids=["synthetic", "cell"])
+#: a smaller tree whose head, 2,048 x 1,000, is no whole number of lane tiles
+#: wide either: it too enters by its transpose's rows
+_NARROW_HEAD = {"blocks": jax.ShapeDtypeStruct((4, 8192, 2048), BF16),
+                "head": jax.ShapeDtypeStruct((2048, 1000), BF16),
+                "norm": jax.ShapeDtypeStruct((2048,), BF16)}
+
+
+@pytest.mark.parametrize(
+    "tree,buckets,shard,head,own_rows,reshape_gb,concatenate_gb", [
+        (lambda: _SYNTHETIC, 13, (23040, 512), (50257, 2048), 1.0, 0.1, 0.4),
+        (_cell_params, 13, (23040, 512), (2048, 50257), 0.9996, 0.1, 0.4),
+        (lambda: _NARROW_HEAD, 2, (16896, 512), (2048, 1000), 1.0, 0.1, 0.4)],
+    ids=["synthetic", "cell", "narrow_head"])
 def test_flat_exchange_reaches_the_chips_as_reduce_scatters(
-        v5e_2x2, tree, own_rows, reshape_gb, concatenate_gb):
+        v5e_2x2, tree, buckets, shard, head, own_rows, reshape_gb,
+        concatenate_gb):
     """The ZeRO-1 flat exchange at the four-chip training cell's size (613 M
     bf16 parameters, dp=4, Adam with f32 masters), compiled for the 2x2, on
     a tree of a few large leaves and on the cell's own 101: every bucket's
@@ -237,10 +248,17 @@ def test_flat_exchange_reaches_the_chips_as_reduce_scatters(
     one contiguous block (a 1-D operand, or a scatter over the major
     dimension), or whose rows it cannot chunk, to an all-reduce of the whole
     operand: twice the wire bytes, and what the cell paid until its exchange
-    was bucketed. And the matrices enter their buckets by their own rows:
-    the program re-tiles (a standalone ``reshape``) and stacks (a standalone
-    ``concatenate``) little more than the head's 50,257 columns, where the
-    cell's tree raveled into rows of 4,096 read 2.86 and 1.36 GB. (23,040
+    was bucketed. And the matrices enter their buckets by their own rows,
+    the head of 2,048 x 50,257 by its transpose's (a bitcast: this compiler
+    keeps that parameter with its rows minor): the program re-tiles (a
+    standalone ``reshape``) next to nothing and stacks (a standalone
+    ``concatenate``) the two vocabulary-high matrices, where the cell's tree
+    raveled into rows of 4,096 read 2.86 and 1.36 GB, and it walks no leaf
+    row by row (no ``while``, no ``dynamic-update-slice``), as it did the
+    head's ravel, nor transposes or copies it in an op of its own: so for
+    the cell's head and for one of 2,048 x 1,000 in a smaller tree, the two
+    shapes compiled here; where a compiler kept such a matrix with its
+    columns minor, each transpose would be a pass over it. (23,040
     rows a bucket is a count this compiler reduces quickly; 23,168, 128 x
     181, took it half as long again on the chips: ``PERF.md`` section 6.)"""
     import re
@@ -256,7 +274,7 @@ def test_flat_exchange_reaches_the_chips_as_reduce_scatters(
     mesh = Mesh(np.array(v5e_2x2.devices[:4]), ("dp",))
     tree = tree()
     meta = upd.flat_meta(tree, 4)
-    assert meta.n_buckets == 13 and meta.shard_shape == (23040, 512)
+    assert meta.n_buckets == buckets and meta.shard_shape == shard
     assert round(meta.own_rows_share, 4) == own_rows
     tx = optax.adam(1e-4)
     opt = jax.eval_shape(lambda: upd.flat_opt_init(
@@ -297,3 +315,11 @@ def test_flat_exchange_reaches_the_chips_as_reduce_scatters(
     ops = entry_ops(hlo)
     assert ops.get("reshape", [0, 0])[1] < reshape_gb * 1e9, ops
     assert ops.get("concatenate", [0, 0])[1] < concatenate_gb * 1e9, ops
+    assert not {"while", "dynamic-update-slice"} & set(ops), ops
+    # by the name of an op or of the fusion that stands for it
+    a, b = head
+    moved = [m.group(0) for m in re.finditer(
+        r"%(\S+) = \w+(\[[\d,]*\])\S* ([\w-]+)\(", entry)
+        if m.group(2) in (f"[{a},{b}]", f"[{b},{a}]")
+        and re.search("copy|transpose", m.group(1) + m.group(3))]
+    assert not moved, moved

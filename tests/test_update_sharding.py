@@ -11,6 +11,8 @@ only come from a structural bug (wrong scaling, dropped microbatch, slice
 misalignment), never from rounding.
 """
 
+import os
+import subprocess
 import sys
 
 import jax
@@ -42,12 +44,18 @@ def _dyadic_data(B=32, D=8, O=4, seed=0):
     return x, y
 
 
-def _dyadic_estimator(cfg, x, y, optimizer=None, mesh=None, D=8, H=16, O=4):
+def _dyadic_model(D=8, H=16, O=4, bias=False):
+    """Two Dense layers, linear; ``bias``: the first has a bias vector."""
+    return Sequential([L.Dense(H, use_bias=bias, input_shape=(D,)),
+                       L.Dense(O, use_bias=False)])
+
+
+def _dyadic_estimator(cfg, x, y, optimizer=None, mesh=None, D=8, H=16, O=4,
+                      bias=False):
     """Linear two-Dense model whose initial weights are rounded to multiples
     of 1/8 (exact f32 arithmetic on the dyadic data)."""
-    model = Sequential([L.Dense(H, use_bias=False, input_shape=(D,)),
-                        L.Dense(O, use_bias=False)])
-    est = Estimator(model, optimizer=optimizer or SGD(lr=0.5), loss="mse",
+    est = Estimator(_dyadic_model(D, H, O, bias),
+                    optimizer=optimizer or SGD(lr=0.5), loss="mse",
                     config=cfg, mesh=mesh)
     state = est._init_state((x, y), seed=0)
     state["params"] = jax.tree_util.tree_map(
@@ -70,9 +78,46 @@ def _leaves(est):
 # (three rows of 64: two of the first leaf, one of the second) exchanges 3
 # buckets in the plain view. ``_OWN_ROWS``: a hidden layer as wide as one
 # lane tile a shard, under a target the toy exceeds, so the first kernel
-# enters the view by its own rows and the second is raveled: (hidden size,
-# bucket target) for the 2-device mesh and for the 8-device one
+# enters the view by its own rows and the second, (hidden, 4), by its
+# transpose's: (hidden size, bucket target) for the 2-device mesh and for the
+# 8-device one. ``_HEAD``: the same hidden layer with a bias vector (raveled)
+# and a head of an odd width, (hidden, 5), again in three buckets
 _OWN_ROWS = {2: (256, 1024), 8: (1024, 4096)}
+_HEAD = {2: (256, 1280), 8: (1024, 5120)}
+#: (bucket target or None, hidden, outputs, bias) of the views the
+#: parametrised tests below run on the 8-device mesh, and on the 2-device one
+_VIEWS8 = [pytest.param(None, 16, 4, False, id="one_bucket"),
+           pytest.param(48, 16, 4, False, id="plain"),
+           pytest.param(_OWN_ROWS[8][1], _OWN_ROWS[8][0], 4, False,
+                        id="own_rows"),
+           pytest.param(_HEAD[8][1], _HEAD[8][0], 5, True, id="head")]
+_VIEWS2 = [pytest.param(48, 16, 4, False, id="plain"),
+           pytest.param(_OWN_ROWS[2][1], _OWN_ROWS[2][0], 4, False,
+                        id="own_rows"),
+           pytest.param(_HEAD[2][1], _HEAD[2][0], 5, True, id="head")]
+
+
+def _view_before(meta):
+    """``meta``'s view as the version before built it: the same width, rows
+    and buckets, with every leaf that now enters by its transpose's rows
+    raveled, and so behind the matrices."""
+    blocks = tuple(max(0, k) for k in meta.col_blocks)
+    return meta._replace(col_blocks=blocks, order=tuple(sorted(
+        range(len(blocks)), key=lambda i: not blocks[i])))
+
+
+def _col_blocks(hidden, bias):
+    """What ``flat_meta`` records of the toy's leaves in its several-bucket
+    view: the first kernel by its own rows, the bias raveled, the second
+    kernel (hidden rows, a few columns) by its transpose's."""
+    if hidden == 16:
+        return (0, 0)
+    return (0, 1, -1) if bias else (1, -1)
+
+
+#: the XLA flag under which a bf16 value is rounded wherever the program
+#: says it is, fused or not
+_ROUNDED = "--xla_allow_excess_precision=false"
 
 
 def _mesh2():
@@ -171,8 +216,8 @@ def test_flat_opt_state_is_one_over_dp(zoo_ctx, bucket_target, bucket_len,
                                        hidden):
     """ZeRO-1 memory claim on the 8-way dp mesh: per-device optimizer-state
     bytes ≈ replicated/8 (within padding + replicated scalar count leaves),
-    with one bucket, with three, and with a kernel that enters its buckets
-    by its own rows."""
+    with one bucket, with three, and with kernels that enter their buckets
+    by their own rows and by their transpose's."""
     x, y = _dyadic_data(B=64, D=16)
     if bucket_len:
         bucket_target(bucket_len)
@@ -193,7 +238,7 @@ def test_flat_opt_state_is_one_over_dp(zoo_ctx, bucket_target, bucket_len,
     if hidden == 64:
         assert (meta.n_buckets, meta.layout) == (3 if bucket_len else 1, None)
     else:
-        assert meta.n_buckets == 5 and meta.col_blocks == (1, 0)
+        assert meta.n_buckets == 5 and meta.col_blocks == (1, -1)
     r, s = opt_bytes(e_r), opt_bytes(e_s)
     assert s <= r / 8 * 1.35 + 512, (r, s)
 
@@ -245,10 +290,10 @@ def test_one_gradient_collective_per_bucket_per_global_step(
     assert counts[4].get("all-gather", 0) == meta.n_buckets, counts
 
 
-@pytest.mark.parametrize("bucket_len,hidden", [
-    (None, 16), (48, 16), (_OWN_ROWS[8][1], _OWN_ROWS[8][0])])
+@pytest.mark.parametrize("bucket_len,hidden,out,bias", _VIEWS8)
 def test_lowered_step_defines_each_collective_once(zoo_ctx, bucket_target,
-                                                   bucket_len, hidden):
+                                                   bucket_len, hidden, out,
+                                                   bias):
     """The benchmark's check (benchmark/drivers/train_fit.py) counts TEXT
     occurrences in the lowered step and wants one reduce_scatter and one
     all_gather (``benchmark/traffic/fit-2k-zero1.json``): every bucket has
@@ -257,14 +302,15 @@ def test_lowered_step_defines_each_collective_once(zoo_ctx, bucket_target,
     the leaves enter them. A refactor that inlines the buckets, or gives a
     leaf a bucket in a shape of its own, fails here before it fails on the
     chip."""
-    x, y = _dyadic_data(B=64)
+    x, y = _dyadic_data(B=64, O=out)
     if bucket_len:
         bucket_target(bucket_len)
     est = _dyadic_estimator(
         TrainConfig(shuffle=False, log_every_n_steps=10 ** 9,
-                    update_sharding=True), x, y, H=hidden)
+                    update_sharding=True), x, y, H=hidden, O=out, bias=bias)
     assert est._flat_meta.n_buckets == (3 if bucket_len else 1)
-    assert (est._flat_meta.own_rows_share > 0) == (hidden > 16)
+    assert est._flat_meta.col_blocks == (
+        _col_blocks(hidden, bias) if bucket_len else (0, 0))
     text = est.lower_train_step((x, y)).as_text()
     assert text.count('stablehlo.reduce_scatter"') == 1
     assert text.count('stablehlo.all_gather"') == 1
@@ -372,10 +418,11 @@ def test_flat_meta_lays_matrices_in_by_their_own_rows():
     """In a model of several buckets the view is as wide as the matrices'
     own minor dimension allows (a shard of whole lane tiles): a matrix that
     many columns wide, or a multiple, enters by its own rows, its column
-    blocks one below the other, the matrices of whole row tiles first;
-    every other leaf is raveled as in the plain view, after them. Bucket
-    then unflatten is the identity, and a tree under one target keeps the
-    plain view in one bucket."""
+    blocks one below the other, the matrices of whole row tiles first; a
+    matrix that cannot but has that many rows, or a multiple, enters by its
+    transpose's rows; every other leaf is raveled as in the plain view,
+    after them. Bucket then unflatten is the identity, and a tree under one
+    target keeps the plain view in one bucket."""
     rng = np.random.default_rng(0)
     params = {"a": rng.normal(size=(16, 512)).astype(np.float32),
               "b": rng.normal(size=(256,)).astype(np.float32),
@@ -387,22 +434,24 @@ def test_flat_meta_lays_matrices_in_by_their_own_rows():
     meta = upd.flat_meta(params, 2, bucket_len=3072)
     assert meta.shard_shape == (12, 128) and meta.bucket_shape == (12, 256)
     # "a" is two column blocks of 16 rows, "d" and "e" one, "g" (seen as
-    # sixteen rows of 512) two; "f" is a matrix of one and a half widths
-    # and is raveled like "b" and "c"
-    assert meta.col_blocks == (2, 0, 0, 1, 1, 0, 2)
+    # sixteen rows of 512) two; "c" has one view width of rows and enters
+    # as the four rows of its transpose; "f" is a matrix of one and a half
+    # widths with sixteen rows and is raveled like "b"
+    assert meta.col_blocks == (2, 0, -1, 1, 1, 0, 2)
     assert meta.leaf_rows == (32, 1, 4, 3, 32, 24, 32)
-    assert meta.order == (0, 4, 6, 3, 1, 2, 5) and meta.n_buckets == 11
-    assert meta.own_rows_share == (8192 + 768 + 8192 + 8192) / meta.n
+    assert meta.order == (0, 4, 6, 2, 3, 1, 5) and meta.n_buckets == 11
+    assert meta.own_rows_share == (8192 + 1024 + 768 + 8192 + 8192) / meta.n
     # bucket 1 holds the last four rows of "a"'s first column block and the
     # first eight of its second
     assert meta.pieces(1) == ((0, 12, 24),)
     assert list(meta.blocks(0, 12, 24)) == [(0, 12, 16), (1, 0, 8)]
-    assert meta.pieces(8) == ((3, 0, 3), (1, 0, 1), (2, 0, 4), (5, 0, 4))
+    assert meta.pieces(8) == ((2, 0, 4), (3, 0, 3), (1, 0, 1), (5, 0, 4))
+    assert list(meta.blocks(2, 0, 4)) == [(0, 0, 4)]
     a, g = params["a"], params["g"].reshape(16, 512)
     view = np.concatenate([
         a[:, :256], a[:, 256:], params["e"], g[:, :256], g[:, 256:],
-        params["d"], params["b"].reshape(1, 256),
-        params["c"].reshape(4, 256), params["f"].reshape(24, 256)])
+        params["c"].T, params["d"], params["b"].reshape(1, 256),
+        params["f"].reshape(24, 256)])
     view = np.pad(view, ((0, 11 * 12 - len(view)), (0, 0)))
     buckets = [upd.flat_bucket(params, meta, b) for b in range(meta.n_buckets)]
     for b, got in enumerate(buckets):
@@ -413,8 +462,8 @@ def test_flat_meta_lays_matrices_in_by_their_own_rows():
         np.testing.assert_array_equal(np.asarray(back[k]), params[k])
     # the raveled leaves take the rows they take in the plain view of that
     # width, one after the other
-    assert [meta.leaf_rows[i] for i in (1, 2, 5)] == [
-        -(-params[k].size // 256) for k in "bcf"]
+    assert [meta.leaf_rows[i] for i in (1, 5)] == [
+        -(-params[k].size // 256) for k in "bf"]
     # under one target: the plain view, one bucket, nothing to tell it by
     one = upd.flat_meta(params, 2)
     assert one.n_buckets == 1 and one.layout is None
@@ -422,39 +471,166 @@ def test_flat_meta_lays_matrices_in_by_their_own_rows():
     whole = np.asarray(upd.flatten_tree(params, one))
     np.testing.assert_array_equal(whole[:8192 + 256], np.concatenate(
         [params["a"].ravel(), params["b"]]))      # raveled, in tree order
-    # the four-chip cell: 2,048 columns (512 a shard) take every matrix but
-    # the head, whose 50,257 columns are raveled with the vectors
+    # the four-chip cell: 2,048 columns (512 a shard) take every matrix by
+    # its own rows but the head, (2048, 50257), which enters by the 50,257
+    # rows of its transpose: as many as its ravel took, so the view has the
+    # rows and the buckets it had with the head raveled, and only the
+    # vectors are raveled still
     cell = upd.flat_meta(_cell_tree(), 4)
     assert cell.n_buckets == 13 and cell.shard_shape == (23040, 512)
-    assert round(cell.own_rows_share, 4) == 0.8317
+    assert round(cell.own_rows_share, 4) == 0.9996
     names = [jax.tree_util.keystr(p) for p, _ in
              jax.tree_util.tree_leaves_with_path(_cell_tree())]
-    assert [names[i] for i in cell.order[32:35]] == [
-        "['pos_embeddings']", "['token_embeddings']",
+    assert [names[i] for i in cell.order[32:36]] == [
+        "['pos_embeddings']", "['logits_kernel']", "['token_embeddings']",
         "['block0']['attn']['out_bias']"]
-    assert cell.col_blocks[names.index("['logits_kernel']")] == 0
+    head = names.index("['logits_kernel']")
+    assert cell.col_blocks[head] == -1 and cell.leaf_rows[head] == 50257
+    assert sum(cell.leaf_rows) == 299_276 == sum(
+        -(-z // 2048) for z in cell.sizes)
+    assert {k for k, s in zip(cell.col_blocks, cell.shapes)
+            if len(s) == 1} == {0}
     assert cell.npad <= cell.n * (1 + 1 / 64)
 
 
-@pytest.mark.parametrize("hidden,target", [(16, 48), _OWN_ROWS[2]],
-                         ids=["plain", "own_rows"])
+def test_flat_meta_lays_a_head_in_by_the_rows_of_its_transpose():
+    """A head of an odd width beside own-rows matrices and vectors: its
+    columns fit no view, its rows are two view widths, so it is recorded as
+    entering by the two column blocks of its transpose, one below the other,
+    behind the matrices of whole row tiles and before the raveled vectors.
+    It takes the rows its ravel took, so the view's rows and buckets are the
+    raveled view's; bucket then unflatten is the identity bit for bit, in
+    bf16 too; the layout tells the view from the one that raveled the head;
+    and under one target the tree keeps the plain view."""
+    rng = np.random.default_rng(1)
+    params = {"bias": rng.normal(size=(512,)).astype(np.float32),
+              "emb": rng.normal(size=(37, 256)).astype(np.float32),
+              "head": rng.normal(size=(512, 37)).astype(np.float32),
+              "ln": rng.normal(size=(256,)).astype(np.float32),
+              "w": rng.normal(size=(256, 512)).astype(np.float32),
+              "wide": rng.normal(size=(2, 512, 37)).astype(np.float32)}
+    meta = upd.flat_meta(params, 2, bucket_len=8192)
+    assert meta.bucket_shape == (31, 256)
+    # "wide" has three dimensions and stays raveled, as does a matrix
+    # neither of whose dimensions fits ("f" of the test above)
+    assert meta.col_blocks == (0, 1, -2, 0, 2, 0)
+    assert meta.leaf_rows == (2, 37, 74, 1, 512, 148)
+    assert meta.order == (4, 1, 2, 0, 3, 5) and meta.n_buckets == 25
+    raveled = _view_before(meta)
+    assert raveled.col_blocks == (0, 1, 0, 0, 2, 0)
+    assert sum(meta.leaf_rows) == sum(-(-z // 256) for z in meta.sizes)
+    assert not np.array_equal(meta.layout, raveled.layout)
+    assert meta.own_rows_share == (37 * 256 + 512 * 37 + 256 * 512) / meta.n
+    # the head's rows 20 to 52 of 74: the last 17 rows of its transpose's
+    # first column block, the first 15 of its second
+    assert list(meta.blocks(2, 20, 52)) == [(0, 20, 37), (1, 0, 15)]
+    w, t = params["w"], params["head"].T
+    view = np.concatenate([
+        w[:, :256], w[:, 256:], params["emb"], t[:, :256], t[:, 256:],
+        params["bias"].reshape(2, 256), params["ln"].reshape(1, 256),
+        np.pad(params["wide"].ravel(), (0, 148 * 256 - 2 * 512 * 37)
+               ).reshape(148, 256)])
+    view = np.pad(view, ((0, 25 * 31 - len(view)), (0, 0)))
+    for dtype in (np.float32, jnp.bfloat16):
+        tree = {k: jnp.asarray(v, dtype) for k, v in params.items()}
+        m = meta._replace(dtypes=(jnp.dtype(dtype),) * 6)
+        buckets = [upd.flat_bucket(tree, m, b, jnp.dtype(dtype))
+                   for b in range(m.n_buckets)]
+        for b, got in enumerate(buckets):
+            np.testing.assert_array_equal(
+                np.asarray(got.astype(np.float32)),
+                np.asarray(jnp.asarray(view[b * 31:(b + 1) * 31], dtype
+                                       ).astype(np.float32)))
+        back = upd.unflatten_buckets(buckets, m)
+        for k in params:
+            assert back[k].dtype == dtype and back[k].shape == tree[k].shape
+            np.testing.assert_array_equal(
+                np.asarray(back[k].astype(np.float32)),
+                np.asarray(tree[k].astype(np.float32)))
+    one = upd.flat_meta(params, 2)
+    assert one.n_buckets == 1 and one.layout is None
+    assert one.col_blocks == (0,) * 6 and one.order == tuple(range(6))
+
+
+@pytest.mark.parametrize("precision", ["f32", "bf16"])
+def test_flat_exchange_is_bit_identical_under_every_view(precision):
+    """The same gradients through ``flat_exchange`` on two devices, three
+    Adam steps, f32 params or bf16 params with f32 masters: the view with a
+    head entering by its transpose's rows gives the parameters, the masters
+    and the moments of the plain one-bucket view and of the view that ravels
+    the head (what the version before built) bit for bit. The view decides
+    which rows of which bucket carry an element, never its arithmetic."""
+    import optax
+    from jax import shard_map
+
+    dtype = jnp.float32 if precision == "f32" else jnp.bfloat16
+    rng = np.random.default_rng(2)
+    shapes = {"bias": (512,), "emb": (37, 256), "head": (512, 37),
+              "ln": (256,), "w": (256, 512)}
+    params = {k: jnp.asarray(rng.normal(size=s), dtype)
+              for k, s in shapes.items()}
+    grads = [{k: jnp.asarray(rng.normal(size=s), dtype)
+              for k, s in shapes.items()} for _ in range(3)]
+    mesh = Mesh(np.array(jax.devices()[:2]), ("dp",))
+    tx = optax.adam(1e-2)
+    head = upd.flat_meta(params, 2, bucket_len=8192)
+    views = {"one": upd.flat_meta(params, 2), "head": head,
+             "raveled": _view_before(head)}
+    assert views["one"].n_buckets == 1 and head.n_buckets > 3
+    assert head.col_blocks == (0, 1, -2, 0, 2)
+    assert views["raveled"].col_blocks == (0, 1, 0, 0, 2)
+
+    def run(meta):
+        opt = upd.flat_opt_init(tx, params, meta,
+                                keep_master=precision == "bf16")
+        specs = jax.tree_util.tree_map(
+            lambda l: P(None, "dp") if l.shape == meta.bucket_shape else P(),
+            opt)
+        replicated = jax.tree_util.tree_map(lambda _: P(), params)
+        step = jax.jit(shard_map(
+            lambda p, g, o: upd.flat_exchange(p, g, o, meta, tx), mesh=mesh,
+            in_specs=(replicated, replicated, specs),
+            out_specs=(replicated, specs, P()), check_vma=False))
+        p = params
+        for g in grads:
+            p, opt, _ = step(p, g, opt)
+        f32 = meta._replace(dtypes=(jnp.dtype("float32"),) * len(shapes))
+        state = [opt.inner_state[b][0] for b in range(meta.n_buckets)]
+        trees = [p, upd.unflatten_buckets([s.mu for s in state], f32),
+                 upd.unflatten_buckets([s.nu for s in state], f32)]
+        if opt.master is not None:
+            trees.append(upd.unflatten_buckets(list(opt.master), f32))
+        return [np.asarray(l.astype(jnp.float32)) for t in trees
+                for l in jax.tree_util.tree_leaves(t)]
+
+    got = {name: run(meta) for name, meta in views.items()}
+    for name in ("one", "raveled"):
+        for a, b in zip(got[name], got["head"]):
+            np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+@pytest.mark.parametrize("target,hidden,out,bias", _VIEWS2)
 @pytest.mark.parametrize("precision", ["f32", "bf16"])
 @pytest.mark.parametrize("optimizer", ["sgd_momentum", "adam"])
 def test_bucketed_update_bit_parity_two_devices(zoo_ctx, bucket_target,
-                                                optimizer, precision, hidden,
-                                                target):
-    """Three buckets on a 2-device dp mesh, in the plain view and with the
-    first kernel entering by its own rows. The view changes which collective
-    carries an element, never its arithmetic: one step and six more are
-    bit-identical to the one-bucket exchange, f32 params or bf16 params with
-    f32 masters. Against the REPLICATED update one f32 step is bit-identical
-    and six more stay within 1e-5; under bf16 compute the replicated path sums
-    the bf16 gradients across replicas before the cast where the flat path
-    casts to f32 first, so there the comparison is at bf16's grain."""
+                                                request, optimizer, precision,
+                                                hidden, target, out, bias):
+    """Three buckets on a 2-device dp mesh: in the plain view; with the
+    first kernel entering by its own rows and the second by its transpose's;
+    and with a bias vector raveled between them and a head of an odd width.
+    The view changes which collective carries an element, never its
+    arithmetic: one step and six more are bit-identical to the one-bucket
+    exchange, f32 params or bf16 params with f32 masters (where a leaf
+    enters by its transpose under bf16 compute, in a process of its own
+    whose compiler keeps no excess precision: see below). Against the
+    REPLICATED update one f32 step is bit-identical and six more stay within
+    1e-5; under bf16 compute the replicated path sums the bf16 gradients
+    across replicas before the cast where the flat path casts to f32 first,
+    so there the comparison is at bf16's grain."""
     make = {"sgd_momentum": lambda: SGD(lr=0.5, momentum=0.5),
             "adam": lambda: Adam(lr=1e-2)}[optimizer]
     extra = {"compute_dtype": "bfloat16"} if precision == "bf16" else {}
-    x, y = _dyadic_data(B=32)
+    x, y = _dyadic_data(B=32, O=out)
     ests = {}
     for name, sharded in (("replicated", False), ("one", True),
                           ("three", True)):
@@ -463,12 +639,14 @@ def test_bucketed_update_bit_parity_two_devices(zoo_ctx, bucket_target,
         cfg = TrainConfig(shuffle=False, log_every_n_steps=10 ** 9,
                           update_sharding=sharded, **extra)
         ests[name] = _dyadic_estimator(cfg, x, y, optimizer=make(),
-                                       mesh=_mesh2(), H=hidden)
+                                       mesh=_mesh2(), H=hidden, O=out,
+                                       bias=bias)
     one, three = ests["one"]._flat_meta, ests["three"]._flat_meta
     assert (one.n_buckets, one.layout) == (1, None)
     assert three.n_buckets == 3
-    assert three.col_blocks == ((1, 0) if hidden > 16 else (0, 0))
+    assert three.col_blocks == _col_blocks(hidden, bias)
     bf16_grain = dict(rtol=0, atol=2 ** -7)
+    transposed = [k < 0 for k in three.col_blocks]
 
     def compare(steps):
         got = {k: [l.astype(np.float32) for l in _leaves(e)]
@@ -485,6 +663,32 @@ def test_bucketed_update_bit_parity_two_devices(zoo_ctx, bucket_target,
 
     for est in ests.values():
         est.fit((x, y), batch_size=32, epochs=1)      # exactly one step
+    if (precision == "bf16" and any(transposed)
+            and _ROUNDED not in os.environ.get("XLA_FLAGS", "")):
+        # XLA:CPU fuses the transpose into the matmul that makes the leaf's
+        # gradient and, allowed excess precision (its default), drops that
+        # gradient's rounding to bf16 on the way: the GRADIENT of that one
+        # leaf, which bf16 does not hold exactly even on the dyadic data,
+        # keeps bits the one-bucket fit rounds away. Here: the first step
+        # leaves every other leaf bit-equal and that leaf within a bf16 ulp
+        # of its largest element. Then this very case runs again in a
+        # process whose compiler rounds where the program says so, and
+        # holds every assertion below bit for bit.
+        got = {k: [l.astype(np.float32) for l in _leaves(ests[k])]
+               for k in ("one", "three")}
+        for t, a, b in zip(transposed, got["one"], got["three"]):
+            np.testing.assert_allclose(
+                a, b, rtol=0, atol=np.abs(a).max() * 2 ** -7 if t else 0)
+        env = {k: v for k, v in os.environ.items()
+               if not k.startswith("PYTEST_")}
+        env["XLA_FLAGS"] = f"{env.get('XLA_FLAGS', '')} {_ROUNDED}".strip()
+        child = subprocess.run(
+            [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+             f"{__file__}::{request.node.name}"],
+            env=env, capture_output=True, text=True, timeout=300)
+        assert child.returncode == 0 and "1 passed" in child.stdout, (
+            child.stdout[-4000:] + child.stderr[-2000:])
+        return
     compare(1)
     for est in ests.values():
         est.fit((x, y), batch_size=32, epochs=7)      # six more
@@ -496,7 +700,7 @@ def test_bucketed_update_bit_parity_two_devices(zoo_ctx, bucket_target,
             [jnp.asarray(m) for m in jax.device_get(
                 ests[k].train_state["opt_state"]).master],
             ests[k]._flat_meta._replace(
-                dtypes=(jnp.dtype("float32"),) * 2)))
+                dtypes=(jnp.dtype("float32"),) * (2 + bias))))
             for k in ("one", "three")}
         for a, b in zip(masters["one"], masters["three"]):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -666,24 +870,23 @@ def test_sanitize_raises_on_overdividing_tuple_axes(zoo_ctx):
 
 
 # ---------------------------------------------------------------- durability
-@pytest.mark.parametrize("bucket_len,hidden", [
-    (None, 16), (48, 16), (_OWN_ROWS[8][1], _OWN_ROWS[8][0])])
+@pytest.mark.parametrize("bucket_len,hidden,out,bias", _VIEWS8)
 def test_flat_mode_checkpoint_roundtrip(zoo_ctx, tmp_path, bucket_target,
-                                        bucket_len, hidden):
-    x, y = _dyadic_data(B=64)
+                                        bucket_len, hidden, out, bias):
+    x, y = _dyadic_data(B=64, O=out)
     if bucket_len:
         bucket_target(bucket_len)
     cfg = TrainConfig(shuffle=False, log_every_n_steps=10 ** 9,
                       update_sharding=True, checkpoint_dir=str(tmp_path))
-    est = _dyadic_estimator(cfg, x, y, optimizer=Adam(1e-2), H=hidden)
+    est = _dyadic_estimator(cfg, x, y, optimizer=Adam(1e-2), H=hidden, O=out,
+                            bias=bias)
     est.fit((x, y), batch_size=32, epochs=2)
     it = est.trainer_state.iteration
     # fresh estimator resumes from the flat-layout checkpoint
     cfg2 = TrainConfig(shuffle=False, log_every_n_steps=10 ** 9,
                        update_sharding=True, checkpoint_dir=str(tmp_path))
-    model = Sequential([L.Dense(hidden, use_bias=False, input_shape=(8,)),
-                        L.Dense(4, use_bias=False)])
-    est2 = Estimator(model, optimizer=Adam(1e-2), loss="mse", config=cfg2)
+    est2 = Estimator(_dyadic_model(H=hidden, O=out, bias=bias),
+                     optimizer=Adam(1e-2), loss="mse", config=cfg2)
     est2.load(str(tmp_path), sample_batch=(x, y))
     # the flat-layout state (FlatUpdateState + dp-sharded vectors) round-trips
     assert est2.trainer_state.iteration == it
@@ -699,40 +902,55 @@ def test_flat_mode_checkpoint_roundtrip(zoo_ctx, tmp_path, bucket_target,
     assert est2.trainer_state.iteration == it + 2
 
 
-@pytest.mark.parametrize("bucket_len,hidden,written_as", [
-    (None, 16, "vector"), (48, 16, "vector"),
-    (_OWN_ROWS[8][1], _OWN_ROWS[8][0], "plain_buckets"),
-    (_OWN_ROWS[8][1], _OWN_ROWS[8][0], "another_view")])
+@pytest.mark.parametrize("bucket_len,hidden,out,bias,written_as", [
+    (None, 16, 4, False, "vector"), (48, 16, 4, False, "vector"),
+    (_OWN_ROWS[8][1], _OWN_ROWS[8][0], 4, False, "plain_buckets"),
+    (_OWN_ROWS[8][1], _OWN_ROWS[8][0], 4, False, "another_view"),
+    (_OWN_ROWS[8][1], _OWN_ROWS[8][0], 4, False, "transposes_raveled"),
+    (_HEAD[8][1], _HEAD[8][0], 5, True, "transposes_raveled")])
 def test_old_flat_layout_checkpoint_is_repadded_or_refused(
-        zoo_ctx, tmp_path, bucket_target, bucket_len, hidden, written_as):
+        zoo_ctx, tmp_path, bucket_target, bucket_len, hidden, out, bias,
+        written_as):
     """A checkpoint whose flat optimizer state is one ``(npad,)`` vector per
     slot (the layout before bucketing): a one-bucket estimator re-pads it
     into its ``bucket_shape`` (same flat order); a several-bucket one refuses
     it in words. So does an estimator whose first kernel enters its buckets
     by its own rows when the snapshot's buckets, of the very same shape,
     were stacked in the plain view (every leaf raveled, in tree order: what
-    the version before wrote) or in a view of other leaves. It is never read
-    as if it were the new layout."""
+    the version before wrote), in a view of other leaves, or in the view
+    of the version that raveled what now enters by its transpose's rows
+    (the same buckets, rows and width; the head's rows hold its ravel). It
+    is never read as if it were the new layout."""
     import optax
 
     from analytics_zoo_tpu.engine import checkpoint as ckpt
 
-    x, y = _dyadic_data(B=64)
+    x, y = _dyadic_data(B=64, O=out)
     if bucket_len:
         bucket_target(bucket_len)
     cfg = TrainConfig(shuffle=False, log_every_n_steps=10 ** 9,
                       update_sharding=True, compute_dtype="bfloat16")
-    est = _dyadic_estimator(cfg, x, y, optimizer=Adam(1e-2), H=hidden)
+    est = _dyadic_estimator(cfg, x, y, optimizer=Adam(1e-2), H=hidden, O=out,
+                            bias=bias)
     state = jax.device_get(est.train_state)
     if written_as != "vector":
-        mine = state["opt_state"]
-        assert mine.master[0].shape == (4, 1024) and len(mine.master) == 3
-        other = (None if written_as == "plain_buckets"
-                 else np.asarray(mine.layout)[::-1].copy())
+        mine, meta = state["opt_state"], est._flat_meta
+        rows = 5 if bias else 4
+        assert mine.master[0].shape == (rows, 1024) and len(mine.master) == 3
+        if written_as == "transposes_raveled":
+            # the same buckets: a transpose takes the rows the ravel took
+            assert sum(meta.leaf_rows) == sum(-(-z // 1024)
+                                              for z in meta.sizes)
+            other = _view_before(meta).layout
+            assert other.shape == meta.layout.shape
+        else:
+            other = (None if written_as == "plain_buckets"
+                     else np.asarray(mine.layout)[::-1].copy())
         old = dict(state, opt_state=mine._replace(layout=other))
         ckpt.save_checkpoint(str(tmp_path), old, iteration=7, epoch=1)
         with pytest.raises(ValueError, match=(
-                r"3 bucket\(s\) of \(4, 1024\), matrices by their own rows.*"
+                rf"3 bucket\(s\) of \({rows}, 1024\), matrices by their own "
+                r"rows.*"
                 + ("16 leaves, template has 17"
                    if written_as == "plain_buckets" else "another flat view")
                 + r".*Resume it with the version that wrote it")):
