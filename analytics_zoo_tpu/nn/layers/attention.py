@@ -22,6 +22,9 @@ import numpy as np
 
 from ...ops.attention import (count_route, full_attention,
                               prefer_flash_single_device, sharded_attention)
+from ...ops.kv_cache import (PAGES, decode_attention, decode_attention_multi,
+                             paged_read, paged_write_multi, prefill_write)
+from ...ops.paged_attention import paged_attention, use_kernel
 from ..activations import get_activation
 from ..module import Layer, as_compute, get_initializer, param_dtype
 from .normalization import LayerNormalization
@@ -45,7 +48,20 @@ class PositionalEmbedding(Layer):
 
 
 class MultiHeadAttention(Layer):
-    """Self-attention with fused QKV projection and strategy dispatch."""
+    """Self-attention with fused QKV projection and strategy dispatch.
+
+    It is a *mixer* (:mod:`.mixers`): ``apply`` is the whole sequence with no
+    cache, ``prefill(params, x, cache, at)`` the same forward that also
+    leaves K and V of the bucket in this layer's pages, ``decode(params, x,
+    cache, at)`` one OR MORE new tokens a row against them (a decode step at
+    width 1; a speculative verify step and a prefill chunk beyond). ``cache``
+    is ``{"k", "v"}``, this layer's two pools; ``at`` a
+    :class:`~analytics_zoo_tpu.ops.kv_cache.StepContext`. A subclass with
+    another projection overrides ``qkv_proj`` / ``out_proj`` and inherits
+    routing, write and attend."""
+
+    state_kind = PAGES
+    scope = "zoo_full_layer"
 
     def __init__(self, hidden_size: int, n_head: int, causal: bool = False,
                  attn_strategy: str = "auto", name=None, input_shape=None):
@@ -54,6 +70,7 @@ class MultiHeadAttention(Layer):
         self.hidden_size = hidden_size
         self.n_head = n_head
         self.head_dim = hidden_size // n_head
+        self.pool_heads = n_head        # the heads axis of this layer's pools
         self.causal = causal
         self.attn_strategy = attn_strategy
 
@@ -84,15 +101,20 @@ class MultiHeadAttention(Layer):
         qkv = qkv.reshape(b, t, 3, self.n_head, self.head_dim)
         return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
 
-    def out_proj(self, params, o, dtype):
+    def out_proj(self, params, o):
         """(B, T, n_head, head_dim) attention output → (B, T, hidden)."""
         b, t = o.shape[:2]
         o = o.reshape(b, t, self.hidden_size)
-        return o @ jnp.asarray(params["out_kernel"], dtype) + jnp.asarray(
-            params["out_bias"], dtype)
+        return o @ jnp.asarray(params["out_kernel"], o.dtype) + jnp.asarray(
+            params["out_bias"], o.dtype)
+
+    def _pool_width(self, a):
+        """Pad the heads axis of (B, T, H, D) with zeros to ``pool_heads``."""
+        extra = self.pool_heads - self.n_head
+        return jnp.pad(a, ((0, 0), (0, 0), (0, extra), (0, 0))) if extra else a
 
     def _attend(self, q, k, v, t, training=False):
-        """Strategy dispatch shared by ``apply`` and ``apply_with_kv``.
+        """Strategy dispatch shared by ``apply`` and ``prefill``.
         ``training`` says that a backward pass follows the call."""
         mesh = self._mesh()
         if mesh is not None and self.attn_strategy != "full":
@@ -119,16 +141,53 @@ class MultiHeadAttention(Layer):
         x = as_compute(x)
         q, k, v = self.qkv_proj(params, x)
         o = self._attend(q, k, v, x.shape[1], training)
-        return self.out_proj(params, o, x.dtype), state
+        return self.out_proj(params, o), state
 
-    def apply_with_kv(self, params, x):
-        """Forward that ALSO returns the projected K/V — the prefill path:
-        same strategy dispatch (flash at long T), K/V handed to the caller
-        for the paged cache. Returns ``(out, k, v)``."""
+    def prefill(self, params, x, cache, at):
+        """``apply`` in inference mode (same strategy dispatch: flash at long
+        T), with K and V of the bucket scattered into this layer's pages;
+        the bucket's padding lands in scratch."""
         x = as_compute(x)
         q, k, v = self.qkv_proj(params, x)
         o = self._attend(q, k, v, x.shape[1])
-        return self.out_proj(params, o, x.dtype), k, v
+        cache = {name: prefill_write(cache[name], at.table,
+                                     self._pool_width(a),
+                                     page_size=at.page_size)
+                 for name, a in (("k", k), ("v", v))}
+        return self.out_proj(params, o), cache
+
+    def decode(self, params, x, cache, at):
+        """``x``: (B, q_len, hidden), the hidden states of ``q_len`` new
+        tokens a row, at positions ``at.lengths .. at.lengths + q_len - 1``
+        (``at.lengths`` == tokens already cached). Their K and V are written
+        BEFORE attending, so a token sees itself; token i attends causally to
+        the whole prefix and the new tokens up to itself: the fused
+        paged-attention kernel when routed on (``ops.paged_attention.
+        use_kernel``), else plain dot against the gathered cache. Shapes are
+        fixed throughout (the ``decode-shape-stability`` lint invariant)."""
+        x = as_compute(x)
+        q_len = x.shape[1]
+        q, k, v = (self._pool_width(a) for a in self.qkv_proj(params, x))
+        pos = at.lengths
+        k_pages = paged_write_multi(cache["k"], at.table, pos, k,
+                                    page_size=at.page_size)
+        v_pages = paged_write_multi(cache["v"], at.table, pos, v,
+                                    page_size=at.page_size)
+        if use_kernel():
+            # fused path: page gather + QK + softmax + PV entirely in VMEM —
+            # the (B, T_max, H, D) contiguous copy below never exists
+            o = paged_attention(q, k_pages.astype(q.dtype),
+                                v_pages.astype(q.dtype), at.table,
+                                pos + q_len, page_size=at.page_size)
+        else:
+            ks = paged_read(k_pages, at.table).astype(q.dtype)
+            vs = paged_read(v_pages, at.table).astype(q.dtype)
+            if q_len == 1:
+                o = decode_attention(q[:, 0], ks, vs, pos + 1)[:, None]
+            else:
+                o = decode_attention_multi(q, ks, vs, pos + q_len)
+        return self.out_proj(params, o[:, :, :self.n_head]), \
+            {"k": k_pages, "v": v_pages}
 
     def _flash_single_device(self, t: int, training: bool = False,
                              batch_heads: int = 1) -> bool:
@@ -196,106 +255,41 @@ class TransformerLayer(Layer):
         return params, {}
 
     def cast_at_use(self, params):
-        # the MLP's kernels and biases (_mlp) and the attention's own; the
+        # the MLP's kernels and biases (block) and the attention's own; the
         # normalizations compute in f32 from f32 scales
         flags = {k: True for k in params}
         for name in ("attn", "ln1", "ln2"):
             flags[name] = getattr(self, name).cast_at_use(params[name])
         return flags
 
-    def _mlp(self, params, x):
-        """ln2 + MLP + residual — the block tail, shared by ``apply`` and the
-        cache-threaded prefill/decode paths."""
+    def block(self, params, x, mix):
+        """The block, written once: ``x + mix(ln1(x))``, then ln2 + MLP +
+        residual. ``mix(attn_params, h) -> (a, state)`` is the attention in
+        whatever form the caller runs it (whole sequence, prefill, decode),
+        ``state`` what it leaves of its cache. Returns ``(x_out, state)``."""
+        x = as_compute(x)
+        h, _ = self.ln1.apply(params["ln1"], {}, x)
+        a, state = mix(params["attn"], h)
+        x = x + a
         h, _ = self.ln2.apply(params["ln2"], {}, x)
         h = h @ jnp.asarray(params["mlp_up_kernel"], x.dtype) + jnp.asarray(
             params["mlp_up_bias"], x.dtype)
         h = self.activation(h)
         h = h @ jnp.asarray(params["mlp_down_kernel"], x.dtype) + jnp.asarray(
             params["mlp_down_bias"], x.dtype)
-        return x + h
+        return x + h, state
 
     def apply(self, params, state, x, *, training=False, rng=None):
-        x = as_compute(x)
-        h, _ = self.ln1.apply(params["ln1"], {}, x)
-        a, _ = self.attn.apply(params["attn"], {}, h, training=training, rng=rng)
-        if training and self.dropout > 0 and rng is not None:
-            keep = 1.0 - self.dropout
-            a = jnp.where(jax.random.bernoulli(jax.random.fold_in(rng, 1), keep,
-                                               a.shape), a / keep, 0.0).astype(a.dtype)
-        x = x + a
-        return self._mlp(params, x), state
+        def mix(p, h):
+            a, _ = self.attn.apply(p, {}, h, training=training, rng=rng)
+            if training and self.dropout > 0 and rng is not None:
+                keep = 1.0 - self.dropout
+                a = jnp.where(
+                    jax.random.bernoulli(jax.random.fold_in(rng, 1), keep,
+                                         a.shape), a / keep, 0.0).astype(a.dtype)
+            return a, None
 
-    def apply_with_kv(self, params, x):
-        """Prefill forward: the exact ``apply`` computation (inference mode)
-        that additionally returns this block's projected K/V,
-        each (B, T, n_head, head_dim), for the paged cache."""
-        x = as_compute(x)
-        h, _ = self.ln1.apply(params["ln1"], {}, x)
-        a, k, v = self.attn.apply_with_kv(params["attn"], h)
-        x = x + a
-        return self._mlp(params, x), k, v
-
-    def decode_step(self, params, x, k_pages, v_pages, table, pos, *,
-                    page_size: int):
-        """One cache-threaded decode step for this block.
-
-        ``x``: (B, 1, hidden) — the new token's hidden state; ``k_pages``/
-        ``v_pages``: (P, page_size, H, D) — this LAYER's page pool;
-        ``table``: (B, pages_per_slot) int32; ``pos``: (B,) int32 — the
-        position being decoded (== tokens already cached). The new K/V are
-        written at ``pos`` BEFORE attending, so the token sees itself;
-        attention is masked to ``pos + 1`` valid positions — the fused
-        paged-attention kernel when routed on (``ops.paged_attention.
-        use_kernel``), else plain dot against the gathered cache. Returns
-        ``(x_out, k_pages, v_pages)`` — fixed shapes throughout (the
-        ``decode-shape-stability`` lint invariant).
-        """
-        return self._cached_step(params, x, k_pages, v_pages, table, pos,
-                                 page_size=page_size)
-
-    def verify_step(self, params, x, k_pages, v_pages, table, pos, *,
-                    page_size: int):
-        """The speculative-decode twin of :meth:`decode_step`: ``k`` tokens
-        per slot (1 certain + k-1 drafted) written and attended in one pass.
-        ``x``: (B, k, hidden); ``pos``: (B,) — the FIRST position written
-        (== tokens already cached); token i lands at ``pos + i`` and attends
-        causally (itself + earlier drafts + the whole prefix)."""
-        return self._cached_step(params, x, k_pages, v_pages, table, pos,
-                                 page_size=page_size)
-
-    def _cached_step(self, params, x, k_pages, v_pages, table, pos, *,
-                     page_size: int):
-        """Shared decode/verify body: write the q_len new tokens' K/V into
-        the paged pool, attend against it, finish with the block tail."""
-        from ...ops.kv_cache import (decode_attention, decode_attention_multi,
-                                     paged_read, paged_write_multi)
-        from ...ops.paged_attention import paged_attention, use_kernel
-
-        x = as_compute(x)
-        q_len = x.shape[1]
-        h, _ = self.ln1.apply(params["ln1"], {}, x)
-        q, k, v = self.attn.qkv_proj(params["attn"], h)   # (B, q_len, H, D)
-        k_pages = paged_write_multi(k_pages, table, pos, k,
-                                    page_size=page_size)
-        v_pages = paged_write_multi(v_pages, table, pos, v,
-                                    page_size=page_size)
-        if use_kernel():
-            # fused path: page gather + QK + softmax + PV entirely in VMEM —
-            # the (B, T_max, H, D) contiguous copy below never exists
-            o = paged_attention(q, k_pages.astype(q.dtype),
-                                v_pages.astype(q.dtype), table,
-                                pos + q_len, page_size=page_size)
-        else:
-            ks = paged_read(k_pages, table)               # (B, T_max, H, D)
-            vs = paged_read(v_pages, table)
-            if q_len == 1:
-                o = decode_attention(q[:, 0], ks.astype(q.dtype),
-                                     vs.astype(q.dtype), pos + 1)[:, None]
-            else:
-                o = decode_attention_multi(q, ks.astype(q.dtype),
-                                           vs.astype(q.dtype), pos + q_len)
-        x = x + self.attn.out_proj(params["attn"], o, x.dtype)
-        return self._mlp(params, x), k_pages, v_pages
+        return self.block(params, x, mix)[0], state
 
     def compute_output_shape(self, input_shape):
         return tuple(input_shape[:-1]) + (self.hidden_size,)

@@ -1,55 +1,47 @@
-"""Sequence mixers of a hybrid decoder, and its gated MLP.
+"""Sequence mixers of a decoder, and the hybrid decoder's gated MLP.
 
 A *mixer* is the part of a decoder layer that moves information between
-positions. Two kinds live here, behind one interface, so that a model built
-from a pattern of them (:class:`~analytics_zoo_tpu.models.hybrid_lm.HybridLM`)
-walks its layers once whatever their kinds:
+positions. Every decoder of the tree is a list of them behind one interface,
+walked once whatever their kinds
+(:class:`~analytics_zoo_tpu.models.decoder.CachedDecoder`):
 
-* :class:`QKNormAttention`: full causal softmax attention with no position
-  signal of its own and an RMS norm over the whole query and key vectors
-  (Olmo 2's QK-norm). It keeps K and V of every cached token, in pages
-  (``state_kind = PAGES``).
+* :class:`~.attention.MultiHeadAttention`: full softmax attention behind a
+  biased fused QKV projection (the GPT-2 block's). It keeps K and V of every
+  cached token, in pages (``state_kind = PAGES``), and holds the one cached
+  attention step of the tree.
+* :class:`QKNormAttention`: that mixer with another projection: no bias, no
+  position signal of its own, an RMS norm over the whole query and key
+  vectors (Olmo 2's QK-norm).
 * :class:`GatedDeltaNet`: linear attention by the gated delta rule
   (arXiv:2412.06464, in the form of ``fla.layers.GatedDeltaNet``). It keeps,
   for each slot, a float32 matrix state a head and the last rows that went
   into its short convolution (``state_kind = SLOT``): a fixed size, whatever
   the sequence's length.
 
-The interface: ``apply(params, state, x)`` is the whole sequence with no cache
-(the teacher-forced forward; JAX differentiates it), ``prefill(params, x,
-cache, at)`` the same forward that also leaves the layer's cache as the
-sequence leaves it, and ``decode(params, x, cache, at)`` one token a slot
-against it. ``cache`` is a dict of THIS layer's leaves (``{"k", "v"}`` or
-``slot_state()``'s names), ``at`` a :class:`StepContext`.
+The interface: ``state_kind`` says what the layer keeps between steps,
+``cast_at_use(params)`` which leaves it reads only through a cast,
+``apply(params, state, x)`` is the whole sequence with no cache (the
+teacher-forced forward; JAX differentiates it), ``prefill(params, x, cache,
+at)`` the same forward that also leaves the layer's cache as the sequence
+leaves it, and ``decode(params, x, cache, at)`` the new tokens of each row
+against it: one or more a row where the state is pages (a decode step, a
+verify step, a prefill chunk), one where it is a slot's. ``cache`` is a dict
+of THIS layer's leaves (``{"k", "v"}`` or ``slot_state()``'s names), ``at`` a
+:class:`~analytics_zoo_tpu.ops.kv_cache.StepContext`.
 """
 
 from __future__ import annotations
 
-from typing import Any, NamedTuple, Tuple
+from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...ops.kv_cache import PAGES, SLOT
+from ...ops.kv_cache import SLOT, StepContext
 from ..module import Layer, as_compute, get_initializer, param_dtype
 from .attention import MultiHeadAttention
 from .normalization import rms_norm
-
-
-class StepContext(NamedTuple):
-    """Where a prefill or a decode step reads and writes the cache.
-    ``table``: (B, pages_per_slot) page tables; ``lengths``: (B,) — a
-    prefill's true prompt lengths, a decode step's positions being written;
-    ``slots``: (B,) the slot each row of a prefill fills; ``live``: (B,) bool,
-    the rows of a decode step that hold a stream (the others must leave every
-    state as it is)."""
-
-    table: Any
-    lengths: Any
-    page_size: int
-    slots: Any = None
-    live: Any = None
 
 
 def _matmul(x, kernel):
@@ -82,8 +74,12 @@ class GatedMLP(Layer):
         return _matmul(h, params["down_kernel"]), state
 
 
-class QKNormAttention(Layer):
-    """Full causal attention, QK-norm, no position signal, no bias.
+class QKNormAttention(MultiHeadAttention):
+    """Full causal attention, QK-norm, no position signal, no bias:
+    :class:`MultiHeadAttention` with another projection. Routing (XLA full
+    attention, the flash kernel on a TPU from 2k tokens in a prefill and from
+    fewer when a backward follows a large batch, sequence-parallel forms under
+    a mesh), the write into the pages and the cached attend are inherited.
 
     The page pools hold the heads rounded up to a multiple of 8
     (``pool_heads``; 32 for 30): the TPU tiles the axis before the last by 8,
@@ -91,25 +87,12 @@ class QKNormAttention(Layer):
     slices the heads axis, which Mosaic takes only tile-aligned. The heads
     added are zeros in Q, K and V and are cut from the output."""
 
-    state_kind = PAGES
-    scope = "zoo_full_layer"
-
     def __init__(self, hidden_size: int, n_head: int, epsilon: float = 1e-6,
                  attn_strategy: str = "auto", name=None):
-        super().__init__(name=name)
-        assert hidden_size % n_head == 0
-        self.hidden_size = hidden_size
-        self.n_head = n_head
-        self.head_dim = hidden_size // n_head
+        super().__init__(hidden_size, n_head, causal=True,
+                         attn_strategy=attn_strategy, name=name)
         self.pool_heads = -(-n_head // 8) * 8
         self.epsilon = epsilon
-        # the routing (XLA full attention, the flash kernel on a TPU from 2k
-        # tokens in a prefill and from fewer when a backward follows a large
-        # batch, sequence-parallel forms under a mesh) is
-        # MultiHeadAttention's, used and not copied
-        self._route = MultiHeadAttention(hidden_size, n_head, causal=True,
-                                         attn_strategy=attn_strategy,
-                                         name=f"{self.name}_route")
 
     def build(self, rng, input_shape=None):
         k1, k2 = jax.random.split(rng)
@@ -124,8 +107,7 @@ class QKNormAttention(Layer):
         return {"qkv_kernel": True, "q_norm": False, "k_norm": False,
                 "out_kernel": True}
 
-    def _qkv(self, params, x):
-        """(B, T, hidden) -> q, k, v, each (B, T, n_head, head_dim)."""
+    def qkv_proj(self, params, x):
         b, t, d = x.shape
         q, k, v = jnp.split(_matmul(x, params["qkv_kernel"]), 3, axis=-1)
         q = rms_norm(q, params["q_norm"], self.epsilon)
@@ -133,54 +115,9 @@ class QKNormAttention(Layer):
         return tuple(a.reshape(b, t, self.n_head, self.head_dim)
                      for a in (q, k, v))
 
-    def _out(self, params, o):
+    def out_proj(self, params, o):
         b, t = o.shape[:2]
         return _matmul(o.reshape(b, t, self.hidden_size), params["out_kernel"])
-
-    def _pool_width(self, a):
-        """Pad the heads axis of (B, T, H, D) with zeros to ``pool_heads``."""
-        extra = self.pool_heads - self.n_head
-        return jnp.pad(a, ((0, 0), (0, 0), (0, extra), (0, 0))) if extra else a
-
-    def apply(self, params, state, x, *, training=False, rng=None):
-        x = as_compute(x)
-        q, k, v = self._qkv(params, x)
-        o = self._route._attend(q, k, v, x.shape[1], training)
-        return self._out(params, o), state
-
-    def prefill(self, params, x, cache, at: StepContext):
-        from ...ops.kv_cache import prefill_write
-
-        x = as_compute(x)
-        q, k, v = self._qkv(params, x)
-        o = self._route._attend(q, k, v, x.shape[1])
-        cache = {name: prefill_write(cache[name], at.table,
-                                     self._pool_width(a),
-                                     page_size=at.page_size)
-                 for name, a in (("k", k), ("v", v))}
-        return self._out(params, o), cache
-
-    def decode(self, params, x, cache, at: StepContext):
-        from ...ops.kv_cache import (decode_attention, paged_read,
-                                     paged_write_multi)
-        from ...ops.paged_attention import paged_attention, use_kernel
-
-        x = as_compute(x)
-        q, k, v = (self._pool_width(a) for a in self._qkv(params, x))
-        pos = at.lengths
-        k_pages = paged_write_multi(cache["k"], at.table, pos, k,
-                                    page_size=at.page_size)
-        v_pages = paged_write_multi(cache["v"], at.table, pos, v,
-                                    page_size=at.page_size)
-        if use_kernel():
-            o = paged_attention(q, k_pages, v_pages, at.table, pos + 1,
-                                page_size=at.page_size)
-        else:
-            o = decode_attention(q[:, 0], paged_read(k_pages, at.table),
-                                 paged_read(v_pages, at.table),
-                                 pos + 1)[:, None]
-        return self._out(params, o[:, :, :self.n_head]), \
-            {"k": k_pages, "v": v_pages}
 
 
 class GatedDeltaNet(Layer):
@@ -331,8 +268,14 @@ class GatedDeltaNet(Layer):
                 tail.astype(cache["conv"].dtype))}
 
     def decode(self, params, x, cache, at: StepContext):
+        """One token a row: the recurrence has no wider step that could be
+        taken back, which is why the batcher refuses this kind of state
+        speculation and chunked prefill."""
         from ...ops.gated_delta import gdn_decode
 
+        if x.shape[1] != 1:
+            raise ValueError(f"{type(self).__name__}.decode takes one token "
+                             f"a row, got {x.shape[1]}")
         x = as_compute(x)
         pre, z, beta, log_alpha = self._project(params, x)
         window = jnp.concatenate([cache["conv"].astype(pre.dtype), pre],

@@ -58,7 +58,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -75,6 +75,21 @@ SCRATCH_PAGE = 0
 
 #: what a layer keeps between decode steps (``KVCacheConfig.layer_kinds``)
 PAGES, SLOT = "pages", "slot"
+
+
+class StepContext(NamedTuple):
+    """Where a prefill or a decode step reads and writes the cache.
+    ``table``: (B, pages_per_slot) page tables; ``lengths``: (B,) — a
+    prefill's true prompt lengths, a decode step's first position being
+    written; ``slots``: (B,) the slot each row of a prefill fills; ``live``:
+    (B,) bool, the rows of a decode step that hold a stream (the others must
+    leave every state as it is)."""
+
+    table: Any
+    lengths: Any
+    page_size: int
+    slots: Any = None
+    live: Any = None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -2,8 +2,9 @@
 
 The one-shot serving path (engine.py) batches *requests*; generation traffic
 batches *tokens*. This module is the decode-side engine on top of the paged
-KV cache (:mod:`analytics_zoo_tpu.ops.kv_cache`) and
-``TransformerLM.prefill()/decode_step()``:
+KV cache (:mod:`analytics_zoo_tpu.ops.kv_cache`) and the cached entry
+points of a decoder (:class:`~analytics_zoo_tpu.models.decoder.
+CachedDecoder`: ``prefill()``, ``decode_step()``, ...):
 
 * :class:`ContinuousBatcher` — ``n_slots`` concurrent decode sequences
   sharing ONE fixed-shape compiled decode step. New requests are admitted
@@ -45,6 +46,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import functools
 import logging
 import queue
 import threading
@@ -595,14 +597,15 @@ DRAIN_REASONS = ("first", "admit", "chunk", "preempt", "swap", "spec")
 class ContinuousBatcher:
     """Continuous micro-batching decode loop over a paged KV cache.
 
-    ``model`` is a :class:`~analytics_zoo_tpu.models.transformer.TransformerLM`
-    (anything with ``init_kv_cache``/``prefill``/``decode_step``), ``params``
-    its pytree. One daemon loop thread admits pending requests into free
-    slots, runs one fixed-shape decode step over all slots, emits per-stream
-    token deltas, and retires finished sequences — all per step. A chaos-
-    killed loop is respawned by a supervisor with cache/slot state intact,
-    so in-flight streams survive (kill-the-engine drill in
-    tests/test_generation.py).
+    ``model`` is a :class:`~analytics_zoo_tpu.models.decoder.CachedDecoder`
+    (``TransformerLM``, ``HybridLM``: one contract, ``init_kv_cache`` /
+    ``prefill`` / ``prefill_from`` / ``prefill_chunk`` / ``decode_step`` /
+    ``verify_step``), ``params`` its pytree. One daemon loop thread admits
+    pending requests into free slots, runs one fixed-shape decode step over
+    all slots, emits per-stream token deltas, and retires finished sequences
+    — all per step. A chaos-killed loop is respawned by a supervisor with
+    cache/slot state intact, so in-flight streams survive (kill-the-engine
+    drill in tests/test_generation.py).
 
     **One decode step stays in flight.** A pass of plain decode is *launch,
     then collect* (:meth:`_launch`, :meth:`_collect`): step n+1 is dispatched
@@ -638,13 +641,16 @@ class ContinuousBatcher:
     ``stats()["param_bytes"]`` (``{dtype: bytes}``; ``cli info``;
     ``zoo_gen_param_bytes{dtype}``) says what is being served.
 
-    **A model with per-slot state** (``HybridLM``: its cache description,
+    **A model with per-slot state** (``cfg.slot_state``, the one thing the
+    batcher asks about the model it holds: its cache description,
     :class:`~analytics_zoo_tpu.ops.kv_cache.KVCacheConfig`, names layers that
-    keep a fixed-size recurrent state a slot instead of pages). The loop is
-    the same; three things follow from the state being addressed by slot and
-    not through the page table. A prefill is told the slot it fills
-    (``model.prefill(..., slots=)``) and writes that slot's whole state, so a
-    reused slot starts from its prompt and from nothing of the stream before;
+    keep a fixed-size recurrent state a slot instead of pages, as a
+    ``HybridLM`` with linear layers does). The loop is the same; three
+    things follow from the state being addressed by slot and not through the
+    page table. Every prefill is told the slot it fills
+    (``model.prefill(..., slots=)``; a model of pages alone ignores it), and
+    such a model writes that slot's whole state, so a reused slot starts
+    from its prompt and from nothing of the stream before;
     that write is dispatched after any decode step still in flight on the
     row, so a step launched ahead for a stream that has since ended cannot
     reach the next stream's state. A row a step does not step (it ended, it
@@ -690,10 +696,6 @@ class ContinuousBatcher:
             raise ValueError("prefill_token_budget requires "
                              "prefill_chunk_tokens > 0 (the budget is spent "
                              "in whole chunks)")
-        if prefill_chunk_tokens and not hasattr(model, "prefill_chunk"):
-            raise ValueError(f"chunked prefill needs a model with "
-                             f"prefill_chunk(); "
-                             f"{type(model).__name__} has none")
         import jax
         import jax.numpy as jnp
 
@@ -712,10 +714,9 @@ class ContinuousBatcher:
             model.init_kv_cache, n_slots, page_size=page_size,
             max_seq_len=max_seq_len, n_pages=n_pages)
         # per-slot state (class docstring): what cannot snapshot it is refused
-        self.slot_state = bool(self.cfg.slot_state)
-        if self.slot_state:
-            self._refuse_for_slot_state(
-                prefix_cache_pages=prefix_cache_pages, spec_k=spec_k)
+        self._refuse_for_slot_state(
+            prefix_cache_pages=prefix_cache_pages, spec_k=spec_k,
+            prefill_chunk_tokens=prefill_chunk_tokens)
         # the pool is COMMITTED to the device the served tree lies on (the
         # same buffers, no copy). An executable that holds a shard_map (the
         # flash prefill) returns committed arrays, and everything the pool is
@@ -852,24 +853,22 @@ class ContinuousBatcher:
                 top_k=self.top_k),
             in_shardings=(None, None, ids_on_device) + (None,) * 5,
             donate_argnums=donate)
-        self._prefill = jax.jit(
-            lambda p, c, ids, ln, tb: pinned(
-                model.prefill,
-                p, c, ids, ln, tb, page_size=cfg.page_size),
+        prefill = jax.jit(
+            lambda p, c, ids, ln, tb, slots: pinned(
+                model.prefill, p, c, ids, ln, tb, slots=slots,
+                page_size=cfg.page_size),
             donate_argnums=donate)
-        if self.slot_state:
-            # the same, told which slots it fills; without them (the
-            # benchmark's logit probe) slots 0 .. B-1, through the one
-            # executable a bucket that serves
-            prefill_at = jax.jit(
-                lambda p, c, ids, ln, tb, slots: pinned(
-                    model.prefill, p, c, ids, ln, tb, slots=slots,
-                    page_size=cfg.page_size),
-                donate_argnums=donate)
-            self._prefill = lambda p, c, ids, ln, tb, slots=None: prefill_at(
-                p, c, ids, ln, tb,
-                np.arange(len(ln), dtype=np.int32) if slots is None
-                else slots)
+
+        @functools.wraps(prefill)
+        def prefill_at(p, c, ids, ln, tb, slots=None):
+            # told which slots it fills (a model of pages alone ignores
+            # them); without them (the benchmark's logit probe) slots
+            # 0 .. B-1, through the one executable a bucket that serves
+            return prefill(p, c, ids, ln, tb,
+                           np.arange(len(ln), dtype=np.int32)
+                           if slots is None else slots)
+
+        self._prefill = prefill_at
         # suffix prefill from the divergence point of a prefix hit (one
         # executable per pow2 suffix bucket, same ladder as _prefill) and
         # the COW boundary-page copy (ONE executable: src/dst are traced)
@@ -908,11 +907,13 @@ class ContinuousBatcher:
         if autostart:
             self.start()
 
-    def _refuse_for_slot_state(self, *, prefix_cache_pages=0,
-                               spec_k=0) -> None:
+    def _refuse_for_slot_state(self, *, prefix_cache_pages=0, spec_k=0,
+                               prefill_chunk_tokens=0) -> None:
         """Raise for an option that assumes the whole cache is pages, for a
-        model that also keeps a recurrent state a slot (chunked prefill is
-        refused before, with every model that has no ``prefill_chunk``)."""
+        model that also keeps a recurrent state a slot
+        (``cfg.slot_state``)."""
+        if not self.cfg.slot_state:
+            return
         kind = type(self.model).__name__
         state = ", ".join(name for name, _, _ in self.cfg.slot_state)
         if int(prefix_cache_pages) > 0:
@@ -926,8 +927,14 @@ class ContinuousBatcher:
         if int(spec_k) >= 2:
             raise ValueError(
                 f"spec_k={spec_k}: {kind} keeps per-slot state ({state}); a "
-                f"rejected draft would have to roll that state back, and "
-                f"the model has no verify_step. Serve it with spec_k=0")
+                f"rejected draft would have to roll that state back. Serve "
+                f"it with spec_k=0")
+        if int(prefill_chunk_tokens) > 0:
+            raise ValueError(
+                f"prefill_chunk_tokens={prefill_chunk_tokens}: chunked "
+                f"prefill needs a prefill_chunk() that resumes the per-slot "
+                f"state ({state}) where the last chunk left it; {kind} has "
+                f"none. Serve it with prefill_chunk_tokens=0")
 
     # ------------------------------------------------------------- served tree
 
@@ -1233,7 +1240,7 @@ class ContinuousBatcher:
         was freed."""
         if req.priority != "critical":
             return False
-        if self.slot_state:
+        if self.cfg.slot_state:
             # a parked stream keeps its pages and resumes in ANOTHER slot,
             # where its recurrent state is not: the request waits for a
             # retirement (class docstring)
@@ -1464,8 +1471,7 @@ class ContinuousBatcher:
                     logits, self.cache = self._prefill(
                         self.params, self.cache, ids,
                         np.array([n_prompt], np.int32), table,
-                        *([np.array([slot_idx], np.int32)]
-                          if self.slot_state else []))
+                        np.array([slot_idx], np.int32))
                 first = self._sample(
                     logits, np.array([req.seed], np.uint32),
                     np.array([0], np.uint32),
@@ -1498,7 +1504,7 @@ class ContinuousBatcher:
         self.prefill_buckets.add(bucket)
         req.prefill_bucket = str(bucket)
         _GEN_TOKENS.labels(phase="prefill").inc(n_suffix)
-        if self.slot_state:
+        if self.cfg.slot_state:
             _GEN_LINEAR_PREFILL.inc(n_prompt)
         if start:
             req.cached_prefix_tokens = start
@@ -2275,8 +2281,7 @@ class ContinuousBatcher:
             elif not isinstance(spec, SpecDecodeConfig):
                 raise TypeError(f"spec must be a SpecDecodeConfig or dict, "
                                 f"got {type(spec).__name__}")
-            if self.slot_state:
-                self._refuse_for_slot_state(spec_k=spec.k)
+            self._refuse_for_slot_state(spec_k=spec.k)
         self._pending_swap = (self._serve_view(params), version, spec)
         self._wake.set()
 

@@ -452,6 +452,6 @@ def test_one_decode_executable_where_a_prefill_returns_committed_arrays(
         launches = _launches(b)
         assert launches["ahead"] and launches["drained"]
         assert b._decode._cache_size() == 1
-        assert prefill._cache_size() == 1
+        assert prefill.__wrapped__._cache_size() == 1
     finally:
         b.close()

@@ -326,6 +326,244 @@ def test_what_assumes_the_cache_is_pages_is_refused_in_words(
         _batcher(model_and_params, autostart=False, **option)
 
 
+# ------------------------------- pages alone: the shared entry points serve
+
+FULL_ONLY = ["full_attention"] * 3
+
+
+@pytest.fixture(scope="module")
+def full_only():
+    """A pattern of full attention alone: its cache is pages, so nothing
+    that assumes pages has to be refused."""
+    m = _model(FULL_ONLY)
+    params, _ = m.build(jax.random.PRNGKey(1))
+    return m, jax.tree_util.tree_map(
+        lambda a: a + 0.05 * jax.random.normal(
+            jax.random.PRNGKey(a.size % 89), a.shape, a.dtype), params)
+
+
+def test_a_pattern_of_pages_alone_verifies_and_chunks_as_its_whole_forward(
+        full_only, np_rng):
+    """``verify_step`` and ``prefill_chunk`` are the base's, over
+    ``MultiHeadAttention.decode`` at a wider query: a prompt fed in
+    chunks (the third starting mid-page) ends at the forward's logits; a
+    verify step over the forward's own greedy continuation accepts every
+    draft and emits the k tokens that follow."""
+    m, params = full_only
+    assert m.init_kv_cache(2, page_size=4, max_seq_len=64)[0].slot_state == ()
+    ids = np_rng.integers(1, VOCAB, size=(1, 40)).astype(np.int32)
+    want = np.asarray(m.apply(params, {}, ids)[0])[0]           # (40, V)
+    cfg, cache = m.init_kv_cache(2, page_size=4, max_seq_len=64)
+    table = np.zeros((2, cfg.pages_per_slot + 2), np.int32)
+    table[1, :12] = 1 + np.arange(12)
+    done = 0
+    for n in (8, 6, 7):                 # 21 tokens: 8, then 6 of 8, then 7
+        chunk = np.zeros((1, 8), np.int32)
+        chunk[0, :n] = ids[0, done:done + n]
+        logits, cache = m.prefill_chunk(
+            params, cache, chunk, np.array([done], np.int32),
+            np.array([n], np.int32), table[[1]], page_size=4)
+        done += n
+        assert np.abs(np.asarray(logits[0]) - want[done - 1]).max() < 1e-4
+    # the forward's own greedy continuation of the 21 tokens, as the draft
+    k, seq = 4, ids[0, :done].tolist()
+    forward = jax.jit(lambda x: m.apply(params, {}, x)[0])
+    for _ in range(k + 1):
+        padded = np.zeros((1, 32), np.int32)
+        padded[0, :len(seq)] = seq
+        seq.append(int(np.argmax(np.asarray(forward(padded))[0, len(seq) - 1])))
+    step = np.zeros((2, k), np.int32)
+    step[1] = seq[done:done + k]        # the certain token, then k-1 drafts
+    zeros = np.zeros(2, np.uint32)
+    accepted, tokens, _, cache = m.verify_step(
+        params, cache, step, np.array([0, done], np.int32),
+        table[:, :cfg.pages_per_slot], zeros, zeros,
+        np.zeros(2, np.float32), page_size=4)
+    assert int(accepted[1]) == k - 1
+    assert np.asarray(tokens)[1].tolist() == seq[done + 1:done + k + 1]
+
+
+def test_a_batcher_over_pages_alone_accepts_what_a_slot_state_refuses(
+        full_only, np_rng):
+    m, params = full_only
+    prompts = [np_rng.integers(1, VOCAB, size=n).astype(np.int32)
+               for n in (5, 23, 9)]
+    plain = _batcher(full_only)
+    both = _batcher(full_only, spec_k=3, prefill_chunk_tokens=16,
+                    prefix_cache_pages=4)
+    try:
+        want = _streams(plain, prompts)
+        assert _streams(both, prompts) == want
+        stats = both.stats()
+        assert stats["prefill"]["chunks"] >= 4
+        assert both.spec_steps > 0
+    finally:
+        plain.close()
+        both.close()
+    forward = jax.jit(lambda ids: m.apply(params, {}, ids)[0])
+    for prompt, out in zip(prompts, want):
+        seq = np.zeros((1, 64), np.int32)
+        seq[0, :len(prompt) + len(out)] = list(prompt) + out
+        best = np.asarray(jnp.argmax(forward(seq)[0], axis=-1))
+        assert best[len(prompt) - 1:len(prompt) + len(out) - 1].tolist() == out
+
+
+def test_a_linear_layer_takes_one_token_a_row(model_and_params):
+    """What the refusals rest on: the recurrence has no k-token step."""
+    m, params = model_and_params
+    cfg, cache = m.init_kv_cache(2, page_size=4, max_seq_len=64)
+    zeros = np.zeros(2, np.uint32)
+    with pytest.raises(ValueError, match="takes one token a row"):
+        m.verify_step(params, cache, np.zeros((2, 3), np.int32),
+                      np.zeros(2, np.int32),
+                      np.zeros((2, cfg.pages_per_slot), np.int32), zeros,
+                      zeros, np.zeros(2, np.float32), page_size=4)
+
+
+# ------------------------------------------- one contract at the batcher
+
+def _gpt():
+    m = TransformerLM(vocab=VOCAB, hidden_size=HIDDEN, n_block=2, n_head=HEADS,
+                      seq_len=128)
+    return m, m.build(jax.random.PRNGKey(2))[0]
+
+
+def test_a_model_of_pages_alone_ignores_the_slots_it_is_told(np_rng):
+    m, params = _gpt()
+    cfg, cache = m.init_kv_cache(4, page_size=4, max_seq_len=64)
+    ids = np_rng.integers(1, VOCAB, size=(2, 16)).astype(np.int32)
+    table = np.zeros((2, cfg.pages_per_slot), np.int32)
+    table[0, :4], table[1, :4] = 1 + np.arange(4), 5 + np.arange(4)
+    args = (params, cache, ids, np.array([13, 16], np.int32), table)
+    logits, filled = m.prefill(*args, page_size=4)
+    told, filled_told = m.prefill(*args, page_size=4,
+                                  slots=np.array([3, 1], np.int32))
+    assert (np.asarray(logits) == np.asarray(told)).all()
+    for a, b in zip(jax.tree_util.tree_leaves(filled),
+                    jax.tree_util.tree_leaves(filled_told)):
+        assert (np.asarray(a) == np.asarray(b)).all()
+
+
+@pytest.mark.parametrize("which", ["transformer", "hybrid"])
+def test_one_prefill_executable_a_bucket_whatever_the_model(
+        which, model_and_params, np_rng):
+    """One jitted prefill serves both kinds of model, told the slot or not
+    (the benchmark's logit probe passes none): an executable a bucket."""
+    b = _batcher(_gpt() if which == "transformer" else model_and_params)
+    try:
+        for n in (5, 11, 7, 20):                    # buckets 8, 16 and 32
+            b.generate(np_rng.integers(1, VOCAB, size=n).astype(np.int32),
+                       max_new_tokens=3)
+        built = b._prefill.__wrapped__._cache_size
+        assert built() == len(b.prefill_buckets) == 3
+        table = np.zeros((1, b.cfg.pages_per_slot), np.int32)
+        table[0, :2] = 1 + np.arange(2)
+        _, b.cache = b._prefill(b.params, b.cache, np.ones((1, 8), np.int32),
+                                np.array([6], np.int32), table)
+        assert built() == 3
+    finally:
+        b.close()
+
+
+# ------------------------------------------------- the trees checkpoints read
+
+GOLDEN = {
+    "transformer": (
+        lambda: TransformerLM(vocab=61, hidden_size=16, n_block=2, n_head=2,
+                              seq_len=24, intermediate_size=40), """
+block0/attn/out_bias (16,) float32 0.000000
+block0/attn/out_kernel (16, 16) float32 -4.764462
+block0/attn/qkv_bias (48,) float32 0.000000
+block0/attn/qkv_kernel (16, 48) float32 -5.345192
+block0/ln1/beta (16,) float32 0.000000
+block0/ln1/gamma (16,) float32 0.715328
+block0/ln2/beta (16,) float32 0.000000
+block0/ln2/gamma (16,) float32 0.715328
+block0/mlp_down_bias (16,) float32 0.000000
+block0/mlp_down_kernel (40, 16) float32 -4.120924
+block0/mlp_up_bias (40,) float32 0.000000
+block0/mlp_up_kernel (16, 40) float32 1.624763
+block1/attn/out_bias (16,) float32 0.000000
+block1/attn/out_kernel (16, 16) float32 0.291964
+block1/attn/qkv_bias (48,) float32 0.000000
+block1/attn/qkv_kernel (16, 48) float32 -5.327025
+block1/ln1/beta (16,) float32 0.000000
+block1/ln1/gamma (16,) float32 0.715328
+block1/ln2/beta (16,) float32 0.000000
+block1/ln2/gamma (16,) float32 0.715328
+block1/mlp_down_bias (16,) float32 0.000000
+block1/mlp_down_kernel (40, 16) float32 -2.776223
+block1/mlp_up_bias (40,) float32 0.000000
+block1/mlp_up_kernel (16, 40) float32 -1.288532
+ln_f/beta (16,) float32 0.000000
+ln_f/gamma (16,) float32 0.715328
+logits_kernel (16, 61) float32 1.898156
+pos_embeddings (24, 16) float32 0.166449
+token_embeddings (61, 16) float32 -0.450775
+"""),
+    "hybrid": (
+        lambda: HybridLM(vocab=61, hidden_size=16, intermediate_size=24,
+                         layer_types=["linear_attention", "full_attention"],
+                         n_head=2, linear_num_heads=2, linear_key_head_dim=4,
+                         linear_value_head_dim=8, seq_len=64), """
+final_norm (16,) float32 0.715328
+layer0/mixer/A_log (2,) float32 3.828246
+layer0/mixer/ba_kernel (16, 4) float32 -0.670827
+layer0/mixer/conv_kernel (32, 4) float32 3.602679
+layer0/mixer/dt_bias (2,) float32 -5.077539
+layer0/mixer/gate_kernel (16, 16) float32 3.780363
+layer0/mixer/norm_scale (8,) float32 1.478254
+layer0/mixer/out_kernel (16, 16) float32 -0.063086
+layer0/mixer/qkv_kernel (16, 32) float32 -0.511350
+layer0/mixer_norm (16,) float32 0.715328
+layer0/mlp/down_kernel (24, 16) float32 -0.831366
+layer0/mlp/gate_kernel (16, 24) float32 -0.503372
+layer0/mlp/up_kernel (16, 24) float32 -6.003270
+layer0/mlp_norm (16,) float32 0.715328
+layer1/mixer/k_norm (16,) float32 0.715328
+layer1/mixer/out_kernel (16, 16) float32 0.800188
+layer1/mixer/q_norm (16,) float32 0.715328
+layer1/mixer/qkv_kernel (16, 48) float32 4.629661
+layer1/mixer_norm (16,) float32 0.715328
+layer1/mlp/down_kernel (24, 16) float32 0.317985
+layer1/mlp/gate_kernel (16, 24) float32 4.045587
+layer1/mlp/up_kernel (16, 24) float32 -2.971600
+layer1/mlp_norm (16,) float32 0.715328
+logits_kernel (16, 61) float32 0.697175
+token_embeddings (61, 16) float32 -0.450775
+"""),
+}
+
+
+@pytest.mark.parametrize("which", sorted(GOLDEN))
+def test_the_parameter_tree_is_the_one_checkpoints_read(which):
+    """Leaf paths, shapes, dtypes, and the values ``build`` draws from
+    ``PRNGKey(11)`` (each leaf's sum against ``cos(0), cos(1), ...``): the
+    benchmark's references, the sharding rules and every checkpoint address
+    the leaves by these names, and a seed's weights are what a run is
+    reproduced from. A renamed leaf or a moved ``jax.random.split`` fails
+    here."""
+    make, golden = GOLDEN[which]
+    params, _ = make().build(jax.random.PRNGKey(11))
+    flat, _ = jax.tree_util.tree_flatten_with_path(params)
+    got = {}
+    for path, leaf in flat:
+        a = np.asarray(leaf, np.float64)
+        ramp = np.cos(np.arange(a.size, dtype=np.float64)).reshape(a.shape)
+        got["/".join(str(k.key) for k in path)] = (
+            str(tuple(leaf.shape)).replace(" ", ""), str(leaf.dtype),
+            float((a * ramp).sum()))
+    want = {}
+    for line in golden.strip().splitlines():
+        name, rest = line.split(" ", 1)
+        shape, dtype, checksum = rest.rsplit(" ", 2)
+        want[name] = (shape.replace(" ", ""), dtype, float(checksum))
+    assert sorted(got) == sorted(want)
+    for name, (shape, dtype, checksum) in want.items():
+        assert got[name][:2] == (shape, dtype), name
+        assert abs(got[name][2] - checksum) < 1e-4, name
+
+
 def test_speculation_by_hot_swap_and_preemption_are_refused(
         model_and_params, np_rng):
     m, params = model_and_params

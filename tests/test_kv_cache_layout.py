@@ -16,6 +16,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from analytics_zoo_tpu.models.hybrid_lm import HybridLM
 from analytics_zoo_tpu.models.transformer import TransformerLM
 from analytics_zoo_tpu.ops.kv_cache import (KVCacheConfig, copy_page,
                                             init_cache)
@@ -25,16 +26,35 @@ pytestmark = pytest.mark.generation
 LAYERS, SLOTS, PAGE, PAGES, SEQ = 3, 2, 4, 512, 32
 
 
+MODELS = {
+    "transformer": lambda: TransformerLM(
+        vocab=64, hidden_size=32, n_block=LAYERS, n_head=2, seq_len=64),
+    # pages and per-slot state through the same walker: two page layers
+    # among three of slot state
+    "hybrid": lambda: HybridLM(
+        vocab=64, hidden_size=32, intermediate_size=48, n_head=2,
+        layer_types=["linear_attention", "full_attention"] * 2
+        + ["linear_attention"], linear_num_heads=2, linear_key_head_dim=8,
+        linear_value_head_dim=16, seq_len=64),
+}
+
+
 @pytest.fixture(scope="module")
-def rig():
+def rigs():
     # a pool (512 pages) far larger than anything the model computes, so a
     # pool-sized temporary cannot hide among the activations
-    m = TransformerLM(vocab=64, hidden_size=32, n_block=LAYERS, n_head=2,
-                      seq_len=64)
-    params, _ = m.build(jax.random.PRNGKey(0))
-    cfg, cache = m.init_kv_cache(SLOTS, page_size=PAGE, max_seq_len=SEQ,
-                                 n_pages=PAGES)
-    return m, params, cfg, cache
+    built = {}
+
+    def rig(which):
+        if which not in built:
+            m = MODELS[which]()
+            params, _ = m.build(jax.random.PRNGKey(0))
+            cfg, cache = m.init_kv_cache(SLOTS, page_size=PAGE,
+                                         max_seq_len=SEQ, n_pages=PAGES)
+            built[which] = m, params, cfg, cache
+        return built[which]
+
+    return rig
 
 
 def test_init_cache_is_one_pool_per_layer():
@@ -133,15 +153,19 @@ def test_the_check_sees_the_copies_of_a_stacked_pool():
     assert compiled.memory_analysis().temp_size_in_bytes >= pool_bytes
 
 
-@pytest.mark.parametrize("program", ["decode_step", "verify_step", "prefill",
-                                     "prefill_chunk", "copy_page"])
-def test_donated_cache_is_written_where_it_lies(rig, program):
-    m, params, cfg, cache = rig
+@pytest.mark.parametrize("which,program", [
+    ("transformer", "decode_step"), ("transformer", "verify_step"),
+    ("transformer", "prefill"), ("transformer", "prefill_chunk"),
+    ("transformer", "copy_page"),
+    # what a model with per-slot state is served by (the rest is refused)
+    ("hybrid", "decode_step"), ("hybrid", "prefill")])
+def test_donated_cache_is_written_where_it_lies(rigs, which, program):
+    m, params, cfg, cache = rigs(which)
     fn, rest = _programs(m, cfg)[program]
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         params, cache, *rest).compile()
     leaves = jax.tree_util.tree_leaves(cache)
-    pool_bytes = leaves[0].nbytes
+    pool_bytes = max(leaf.nbytes for leaf in leaves)
     text = compiled.as_text()
 
     # (a) every cache leaf is aliased input to output. jit drops copy_page's
@@ -156,7 +180,7 @@ def test_donated_cache_is_written_where_it_lies(rig, program):
 
     # (b) nothing pool-sized is made besides the in-place writes
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes == len(leaves) * pool_bytes
+    assert mem.alias_size_in_bytes == sum(leaf.nbytes for leaf in leaves)
     assert mem.temp_size_in_bytes < pool_bytes, (
         f"{program}: {mem.temp_size_in_bytes} bytes of temporaries, one "
         f"layer's pool is {pool_bytes}")
