@@ -10,13 +10,17 @@ cache, so ``prefill``, ``prefill_chunk``, ``prefill_from``, ``decode_step``,
 architecture. A model supplies what is really its own:
 
 * ``build`` / ``cast_at_use``: its parameter tree;
-* ``self.mixers``: one mixer a layer, and ``self.seq_len``;
+* ``self.mixers``: one entry a layer, a mixer or, for a layer that runs
+  several side by side on the same input, a tuple of them; and
+  ``self.seq_len``;
 * ``_embed(params, ids, positions=None)``: ids ``(...)`` -> hidden states
   ``(..., hidden)`` in the compute dtype; ``positions`` of the same shape as
   ``ids``, or None for ``0 .. T-1`` (a model with no position signal ignores
   them);
 * ``_block(i, params, h, mix)``: layer ``i``'s block around its mixer, called
-  as ``mix(mixer_params, x) -> (y, state)``; returns ``(h, state)``;
+  as ``mix(mixer_params, x) -> (y, state)``; returns ``(h, state)``. For a
+  layer of several mixers ``mix`` is a tuple of such functions, in the
+  entry's order, and ``state`` the states they returned, merged;
 * ``_head(params, h, one=None)``: final norm and LM head, (B, T, hidden) ->
   (B, T, V); or, given ``one``, a function that leaves one position a sequence
   (B, 1, hidden), the logits of that position, (B, V). Where ``one`` is
@@ -35,6 +39,7 @@ with one such layer (``KVCacheConfig.slot_state``).
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -44,6 +49,11 @@ from ..nn.module import Layer, compute_dtype
 from ..nn.topology import KerasNet
 from ..ops.kv_cache import (PAGES, SCRATCH_PAGE, SLOT, KVCacheConfig,
                             StepContext, init_cache, sample_tokens)
+
+
+def _side_by_side(layer):
+    """The mixers of one entry of ``self.mixers``."""
+    return layer if isinstance(layer, tuple) else (layer,)
 
 
 def _last(counts):
@@ -71,10 +81,10 @@ class CachedDecoder(Layer, KerasNet):
                       n_pages: Optional[int] = None, dtype=None):
         """``(KVCacheConfig, cache)`` for ``n_slots`` concurrent sequences:
         ``{"k": (k_0, ...), "v": (v_0, ...)}``, one pool ``(n_pages,
-        page_size, pool_heads, head_dim)`` for each layer whose mixer keeps
-        pages, and for the others the leaves their ``slot_state`` names,
-        ``(n_slots, ...)`` each (:func:`~analytics_zoo_tpu.ops.kv_cache.
-        init_cache`)."""
+        page_size, pool_heads, head_dim)`` for each layer with a mixer that
+        keeps pages, and for each with one that keeps a slot state the leaves
+        its ``slot_state`` names, ``(n_slots, ...)`` each
+        (:func:`~analytics_zoo_tpu.ops.kv_cache.init_cache`)."""
         max_seq = int(max_seq_len or self.seq_len)
         pps = -(-max_seq // page_size)          # ceil: full pages only
         if pps * page_size > self.seq_len:
@@ -86,9 +96,13 @@ class CachedDecoder(Layer, KerasNet):
                 f"the model is declared for; choose max_seq_len <= "
                 f"{self.seq_len // page_size * page_size}")
         dtype = dtype or compute_dtype()
-        kinds = tuple(m.state_kind for m in self.mixers)
-        paged = next((m for m in self.mixers if m.state_kind == PAGES), None)
-        slot = next((m for m in self.mixers if m.state_kind == SLOT), None)
+        # a layer of one mixer names its kind as a word, as it always has
+        kinds = tuple(layer.state_kind if not isinstance(layer, tuple)
+                      else tuple(m.state_kind for m in layer)
+                      for layer in self.mixers)
+        every = [m for layer in self.mixers for m in _side_by_side(layer)]
+        paged = next((m for m in every if m.state_kind == PAGES), None)
+        slot = next((m for m in every if m.state_kind == SLOT), None)
         cfg = KVCacheConfig(
             n_layers=len(self.mixers),
             n_heads=paged.pool_heads if paged else 1,
@@ -111,31 +125,40 @@ class CachedDecoder(Layer, KerasNet):
     def _walk(self, params, h, mix, cache=None):
         """Every layer in order: ``mix(mixer, params, h, state) -> (y,
         state)`` is the layer's mixer in whatever form the caller runs
-        (whole sequence, prefill, decode), ``state`` that layer's own leaves
+        (whole sequence, prefill, decode), ``state`` that mixer's own leaves
         of ``cache`` (None without one); the block around it is the model's
-        (``_block``). Returns ``(h, cache)``, the cache with the structure it
-        came in: no leaf is sliced out of or stored back into a larger array,
-        so with the cache donated every leaf aliases input to output and a
-        mixer's scatter writes where the leaf lies."""
+        (``_block``), which is handed one such function a mixer of the layer.
+        Returns ``(h, cache)``, the cache with the structure it came in: no
+        leaf is sliced out of or stored back into a larger array, so with the
+        cache donated every leaf aliases input to output and a mixer's scatter
+        writes where the leaf lies."""
         new = None if cache is None else {k: list(v) for k, v in cache.items()}
-        kinds = [m.state_kind for m in self.mixers]
-        for i, mixer in enumerate(self.mixers):
-            state = None
-            if cache is not None:
-                # layer i's leaves are the j-th of its kind in the cache
-                # (KVCacheConfig.index_in_kind)
-                j = kinds[:i].count(kinds[i])
-                state = {name: cache[name][j] for name in cache
-                         if (name in ("k", "v")) == (kinds[i] == PAGES)}
+        kept = [[m.state_kind for m in _side_by_side(layer)]
+                for layer in self.mixers]
 
-            def run(p, x):          # what the block calls, once, right now
+        def kind_of(name):
+            return PAGES if name in ("k", "v") else SLOT
+
+        for i, layer in enumerate(self.mixers):
+            # layer i's leaves of a kind are the j-th of that kind in the
+            # cache (KVCacheConfig.index_in_kind)
+            at = {kind: sum(kind in kinds for kinds in kept[:i])
+                  for kind in kept[i]}
+
+            def run(p, x, mixer):   # what the block calls, once, right now
+                state = None if cache is None else {
+                    name: cache[name][at[mixer.state_kind]] for name in cache
+                    if kind_of(name) == mixer.state_kind}
                 with jax.named_scope(mixer.scope):
                     return mix(mixer, p, x, state)
 
-            h, state = self._block(i, params, h, run)
+            runs = tuple(functools.partial(run, mixer=m)
+                         for m in _side_by_side(layer))
+            h, state = self._block(
+                i, params, h, runs if isinstance(layer, tuple) else runs[0])
             if cache is not None:
                 for name, leaf in state.items():
-                    new[name][j] = leaf
+                    new[name][at[kind_of(name)]] = leaf
         if new is not None:
             new = {k: tuple(v) for k, v in new.items()}
         return h, new

@@ -23,7 +23,8 @@ import numpy as np
 from ...ops.attention import (count_route, full_attention,
                               prefer_flash_single_device, sharded_attention)
 from ...ops.kv_cache import (PAGES, decode_attention, decode_attention_multi,
-                             paged_read, paged_write_multi, prefill_write)
+                             for_query_heads, paged_read, paged_write_multi,
+                             prefill_write)
 from ...ops.paged_attention import paged_attention, use_kernel
 from ..activations import get_activation
 from ..module import Layer, as_compute, get_initializer, param_dtype
@@ -58,7 +59,9 @@ class MultiHeadAttention(Layer):
     is ``{"k", "v"}``, this layer's two pools; ``at`` a
     :class:`~analytics_zoo_tpu.ops.kv_cache.StepContext`. A subclass with
     another projection overrides ``qkv_proj`` / ``out_proj`` and inherits
-    routing, write and attend."""
+    routing, write and attend; one whose K and V have fewer heads than Q
+    (grouped KV heads) says how many in ``n_kv_head``, and one whose
+    projection depends on the positions reads them from ``first``."""
 
     state_kind = PAGES
     scope = "zoo_full_layer"
@@ -70,6 +73,7 @@ class MultiHeadAttention(Layer):
         self.hidden_size = hidden_size
         self.n_head = n_head
         self.head_dim = hidden_size // n_head
+        self.n_kv_head = n_head         # heads of K and V: a divisor of n_head
         self.pool_heads = n_head        # the heads axis of this layer's pools
         self.causal = causal
         self.attn_strategy = attn_strategy
@@ -91,10 +95,12 @@ class MultiHeadAttention(Layer):
         # both projections' kernels and biases: qkv_proj / out_proj
         return jax.tree_util.tree_map(lambda _: True, params)
 
-    def qkv_proj(self, params, x):
+    def qkv_proj(self, params, x, first=None):
         """Fused QKV projection → (q, k, v), each (B, T, n_head, head_dim).
         Shared by the batched forward and the KV-cache prefill/decode paths
-        so cached K/V are definitionally the ones ``apply`` would compute."""
+        so cached K/V are definitionally the ones ``apply`` would compute.
+        ``first``: (B,) the position of each row's first token (None: 0),
+        for a projection that depends on it; this one does not."""
         b, t, _ = x.shape
         qkv = x @ jnp.asarray(params["qkv_kernel"], x.dtype) + jnp.asarray(
             params["qkv_bias"], x.dtype)
@@ -109,13 +115,16 @@ class MultiHeadAttention(Layer):
             params["out_bias"], o.dtype)
 
     def _pool_width(self, a):
-        """Pad the heads axis of (B, T, H, D) with zeros to ``pool_heads``."""
-        extra = self.pool_heads - self.n_head
+        """Pad the heads axis of (B, T, H, D) with zeros: K and V to
+        ``pool_heads``, Q to the query heads that attend so many."""
+        extra = (self.pool_heads - self.n_kv_head) * (
+            a.shape[2] // self.n_kv_head)
         return jnp.pad(a, ((0, 0), (0, 0), (0, extra), (0, 0))) if extra else a
 
     def _attend(self, q, k, v, t, training=False):
         """Strategy dispatch shared by ``apply`` and ``prefill``.
         ``training`` says that a backward pass follows the call."""
+        k, v = for_query_heads(k, self.n_head), for_query_heads(v, self.n_head)
         mesh = self._mesh()
         if mesh is not None and self.attn_strategy != "full":
             return sharded_attention(q, k, v, mesh,
@@ -167,7 +176,8 @@ class MultiHeadAttention(Layer):
         fixed throughout (the ``decode-shape-stability`` lint invariant)."""
         x = as_compute(x)
         q_len = x.shape[1]
-        q, k, v = (self._pool_width(a) for a in self.qkv_proj(params, x))
+        q, k, v = (self._pool_width(a)
+                   for a in self.qkv_proj(params, x, at.lengths))
         pos = at.lengths
         k_pages = paged_write_multi(cache["k"], at.table, pos, k,
                                     page_size=at.page_size)

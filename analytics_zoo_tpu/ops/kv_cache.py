@@ -12,11 +12,13 @@ store behind ``prefill()``/``decode_step()`` of ``TransformerLM`` and
 by layer, what a layer keeps between steps (``layer_kinds``): ``"pages"``, K
 and V of every cached token in the paged pools below, addressed through the
 slot's row of the page table; or ``"slot"``, a fixed-size state a slot (a
-linear-attention layer's matrix state and the tail of its short convolution:
-``slot_state`` names the leaves), addressed by the slot's index, of a size
-that does not grow with the sequence. :func:`init_cache` builds both: pools
-for the page layers only, and for each leaf of ``slot_state`` one
-``(n_slots, ...)`` array a slot layer. Every leaf is donated into the
+linear-attention or state-space layer's matrix state and the tail of its
+short convolution: ``slot_state`` names the leaves), addressed by the slot's
+index, of a size that does not grow with the sequence; or both, ``("pages",
+"slot")``, for a layer that runs an attention and a state-space mixer side by
+side. :func:`init_cache` builds both: pools for the layers that keep pages,
+and for each leaf of ``slot_state`` one ``(n_slots, ...)`` array a layer that
+keeps a slot state. Every leaf is donated into the
 dispatches and updated where it lies. A model whose layers all hold pages
 (``TransformerLM``) leaves ``layer_kinds`` empty and gets the pytree it always
 got. What follows is about the pages:
@@ -77,6 +79,12 @@ SCRATCH_PAGE = 0
 PAGES, SLOT = "pages", "slot"
 
 
+def kinds_of(entry) -> Tuple[str, ...]:
+    """The kinds one entry of ``layer_kinds`` names: a layer that keeps one
+    kind names it as a word, a layer that keeps both as a tuple of the two."""
+    return (entry,) if isinstance(entry, str) else tuple(entry)
+
+
 class StepContext(NamedTuple):
     """Where a prefill or a decode step reads and writes the cache.
     ``table``: (B, pages_per_slot) page tables; ``lengths``: (B,) — a
@@ -106,11 +114,12 @@ class KVCacheConfig:
     pages_per_slot: int = 16           # max_seq_len = page_size * pages_per_slot
     n_pages: Optional[int] = None      # pool size incl. scratch (None = full)
     dtype: Any = jnp.float32
-    #: per layer, ``PAGES`` or ``SLOT``; empty = every layer holds pages
-    layer_kinds: Tuple[str, ...] = ()
-    #: the leaves a ``SLOT`` layer keeps for each slot: ``(name, shape,
-    #: dtype)``, the name being the leaf's key in the cache pytree and its
-    #: ``kind`` in the byte accounting
+    #: per layer, ``PAGES``, ``SLOT`` or ``(PAGES, SLOT)`` for a layer that
+    #: keeps both; empty = every layer holds pages
+    layer_kinds: Tuple[Any, ...] = ()
+    #: the leaves a layer that keeps ``SLOT`` state holds for each slot:
+    #: ``(name, shape, dtype)``, the name being the leaf's key in the cache
+    #: pytree and its ``kind`` in the byte accounting
     slot_state: Tuple[Tuple[str, Tuple[int, ...], Any], ...] = ()
 
     def __post_init__(self):
@@ -118,34 +127,46 @@ class KVCacheConfig:
             raise ValueError("page_size and pages_per_slot must be >= 1")
         if self.n_pages is not None and self.n_pages < 2:
             raise ValueError("n_pages must leave room for scratch + 1 page")
+        each = [kinds_of(entry) for entry in self.layer_kinds]
         if self.layer_kinds and (
-                len(self.layer_kinds) != self.n_layers
-                or set(self.layer_kinds) - {PAGES, SLOT}):
-            raise ValueError(f"layer_kinds must name {PAGES!r} or {SLOT!r} "
-                             f"for each of {self.n_layers} layers, got "
-                             f"{self.layer_kinds}")
-        if (SLOT in self.layer_kinds) != bool(self.slot_state):
+                len(each) != self.n_layers
+                or any(not kinds or set(kinds) - {PAGES, SLOT}
+                       or len(set(kinds)) != len(kinds) for kinds in each)):
+            raise ValueError(f"layer_kinds must name {PAGES!r}, {SLOT!r} or "
+                             f"both, once each, for each of {self.n_layers} "
+                             f"layers, got {self.layer_kinds}")
+        if bool(self.n_slot_layers) != bool(self.slot_state):
             raise ValueError("slot_state names the leaves of the layers "
                              "that layer_kinds marks as holding slot state: "
                              "one without the other")
 
     @property
-    def kinds(self) -> Tuple[str, ...]:
+    def kinds(self) -> Tuple[Any, ...]:
         """``layer_kinds``, spelled out for a model that left it empty."""
         return self.layer_kinds or (PAGES,) * self.n_layers
 
+    def _count(self, kind: str, below: Optional[int] = None) -> int:
+        """Layers (the first ``below``) that keep ``kind``."""
+        return sum(kind in kinds_of(entry) for entry in self.kinds[:below])
+
     @property
     def n_page_layers(self) -> int:
-        return self.kinds.count(PAGES)
+        return self._count(PAGES)
 
     @property
     def n_slot_layers(self) -> int:
-        return self.kinds.count(SLOT)
+        return self._count(SLOT)
 
-    def index_in_kind(self, layer: int) -> int:
-        """Which of its kind's leaves is ``layer``'s: ``cache["k"][i]`` of a
-        page layer, ``cache[name][i]`` of a slot layer."""
-        return self.kinds[:layer].count(self.kinds[layer])
+    def index_in_kind(self, layer: int, kind: Optional[str] = None) -> int:
+        """Which of a kind's leaves is ``layer``'s: ``cache["k"][i]`` for the
+        pages it keeps, ``cache[name][i]`` for its slot state. ``kind`` has
+        to be said only for a layer that keeps both."""
+        kept = kinds_of(self.kinds[layer])
+        if kind is None and len(kept) == 1:
+            kind = kept[0]
+        if kind not in kept:
+            raise ValueError(f"layer {layer} keeps {kept}, not {kind!r}")
+        return self._count(kind, layer)
 
     def bytes_by_kind(self) -> Dict[str, int]:
         """Bytes the cache holds on the device: ``pages`` (K and V pools) and
@@ -658,6 +679,15 @@ def prefill_write(pages: jax.Array, table: jax.Array, kv: jax.Array,
     if t % page_size:
         raise ValueError(f"prefill bucket {t} must divide page_size "
                          f"{page_size}")
+    if h < 8:
+        # a pool of fewer heads than the 8 rows of a tile (grouped KV heads:
+        # 4) lies in tiles of its own rows, and round a scatter of page tiles
+        # the TPU's compiler re-lays the WHOLE pool with the page's tokens on
+        # the tiled axis, two copies of it a prefill a layer (134 MB each in
+        # gen-falconh1-chat-steady; PERF.md section 6, PR 50); token rows it
+        # scatters where the pool lies
+        return paged_write_multi(pages, table, jnp.zeros((b,), jnp.int32), kv,
+                                 page_size=page_size)
     n_pages = t // page_size
     tiles = kv.reshape(b, n_pages, page_size, h, d).astype(pages.dtype)
     return pages.at[table[:, :n_pages]].set(tiles)
@@ -683,18 +713,28 @@ def paged_write_multi(pages: jax.Array, table: jax.Array, pos: jax.Array,
     return pages.at[page_ids, offsets].set(new.astype(pages.dtype))
 
 
+def for_query_heads(kv: jax.Array, n_q_heads: int) -> jax.Array:
+    """K or V ``(B, T, H_kv, D)`` as ``n_q_heads = g x H_kv`` query heads
+    read it: query head ``j`` attends KV head ``j // g`` (grouped-query
+    attention). With ``g = 1`` it is ``kv`` itself."""
+    g = n_q_heads // kv.shape[2]
+    return kv if g == 1 else jnp.repeat(kv, g, axis=2)
+
+
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      lengths: jax.Array) -> jax.Array:
     """Single-query attention against a cached prefix, masked to each row's
     true length.
 
-    ``q``: (B, H, D); ``k``/``v``: (B, T_max, H, D); ``lengths``: (B,) —
+    ``q``: (B, H, D); ``k``/``v``: (B, T_max, H_kv, D), ``H`` a multiple of
+    ``H_kv`` (:func:`for_query_heads`); ``lengths``: (B,) —
     number of VALID cache positions (the new token's K/V already written, so
     the query attends to itself). Plain dot attention on purpose: at query
     length 1 flash tiling is pure overhead (see
     ``ops.attention.prefer_flash_single_device``); softmax statistics in f32.
     """
     d = q.shape[-1]
+    k, v = for_query_heads(k, q.shape[1]), for_query_heads(v, q.shape[1])
     scores = jnp.einsum("bhd,bthd->bht", q, k).astype(jnp.float32)
     scores = scores / np.sqrt(d).astype(np.float32)
     t = k.shape[1]
@@ -711,7 +751,8 @@ def decode_attention_multi(q: jax.Array, k: jax.Array, v: jax.Array,
     step (the fused twin is :func:`~analytics_zoo_tpu.ops.paged_attention.
     paged_attention` at q_len>1).
 
-    ``q``: (B, T, H, D); ``k``/``v``: (B, T_max, H, D); ``lengths``: (B,) —
+    ``q``: (B, T, H, D); ``k``/``v``: (B, T_max, H_kv, D), ``H`` a multiple
+    of ``H_kv`` (:func:`for_query_heads`); ``lengths``: (B,) —
     VALID cache positions *including* the T new tokens (their K/V already
     written). Query ``i`` attends to positions ``<= lengths - T + i``:
     causal among the new tokens, full prefix before them. At T=1 this is
@@ -719,6 +760,7 @@ def decode_attention_multi(q: jax.Array, k: jax.Array, v: jax.Array,
     """
     t_new = q.shape[1]
     d = q.shape[-1]
+    k, v = for_query_heads(k, q.shape[2]), for_query_heads(v, q.shape[2])
     scores = jnp.einsum("bqhd,bthd->bhqt", q, k).astype(jnp.float32)
     scores = scores / np.sqrt(d).astype(np.float32)
     t = k.shape[1]
@@ -780,7 +822,8 @@ def sample_tokens(logits: jax.Array, seeds: jax.Array, token_idx: jax.Array,
 __all__ = [
     "KVCacheConfig", "OutOfPages", "PAGES", "PagePool", "PrefixCache",
     "PrefixMatch", "SCRATCH_PAGE", "SLOT", "copy_page", "decode_attention",
-    "decode_attention_multi", "init_cache", "paged_read", "paged_write",
+    "decode_attention_multi", "for_query_heads", "init_cache", "kinds_of",
+    "paged_read", "paged_write",
     "paged_write_multi", "prefill_write", "prefix_block_key",
     "sample_tokens",
 ]

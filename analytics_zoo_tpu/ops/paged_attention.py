@@ -141,11 +141,14 @@ def pages_per_block(pages_per_slot: int, page_size: int, block_h: int,
 def _paged_kernel(table_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
                   k_buf, v_buf, sems, m_scr, l_scr, acc_scr, *, scale: float,
                   page_size: int, q_len: int, block_q: int, block_h: int,
-                  d: int, n_block: int):
+                  d: int, n_block: int, group: int):
     b = pl.program_id(0)
     hb = pl.program_id(1)
     qi = pl.program_id(2)
-    rows = block_h * block_q
+    # a KV head's dots have a row for each query head that attends it
+    # (grouped KV heads: ``group`` of them) and each query of the tile
+    rows_q = group * block_q
+    rows = block_h * rows_q
     bk = n_block * page_size
     pps = table_ref.shape[1]
 
@@ -189,11 +192,17 @@ def _paged_kernel(table_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     # operands stay in storage dtype (bf16 MXU full-rate), statistics
     # accumulate in f32 — same discipline as the flash kernel
-    q = q_ref[0].transpose(1, 0, 2)                 # (block_h, block_q, D)
     # query i's last visible position: the whole prefix AND itself/earlier
     # drafts, never later drafts
-    bound = length - q_len + qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_h, block_q, bk), 1)
+    if group == 1:
+        q = q_ref[0].transpose(1, 0, 2)             # (block_h, block_q, D)
+        bound = length - q_len + qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_h, block_q, bk), 1)
+    else:
+        # laid out by the caller: row r of a KV head is query r // group
+        q = q_ref[0]                                # (block_h, rows_q, D)
+        bound = length - q_len + qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_h, rows_q, bk), 1) // group
 
     def block(i, carry):
         buf = i % 2
@@ -214,10 +223,10 @@ def _paged_kernel(table_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
         s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32) * scale
         seen = i * bk + jax.lax.broadcasted_iota(
-            jnp.int32, (block_h, block_q, bk), 2) <= bound
+            jnp.int32, (block_h, rows_q, bk), 2) <= bound
         s = jnp.where(seen, s, NEG_INF)
-        m_prev = m_scr[:rows, 0:1].reshape(block_h, block_q, 1)
-        l_prev = l_scr[:rows, 0:1].reshape(block_h, block_q, 1)
+        m_prev = m_scr[:rows, 0:1].reshape(block_h, rows_q, 1)
+        l_prev = l_scr[:rows, 0:1].reshape(block_h, rows_q, 1)
         m_new = jnp.maximum(m_prev, s.max(axis=2, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
         # exact zeros where masked, also in a row that has seen nothing yet
@@ -239,8 +248,10 @@ def _paged_kernel(table_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     l = l_scr[:rows, 0:1]
     safe_l = jnp.where(l == 0, 1.0, l)      # rows that saw nothing emit zeros
-    o = (acc_scr[:rows, :d] / safe_l).reshape(block_h, block_q, d)
-    o_ref[0] = o.transpose(1, 0, 2).astype(o_ref.dtype)
+    o = (acc_scr[:rows, :d] / safe_l).reshape(block_h, rows_q, d)
+    if group == 1:
+        o = o.transpose(1, 0, 2)
+    o_ref[0] = o.astype(o_ref.dtype)
 
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -250,14 +261,28 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Fused page-gather attention.
 
-    ``q``: (B, q_len, H, D); ``k_pages``/``v_pages``: (P, page_size, H, D)
-    — ONE layer's pool; ``table``: (B, pages_per_slot) int32; ``lengths``:
+    ``q``: (B, q_len, H, D); ``k_pages``/``v_pages``: (P, page_size, H_kv,
+    D) — ONE layer's pool, ``H = g x H_kv`` (grouped KV heads: query head
+    ``j`` attends KV head ``j // g``; ``g = 1`` is plain multi-head
+    attention); ``table``: (B, pages_per_slot) int32; ``lengths``:
     (B,) int32 valid positions INCLUDING the q_len new tokens. Returns
-    (B, q_len, H, D). A ``block_h`` that does not divide H, or a ``q_len``
-    no query tile fits (:func:`query_block`), is a ``ValueError`` naming
-    the shape; the gather + masked-dot path of :mod:`ops.kv_cache` is the
-    route :func:`use_kernel` selects off TPU and the parity reference."""
-    b, q_len, h, d = q.shape
+    (B, q_len, H, D). ``block_h`` counts KV heads; one that does not divide
+    them, or a ``q_len`` no query tile fits (:func:`query_block`), is a
+    ``ValueError`` naming the shape; the gather + masked-dot path of
+    :mod:`ops.kv_cache` is the route :func:`use_kernel` selects off TPU and
+    the parity reference.
+
+    With ``g > 1`` the ``g`` query heads of a KV head are rows of ITS dots,
+    beside the tile's queries (a decode step's QK^T has ``g`` rows a KV head
+    where plain heads have one), so a page's K and V are fetched and held
+    once for all of them: the pools and the kernel's buffers have the KV
+    heads' width, and ``q`` is handed over as ``(B, H_kv, q_len * g, D)``."""
+    b, q_len, h_q, d = q.shape
+    h = k_pages.shape[2]
+    group = h_q // h
+    if h_q != group * h:
+        raise ValueError(f"paged_attention: the {h_q} heads of q{q.shape} "
+                         f"are no multiple of the pool's {h}")
     pps = table.shape[1]
     if interpret is None:
         interpret = interpret_default()
@@ -270,18 +295,24 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         raise ValueError(f"paged_attention: block_h={block_h} does not "
                          f"divide the {h} heads of q{q.shape}")
     if block_q is None:
-        block_q = query_block(q_len, block_h, d, q.dtype)
+        block_q = query_block(q_len, block_h * group, d, q.dtype)
     if q_len % block_q:
         raise ValueError(f"paged_attention: block_q={block_q} does not "
                          f"divide q_len of q{q.shape}")
     n_block = pages_per_block(pps, page_size, block_h, d, k_pages.dtype)
     scale = 1.0 / float(np.sqrt(d))
-    rows = max(8, block_h * block_q)
+    rows = max(8, block_h * group * block_q)
     kern = functools.partial(_paged_kernel, scale=scale, page_size=page_size,
                              q_len=q_len, block_q=block_q, block_h=block_h,
-                             d=d, n_block=n_block)
-    q_spec = pl.BlockSpec((1, block_q, block_h, d),
-                          lambda b, hb, qi, tbl, ln: (b, qi, hb, 0))
+                             d=d, n_block=n_block, group=group)
+    if group == 1:
+        q_spec = pl.BlockSpec((1, block_q, block_h, d),
+                              lambda b, hb, qi, tbl, ln: (b, qi, hb, 0))
+    else:
+        q = q.reshape(b, q_len, h, group, d).transpose(0, 2, 1, 3, 4).reshape(
+            b, h, q_len * group, d)
+        q_spec = pl.BlockSpec((1, block_h, block_q * group, d),
+                              lambda b, hb, qi, tbl, ln: (b, hb, qi, 0))
     kv_buf = pltpu.VMEM((2, n_block, page_size, block_h, d), k_pages.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -305,9 +336,9 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             pltpu.VMEM((rows, max(d, 128)), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    o = pl.pallas_call(
         kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, q_len, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         # every program owns its output block and its copies end with it
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
@@ -315,6 +346,10 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         name="zoo_paged_attention",
     )(jnp.asarray(table, jnp.int32), jnp.asarray(lengths, jnp.int32),
       q, k_pages, v_pages)
+    if group == 1:
+        return o
+    return o.reshape(b, h, q_len, group, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, q_len, h_q, d)
 
 
 def synthetic_paged_case(n_slots: int, pages_per_slot: int, page_size: int,

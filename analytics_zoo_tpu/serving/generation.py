@@ -256,8 +256,9 @@ _tm.collector("zoo_gen_param_bytes",
 _tm.collector("zoo_gen_cache_bytes",
               "Bytes of decode cache live continuous batchers hold on the "
               "device, by kind: pages (the K and V pools), and for a model "
-              "with per-slot state recurrent (the linear-attention layers' "
-              "matrix states) and conv (their convolution tails)",
+              "with per-slot state one kind a leaf: recurrent (the "
+              "linear-attention layers' matrix states) or ssm (the "
+              "state-space layers'), and conv (their convolution tails)",
               lambda: [((kind,), float(n)) for kind, n in sorted(sum(
                   (collections.Counter(g.cfg.bytes_by_kind())
                    for g in list(_LIVE_GENERATORS)),

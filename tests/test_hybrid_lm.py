@@ -22,6 +22,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from analytics_zoo_tpu.analysis import GraphLintError  # noqa: E402
 from analytics_zoo_tpu.analysis.rules.decode import lint_decode_stability  # noqa: E402
 from analytics_zoo_tpu.common import telemetry  # noqa: E402
+from analytics_zoo_tpu.models.falcon_h1 import FalconH1LM  # noqa: E402
 from analytics_zoo_tpu.models.hybrid_lm import HybridLM  # noqa: E402
 from analytics_zoo_tpu.models.transformer import TransformerLM  # noqa: E402
 from analytics_zoo_tpu.nn.module import precision_policy  # noqa: E402
@@ -315,15 +316,21 @@ def test_a_stream_ended_by_eos_leaves_nothing_to_the_slots_next_stream(
         reused.close()
 
 
+@pytest.mark.parametrize("which", ["hybrid", "falcon_h1"])
 @pytest.mark.parametrize("option,words", [
     (dict(prefix_cache_pages=4), "prefix reuse needs a snapshot"),
     (dict(spec_k=3), "rejected draft would have to roll that state back"),
-    (dict(prefill_chunk_tokens=8), "HybridLM has none"),
+    (dict(prefill_chunk_tokens=8), "{model} has none"),
 ], ids=["prefix_cache", "speculation", "chunked_prefill"])
 def test_what_assumes_the_cache_is_pages_is_refused_in_words(
-        model_and_params, option, words):
-    with pytest.raises(ValueError, match=words):
-        _batcher(model_and_params, autostart=False, **option)
+        model_and_params, option, words, which):
+    """The batcher decides by ``cfg.slot_state`` alone: a model whose every
+    layer keeps pages beside its slot state is refused what a model with one
+    linear layer is, in the same words."""
+    served = model_and_params if which == "hybrid" else _falcon_and_params()
+    with pytest.raises(ValueError, match=words.format(
+            model=type(served[0]).__name__)):
+        _batcher(served, autostart=False, **option)
 
 
 # ------------------------------- pages alone: the shared entry points serve
@@ -444,12 +451,29 @@ def test_a_model_of_pages_alone_ignores_the_slots_it_is_told(np_rng):
         assert (np.asarray(a) == np.asarray(b)).all()
 
 
-@pytest.mark.parametrize("which", ["transformer", "hybrid"])
+def _falcon(**kw):
+    """Falcon-H1's layer at a tiny size: every layer keeps pages AND a slot
+    state."""
+    return FalconH1LM(**dict(dict(
+        vocab=VOCAB, hidden_size=HIDDEN, intermediate_size=INNER, n_layer=2,
+        n_head=4, n_kv_head=2, head_dim=8, mamba_n_heads=4, mamba_d_head=8,
+        mamba_d_state=8, mamba_n_groups=2, mamba_chunk_size=8, seq_len=128,
+        key_multiplier=0.5, ssm_multipliers=(0.5, 0.25, 0.5, 0.5, 0.5),
+        mlp_multipliers=(0.5, 0.25)), **kw))
+
+
+def _falcon_and_params():
+    m = _falcon()
+    return m, m.build(jax.random.PRNGKey(3))[0]
+
+
+@pytest.mark.parametrize("which", ["transformer", "hybrid", "falcon_h1"])
 def test_one_prefill_executable_a_bucket_whatever_the_model(
         which, model_and_params, np_rng):
-    """One jitted prefill serves both kinds of model, told the slot or not
+    """One jitted prefill serves every kind of model, told the slot or not
     (the benchmark's logit probe passes none): an executable a bucket."""
-    b = _batcher(_gpt() if which == "transformer" else model_and_params)
+    b = _batcher({"transformer": _gpt, "falcon_h1": _falcon_and_params,
+                  "hybrid": lambda: model_and_params}[which]())
     try:
         for n in (5, 11, 7, 20):                    # buckets 8, 16 and 32
             b.generate(np_rng.integers(1, VOCAB, size=n).astype(np.int32),
@@ -533,6 +557,35 @@ logits_kernel (16, 61) float32 0.697175
 token_embeddings (61, 16) float32 -0.450775
 """),
 }
+
+
+GOLDEN["falcon_h1"] = (
+    lambda: FalconH1LM(vocab=61, hidden_size=16, intermediate_size=24,
+                       n_layer=1, n_head=4, n_kv_head=2, head_dim=4,
+                       mamba_n_heads=2, mamba_d_head=8, mamba_d_state=4,
+                       mamba_n_groups=2, seq_len=64, key_multiplier=0.5,
+                       ssm_in_multiplier=0.25,
+                       ssm_multipliers=(0.5, 0.25, 0.5, 2.0, 0.5),
+                       mlp_multipliers=(0.5, 0.25)), """
+final_norm (16,) float32 0.715328
+layer0/attn/out_kernel (16, 16) float32 3.780363
+layer0/attn/qkv_kernel (16, 32) float32 0.234265
+layer0/input_norm (16,) float32 0.715328
+layer0/mlp/down_kernel (24, 16) float32 1.852206
+layer0/mlp/gate_kernel (16, 24) float32 9.368815
+layer0/mlp/up_kernel (16, 24) float32 0.472813
+layer0/mlp_norm (16,) float32 0.715328
+layer0/ssm/A_log (2,) float32 2.557086
+layer0/ssm/D (2,) float32 1.540302
+layer0/ssm/conv_bias (32,) float32 0.000000
+layer0/ssm/conv_kernel (32, 4) float32 -0.761026
+layer0/ssm/dt_bias (2,) float32 -6.236320
+layer0/ssm/in_kernel (16, 50) float32 -15.658224
+layer0/ssm/norm_scale (16,) float32 0.715328
+layer0/ssm/out_kernel (16, 16) float32 -2.022944
+logits_kernel (16, 61) float32 0.697175
+token_embeddings (61, 16) float32 -0.450775
+""")
 
 
 @pytest.mark.parametrize("which", sorted(GOLDEN))
@@ -653,12 +706,63 @@ def test_a_model_of_pages_alone_gets_the_cache_it_always_got():
         int(a.nbytes) for a in jax.tree_util.tree_leaves(cache))}
 
 
+def test_a_layer_may_keep_both_kinds():
+    """Pages alone, then both, then a slot state alone, then both: each kind
+    counts the layers that keep it, and a layer of both has a place in
+    each."""
+    both = (PAGES, SLOT)
+    leaves = (("ssm", (2, 4, 8), jnp.float32), ("conv", (3, 24), jnp.bfloat16))
+    cfg = KVCacheConfig(n_layers=4, n_heads=2, head_dim=8, n_slots=3,
+                        page_size=4, pages_per_slot=4, n_pages=10,
+                        dtype=jnp.bfloat16, layer_kinds=(PAGES, both, SLOT,
+                                                         both),
+                        slot_state=leaves)
+    assert (cfg.n_page_layers, cfg.n_slot_layers) == (3, 3)
+    assert [cfg.index_in_kind(i, PAGES) for i in (0, 1, 3)] == [0, 1, 2]
+    assert [cfg.index_in_kind(i, SLOT) for i in (1, 2, 3)] == [0, 1, 2]
+    assert cfg.index_in_kind(0) == 0 and cfg.index_in_kind(2) == 1
+    for layer, kind in ((1, None), (0, SLOT), (2, PAGES)):
+        with pytest.raises(ValueError, match="keeps"):
+            cfg.index_in_kind(layer, kind)
+    cache = init_cache(cfg)
+    assert {name: len(v) for name, v in cache.items()} == {
+        "k": 3, "v": 3, "ssm": 3, "conv": 3}
+    assert cache["k"][0].shape == (10, 4, 2, 8)
+    assert cache["ssm"][0].shape == (3, 2, 4, 8)
+    assert cache["conv"][0].dtype == jnp.bfloat16
+    held = {name: sum(int(a.nbytes) for a in v) for name, v in cache.items()}
+    assert cfg.bytes_by_kind() == {
+        "pages": held["k"] + held["v"], "ssm": held["ssm"],
+        "conv": held["conv"]}
+
+
+def test_falcon_h1s_cache_is_both_kinds_in_every_layer():
+    m = _falcon()
+    cfg, cache = m.init_kv_cache(3, page_size=4, max_seq_len=64, n_pages=20)
+    assert cfg.kinds == ((PAGES, SLOT),) * 2
+    assert cfg.n_heads == 2 and cfg.head_dim == 8      # the KV heads' pools
+    assert cache["k"][1].shape == (20, 4, 2, 8)
+    assert cache["ssm"][1].shape == (3, 4, 8, 8)
+    assert cache["ssm"][1].dtype == jnp.float32
+    assert cache["conv"][1].shape == (3, 3, 4 * 8 + 2 * 2 * 8)
+    held = {name: sum(int(a.nbytes) for a in v) for name, v in cache.items()}
+    assert cfg.bytes_by_kind() == {
+        "pages": held["k"] + held["v"], "ssm": held["ssm"],
+        "conv": held["conv"]}
+
+
 @pytest.mark.parametrize("bad", [
     dict(layer_kinds=(PAGES,)),                          # one of two layers
     dict(layer_kinds=(PAGES, "window")),                 # no such kind
     dict(layer_kinds=(PAGES, SLOT)),                     # no leaves named
     dict(slot_state=(("recurrent", (2, 2), jnp.float32),)),     # no layer
-], ids=["count", "kind", "no_leaves", "no_layer"])
+    dict(layer_kinds=(PAGES, (PAGES, SLOT))),            # no leaves named
+    dict(layer_kinds=(PAGES, (SLOT, SLOT)),              # a kind twice
+         slot_state=(("recurrent", (2, 2), jnp.float32),)),
+    dict(layer_kinds=(PAGES, ())),                       # a layer of nothing
+    dict(layer_kinds=(PAGES, (PAGES, "window"))),        # no such kind
+], ids=["count", "kind", "no_leaves", "no_layer", "both_no_leaves",
+        "kind_twice", "no_kind", "both_unknown_kind"])
 def test_a_description_that_does_not_add_up_is_refused(bad):
     with pytest.raises(ValueError):
         KVCacheConfig(n_layers=2, n_heads=2, head_dim=4, n_slots=2, **bad)

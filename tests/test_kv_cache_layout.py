@@ -16,6 +16,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from analytics_zoo_tpu.models.falcon_h1 import FalconH1LM
 from analytics_zoo_tpu.models.hybrid_lm import HybridLM
 from analytics_zoo_tpu.models.transformer import TransformerLM
 from analytics_zoo_tpu.ops.kv_cache import (KVCacheConfig, copy_page,
@@ -36,6 +37,12 @@ MODELS = {
         layer_types=["linear_attention", "full_attention"] * 2
         + ["linear_attention"], linear_num_heads=2, linear_key_head_dim=8,
         linear_value_head_dim=16, seq_len=64),
+    # both kinds in every layer: each layer's two mixers write their own
+    # leaves in one visit
+    "falcon_h1": lambda: FalconH1LM(
+        vocab=64, hidden_size=32, intermediate_size=48, n_layer=LAYERS,
+        n_head=4, n_kv_head=2, head_dim=8, mamba_n_heads=4, mamba_d_head=8,
+        mamba_d_state=8, mamba_n_groups=2, mamba_chunk_size=8, seq_len=64),
 }
 
 
@@ -158,7 +165,8 @@ def test_the_check_sees_the_copies_of_a_stacked_pool():
     ("transformer", "prefill"), ("transformer", "prefill_chunk"),
     ("transformer", "copy_page"),
     # what a model with per-slot state is served by (the rest is refused)
-    ("hybrid", "decode_step"), ("hybrid", "prefill")])
+    ("hybrid", "decode_step"), ("hybrid", "prefill"),
+    ("falcon_h1", "decode_step"), ("falcon_h1", "prefill")])
 def test_donated_cache_is_written_where_it_lies(rigs, which, program):
     m, params, cfg, cache = rigs(which)
     fn, rest = _programs(m, cfg)[program]

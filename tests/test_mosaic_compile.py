@@ -190,6 +190,108 @@ def test_paged_attention_at_the_hybrid_cells_pool(v5e):
     assert "zoo_paged_attention" in text
 
 
+# Falcon-H1's serving cell (gen-falconh1-chat-steady): 64 slots, 32
+# state-space heads of 128 with a state of 256 (a slot's state a layer is
+# 32 x 256 x 128 float32, aliased in to out, 8 heads a program), prompts of
+# 32 to 1,024 tokens in chunks of 128; and the paged kernel at the 4 KV heads
+# its pools hold, the 5 query heads of each as rows of that head's dots
+def test_ssd_decode_at_the_serving_shape(v5e):
+    from analytics_zoo_tpu.ops.ssd import ssd_decode
+
+    slots, h, p, n, g = 64, 32, 128, 256, 2
+    compiled = v5e(
+        lambda *a: ssd_decode(*a, interpret=False),
+        ((slots, h, n, p), F32), ((slots, h, p), F32), ((slots, h), F32),
+        ((h,), F32), ((slots, g, n), F32), ((slots, g, n), F32),
+        ((slots,), jnp.bool_), donate_argnums=(0,))
+    assert "zoo_ssd_decode" in compiled.as_text()
+    # the state is updated where it lies: no second copy of it
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == slots * h * n * p * 4
+
+
+@pytest.mark.parametrize("tokens", [32, 1024])
+def test_ssd_chunk_scan_at_the_serving_buckets(v5e, tokens):
+    from analytics_zoo_tpu.ops.ssd import ssd_chunked
+
+    h, p, n, g = 32, 128, 256, 2
+    text = v5e(lambda *a: ssd_chunked(*a, chunk=128, kernel=True,
+                                      interpret=False),
+               ((1, tokens, h, p), F32), ((1, tokens, h), F32), ((h,), F32),
+               ((1, tokens, g, n), F32), ((1, tokens, g, n), F32)).as_text()
+    assert "zoo_ssd_chunk_fwd" in text
+
+
+@pytest.mark.parametrize("q_len", [1, 4])
+def test_paged_attention_at_grouped_kv_heads(v5e, q_len):
+    pool = ((8193, 16, 4, 128), BF16)
+    text = v5e(lambda q, k, v, tb, ln: paged_attention(
+        q, k, v, tb, ln, page_size=16, interpret=False),
+        ((64, q_len, 20, 128), BF16), pool, pool, ((64, 128), I32),
+        ((64,), I32)).as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "zoo_paged_attention" in text
+
+
+def test_decode_step_of_the_falcon_h1_cell_fits_the_chip(v5e, monkeypatch):
+    """The whole decode step of ``gen-falconh1-chat-steady`` at the widths
+    of its configuration's file: 10.51 GB of bfloat16 parameters and 3.23 GB
+    of cache among the arguments (pages 12,288 B a token: 1.61 GB; state
+    1.61 GB; tails 12 MB), every leaf of the cache aliased, temporaries of
+    megabytes, both mixers' kernels in every layer."""
+    import json
+    import os
+
+    from analytics_zoo_tpu.models.falcon_h1 import FalconH1LM
+    from analytics_zoo_tpu.nn.module import precision_policy
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs",
+                           "falcon-h1-34b.json")) as f:
+        config = json.load(f)
+    kwargs = {k: config[v[1:]] if isinstance(v, str) and v.startswith("@")
+              else v for k, v in config["build"]["kwargs"].items()}
+    sizes = config["serving"]["ServingConfig"]
+    slots, page, pages = (sizes["gen_slots"], sizes["gen_page_size"],
+                          sizes["gen_pages"])
+    pps, layers = sizes["gen_max_seq_len"] // page, kwargs["n_layer"]
+    m = FalconH1LM(**kwargs)
+    with precision_policy(param_dtype="bfloat16"):
+        given = jax.eval_shape(lambda: m.build(jax.random.PRNGKey(0))[0])
+    leaves, treedef = jax.tree_util.tree_flatten(given)
+    served = [(leaf.shape, leaf.dtype) for leaf in leaves]
+    n = len(served)
+    cache = ([((pages, page, 4, 128), BF16)] * (2 * layers)
+             + [((slots, 32, 256, 128), F32)] * layers
+             + [((slots, 3, 5120), BF16)] * layers)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def step(*flat):
+        kv = flat[n:n + 4 * layers]
+        with precision_policy(compute_dtype="bfloat16"):
+            return m.decode_step(
+                treedef.unflatten(flat[:n]),
+                {"k": kv[:layers], "v": kv[layers:2 * layers],
+                 "ssm": kv[2 * layers:3 * layers], "conv": kv[3 * layers:]},
+                *flat[n + 4 * layers:], page_size=page)
+
+    compiled = v5e(
+        step, *served, *cache, ((slots,), I32), ((slots,), I32),
+        ((slots, pps), I32), ((slots,), jnp.uint32), ((slots,), jnp.uint32),
+        ((slots,), F32), donate_argnums=tuple(range(n, n + 4 * layers)))
+    mem = compiled.memory_analysis()
+    page_bytes = 2 * layers * pages * page * 4 * 128 * 2
+    assert page_bytes == pages * page * 12288
+    cache_bytes = page_bytes + layers * slots * (32 * 256 * 128 * 4
+                                                 + 3 * 5120 * 2)
+    assert 10.50e9 < mem.argument_size_in_bytes - cache_bytes < 10.52e9
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < 64e6
+    text = compiled.as_text()
+    assert text.count("zoo_paged_attention") >= layers
+    assert text.count("zoo_ssd_decode") >= layers
+
+
 @pytest.mark.parametrize("m", [1, 16, 512])
 def test_fused_int8_matmul_at_the_mlp_shapes(v5e, m):
     v5e(lambda x, q, s: int8_fused.int8_matmul_fused(
